@@ -28,62 +28,3 @@ Package layout:
 
 __version__ = "0.1.0"
 
-
-def _install_jax_compat() -> None:
-    """Back-fill the ambient-mesh API (``jax.sharding.set_mesh`` /
-    ``get_abstract_mesh`` / ``get_mesh``) on jax builds that predate it
-    (the pinned 0.4.x). Everything here — trainers, bench, tools, tests
-    — enters the mesh via ``with jax.sharding.set_mesh(mesh):``; on old
-    jax the equivalent ambient-mesh mechanism is the Mesh context
-    manager itself (``thread_resources.env.physical_mesh``), so the
-    setter shim enters that context and the getter shims read it back —
-    consumers (``auto_axes``, shard_map, ``_ambient_mesh``) all accept
-    the concrete Mesh the old API tracks. No-ops on jax that already
-    has the symbols."""
-    import contextlib
-
-    import jax
-
-    if not hasattr(jax.sharding, "set_mesh"):
-        @contextlib.contextmanager
-        def set_mesh(mesh):
-            with mesh:
-                yield mesh
-
-        jax.sharding.set_mesh = set_mesh
-
-    if not (hasattr(jax.sharding, "get_abstract_mesh")
-            and hasattr(jax.sharding, "get_mesh")):
-        from jax._src.mesh import thread_resources
-
-        def get_ambient_mesh():
-            return thread_resources.env.physical_mesh
-
-        if not hasattr(jax.sharding, "get_abstract_mesh"):
-            jax.sharding.get_abstract_mesh = get_ambient_mesh
-        if not hasattr(jax.sharding, "get_mesh"):
-            jax.sharding.get_mesh = get_ambient_mesh
-
-    if not hasattr(jax, "shard_map"):
-        from jax._src.mesh import thread_resources
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                      axis_names=None, check_vma=True):
-            """New-API adapter over the experimental shard_map:
-            ``axis_names`` lists the MANUAL axes (everything else stays
-            auto -> old ``auto=`` complement), ``check_vma`` maps to
-            ``check_rep``, and an omitted mesh means the ambient one."""
-            if mesh is None:
-                mesh = thread_resources.env.physical_mesh
-            auto = frozenset()
-            if axis_names is not None:
-                auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            return _shard_map(f, mesh, in_specs=in_specs,
-                              out_specs=out_specs,
-                              check_rep=bool(check_vma), auto=auto)
-
-        jax.shard_map = shard_map
-
-
-_install_jax_compat()

@@ -28,6 +28,7 @@ from dla_tpu.generation.engine import GenerationConfig, GenerationEngine
 from dla_tpu.training.config import load_config
 from dla_tpu.training.model_io import load_causal_lm
 from dla_tpu.training.utils import seed_everything
+from dla_tpu.utils.compile_cache import enable_compile_cache
 from dla_tpu.utils.logging import log_rank_zero
 
 REFUSAL_KEYWORDS = ("sorry", "cannot", "not able", "as an ai")
@@ -174,6 +175,7 @@ def generate_batched(engine: GenerationEngine, params, prompts: List[str],
 def main(argv=None) -> None:
     args = parse_args(argv)
     config = load_config(args.config)
+    enable_compile_cache()
     rng = seed_everything(int(config.get("seed", 0)))
     gen_cfg = config.get("generation", {})
     gen = GenerationConfig(

@@ -90,21 +90,9 @@ def _constrain(x, spec):
 
 
 def _ambient_mesh():
-    """The ambient mesh, or None when absent/empty/unavailable.
-
-    Prefers ``jax.sharding.get_abstract_mesh`` (jax >= 0.5); on older
-    jax — where that symbol is a deprecation stub or missing — the
-    ``with mesh:`` context lives in ``thread_resources.env.physical_mesh``
-    (a concrete Mesh, which every consumer here accepts: ``auto_axes``
-    treats it as all-auto and shard_map takes it directly)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except (ValueError, RuntimeError, AttributeError):
-        try:
-            from jax._src.mesh import thread_resources
-            mesh = thread_resources.env.physical_mesh
-        except (ImportError, AttributeError, ValueError, RuntimeError):
-            return None
+    """The ambient mesh (``jax.sharding.set_mesh``), or None when none
+    is set."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh
@@ -832,11 +820,16 @@ class Transformer:
         mesh = _flash_mesh()
         if mesh is None:
             return flash_causal_attention(q, k, v, segs=segs, **kw)
-        # wrap over the batch/head axes that are still GSPMD-auto; under
-        # the pipeline's stage shard_map this nests partial-manual with
-        # `stage` untouched (already manual in the enclosing scope)
+        # shard over the batch/head axes that are still GSPMD-auto, and
+        # make EVERY remaining auto axis manual: Mosaic refuses a kernel
+        # under a partly-manual mesh ("cannot be automatically
+        # partitioned"), even when the axes left auto have size 1. Axes
+        # the specs do not name see replicated operands. Under the
+        # pipeline's stage shard_map `stage` is already manual in the
+        # enclosing scope and stays untouched.
+        manual_axes = auto_axes(mesh)
         wrap_axes = {a for a in ("data", "fsdp", "model")
-                     if a in auto_axes(mesh)}
+                     if a in manual_axes}
         model_size = mesh.shape.get("model", 1) if "model" in wrap_axes \
             else 1
         batch_shards = 1
@@ -870,14 +863,14 @@ class Transformer:
             fn = jax.shard_map(
                 lambda a, b, c: flash_causal_attention(a, b, c, **kw),
                 mesh=mesh, in_specs=(bspec, bspec, bspec),
-                out_specs=bspec, axis_names=wrap_axes, check_vma=False)
+                out_specs=bspec, axis_names=manual_axes, check_vma=False)
             return fn(q, k, v)
         sspec = P(batch_axes or None, None, None)
         fn = jax.shard_map(
             lambda a, b, c, s: flash_causal_attention(a, b, c, segs=s, **kw),
             mesh=mesh,
             in_specs=(bspec, bspec, bspec, (sspec, sspec)),
-            out_specs=bspec, axis_names=wrap_axes, check_vma=False)
+            out_specs=bspec, axis_names=manual_axes, check_vma=False)
         return fn(q, k, v, segs)
 
     def _maybe_remat(self, fn):

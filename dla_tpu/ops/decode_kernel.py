@@ -38,10 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 DEFAULT_BLOCK_S = 512
 GP = 8  # query-group sublane padding
@@ -67,15 +63,17 @@ def _body(lb_ref, q_ref, kn_ref, vn_ref, bias_ref, k_ref, v_ref, ks_ref,
         for kh in range(kheads):
             rows = slice(kh * GP, (kh + 1) * GP)
             dcol = slice(kh * dh, (kh + 1) * dh)
-            q = q_ref[0, rows, :]                           # [Gp, D]
-            kn = kn_ref[0, dcol][None, :]                   # [1, D]
-            s_self = cap(jax.lax.dot_general(
-                q, kn, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale)  # [Gp, 1]
+            q = q_ref[0, rows, :].astype(jnp.float32)       # [Gp, D]
+            kn = kn_ref[0, :, dcol].astype(jnp.float32)     # [1, D]
+            # a [Gp, D] x [1, D]^T dot is a one-column matmul Mosaic
+            # cannot lower; widen to fp32 first (bf16 products are
+            # exact in fp32) and reduce along the lanes instead
+            s_self = cap(jnp.sum(q * kn, axis=1, keepdims=True)
+                         * scale)                           # [Gp, 1]
             m_ref[rows, :] = jnp.broadcast_to(s_self, (GP, 128))
             l_ref[rows, :] = jnp.ones((GP, 128), jnp.float32)
             acc_ref[rows, :] = jnp.broadcast_to(
-                vn_ref[0, dcol][None, :].astype(jnp.float32), (GP, dh))
+                vn_ref[0, :, dcol].astype(jnp.float32), (GP, dh))
 
     # blocks past the cache fill level are SKIPPED outright: their index
     # maps clamp to the last active block (no DMA on a revisited block)
@@ -91,7 +89,7 @@ def _body(lb_ref, q_ref, kn_ref, vn_ref, bias_ref, k_ref, v_ref, ks_ref,
         bound = jnp.minimum(jnp.int32(s), lb_ref[1])
         col = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         colmask = col < bound                               # [1, bs]
-        bias = jnp.where(colmask, bias_ref[0, :][None, :], 0.0)
+        bias = jnp.where(colmask, bias_ref[0], 0.0)        # [1, bs]
         vrow = si * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
         vmask = vrow < bound                                # [bs, 1]
 
@@ -154,15 +152,20 @@ def _call(q3, kn2, vn2, bias, kc, vc, ks, vs, kv_fill, scale, block_s,
 
     in_specs = [
         pl.BlockSpec((1, khgp, dh), lambda bi, si, lb: (bi, 0, 0)),
-        pl.BlockSpec((1, khd), lambda bi, si, lb: (bi, 0)),
-        pl.BlockSpec((1, khd), lambda bi, si, lb: (bi, 0)),
-        pl.BlockSpec((1, bs), lambda bi, si, lb: (bi, clamp(si, lb))),
+        # per-row operands ride a unit middle dim: a (1, n) block over a
+        # [B, n] array breaks Mosaic's tiling rule (second-to-last block
+        # dim must be 8-divisible or the array's own), (1, 1, n) over
+        # [B, 1, n] satisfies it
+        pl.BlockSpec((1, 1, khd), lambda bi, si, lb: (bi, 0, 0)),
+        pl.BlockSpec((1, 1, khd), lambda bi, si, lb: (bi, 0, 0)),
+        pl.BlockSpec((1, 1, bs),
+                     lambda bi, si, lb: (bi, 0, clamp(si, lb))),
         pl.BlockSpec((1, bs, khd),
                      lambda bi, si, lb: (bi, clamp(si, lb), 0)),
         pl.BlockSpec((1, bs, khd),
                      lambda bi, si, lb: (bi, clamp(si, lb), 0)),
     ]
-    args = [q3, kn2, vn2, bias, kc, vc]
+    args = [q3, kn2[:, None, :], vn2[:, None, :], bias[:, None, :], kc, vc]
     quant = ks is not None
     if quant:
         in_specs += [
@@ -202,7 +205,7 @@ def _call(q3, kn2, vn2, bias, kc, vc, ks, vs, kv_fill, scale, block_s,
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, khgp, dh), jnp.float32),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(last_blk, *args)

@@ -45,22 +45,25 @@ def _kernel(x_ref, w_ref, s_ref, o_ref):
     o_ref[...] = (acc * s_ref[...]).astype(o_ref.dtype)
 
 
-# VMEM block budget: x block + double-buffered w blocks + out blocks
-# must fit alongside Mosaic's own overhead in ~16 MB of VMEM
+# VMEM block budget: the pipeline's x, w and out buffers must fit
+# under Mosaic's 16 MiB scoped-VMEM default with room for its own stack
 _VMEM_BUDGET = 14 * 1024 * 1024
 
 
 def _pick_blocks(m: int, k: int, n: int, block_m: int, block_n: int):
-    """Shrink (bm, bn) until the working set fits VMEM. The x block is
-    revisited across the N grid (no double buffer); w/out blocks change
-    every step (double-buffered). bn shrinks first — smaller bn only
-    adds grid steps; smaller bm re-reads the WEIGHTS once per M block,
-    which is the traffic this kernel exists to minimize."""
+    """Shrink (bm, bn) until the working set fits VMEM. Every blocked
+    operand is double-buffered by the pipeline — x too, whenever the M
+    grid has more than one block (Mosaic's scoped allocation for
+    bm=256, K=14336, bn=128 is 2*7.0 + 2*1.75 + 0.125 = 17.63 MiB,
+    over the limit a single-x count let through). bn shrinks first —
+    smaller bn only adds grid steps; smaller bm re-reads the WEIGHTS
+    once per M block, which is the traffic this kernel exists to
+    minimize."""
     bm = min(block_m, max(16, -(-m // 16) * 16))  # sublane-align small M
     bn = min(block_n, max(128, -(-n // 128) * 128))  # lane-align small N
 
     def fits(bm, bn):
-        return (bm * k * 2 + 2 * k * bn + 2 * bm * bn * 2) <= _VMEM_BUDGET
+        return 2 * (bm * k * 2 + k * bn + bm * bn * 2) <= _VMEM_BUDGET
 
     while not fits(bm, bn) and bn > 128:
         bn //= 2

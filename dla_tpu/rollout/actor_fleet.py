@@ -210,20 +210,6 @@ def shard_trajectory_groups(groups: Sequence[TrajectoryGroup],
 _CPU_DISPATCH_GATE = threading.Lock()
 
 
-def _read_jax_flag(name: str) -> Optional[bool]:
-    """Current value of a JAX config flag, or None if this JAX version
-    exposes no way to read it (in which case the caller skips the
-    restore rather than guessing)."""
-    try:
-        return bool(getattr(jax.config, name))
-    except AttributeError:
-        pass
-    try:
-        return bool(jax.config._value_holders[name].value)
-    except Exception:
-        return None
-
-
 def ensure_cpu_sync_dispatch() -> None:
     """Disable async dispatch for the CPU backend. MUST run before the
     process's first jax computation: the flag is read ONCE when the CPU
@@ -356,8 +342,8 @@ class SamplerFleet:
         self._prev_async_dispatch: Optional[bool] = None
         self._dispatch_gate: Optional[threading.Lock] = None
         if jax.default_backend() == "cpu":
-            self._prev_async_dispatch = _read_jax_flag(
-                "jax_cpu_enable_async_dispatch")
+            self._prev_async_dispatch = bool(jax.config.read(
+                "jax_cpu_enable_async_dispatch"))
             jax.config.update("jax_cpu_enable_async_dispatch", False)
             self._dispatch_gate = _CPU_DISPATCH_GATE
         for _ in range(int(fleet_cfg.samplers)):

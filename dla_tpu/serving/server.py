@@ -586,10 +586,12 @@ class ServingEngine:
         step, sample PER-ROW (each slot's traced temperature/top_p/top_k/
         seed, keyed by the slot's generated-token index), scatter the
         fresh KV column back. Free slots compute garbage routed to the
-        trash page. Returns the fresh KV pools plus a packed [2, B] f32
-        array — row 0 the sampled tokens bitcast to f32, row 1 their
-        chosen-token logprobs — so the host still performs exactly ONE
-        D2H fetch per decode step (the execution-model invariant)."""
+        trash page. Returns the fresh KV pools plus a packed [2, B] int32
+        array — row 0 the sampled tokens, row 1 their chosen-token
+        logprobs bitcast to int32 — so the host still performs exactly
+        ONE D2H fetch per decode step (the execution-model invariant).
+        The pack is integer because a small token id viewed as f32 is a
+        subnormal, and the TPU flushes those to zero."""
         self.decode_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the serving compile-once tests
         geom = self.cache.geom
         ps = geom.page_size
@@ -619,7 +621,7 @@ class ServingEngine:
         k_pages = k_pages.at[:, page_ids, offs].set(k_cols[:, :, 0])
         v_pages = v_pages.at[:, page_ids, offs].set(v_cols[:, :, 0])
         packed = jnp.stack(
-            [jax.lax.bitcast_convert_type(new_tok, jnp.float32), logp])
+            [new_tok, jax.lax.bitcast_convert_type(logp, jnp.int32)])
         return k_pages, v_pages, packed
 
     def _spec_draft_fn(self, draft_params, k_pages, v_pages, block_tables,
@@ -701,8 +703,8 @@ class ServingEngine:
         accepted prefix, so rejected columns are never marked valid —
         rollback costs nothing and rejected tokens can never reach the
         PrefixCache index (only prefill registers pages). Returns a
-        packed [3, B, K+1] f32 array — tokens bitcast / chosen-token
-        logps / accept-count bitcast broadcast — ONE D2H per round."""
+        packed [3, B, K+1] int32 array — tokens / chosen-token logps
+        bitcast / accept-count broadcast — ONE D2H per round."""
         self.spec_verify_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the speculative compile-once tests
         geom = self.cache.geom
         ps = geom.page_size
@@ -736,10 +738,9 @@ class ServingEngine:
         k_pages = k_pages.at[:, page_ids, offs].set(k_cols)
         v_pages = v_pages.at[:, page_ids, offs].set(v_cols)
         packed = jnp.stack([
-            jax.lax.bitcast_convert_type(toks, jnp.float32),
-            logps,
-            jax.lax.bitcast_convert_type(
-                jnp.broadcast_to(acc[:, None], (b, g)), jnp.float32)])
+            toks,
+            jax.lax.bitcast_convert_type(logps, jnp.int32),
+            jnp.broadcast_to(acc[:, None], (b, g))])
         return k_pages, v_pages, packed
 
     # ------------------------------------------------------------- intake
@@ -1840,8 +1841,8 @@ class ServingEngine:
                 self._dev(self.gen_pos), self._adapters_args())
             # dla: disable=host-sync-in-hot-loop -- the designed single D2H per decode step (execution-model invariant)
             packed_np = np.asarray(packed)
-        toks_np = packed_np[0].view(np.int32)
-        logps_np = packed_np[1]
+        toks_np = packed_np[0]
+        logps_np = packed_np[1].view(np.float32)
         if self._fault_nan_logits:
             # injected AFTER the fetch, where the real NaN guard below
             # (_sample_host) and a device-side check would trip: the
@@ -1915,9 +1916,9 @@ class ServingEngine:
                 top_ks, seeds, gpos, adapters)
             # dla: disable=host-sync-in-hot-loop -- the designed single D2H per speculative round (proposals never leave the device)
             packed_np = np.asarray(packed)
-        toks_np = packed_np[0].view(np.int32)         # [B, K+1]
-        logps_np = packed_np[1]
-        acc_np = packed_np[2].view(np.int32)[:, 0]    # [B] accepts 0..K
+        toks_np = packed_np[0]                        # [B, K+1]
+        logps_np = packed_np[1].view(np.float32)
+        acc_np = packed_np[2][:, 0]                   # [B] accepts 0..K
         if self._fault_nan_logits:
             # injected AFTER the fetch, where a real device-side NaN
             # would surface: nothing was committed, replay is clean

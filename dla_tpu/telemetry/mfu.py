@@ -7,15 +7,15 @@ achieves, using the standard dense-transformer cost model
     train FLOPs/token ~= 6 * N        (fwd 2N + bwd 4N, N = params)
     MFU = tokens/sec/chip * 6N / peak_flops(chip)
 
-This module is deliberately dependency-free (no jax import) so
-``bench.py``'s parent orchestrator — which must never initialize the jax
-backend — and offline report tooling can both use the tables. The tables
-lived in bench.py before telemetry existed; they moved here so the
-trainer, bench, and the sweep tools all read ONE set of peak numbers.
+This module is deliberately dependency-free (no jax import) so offline
+report tooling can use the tables: the trainer, bench and the sweep
+tools all read ONE set of peak numbers. A host CPU has no entry — a CPU
+run reports no MFU and no roofline verdict — and an accelerator that is
+not in the tables is an error, never another chip's figure.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 #: Documented tolerance for the XLA-vs-6N FLOPs cross-check
 #: (``MFUCalculator.check_estimate``). 6N ignores attention's quadratic
@@ -27,45 +27,44 @@ from typing import Dict, Optional, Tuple
 ESTIMATE_TOLERANCE = 0.35
 
 #: Per-chip peak bf16 FLOP/s by device kind (substring match against
-#: jax's ``device_kind``). "cpu" is a nominal figure so CPU-hosted smoke
-#: runs report a non-degenerate MFU.
+#: jax's ``device_kind``; Google Cloud TPU documentation).
 PEAK_BF16_FLOPS = {
     "v5 lite": 197e12, "v5e": 197e12, "v5p": 459e12,
     "v4": 275e12, "v6": 918e12, "trillium": 918e12,
-    "cpu": 5e11,
 }
 
 #: Per-chip HBM bandwidth, bytes/s (same substring match).
 PEAK_HBM_BW = {
     "v5 lite": 819e9, "v5e": 819e9, "v5p": 2765e9,
     "v4": 1228e9, "v6": 1640e9, "trillium": 1640e9,
-    "cpu": 50e9,
 }
 
 
-def peak_flops_for(device_kind: str, platform: str = "") -> float:
-    """Peak bf16 FLOP/s for a device kind string. Unrecognized
-    accelerators fall back to the v5e figure; unrecognized CPU-platform
-    kinds to the nominal CPU figure."""
-    kind = (device_kind or "cpu").lower()
-    for key, val in PEAK_BF16_FLOPS.items():
+def _lookup(table: Dict[str, float], device_kind: str, platform: str,
+            what: str) -> Optional[float]:
+    if platform == "cpu":
+        return None
+    kind = (device_kind or "").lower()
+    for key, val in table.items():
         if key in kind:
             return val
-    return PEAK_BF16_FLOPS["cpu"] if platform == "cpu" else 197e12
+    raise ValueError(
+        f"no {what} on record for device kind {device_kind!r} "
+        f"(platform {platform!r}); add it to dla_tpu/telemetry/mfu.py "
+        "with its source rather than assuming another chip's figure")
 
 
-def hbm_bw_for(device_kind: str, platform: str = "") -> Tuple[float, bool]:
-    """(per-chip HBM bytes/s, assumed?) — ``assumed`` is True when the
-    figure is the v5e fallback, not a known-chip number; callers must
-    surface that in their emitted detail rather than silently skewing
-    rooflines."""
-    kind = (device_kind or "cpu").lower()
-    for key, val in PEAK_HBM_BW.items():
-        if key in kind:
-            return val, False
-    if platform == "cpu":
-        return PEAK_HBM_BW["cpu"], False
-    return 819e9, True
+def peak_flops_for(device_kind: str, platform: str = "") -> Optional[float]:
+    """Peak bf16 FLOP/s for a device kind string; None on the CPU
+    platform. An accelerator that is not in the table is an error."""
+    return _lookup(PEAK_BF16_FLOPS, device_kind, platform,
+                   "peak bf16 FLOP/s")
+
+
+def hbm_bw_for(device_kind: str, platform: str = "") -> Optional[float]:
+    """Per-chip HBM bytes/s for a device kind string; None on the CPU
+    platform, raises like :func:`peak_flops_for` on an unknown chip."""
+    return _lookup(PEAK_HBM_BW, device_kind, platform, "HBM bandwidth")
 
 
 def flops_per_token(n_params: int, training: bool = True) -> float:
@@ -92,13 +91,17 @@ class MFUCalculator:
         self.device_kind = device_kind
         self.platform = platform
         self.peak = peak_flops_for(device_kind, platform)
-        self.hbm_bw, self.hbm_bw_assumed = hbm_bw_for(device_kind, platform)
+        self.hbm_bw = hbm_bw_for(device_kind, platform)
         self.flops_per_token = flops_per_token(self.n_params, training)
 
-    def mfu(self, tokens_per_sec_per_chip: Optional[float]) -> float:
+    def mfu(self, tokens_per_sec_per_chip: Optional[float]
+            ) -> Optional[float]:
         """MFU in [0, ~1] from per-chip token throughput; 0.0 when the
-        rate is unknown (no steps yet) — a metrics report never throws."""
-        if not tokens_per_sec_per_chip or self.peak <= 0:
+        rate is unknown (no steps yet), None on a device with no peak on
+        record (a CPU) — callers publish no gauge then."""
+        if self.peak is None:
+            return None
+        if not tokens_per_sec_per_chip:
             return 0.0
         return tokens_per_sec_per_chip * self.flops_per_token / self.peak
 
@@ -110,17 +113,17 @@ class MFUCalculator:
         Arithmetic intensity (FLOPs per HBM byte) above the chip's ridge
         point (peak FLOP/s over peak HBM bytes/s) means the function is
         compute-bound; below it, bandwidth-bound. Values are plain
-        floats so they publish directly as gauges:
-        ``compute_bound`` 1.0/0.0, ``bw_assumed`` flags a fallback
-        bandwidth table entry (unknown chip)."""
+        floats so they publish directly as gauges: ``compute_bound``
+        1.0/0.0. Empty on a device with no peaks on record."""
+        if self.peak is None or self.hbm_bw is None:
+            return {}
         intensity = (float(flops) / float(bytes_accessed)
                      if bytes_accessed > 0 else 0.0)
-        ridge = self.peak / self.hbm_bw if self.hbm_bw > 0 else 0.0
+        ridge = self.peak / self.hbm_bw
         return {
             "intensity": intensity,
             "ridge": ridge,
             "compute_bound": 1.0 if intensity >= ridge else 0.0,
-            "bw_assumed": 1.0 if self.hbm_bw_assumed else 0.0,
         }
 
     def check_estimate(self, xla_flops: float, tokens: float,

@@ -28,10 +28,9 @@ once) then ``.compile()``, and every subsequent call with the same
 fingerprint dispatches through the cached ``Compiled`` object without
 touching the tracing machinery. A changed fingerprint re-lowers, exactly
 as plain ``jax.jit`` would have re-traced — same compile count, but now
-attributed. Any AOT failure (an exotic backend, a Compiled call
-signature mismatch) permanently falls back to the raw jitted callable
-for that wrapper; attribution then still works from the fingerprint
-diff, only the cost/memory accounting is lost.
+attributed. A lowering, compile or dispatch error propagates to the
+caller: retrying through the raw jitted callable would compile the same
+program a second time and report the step as healthy.
 
 Fingerprints deliberately cover structure + shape + dtype, not values:
 traced scalars (the guard EMA, fault injectors) change value every step
@@ -103,11 +102,9 @@ def diff_fingerprints(old, new, limit: int = 4) -> List[Dict[str, str]]:
 
 
 def normalize_cost_analysis(cost: Any) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict on new jax, a
-    one-element list of dicts on older releases; flatten either into
-    ``{"flops", "bytes_accessed", "transcendentals"}``."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
+    """``Compiled.cost_analysis()``'s dict under telemetry key names:
+    ``{"flops", "bytes_accessed", "transcendentals"}`` (empty when the
+    backend reports none)."""
     if not isinstance(cost, dict):
         return {}
     return {
@@ -199,8 +196,6 @@ class IntrospectedFunction:
         self.step: Optional[int] = None
         self.compiles = 0
         self.recompiles = 0
-        self.fallback = False
-        self.fallback_reason: Optional[str] = None
         self.last_event: Optional[Dict[str, Any]] = None
         self.stats: Dict[str, float] = {}
         self._cache: "OrderedDict[Any, _Entry]" = OrderedDict()
@@ -214,39 +209,19 @@ class IntrospectedFunction:
             return self.jitted(*args)
         # fingerprint BEFORE dispatch: donated buffers are dead after
         fp = fingerprint_args(args)
-        if self.fallback:
-            if self._last_fp is not None and fp != self._last_fp:
-                self._emit_compile_event(fp, aot=False)
-            self._last_fp = fp
-            return self.jitted(*args)
         entry = self._cache.get(fp)
         if entry is None:
             entry = self._compile(fp, args)
-            if entry is None:               # AOT failed -> raw jit path
-                self._last_fp = fp
-                return self.jitted(*args)
         else:
             self._cache.move_to_end(fp)
         self._last_fp = fp
-        try:
-            return entry.compiled(*args)
-        except (TypeError, ValueError) as exc:
-            # Compiled-call signature/sharding mismatch the fingerprint
-            # could not see: drop to the raw jitted path for good (it
-            # re-traces, which the caller's compile counter will surface
-            # as an unattributed recompile)
-            self._note_fallback(f"aot call failed: {exc}")
-            return self.jitted(*args)
+        return entry.compiled(*args)
 
-    def _compile(self, fp, args) -> Optional[_Entry]:
+    def _compile(self, fp, args) -> _Entry:
         is_recompile = self.compiles > 0
         if is_recompile:
             self._emit_compile_event(fp, aot=True)
-        try:
-            compiled = self.jitted.lower(*args).compile()
-        except Exception as exc:                      # noqa: BLE001
-            self._note_fallback(f"lower/compile failed: {exc}")
-            return None
+        compiled = self.jitted.lower(*args).compile()
         self.compiles += 1
         if not is_recompile and self.recorder is not None:
             # first compile is expected, not a recompile: ring event only
@@ -260,9 +235,7 @@ class IntrospectedFunction:
         if self.mfu_calc is not None and stats.get("flops"):
             verdict = self.mfu_calc.roofline(
                 stats["flops"], stats.get("bytes_accessed", 0.0))
-            stats["roofline_intensity"] = verdict["intensity"]
-            stats["roofline_ridge"] = verdict["ridge"]
-            stats["roofline_compute_bound"] = verdict["compute_bound"]
+            stats.update({f"roofline_{k}": v for k, v in verdict.items()})
         self.stats = stats
         self._publish(stats)
         entry = _Entry(compiled, stats)
@@ -275,9 +248,9 @@ class IntrospectedFunction:
 
     def note_unattributed_compile(self, step: Optional[int] = None) -> None:
         """The caller's trace-time compile counter ticked but this wrapper
-        saw no fingerprint delta (fallback-path re-trace, external jit
-        cache thrash): count and record it as an unattributed recompile so
-        it still shows up in the ring and the counters."""
+        saw no fingerprint delta (external jit cache thrash): count and
+        record it as an unattributed recompile so it still shows up in
+        the ring and the counters."""
         if step is not None:
             self.step = step
         self._emit_compile_event(self._last_fp, aot=False)
@@ -303,13 +276,6 @@ class IntrospectedFunction:
                 for k, v in event.items()})
         if self.on_compile is not None:
             self.on_compile(dict(event, step=self.step))
-
-    def _note_fallback(self, reason: str) -> None:
-        self.fallback = True
-        self.fallback_reason = reason
-        if self.recorder is not None:
-            self.recorder.record("xla_introspect_fallback", step=self.step,
-                                 fn=self.name, reason=reason[:300])
 
     def _publish(self, stats: Dict[str, float]) -> None:
         if self.registry is None:

@@ -315,6 +315,7 @@ class ModelSchema:
     gradient_checkpointing: Any = None
     label_smoothing: Any = None
     max_seq_length: Any = None
+    num_layers: Any = None
     pooling: Any = None
     lora: Any = None
     kv_cache_dtype: Any = None
@@ -635,7 +636,6 @@ class ServingLatencySchema:
 class LatencySchema:
     batch_sizes: Any = None
     seq_lengths: Any = None
-    hardware: Any = None
     measure_steps: Any = None
     warmup_steps: Any = None
     decode: Optional[DecodeLatencySchema] = None

@@ -30,6 +30,7 @@ from dla_tpu.data.jsonl import append_jsonl, read_jsonl
 from dla_tpu.generation.engine import GenerationConfig, GenerationEngine
 from dla_tpu.training.model_io import build_reward_model, load_causal_lm
 from dla_tpu.training.utils import seed_everything
+from dla_tpu.utils.compile_cache import enable_compile_cache
 from dla_tpu.utils.logging import log_rank_zero
 
 PROMPT_TEMPLATE = "{prompt}\n\n"
@@ -65,6 +66,7 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main(argv=None) -> None:
     args = parse_args(argv)
+    enable_compile_cache()
     rng = seed_everything(args.seed)
     model_cfg = {"tokenizer": args.tokenizer} if args.tokenizer else {}
     bundle = load_causal_lm(args.model_name_or_path, model_cfg, rng)

@@ -63,7 +63,8 @@ def _arch_overrides(model_cfg: Dict[str, Any]) -> Dict[str, Any]:
     if "use_flash_attention" in model_cfg:
         out["attention"] = ("flash" if model_cfg["use_flash_attention"]
                             else "xla")
-    for key in ("dtype", "param_dtype", "remat", "vocab_size", "attention",
+    for key in ("dtype", "param_dtype", "remat", "vocab_size", "num_layers",
+                "attention",
                 "kv_cache_dtype", "decode_kernel",
                 "context_parallel", "arch", "rotary_pct", "attention_bias",
                 "sliding_window", "sliding_window_pattern",

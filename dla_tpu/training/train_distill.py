@@ -46,6 +46,7 @@ from dla_tpu.training.model_io import (
 )
 from dla_tpu.training.trainer import Trainer
 from dla_tpu.training.utils import seed_everything
+from dla_tpu.utils.compile_cache import enable_compile_cache
 from dla_tpu.utils.logging import log_rank_zero
 
 
@@ -124,6 +125,7 @@ def make_distill_loss(student_model, teacher_models: List[Any],
 def main(argv=None) -> None:
     args = make_arg_parser("dla_tpu distillation trainer").parse_args(argv)
     config = config_from_args(args)
+    enable_compile_cache()
     initialize_distributed(config.get("hardware"))
     mesh = mesh_from_config(config.get("hardware"))
     rng = seed_everything(int(config.get("seed", 0)))

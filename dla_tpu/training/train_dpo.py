@@ -39,6 +39,7 @@ from dla_tpu.training.model_io import (
 )
 from dla_tpu.training.trainer import Trainer
 from dla_tpu.training.utils import seed_everything
+from dla_tpu.utils.compile_cache import enable_compile_cache
 from dla_tpu.utils.logging import log_rank_zero
 
 
@@ -106,6 +107,7 @@ def make_dpo_loss(policy_model, ref_model, beta: float,
 def main(argv=None) -> None:
     args = make_arg_parser("dla_tpu DPO trainer").parse_args(argv)
     config = config_from_args(args)
+    enable_compile_cache()
     initialize_distributed(config.get("hardware"))
     mesh = mesh_from_config(config.get("hardware"))
     rng = seed_everything(int(config.get("seed", 0)))

@@ -33,6 +33,7 @@ from dla_tpu.training.model_io import (
     save_merged_lora_final,
 )
 from dla_tpu.training.trainer import Trainer
+from dla_tpu.utils.compile_cache import enable_compile_cache
 from dla_tpu.utils.logging import log_rank_zero
 
 
@@ -98,6 +99,7 @@ def make_reward_eval(model, lora: bool = False, n_segments: int = 0):
 def main(argv=None) -> None:
     args = make_arg_parser("dla_tpu reward-model trainer").parse_args(argv)
     config = config_from_args(args)
+    enable_compile_cache()
     initialize_distributed(config.get("hardware"))
     mesh = mesh_from_config(config.get("hardware"))
     from dla_tpu.training.utils import seed_everything

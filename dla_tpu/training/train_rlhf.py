@@ -79,6 +79,7 @@ from dla_tpu.training.model_io import (
 )
 from dla_tpu.training.trainer import Trainer
 from dla_tpu.training.utils import seed_everything
+from dla_tpu.utils.compile_cache import enable_compile_cache
 from dla_tpu.utils.logging import log_rank_zero
 
 PROMPT_TEMPLATE = "{prompt}\n\n"
@@ -283,6 +284,7 @@ def compute_local_rollout_shape(batch_size: int, n_procs: int,
 def main(argv=None) -> None:
     args = make_arg_parser("dla_tpu PPO-RLHF trainer").parse_args(argv)
     config = config_from_args(args)
+    enable_compile_cache()
     # a sampler fleet on the CPU backend needs synchronous dispatch,
     # and that flag is baked into the CPU client at creation — decide
     # BEFORE the first jax call below (the fleet constructor's own

@@ -33,6 +33,7 @@ from dla_tpu.training.model_io import (
 )
 from dla_tpu.training.trainer import Trainer
 from dla_tpu.training.utils import seed_everything
+from dla_tpu.utils.compile_cache import enable_compile_cache
 from dla_tpu.utils.logging import log_rank_zero
 
 
@@ -73,12 +74,16 @@ def build_trainer(config: Dict[str, Any], mesh, rng) -> tuple:
             config=config, mesh=mesh,
             loss_fn=make_sft_loss(bundle.model),
             params=bundle.params, param_specs=bundle.specs)
+    # the trainer holds the placed tree; a second reference would pin the
+    # unsharded initial copy (the whole model, on device 0) for the run
+    bundle.params = None
     return trainer, bundle
 
 
 def main(argv=None) -> None:
     args = make_arg_parser("dla_tpu SFT trainer").parse_args(argv)
     config = config_from_args(args)
+    enable_compile_cache()
     initialize_distributed(config.get("hardware"))
     mesh = mesh_from_config(config.get("hardware"))
     rng = seed_everything(int(config.get("seed", 0)))

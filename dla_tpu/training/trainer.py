@@ -604,9 +604,9 @@ class Trainer:
     def _attribute_compile(self, step_fn) -> None:
         """The trace-time compile counter ticked during that dispatch:
         name why. The introspection wrapper's ``last_event`` carries the
-        argument diff; a tick it did not predict (AOT fallback re-trace)
-        is recorded as an UNattributed recompile — the anomaly monitor
-        treats those as triage triggers after warmup."""
+        argument diff; a tick it did not predict is recorded as an
+        UNattributed recompile — the anomaly monitor treats those as
+        triage triggers after warmup."""
         if not isinstance(step_fn, IntrospectedFunction):
             return
         first = self.train_step_compiles == 1
@@ -713,8 +713,10 @@ class Trainer:
                             payload["train/guard_bad_steps"] = float(
                                 self.guard.bad_steps_total)
                         payload.update(self.clock.interval_metrics())
-                        payload["telemetry/mfu"] = self.mfu_calc.mfu(
+                        mfu = self.mfu_calc.mfu(
                             payload.get("tokens_per_sec_per_chip"))
+                        if mfu is not None:
+                            payload["telemetry/mfu"] = mfu
                         if self.xla_introspect_enabled:
                             payload["telemetry/xla/live_bytes"] = \
                                 live_array_bytes()
@@ -747,8 +749,9 @@ class Trainer:
                             f"step {self.step}: loss {running.average:.4f} "
                             f"({payload.get('tokens_per_sec_per_chip', 0):.0f}"
                             f" tok/s/chip, goodput "
-                            f"{100 * payload.get('telemetry/goodput', 0):.0f}%,"
-                            f" mfu {100 * payload['telemetry/mfu']:.1f}%)")
+                            f"{100 * payload.get('telemetry/goodput', 0):.0f}%"
+                            + ("" if mfu is None
+                               else f", mfu {100 * mfu:.1f}%") + ")")
 
                 if self.eval_every and eval_iter_fn and self.step % self.eval_every == 0:
                     with self.clock.segment("eval"):

@@ -11,8 +11,8 @@ the parent to kill this worker MID-STREAM (the zero-loss replay path)
 or migrate a live request away.
 
 Usage: python tests/_gateway_worker.py <gossip_dir> <name> [slow_ms]
-[spool_dir] (launched with a scrubbed CPU env; see
-_cpuhost.scrubbed_cpu_env). A non-empty ``spool_dir`` installs an
+[spool_dir] (launched on the virtual CPU platform; see
+_cpuhost.cpu_child_env). A non-empty ``spool_dir`` installs an
 enabled process tracer spooling into it — the distributed-tracing
 acceptance test merges every worker's spool with tools/trace_merge.py.
 """

@@ -79,7 +79,6 @@ def test_eval_latency_end_to_end(tmp_path):
         "models": {"tiny": "tiny"},
         "model": {"tokenizer": "byte"},
         "latency": {
-            "hardware": "cpu-test",
             "batch_sizes": [1, 2],
             "seq_lengths": [16],
             "warmup_steps": 1,
@@ -93,7 +92,9 @@ def test_eval_latency_end_to_end(tmp_path):
     p.write_text(yaml.safe_dump(cfg))
     main(["--config", str(p)])
     lat = json.loads((tmp_path / "out" / "latency.json").read_text())
-    assert lat["hardware"] == "cpu-test"
+    # the label comes from the device the run used, not from the YAML
+    assert lat["hardware"]["platform"] == "cpu"
+    assert lat["hardware"]["count"] == 8
     rows = lat["tiny"]["forward"]
     assert len(rows) == 2
     assert all(r["tokens_per_second"] > 0 and r["latency_ms"] > 0
@@ -111,7 +112,6 @@ def test_eval_latency_serving_mode(tmp_path):
         "models": {"tiny": "tiny"},
         "model": {"tokenizer": "byte"},
         "latency": {
-            "hardware": "cpu-test",
             "batch_sizes": [1],
             "seq_lengths": [16],
             "warmup_steps": 0,

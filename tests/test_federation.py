@@ -250,14 +250,14 @@ def test_cross_process_fleets_bit_identical_and_kill_safe(
     is lost — orphaned streams re-place on the survivor and replay to
     the same tokens."""
     sys.path.insert(0, str(REPO_ROOT))
-    from _cpuhost import scrubbed_cpu_env
+    from _cpuhost import cpu_child_env
 
     prompts = _prompts()
     ref_greedy = _reference(serve_setup, prompts, new_tokens=8)
     ref_seeded = _reference(serve_setup, prompts, new_tokens=8,
                             sampling=SEEDED)
 
-    env = scrubbed_cpu_env(1, str(REPO_ROOT))
+    env = cpu_child_env(1, str(REPO_ROOT))
     procs = {}
     fed = FederatedRouter(tmp_path, FederationConfig())
     try:

@@ -265,7 +265,7 @@ def test_arch_overrides_cover_every_model_config_field():
 
     # fields set by structural/weight context, not per-run YAML keys
     excluded = {
-        "vocab_size", "hidden_size", "intermediate_size", "num_layers",
+        "vocab_size", "hidden_size", "intermediate_size",
         "num_heads", "num_kv_heads", "head_dim", "rope_theta",
         "rope_scaling", "rms_norm_eps", "tie_embeddings",
         "max_seq_length",  # handled explicitly above the whitelist

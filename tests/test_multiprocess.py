@@ -28,10 +28,10 @@ def _run_worker_world(worker: str, n_procs: int, devices_per_proc: int,
     """Launch ``worker`` as an n-process jax.distributed world and assert
     every rank exits 0 and prints its OK marker. Returns the outputs."""
     sys.path.insert(0, str(REPO_ROOT))
-    from _cpuhost import scrubbed_cpu_env
+    from _cpuhost import cpu_child_env
 
     port = _free_port()
-    env = scrubbed_cpu_env(devices_per_proc, str(REPO_ROOT))
+    env = cpu_child_env(devices_per_proc, str(REPO_ROOT))
     procs = [
         subprocess.Popen(
             [sys.executable, str(REPO_ROOT / "tests" / worker),

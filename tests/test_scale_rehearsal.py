@@ -14,7 +14,7 @@ REPO_ROOT = Path(__file__).resolve().parent.parent
 
 def _run_rehearsal(tmp_path, n_devices, mesh_override, **model_over):
     sys.path.insert(0, str(REPO_ROOT))
-    from _cpuhost import scrubbed_cpu_env
+    from _cpuhost import cpu_child_env
 
     cfg = yaml.safe_load(
         (REPO_ROOT / "config" / "sft_llama2_70b_v5e256.yaml").read_text())
@@ -31,7 +31,7 @@ def _run_rehearsal(tmp_path, n_devices, mesh_override, **model_over):
     out = subprocess.run(
         [sys.executable, str(REPO_ROOT / "tools" / "scale_rehearsal.py"),
          str(p), str(n_devices), mesh_s],
-        env=scrubbed_cpu_env(n_devices, str(REPO_ROOT)),
+        env=cpu_child_env(n_devices, str(REPO_ROOT)),
         cwd=str(REPO_ROOT), capture_output=True, text=True, timeout=420)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])

@@ -347,14 +347,14 @@ def test_cross_process_merge_with_midstream_migration(tmp_path):
     links, the migrated request's tree touches all three processes, and
     no process fell back to wall-clock alignment."""
     sys.path.insert(0, str(REPO_ROOT))
-    from _cpuhost import scrubbed_cpu_env
+    from _cpuhost import cpu_child_env
     from dla_tpu.serving import FederatedRouter, FederationConfig
 
     gossip = tmp_path / "gossip"
     spool = tmp_path / "spool"
     gossip.mkdir()
     spool.mkdir()
-    env = scrubbed_cpu_env(1, str(REPO_ROOT))
+    env = cpu_child_env(1, str(REPO_ROOT))
     rs = np.random.RandomState(11)
     prompts = [[int(t) for t in rs.randint(3, 500, (6,))]
                for _ in range(4)]

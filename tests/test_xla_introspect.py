@@ -68,7 +68,7 @@ def test_fingerprint_structure_change_is_one_row():
 
 
 # ---------------------------------------------------------------------------
-# the wrapper: zero extra compiles, attributed recompiles, fallback
+# the wrapper: zero extra compiles, attributed recompiles, loud failures
 # ---------------------------------------------------------------------------
 
 def _wrapped(name="fn", **kw):
@@ -163,11 +163,14 @@ def test_disabled_wrapper_is_a_passthrough():
     assert len(ticks) == 2           # plain jit retraced, untouched
 
 
-def test_aot_failure_falls_back_permanently_but_still_attributes():
+def test_compile_failure_propagates_without_a_second_compile():
     class BrokenJit:
-        """Callable without .lower(): forces the fallback path."""
+        """Jitted-shaped callable whose lowering fails."""
         def __init__(self):
             self.calls = 0
+
+        def lower(self, *args):
+            raise RuntimeError("injected compile error")
 
         def __call__(self, x):
             self.calls += 1
@@ -175,12 +178,10 @@ def test_aot_failure_falls_back_permanently_but_still_attributes():
 
     raw = BrokenJit()
     fn = IntrospectedFunction("broken", raw)
-    x = np.ones((2, 2), np.float32)
-    assert fn(x) is x                # result still flows
-    assert fn.fallback and "lower/compile failed" in fn.fallback_reason
-    fn(np.ones((2, 4), np.float32))  # fingerprint diff still attributes
-    assert fn.recompiles == 1 and fn.last_event["attributed"]
-    assert raw.calls == 2
+    with pytest.raises(RuntimeError, match="injected compile error"):
+        fn(np.ones((2, 2), np.float32))
+    assert raw.calls == 0            # never retried through the raw path
+    assert fn.compiles == 0
 
 
 def test_cache_eviction_respects_max_entries():
@@ -213,7 +214,9 @@ def test_six_n_crosscheck_and_roofline_with_zero_extra_compiles():
         ticks.append(1)
         return jnp.mean((x @ w - y) ** 2)
 
-    mfu = MFUCalculator(D * O, device_kind="cpu", platform="cpu",
+    # the verdict is arithmetic on the compiled step's cost analysis and
+    # a chip's published peaks; the CPU the test runs on has none
+    mfu = MFUCalculator(D * O, device_kind="TPU v5 lite", platform="tpu",
                         training=True)
     reg = MetricRegistry()
     fn = IntrospectedFunction("train_step",
@@ -273,12 +276,12 @@ def test_trainer_steady_run_one_compile_with_xla_gauges(mesh8, tmp_path):
         step_fn = tr._jit_train_step
         assert isinstance(step_fn, IntrospectedFunction)
         assert step_fn.compiles == 1 and step_fn.recompiles == 0
-        assert not step_fn.fallback, step_fn.fallback_reason
 
         snap = tr.registry.snapshot()
         assert snap["telemetry/xla/train_step/flops"] > 0.0
         assert snap["telemetry/xla/train_step/bytes_accessed"] > 0.0
-        assert snap["telemetry/xla/train_step/roofline_ridge"] > 0.0
+        # no roofline verdict for a device with no peaks on record
+        assert "telemetry/xla/train_step/roofline_ridge" not in snap
         assert snap["telemetry/xla/live_bytes"] > 0.0
         # the 6N cross-check rode the log interval into the registry
         assert "telemetry/xla/train_step/flops_vs_6n_ratio" in snap

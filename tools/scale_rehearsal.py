@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -97,6 +96,15 @@ def rehearse(config_path: str, n_devices: int,
     import jax.numpy as _jnp
     stub.grad_accum_dtype = _jnp.dtype(
         opt_cfg.get("grad_accum_dtype", "float32"))
+    # what the step reads off the trainer besides the above: the
+    # in-graph collector and non-finite guard at their config defaults
+    from dla_tpu.resilience import GuardState, ResilienceConfig
+    from dla_tpu.telemetry import CollectorConfig
+    stub.train_step_compiles = 0
+    stub.collector_cfg = CollectorConfig.from_config(
+        (cfg.get("logging") or {}).get("telemetry") or {})
+    stub.guard = GuardState(
+        ResilienceConfig.from_config(cfg.get("resilience")).guard)
 
     with jax.sharding.set_mesh(mesh):
         specs = model.partition_specs()
@@ -131,12 +139,13 @@ def rehearse(config_path: str, n_devices: int,
         # argument buffers and the outputs cost nothing extra
         fn = jax.jit(
             _Step._train_step.__get__(stub),
-            in_shardings=(param_sh, opt_sh, None, None, None),
+            in_shardings=(param_sh, opt_sh, None, None, None, None, None),
             out_shardings=(param_sh, opt_sh,
                            NamedSharding(mesh, P()), None))
         print("[rehearsal] lowering...", file=sys.stderr)
         lowered = fn.lower(params_abs, opt_abs, None, batch_abs,
-                           jax.random.key(0))
+                           jax.random.key(0), jnp.float32(0.0),
+                           jnp.float32(0.0))
         print("[rehearsal] compiling (SPMD partitioning + XLA:CPU)...",
               file=sys.stderr)
         compiled = lowered.compile()
@@ -196,13 +205,12 @@ def main() -> None:
         os.environ.get("XLA_FLAGS", "")
         + " --xla_disable_hlo_passes=all-reduce-promotion")
 
-    from _cpuhost import force_cpu_platform, scrubbed_cpu_env
+    # compile-only analysis on the virtual CPU platform: forced before
+    # the backend initializes, so this process never claims a chip and
+    # starts no child
+    from _cpuhost import force_cpu_platform
     if not force_cpu_platform(n):
-        code = (f"import tools.scale_rehearsal as t; "
-                f"t.rehearse({config!r}, {n}, {override!r})")
-        proc = subprocess.run([sys.executable, "-c", code], cwd=_REPO,
-                              env=scrubbed_cpu_env(n, _REPO), timeout=3600)
-        sys.exit(proc.returncode)
+        sys.exit(f"could not bring up {n} virtual CPU devices")
     rehearse(config, n, override)
 
 

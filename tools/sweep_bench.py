@@ -192,12 +192,16 @@ def main():
     # parent mode: FRESH process per variant — a variant that OOMs (or
     # even completes) leaves buffers behind that poison later compiles in
     # the same TPU client (observed: every variant after the first fails
-    # RESOURCE_EXHAUSTED in-process)
+    # RESOURCE_EXHAUSTED in-process). This parent never imports jax: a
+    # chip belongs to one process at a time.
     import subprocess
-    for n in names:
-        subprocess.run([sys.executable, os.path.abspath(__file__), n],
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
+    failed = [
+        n for n in names
+        if subprocess.run([sys.executable, os.path.abspath(__file__), n],
+                          cwd=os.path.dirname(os.path.dirname(
+                              os.path.abspath(__file__)))).returncode]
+    if failed:
+        sys.exit(f"== sweep FAILED for {failed} ==")
     print("== sweep done ==")
 
 

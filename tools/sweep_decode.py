@@ -144,13 +144,14 @@ def run_variant(name: str, *, batch=8, prompt=128, new=256,
     avg_fill = prompt + new / 2
     kv_bytes = (2 * layers * batch * avg_fill
                 * kv_heads * cfg.head_dim_ * kv_elem)
-    roofline_ms = (p_bytes_step + kv_bytes) / hbm_bw(dev) * 1000
+    bw = hbm_bw(dev)    # None on a CPU: a host has no roofline
+    roofline_ms = bw and (p_bytes_step + kv_bytes) / bw * 1000
     out = {"variant": name, "ms_per_token": round(decode_ms, 3),
            "ms_per_token_incl_prefill": round(row["ms_per_token"], 3),
            "decode_tok_s_chip": round(
                1000.0 * batch / decode_ms / jax.device_count(), 1),
-           "roofline_ms": round(roofline_ms, 3),
-           "x_roofline": round(decode_ms / roofline_ms, 2),
+           "roofline_ms": roofline_ms and round(roofline_ms, 3),
+           "x_roofline": roofline_ms and round(decode_ms / roofline_ms, 2),
            "batch": batch, "prompt": prompt, "new": new,
            "kv": kv_dtype, "weights": weights,
            "params_m": round(n_params / 1e6),
@@ -206,11 +207,16 @@ def main():
                   flush=True)
             sys.exit(1)
         return
+    # one child per variant, one at a time; this parent never imports
+    # jax (a chip belongs to one process at a time)
     import subprocess
-    for n in names:
-        subprocess.run([sys.executable, os.path.abspath(__file__), n],
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
+    failed = [
+        n for n in names
+        if subprocess.run([sys.executable, os.path.abspath(__file__), n],
+                          cwd=os.path.dirname(os.path.dirname(
+                              os.path.abspath(__file__)))).returncode]
+    if failed:
+        sys.exit(f"== sweep FAILED for {failed} ==")
     print("== decode sweep done ==")
 
 
