@@ -1,0 +1,14 @@
+"""``memory_stats()["peak_bytes_in_use"]`` after the window, the largest
+over devices."""
+
+LAYER = "device"
+UNIT = "GiB"
+BETTER = "lower"
+MOVES = "train_tok_s_chip"
+SOURCE = "program_counter"
+DRIVERS = ('train_packed',)
+
+
+def read(ctx):
+    peak = ctx.device.get("memory_peak_bytes")
+    return None if peak is None else peak / 2 ** 30
