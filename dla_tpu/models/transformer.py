@@ -1252,10 +1252,11 @@ class Transformer:
         input embedding by sqrt(hidden) (normalizer cast to the activation
         dtype, matching HF GemmaModel's bf16-rounded multiplier); the tied
         unembedding stays unscaled."""
-        x = jnp.take(params["embed"]["embedding"], ids, axis=0
-                     ).astype(self.adtype)
-        if self.cfg.arch in ("gemma", "gemma2"):
-            x = x * jnp.asarray(self.cfg.hidden_size ** 0.5, self.adtype)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"]["embedding"], ids, axis=0
+                         ).astype(self.adtype)
+            if self.cfg.arch in ("gemma", "gemma2"):
+                x = x * jnp.asarray(self.cfg.hidden_size ** 0.5, self.adtype)
         return x
 
     def unembed_params(self, params: Params

@@ -146,10 +146,11 @@ def model_fused_ce(model, params, batch, lora=None, dropout_rng=None,
         attention_mask=batch.get("attention_mask"),
         segment_ids=batch.get("segment_ids"),
         lora=lora, dropout_rng=dropout_rng)
-    w, bias = model.unembed_params(params)
-    loss, n = fused_cross_entropy_loss(
-        h, w, batch["labels"], bias=bias, chunk=chunk,
-        softcap=model.cfg.final_logit_softcap)
+    with jax.named_scope("head_loss"):
+        w, bias = model.unembed_params(params)
+        loss, n = fused_cross_entropy_loss(
+            h, w, batch["labels"], bias=bias, chunk=chunk,
+            softcap=model.cfg.final_logit_softcap)
     return loss + weighted_moe_aux(model, moe_aux), n
 
 

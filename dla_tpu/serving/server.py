@@ -82,7 +82,8 @@ from dla_tpu.telemetry.xla_introspect import (
     IntrospectedFunction,
     register_live_bytes_gauge,
 )
-from dla_tpu.utils.profiling import ProfileWindow, annotate, step_annotation
+from dla_tpu.utils.profiling import (
+    ProfileWindow, annotate, mark, step_annotation)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -813,6 +814,8 @@ class ServingEngine:
         self.scheduler.submit(req)
         self._results[req.rid] = req
         self.metrics.requests_submitted.inc()
+        mark("serve_req_submit", rid=req.rid,
+             prompt_len=len(req.prompt_tokens), max_new=req.max_new_tokens)
         if self.tracer.enabled:
             # root of the request's async span tree, keyed by rid and
             # opened at the recorded arrival time — so the tree's span
@@ -852,10 +855,7 @@ class ServingEngine:
         self.recorder.record("request_cancelled",
                              step=self.engine_steps, rid=rid,
                              reason=reason)
-        if self.tracer.enabled:
-            self.tracer.async_end("request", "request", req.rid,
-                                  status="cancelled",
-                                  tokens=len(req.generated))
+        self._req_end(req, "cancelled")
         return req
 
     def publish_params(self, new_params, donate: bool = False) -> None:
@@ -1084,7 +1084,7 @@ class ServingEngine:
                 f"request {rid}: uncomputed committed columns")
         ids = np.zeros((geom.pages_per_slot,), np.int32)
         ids[:needed] = req.pages[:needed]
-        with annotate("serve_kv_export"):
+        with annotate("serve_kv_export", rid=req.rid):
             k_payload, v_payload = self._export_kv(
                 self.cache.k_pages, self.cache.v_pages, self._dev(ids))
         return MigrationTicket(
@@ -1176,7 +1176,7 @@ class ServingEngine:
                 f"{n_alloc} pages")
         ids = np.zeros((geom.pages_per_slot,), np.int32)
         ids[:needed] = pages[:needed]
-        with annotate("serve_kv_import"):
+        with annotate("serve_kv_import", rid=ticket.rid):
             self.cache.k_pages, self.cache.v_pages = self._import_kv(
                 self.cache.k_pages, self.cache.v_pages,
                 ticket.k_payload, ticket.v_payload, self._dev(ids))
@@ -1232,9 +1232,18 @@ class ServingEngine:
             return
         if req.state is RequestState.DECODE:
             self.scheduler.cancel(req, "migrated")
+        self._req_end(req, "migrated")
+
+    def _req_end(self, req: Request, status: str,
+                 t: Optional[float] = None) -> None:
+        """A request reached a terminal status: the lifecycle mark on the
+        profiler's clock and the close of its async tree in the host
+        tracer, side by side."""
+        mark("serve_req_finish", rid=req.rid, status=status,
+             tokens=len(req.generated))
         if self.tracer.enabled:
-            self.tracer.async_end("request", "request", req.rid,
-                                  status="migrated",
+            self.tracer.async_end("request", "request", req.rid, t=t,
+                                  status=status,
                                   tokens=len(req.generated))
 
     def has_work(self) -> bool:
@@ -1266,34 +1275,48 @@ class ServingEngine:
         # page headroom / copy-on-write cover the whole write span
         span = self._spec_k + 1
         with step_annotation(self.engine_steps, name="serve"):
-            self._poll_faults()
-            self._expire(self.now())
-            self._resilience_pass()
-            for req in self.scheduler.ensure_decode_pages(span=span):
-                self.metrics.preemptions.inc()
+            with annotate("serve_schedule"):
+                self._poll_faults()
+                self._expire(self.now())
+                self._resilience_pass()
+                self._ensure_decode_pages(span)
+            with annotate("serve_admit",
+                          queued=self.scheduler.queue_depth):
+                if self.cfg.prefill_chunk:
+                    self._admit_chunked(emitted)
+                else:
+                    self._admit(emitted)
             if self.cfg.prefill_chunk:
-                self._admit_chunked(emitted)
                 self._chunk_step(emitted)
-                # second page-safety pass: requests admitted ABOVE (via
-                # cache hit or final chunk) decode THIS step, and their
-                # first write may land in a shared/indexed tail page —
-                # copy-on-write must run before the decode, not next step
-                for req in self.scheduler.ensure_decode_pages(span=span):
-                    self.metrics.preemptions.inc()
-            else:
-                self._admit(emitted)
-                # same second pass for the one-shot prefill path: an
-                # admission's decode reserve guarantees ONE column, but
-                # a speculative round commits up to span columns in the
-                # admission step itself — grow (or preempt) before the
-                # round, or commits could advance past allocated pages
-                if self._spec_k:
-                    for req in self.scheduler.ensure_decode_pages(
-                            span=span):
-                        self.metrics.preemptions.inc()
+            if self.cfg.prefill_chunk or self._spec_k:
+                # second page-safety pass. Chunked: requests admitted
+                # ABOVE (via cache hit or final chunk) decode THIS step,
+                # and their first write may land in a shared/indexed tail
+                # page — copy-on-write must run before the decode, not
+                # next step. One-shot prefill: an admission's decode
+                # reserve guarantees ONE column, but a speculative round
+                # commits up to span columns in the admission step itself
+                # — grow (or preempt) before the round, or commits could
+                # advance past allocated pages
+                with annotate("serve_schedule"):
+                    self._ensure_decode_pages(span)
             if self.scheduler.running:
                 emitted.extend(self._spec_decode_step() if self._spec_k
                                else self._decode_step())
+            with annotate("serve_post"):
+                self._post_step()
+        return emitted
+
+    def _ensure_decode_pages(self, span: int) -> None:
+        """One page-safety pass; every request it preempts is counted and
+        marked on the profiler's clock."""
+        for req in self.scheduler.ensure_decode_pages(span=span):
+            self.metrics.preemptions.inc()
+            mark("serve_req_preempt", rid=req.rid)
+
+    def _post_step(self) -> None:
+        """The tail of a step: counter mirrors, gauges, SLO and tenant
+        passes (inside the step span as ``serve_post``)."""
         self.engine_steps += 1
         self.readiness.beat()
         if self.anomaly is not None:
@@ -1316,7 +1339,6 @@ class ServingEngine:
             self.tenants.observe(step=self.engine_steps)
             for victim in self.tenants.shed_pass(self.scheduler):
                 self._shed(victim, at="tenant_slo")
-        return emitted
 
     def run_until_drained(self, max_steps: int = 100000,
                           on_cap: str = "raise") -> Dict[int, Request]:
@@ -1360,10 +1382,7 @@ class ServingEngine:
             self.recorder.record("request_shed", step=self.engine_steps,
                                  rid=req.rid, priority=req.priority,
                                  at="drain_cap")
-            if self.tracer.enabled:
-                self.tracer.async_end("request", "request", req.rid,
-                                      status="shed",
-                                      tokens=len(req.generated))
+            self._req_end(req, "shed")
 
     # -------------------------------------------------------- observability
 
@@ -1411,9 +1430,7 @@ class ServingEngine:
         for req in [r for r in self.scheduler.queue if not r.generated]:
             self.scheduler.cancel(req, "cancelled")
             self.metrics.requests_cancelled.inc()
-            if self.tracer.enabled:
-                self.tracer.async_end("request", "request", req.rid,
-                                      status="cancelled", tokens=0)
+            self._req_end(req, "cancelled")
 
     @property
     def draining(self) -> bool:
@@ -1451,10 +1468,7 @@ class ServingEngine:
                 # queue wait alone blew the deadline — the admission-
                 # pressure signal, distinct from slow decode
                 self.metrics.queue_timeouts.inc()
-            if self.tracer.enabled:
-                self.tracer.async_end(
-                    "request", "request", req.rid, t=now,
-                    status="timeout", tokens=len(req.generated))
+            self._req_end(req, "timeout", t=now)
 
     # ----------------------------------------------------------- resilience
 
@@ -1471,9 +1485,7 @@ class ServingEngine:
         self.recorder.record("request_shed", step=self.engine_steps,
                              rid=req.rid, priority=req.priority, at=at,
                              tenant=req.tenant)
-        if self.tracer.enabled:
-            self.tracer.async_end("request", "request", req.rid,
-                                  status="shed", tokens=0)
+        self._req_end(req, "shed")
 
     def _resilience_pass(self) -> None:
         """Once per step, after deadline expiry and before scheduling:
@@ -1643,7 +1655,10 @@ class ServingEngine:
             page_rows[i] = req.pages[:n_prompt_pages]
         for i in range(len(batch), pb):
             mask[i, 0] = 1   # dummy rows: one valid token, trash pages
-        with annotate("serve_prefill"):
+        for req in batch:
+            mark("serve_req_admit", rid=req.rid, slot=req.slot,
+                 cached_tokens=0)
+        with annotate("serve_prefill", n=len(batch), width=width):
             self.cache.k_pages, self.cache.v_pages, logits = self._prefill(
                 self.params, self.cache.k_pages, self.cache.v_pages,
                 jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(page_rows))
@@ -1651,26 +1666,31 @@ class ServingEngine:
             logits_np = np.asarray(logits)
         t_done = self.now()
         self.metrics.prefill_batches.inc()
-        first, first_lps = self._sample_host(logits_np[:len(batch)], batch)
-        for i, req in enumerate(batch):
-            tok = int(first[i])
-            if req.admitted_time is None:
-                # queue wait = arrival -> first admission (re-prefills
-                # after eviction are decode-path stalls, not queue time)
-                req.admitted_time = t_done
-                self.metrics.queue_wait_ms.record(
-                    (t_done - req.arrival_time) * 1000.0)
-                if self.tracer.enabled:
-                    self.tracer.async_instant(
-                        "request", "admitted", req.rid, t=t_done,
-                        queue_wait_ms=(t_done - req.arrival_time)
-                        * 1000.0)
-            self.cache.open_slot(req.slot, req.pages,
-                                 len(req.prefix_tokens), width, tok)
-            self.scheduler.activate(req)
-            self._bind_slot_sampling(req)
-            self._emit(req, tok, t_done, emitted, first_of_prefill=True,
-                       logp=float(first_lps[i]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the prefill batch fetch above
+        # one span for the batch, under its first request's id; every
+        # request's own first-token mark follows from _emit
+        with annotate("serve_first_token", rid=batch[0].rid):
+            first, first_lps = self._sample_host(
+                logits_np[:len(batch)], batch)
+            for i, req in enumerate(batch):
+                tok = int(first[i])
+                if req.admitted_time is None:
+                    # queue wait = arrival -> first admission (re-prefills
+                    # after eviction are decode-path stalls, not queue
+                    # time)
+                    req.admitted_time = t_done
+                    self.metrics.queue_wait_ms.record(
+                        (t_done - req.arrival_time) * 1000.0)
+                    if self.tracer.enabled:
+                        self.tracer.async_instant(
+                            "request", "admitted", req.rid, t=t_done,
+                            queue_wait_ms=(t_done - req.arrival_time)
+                            * 1000.0)
+                self.cache.open_slot(req.slot, req.pages,
+                                     len(req.prefix_tokens), width, tok)
+                self.scheduler.activate(req)
+                self._bind_slot_sampling(req)
+                self._emit(req, tok, t_done, emitted, first_of_prefill=True,
+                           logp=float(first_lps[i]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the prefill batch fetch above
 
     def _admit_chunked(self, emitted: List[Tuple[int, int]]) -> None:
         """Strict-FCFS chunked admission. Exact-full-prompt cache hits
@@ -1685,6 +1705,8 @@ class ServingEngine:
             # slot assignment — before the first chunk dispatch, not at
             # activation (this is also where a cold adapter loads)
             self._bind_adapter(req)
+            mark("serve_req_admit", rid=req.rid, slot=req.slot,
+                 cached_tokens=req.prefill_pos)
             t = self.now()
             if req.admitted_time is None:
                 req.admitted_time = t
@@ -1701,14 +1723,8 @@ class ServingEngine:
                 # logits served from the cache — zero prefill FLOPs
                 # dla: disable=host-sync-in-hot-loop -- cached_logits is already host numpy (stored by register); no device fetch happens
                 logits_row = np.asarray(req.cached_logits)[None, :]
-                toks, lps = self._sample_host(logits_row, [req])
-                tok = int(toks[0])
                 req.cached_logits = None
-                self.cache.begin_decode(req.slot, n, tok)
-                self.scheduler.activate(req)
-                self._bind_slot_sampling(req)
-                self._emit(req, tok, t, emitted, first_of_prefill=True,
-                           logp=float(lps[0]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar from the cached-logits sample
+                self._first_token(req, logits_row, t, emitted)
 
     def _chunk_step(self, emitted: List[Tuple[int, int]]) -> None:
         """Advance the (single) mid-prefill request by one fixed-shape
@@ -1739,7 +1755,9 @@ class ServingEngine:
         ids = np.zeros((1, self.cfg.prefill_chunk), np.int32)
         ids[0, :nvalid] = prefix[start:start + nvalid]
         c = self.cache
-        with annotate("serve_prefill_chunk"):
+        with annotate("serve_prefill_chunk", rid=req.rid, slot=slot,
+                      start=start, nvalid=nvalid,
+                      last=int(start + nvalid >= n)):
             c.k_pages, c.v_pages, logits = self._prefill_chunk(
                 self.params, c.k_pages, c.v_pages,
                 self._dev(c.block_tables[slot:slot + 1]),
@@ -1754,23 +1772,35 @@ class ServingEngine:
         req.prefill_pos = start + nvalid
         if req.prefill_pos < n:
             return
-        # dla: disable=host-sync-in-hot-loop -- designed prefill D2H: one logits fetch per REQUEST (final chunk only), not per chunk
-        logits_np = np.asarray(logits)
+        with annotate("serve_chunk_fetch", rid=req.rid):
+            # dla: disable=host-sync-in-hot-loop -- designed prefill D2H: one logits fetch per REQUEST (final chunk only), not per chunk
+            logits_np = np.asarray(logits)
         t_done = self.now()
         self.metrics.prefill_batches.inc()
-        toks, lps = self._sample_host(logits_np, [req])
-        tok = int(toks[0])
-        self.cache.begin_decode(slot, n, tok)
-        if self.prefix_cache is not None:
-            # first-writer-wins: later identical prompts alias these
-            # pages; the stored logits make the NEXT identical prompt a
-            # zero-prefill full hit
-            self.prefix_cache.register(prefix, req.pages, logits_np[0],
-                                       namespace=req.tenant)
-        self.scheduler.activate(req)
-        self._bind_slot_sampling(req)
-        self._emit(req, tok, t_done, emitted, first_of_prefill=True,
-                   logp=float(lps[0]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the final-chunk logits fetch
+        self._first_token(req, logits_np, t_done, emitted, register=True)
+
+    def _first_token(self, req: Request, logits_np: np.ndarray, t: float,
+                     emitted: List[Tuple[int, int]],
+                     register: bool = False) -> None:
+        """A chunk-prefilled (or fully cache-hit) request's first token:
+        host sampling from its one logits row, decode state, prefix-cache
+        registration (``register``: the row was just computed, not served
+        from the cache), activation."""
+        with annotate("serve_first_token", rid=req.rid):
+            toks, lps = self._sample_host(logits_np, [req])
+            tok = int(toks[0])
+            self.cache.begin_decode(req.slot, len(req.prefix_tokens), tok)
+            if register and self.prefix_cache is not None:
+                # first-writer-wins: later identical prompts alias these
+                # pages; the stored logits make the NEXT identical prompt
+                # a zero-prefill full hit
+                self.prefix_cache.register(
+                    req.prefix_tokens, req.pages, logits_np[0],
+                    namespace=req.tenant)
+            self.scheduler.activate(req)
+            self._bind_slot_sampling(req)
+            self._emit(req, tok, t, emitted, first_of_prefill=True,
+                       logp=float(lps[0]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the logits row's one fetch
 
     def _mirror_cache_counters(self) -> None:
         """Mirror the PrefixCache's plain-int counters into the metrics
@@ -1813,52 +1843,80 @@ class ServingEngine:
         # dla: disable=host-sync-in-hot-loop -- prefill sample fetch: one D2H per admitted batch
         return np.asarray(toks), np.asarray(lps)
 
+    def _decode_span(self, active_slots: List[int]) -> annotate:
+        """The decode phase's span. Its arguments are the KV read as the
+        host knows it on entry: running slots, the tokens they hold, and
+        the columns the step's gathers read (every slot's whole window,
+        once per forward of the round, whatever the fill: a constant of
+        the geometry until the read is bounded by the fill)."""
+        geom = self.cache.geom
+        return annotate(
+            "serve_decode", slots=len(active_slots),
+            # dla: disable=host-sync-in-hot-loop -- host numpy mirror of the slot lengths, no device fetch
+            live_tokens=int(self.cache.lengths[active_slots].sum()),
+            read_tokens=(self._spec_k + 1) * geom.num_slots
+            * geom.slot_window)
+
+    def _decode_args(self, active_slots: List[int]) -> tuple:
+        """Everything a decode dispatch takes after the pool, built and
+        put on the device inside ``serve_decode_args``."""
+        c = self.cache
+        with annotate("serve_decode_args"):
+            active = np.zeros((c.geom.num_slots,), bool)
+            active[active_slots] = True
+            for slot in active_slots:
+                # the PRNG position of the (first) token this step
+                # samples: the request's generated-token index (re-binds
+                # every step so evicted/re-admitted requests resume their
+                # stream exactly; a speculative round advances it as
+                # gen_pos + i in-graph)
+                self.gen_pos[slot] = len(
+                    self.scheduler.running[slot].generated)
+            return (self._dev(c.block_tables), self._dev(c.valid),
+                    self._dev(c.pos), self._dev(c.lengths),
+                    self._dev(c.tokens), jnp.asarray(active),
+                    self._dev(self.samp_temp), self._dev(self.samp_top_p),
+                    self._dev(self.samp_top_k), self._dev(self.samp_seed),
+                    self._dev(self.gen_pos), self._adapters_args())
+
     def _decode_step(self) -> List[Tuple[int, int]]:
         c = self.cache
         active_slots = sorted(self.scheduler.running)
-        active = np.zeros((c.geom.num_slots,), bool)
-        active[active_slots] = True
-        for slot in active_slots:
-            # the PRNG position of the token this step samples: the
-            # request's generated-token index (re-binds every step so
-            # evicted/re-admitted requests resume their stream exactly)
-            self.gen_pos[slot] = len(self.scheduler.running[slot].generated)
-        if self._fault_device_error:
-            # injected BEFORE dispatch: no KV column was written, no
-            # token sampled — exactly the state a real dispatch failure
-            # leaves behind, so supervisor replay recomputes cleanly
-            self._fault_device_error = False
-            raise DeviceStepError(
-                "injected device error (fault plan engine_step)")
-        with annotate("serve_decode"):
-            self.cache.k_pages, self.cache.v_pages, packed = self._decode(
-                self.params, c.k_pages, c.v_pages,
-                self._dev(c.block_tables), self._dev(c.valid),
-                self._dev(c.pos), self._dev(c.lengths),
-                self._dev(c.tokens), jnp.asarray(active),
-                self._dev(self.samp_temp), self._dev(self.samp_top_p),
-                self._dev(self.samp_top_k), self._dev(self.samp_seed),
-                self._dev(self.gen_pos), self._adapters_args())
-            # dla: disable=host-sync-in-hot-loop -- the designed single D2H per decode step (execution-model invariant)
-            packed_np = np.asarray(packed)
-        toks_np = packed_np[0]
-        logps_np = packed_np[1].view(np.float32)
-        if self._fault_nan_logits:
-            # injected AFTER the fetch, where the real NaN guard below
-            # (_sample_host) and a device-side check would trip: the
-            # sampled tokens are garbage, so nothing is committed
-            self._fault_nan_logits = False
-            raise NaNLogitsError(
-                "injected non-finite logits (fault plan engine_step)")
-        t_done = self.now()
-        self.metrics.decode_steps.inc()
-        emitted: List[Tuple[int, int]] = []
-        for slot in active_slots:
-            req = self.scheduler.running[slot]
-            tok = int(toks_np[slot])
-            c.advance_slot(slot, tok)
-            self._emit(req, tok, t_done, emitted,
-                       logp=float(logps_np[slot]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the packed decode fetch
+        with self._decode_span(active_slots):
+            args = self._decode_args(active_slots)
+            if self._fault_device_error:
+                # injected BEFORE dispatch: no KV column was written, no
+                # token sampled — exactly the state a real dispatch
+                # failure leaves behind, so supervisor replay recomputes
+                # cleanly
+                self._fault_device_error = False
+                raise DeviceStepError(
+                    "injected device error (fault plan engine_step)")
+            with annotate("serve_decode_dispatch"):
+                c.k_pages, c.v_pages, packed = self._decode(
+                    self.params, c.k_pages, c.v_pages, *args)
+            with annotate("serve_decode_fetch"):
+                # dla: disable=host-sync-in-hot-loop -- the designed single D2H per decode step (execution-model invariant)
+                packed_np = np.asarray(packed)
+            toks_np = packed_np[0]
+            logps_np = packed_np[1].view(np.float32)
+            if self._fault_nan_logits:
+                # injected AFTER the fetch, where the real NaN guard below
+                # (_sample_host) and a device-side check would trip: the
+                # sampled tokens are garbage, so nothing is committed
+                self._fault_nan_logits = False
+                raise NaNLogitsError(
+                    "injected non-finite logits (fault plan engine_step)")
+            t_done = self.now()
+            self.metrics.decode_steps.inc()
+            emitted: List[Tuple[int, int]] = []
+            with annotate("serve_emit", slots=len(active_slots)):
+                for slot in active_slots:
+                    req = self.scheduler.running[slot]
+                    tok = int(toks_np[slot])
+                    c.advance_slot(slot, tok)
+                    self._emit(req, tok, t_done, emitted,
+                               logp=float(logps_np[slot]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the packed decode fetch
         return emitted
 
     def _spec_decode_step(self) -> List[Tuple[int, int]]:
@@ -1876,79 +1934,65 @@ class ServingEngine:
         c = self.cache
         k = self._spec_k
         active_slots = sorted(self.scheduler.running)
-        active = np.zeros((c.geom.num_slots,), bool)
-        active[active_slots] = True
-        for slot in active_slots:
-            # the PRNG position of the FIRST token this round samples:
-            # the request's generated-token index (re-binds every round
-            # so evicted/re-admitted requests resume their stream, and
-            # in-round positions advance as gen_pos + i in-graph)
-            self.gen_pos[slot] = len(self.scheduler.running[slot].generated)
-        if self._fault_device_error:
-            # injected BEFORE dispatch: no KV column written, no token
-            # sampled — the state a real dispatch failure leaves behind
-            self._fault_device_error = False
-            raise DeviceStepError(
-                "injected device error (fault plan engine_step)")
-        with annotate("serve_spec_decode"):
-            btab = self._dev(c.block_tables)
-            valid = self._dev(c.valid)
-            pos = self._dev(c.pos)
-            lengths = self._dev(c.lengths)
-            tokens = self._dev(c.tokens)
-            active_d = jnp.asarray(active)
-            temps = self._dev(self.samp_temp)
-            top_ps = self._dev(self.samp_top_p)
-            top_ks = self._dev(self.samp_top_k)
-            seeds = self._dev(self.samp_seed)
-            gpos = self._dev(self.gen_pos)
-            # draft and verify share one adapter view: the draft
-            # proposes under the SAME per-slot deltas the target
-            # verifies with, so per-tenant acceptance stays high
-            adapters = self._adapters_args()
-            c.k_pages, c.v_pages, proposals = self._spec_draft(
-                self.draft_params, c.k_pages, c.v_pages, btab, valid,
-                pos, lengths, tokens, active_d, temps, top_ps, top_ks,
-                seeds, gpos, adapters)
-            c.k_pages, c.v_pages, packed = self._spec_verify(
-                self.params, c.k_pages, c.v_pages, btab, valid, pos,
-                lengths, tokens, proposals, active_d, temps, top_ps,
-                top_ks, seeds, gpos, adapters)
-            # dla: disable=host-sync-in-hot-loop -- the designed single D2H per speculative round (proposals never leave the device)
-            packed_np = np.asarray(packed)
-        toks_np = packed_np[0]                        # [B, K+1]
-        logps_np = packed_np[1].view(np.float32)
-        acc_np = packed_np[2][:, 0]                   # [B] accepts 0..K
-        if self._fault_nan_logits:
-            # injected AFTER the fetch, where a real device-side NaN
-            # would surface: nothing was committed, replay is clean
-            self._fault_nan_logits = False
-            raise NaNLogitsError(
-                "injected non-finite logits (fault plan engine_step)")
-        t_done = self.now()
-        self.metrics.decode_steps.inc()
-        emitted: List[Tuple[int, int]] = []
-        for slot in active_slots:
-            req = self.scheduler.running[slot]
-            a = int(acc_np[slot])
-            self._spec_stats["rounds"] += 1
-            self._spec_stats["proposed"] += k
-            self._spec_stats["accepted"] += a
-            if a < k:
-                self._spec_stats["rollbacks"] += 1
-            # commit the accepted prefix: a+1 target samples (column
-            # lengths+j holds block token j's target KV; the emitted
-            # token becomes the next pending). EOS/length may finish
-            # the request mid-block — the tail accepts are dropped,
-            # exactly as the non-speculative engine would never have
-            # sampled past the terminal token.
-            for j in range(a + 1):
-                tok = int(toks_np[slot, j])
-                c.advance_slot(slot, tok)
-                self._emit(req, tok, t_done, emitted,
-                           logp=float(logps_np[slot, j]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the packed round fetch
-                if self.scheduler.running.get(slot) is not req:
-                    break
+        with self._decode_span(active_slots):
+            (btab, valid, pos, lengths, tokens, active_d, temps, top_ps,
+             top_ks, seeds, gpos, adapters) = self._decode_args(active_slots)
+            if self._fault_device_error:
+                # injected BEFORE dispatch: no KV column written, no token
+                # sampled — the state a real dispatch failure leaves behind
+                self._fault_device_error = False
+                raise DeviceStepError(
+                    "injected device error (fault plan engine_step)")
+            with annotate("serve_decode_dispatch"):
+                # draft and verify share one adapter view: the draft
+                # proposes under the SAME per-slot deltas the target
+                # verifies with, so per-tenant acceptance stays high
+                c.k_pages, c.v_pages, proposals = self._spec_draft(
+                    self.draft_params, c.k_pages, c.v_pages, btab, valid,
+                    pos, lengths, tokens, active_d, temps, top_ps, top_ks,
+                    seeds, gpos, adapters)
+                c.k_pages, c.v_pages, packed = self._spec_verify(
+                    self.params, c.k_pages, c.v_pages, btab, valid, pos,
+                    lengths, tokens, proposals, active_d, temps, top_ps,
+                    top_ks, seeds, gpos, adapters)
+            with annotate("serve_decode_fetch"):
+                # dla: disable=host-sync-in-hot-loop -- the designed single D2H per speculative round (proposals never leave the device)
+                packed_np = np.asarray(packed)
+            toks_np = packed_np[0]                        # [B, K+1]
+            logps_np = packed_np[1].view(np.float32)
+            acc_np = packed_np[2][:, 0]                   # [B] accepts 0..K
+            if self._fault_nan_logits:
+                # injected AFTER the fetch, where a real device-side NaN
+                # would surface: nothing was committed, replay is clean
+                self._fault_nan_logits = False
+                raise NaNLogitsError(
+                    "injected non-finite logits (fault plan engine_step)")
+            t_done = self.now()
+            self.metrics.decode_steps.inc()
+            emitted: List[Tuple[int, int]] = []
+            with annotate("serve_emit", slots=len(active_slots)):
+                for slot in active_slots:
+                    req = self.scheduler.running[slot]
+                    a = int(acc_np[slot])
+                    self._spec_stats["rounds"] += 1
+                    self._spec_stats["proposed"] += k
+                    self._spec_stats["accepted"] += a
+                    if a < k:
+                        self._spec_stats["rollbacks"] += 1
+                    # commit the accepted prefix: a+1 target samples
+                    # (column lengths+j holds block token j's target KV;
+                    # the emitted token becomes the next pending).
+                    # EOS/length may finish the request mid-block — the
+                    # tail accepts are dropped, exactly as the
+                    # non-speculative engine would never have sampled
+                    # past the terminal token.
+                    for j in range(a + 1):
+                        tok = int(toks_np[slot, j])
+                        c.advance_slot(slot, tok)
+                        self._emit(req, tok, t_done, emitted,
+                                   logp=float(logps_np[slot, j]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the packed round fetch
+                        if self.scheduler.running.get(slot) is not req:
+                            break
         return emitted
 
     def _mirror_spec_counters(self) -> None:
@@ -2007,6 +2051,7 @@ class ServingEngine:
             self.metrics.ttft_ms.record((t - req.arrival_time) * 1000.0)
             if ten is not None:
                 ten.on_ttft(req.tenant, (t - req.arrival_time) * 1000.0)
+            mark("serve_req_first_token", rid=req.rid)
             if traced:
                 self.tracer.async_instant(
                     "request", "first_token", req.rid, t=t,
@@ -2038,7 +2083,5 @@ class ServingEngine:
             status = "length"
         if ten is not None and status is not None:
             ten.on_finish(req.tenant)
-        if traced and status is not None:
-            self.tracer.async_end("request", "request", req.rid, t=t,
-                                  status=status,
-                                  tokens=len(req.generated))
+        if status is not None:
+            self._req_end(req, status, t=t)

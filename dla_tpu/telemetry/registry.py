@@ -161,6 +161,8 @@ CATALOG: Tuple[MetricSpec, ...] = (
        "batch reshape + host-to-device placement"),
     _s("telemetry/compute_ms", "gauge", "ms",
        "jitted step dispatch-to-sync (device compute)"),
+    _s("telemetry/metrics_fetch_ms", "gauge", "ms",
+       "step metrics crossing device-to-host (externally driven steps)"),
     _s("telemetry/checkpoint_stall_ms", "gauge", "ms",
        "step loop blocked on checkpointing"),
     _s("telemetry/logging_ms", "gauge", "ms", "metric emission"),

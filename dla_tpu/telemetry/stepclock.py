@@ -30,6 +30,7 @@ Usage (the trainer's fit loop)::
     clock.mark_compile()              # first step only
     with clock.segment("compute"):    loss = step(batch)
     clock.end_step(ok=True)
+    clock.count_tokens(n_tokens)      # feeds rates(): tokens_per_sec
     ...
     logger.log(clock.interval_metrics(), step)   # every log interval
 
@@ -40,14 +41,14 @@ from __future__ import annotations
 
 import time
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Callable, ContextManager, Dict, List, Optional
 
 from dla_tpu.telemetry.trace import Tracer, get_tracer
 
 #: Segment names a step decomposes into. "other" is derived (wall minus
 #: attributed), never passed to segment().
-SEGMENTS = ("data_wait", "h2d", "compute", "checkpoint_stall", "logging",
-            "eval")
+SEGMENTS = ("data_wait", "h2d", "compute", "metrics_fetch",
+            "checkpoint_stall", "logging", "eval")
 LOSS_KINDS = ("compile", "fault", "checkpoint", "elastic")
 
 
@@ -68,9 +69,15 @@ class StepClock:
     """
 
     def __init__(self, enabled: bool = True, now=time.perf_counter,
-                 tracer: Optional[Tracer] = None):
+                 tracer: Optional[Tracer] = None,
+                 span: Optional[Callable[[str], ContextManager]] = None):
         self.enabled = enabled
         self.now = now
+        # a second sink for every segment: ``span(name)`` is entered
+        # inside the timed region (the trainer hands the profiler's
+        # ``train_<segment>`` annotation, so segments sit on the device
+        # trace's clock too)
+        self.span = span
         # trace feed: each segment becomes a slice on the trainer thread,
         # each step a parent slice + goodput counter sample. The tracer
         # must share this clock's time base (both default perf_counter);
@@ -94,6 +101,10 @@ class StepClock:
         self.last_wall_ms = 0.0
         # interval window (reset by interval_metrics)
         self._win: List[Dict[str, float]] = []
+        # throughput since the first counted step (see count_tokens)
+        self._rate_t0: Optional[float] = None
+        self._rate_tokens = 0
+        self._rate_steps = 0
 
     # ------------------------------------------------------------- recording
 
@@ -107,7 +118,11 @@ class StepClock:
         self._ensure_started()
         t0 = self.now()
         try:
-            yield
+            if self.span is None:
+                yield
+            else:
+                with self.span(name):
+                    yield
         finally:
             t1 = self.now()
             self._seg_acc[name] = (self._seg_acc.get(name, 0.0)
@@ -177,6 +192,28 @@ class StepClock:
         self._seg_acc = {}
         self._compile_pending = False
 
+    def count_tokens(self, n_tokens: int) -> None:
+        """Count one completed step's tokens for :meth:`rates`. The first
+        call only starts the rate clock, so the first (compiling) step is
+        left out. Counts whether or not the clock is ``enabled``: the
+        log payload's throughput keys do not depend on telemetry."""
+        if self._rate_t0 is None:
+            self._rate_t0 = self.now()
+            return
+        self._rate_tokens += n_tokens
+        self._rate_steps += 1
+
+    def rates(self, chips: int) -> Dict[str, float]:
+        """Wall-clock throughput since the first counted step."""
+        if self._rate_t0 is None or not self._rate_steps:
+            return {"tokens_per_sec": 0.0, "ms_per_step": 0.0}
+        dt = self.now() - self._rate_t0
+        return {
+            "tokens_per_sec": self._rate_tokens / dt,
+            "tokens_per_sec_per_chip": self._rate_tokens / dt / chips,
+            "ms_per_step": 1000.0 * dt / self._rate_steps,
+        }
+
     def charge_external(self, kind: str, seconds: float) -> None:
         """Attribute wall time that happened OUTSIDE this step loop to
         one badput kind — the elastic detect → restart → resume gap
@@ -220,6 +257,7 @@ class StepClock:
             "telemetry/data_wait_ms": mean("data_wait"),
             "telemetry/h2d_ms": mean("h2d"),
             "telemetry/compute_ms": mean("compute"),
+            "telemetry/metrics_fetch_ms": mean("metrics_fetch"),
             "telemetry/checkpoint_stall_ms": mean("checkpoint_stall"),
             "telemetry/logging_ms": mean("logging"),
             "telemetry/eval_ms": mean("eval"),

@@ -39,6 +39,7 @@ and must never re-key the cache — mirroring jit's own cache key.
 from __future__ import annotations
 
 import dataclasses
+import re
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -156,6 +157,74 @@ def register_live_bytes_gauge(registry: MetricRegistry):
     return registry.func_gauge("telemetry/xla/live_bytes", live_array_bytes)
 
 
+_HLO_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+
+
+def hlo_scopes(hlo_text: str) -> Dict[str, str]:
+    """``Compiled.as_text()`` -> {instruction name: ``op_name``}: the name
+    stack JAX recorded for each instruction (``jit(_train_step)/optimizer/
+    mul``, ``.../transpose(jvp())/.../checkpoint/rematted_computation/
+    dot_general``). A profiler trace names a device event by its
+    instruction, so this map is what ties device time to ``jax.named_scope``
+    and to JAX's own forward / backward / remat frames. A fusion that
+    carries no ``op_name`` of its own takes the root's of the computation
+    it calls."""
+    scopes: Dict[str, str] = {}
+    roots: Dict[str, str] = {}             # computation -> its root's op_name
+    unnamed: List[Tuple[str, str]] = []    # (instruction, called computation)
+    computation = None
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None:
+                computation = c.group(1)
+            continue
+        op = _HLO_OP_NAME.search(line)
+        if op is not None:
+            scopes[m.group(2)] = op.group(1)
+            if m.group(1) and computation is not None:
+                roots[computation] = op.group(1)
+        else:
+            call = _HLO_CALLS.search(line)
+            if call is not None:
+                unnamed.append((m.group(2), call.group(1)))
+    for name, called in unnamed:
+        if called in roots:
+            scopes[name] = roots[called]
+    return scopes
+
+
+#: IntrospectedFunction name -> the executable it compiled last, and the
+#: (module name, scopes) read from it on first request
+_LATEST_COMPILED: Dict[str, Any] = {}
+_SCOPES: Dict[str, Tuple[Any, str, Dict[str, str]]] = {}
+
+
+def compiled_scopes(module_pattern: str) -> Dict[str, str]:
+    """:func:`hlo_scopes` of the program(s) this process compiled through
+    an :class:`IntrospectedFunction` whose HLO module name matches the
+    pattern (``jit__train_step``). Parsed on first request, never on the
+    step path; empty where nothing matches."""
+    rx = re.compile(module_pattern)
+    out: Dict[str, str] = {}
+    for name, compiled in _LATEST_COMPILED.items():
+        cached = _SCOPES.get(name)
+        if cached is None or cached[0] is not compiled:
+            text = compiled.as_text()
+            module = _HLO_MODULE.match(text)
+            cached = (compiled, module.group(1) if module else name,
+                      hlo_scopes(text))
+            _SCOPES[name] = cached
+        if rx.search(cached[1]):
+            out.update(cached[2])
+    return out
+
+
 @dataclasses.dataclass
 class _Entry:
     """One compiled specialization: the AOT executable + its analysis."""
@@ -222,6 +291,7 @@ class IntrospectedFunction:
         if is_recompile:
             self._emit_compile_event(fp, aot=True)
         compiled = self.jitted.lower(*args).compile()
+        _LATEST_COMPILED[self.name] = compiled    # for compiled_scopes()
         self.compiles += 1
         if not is_recompile and self.recorder is not None:
             # first compile is expected, not a recompile: ring event only

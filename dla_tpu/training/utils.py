@@ -1,8 +1,7 @@
-"""Shared training utilities: seeding, batch-identity checks, timing."""
+"""Shared training utilities: seeding, batch-identity checks."""
 from __future__ import annotations
 
 import random
-import time
 from typing import Any, Dict
 
 import jax
@@ -35,29 +34,3 @@ def check_batch_identity(opt_cfg: Dict[str, Any], dp_size: int) -> int:
             f"(micro {micro} x dp {dp_size} x accum {accum}) "
             f"!= configured total_batch_size {target}")
     return effective
-
-
-class StepTimer:
-    """Wall-clock tokens/sec tracking around the jitted step."""
-
-    def __init__(self):
-        self.t0 = None
-        self.tokens = 0
-        self.steps = 0
-
-    def tick(self, n_tokens: int) -> None:
-        if self.t0 is None:
-            self.t0 = time.perf_counter()  # start after first (compile) step
-            return
-        self.tokens += n_tokens
-        self.steps += 1
-
-    def rates(self) -> Dict[str, float]:
-        if not self.t0 or not self.steps:
-            return {"tokens_per_sec": 0.0, "ms_per_step": 0.0}
-        dt = time.perf_counter() - self.t0
-        return {
-            "tokens_per_sec": self.tokens / dt,
-            "tokens_per_sec_per_chip": self.tokens / dt / jax.device_count(),
-            "ms_per_step": 1000.0 * dt / self.steps,
-        }

@@ -23,6 +23,13 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 def enable_compile_cache() -> str:
     """Point the persistent compile cache at its directory and return
     that directory. Safe to call more than once."""
+    # An executable carries the op_name of each of its instructions (named
+    # scopes, JAX's jvp / transpose / remat frames), and a trace's device
+    # time is read through them (telemetry.xla_introspect.hlo_scopes). By
+    # default the cache key leaves that metadata out, so a run could be
+    # handed an executable another version of the code compiled, with that
+    # version's names: key on the metadata too.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -16,8 +16,10 @@ and "Race detection / sanitizers"). TPU-native replacement:
           start_step: 10             # first profiled step
           num_steps: 3               # how many steps to capture
 
-- **Step annotations**: every trainer step runs under
-  ``jax.profiler.StepTraceAnnotation`` so traces segment per-step.
+- **Spans on the profiler's clock**: ``annotate`` / ``step_annotation`` /
+  ``mark`` emit ``jax.profiler.TraceAnnotation`` events with arguments,
+  in the same xplane as the device ops. ``SPANS`` is the one table of
+  what the engine and the trainer emit (docs/OBSERVABILITY.md).
 
 - **Live profiler server**: ``hardware.profiler_port: 9999`` starts
   ``jax.profiler.start_server`` for on-demand capture from TensorBoard
@@ -33,10 +35,12 @@ and "Race detection / sanitizers"). TPU-native replacement:
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Dict, Optional
+import time
+from typing import Any, Dict, Optional, Tuple
 
 import jax
+
+from dla_tpu.telemetry.trace import get_tracer
 
 _SERVER = None  # keep a ref so the profiler server outlives the call
 
@@ -125,26 +129,92 @@ class ProfileWindow:
         self._done = True
 
 
-@contextlib.contextmanager
-def step_annotation(step: int, name: str = "train"):
-    """Per-step trace annotation; no-op cost when no trace is active.
-    ``name`` distinguishes loops sharing a trace ("train" vs the serving
-    engine's "serve"). Mirrors into the host tracer (telemetry.trace)
-    under the same name, so the host timeline lines up with XLA profiler
-    step windows — the span name is the constant ``<name>_step`` (one
-    Perfetto track row per loop) with the step number in args."""
-    from dla_tpu.telemetry.trace import get_tracer
-    with jax.profiler.StepTraceAnnotation(name, step_num=step):
-        with get_tracer().span(f"{name}_step", cat=name, step=int(step)):
-            yield
+#: Every span and mark the program emits on the profiler's clock:
+#: name -> (layer as PERF.md section 3 names it, argument names). Spans
+#: open through :class:`annotate` / :class:`step_annotation`, point events
+#: through :func:`mark`; names starting ``serve_req_`` are marks. PERF.md
+#: section 3 and docs/OBSERVABILITY.md mirror this table, and
+#: tests/test_profiler_spans.py holds the engine and the trainer to it.
+SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
+    # ---- serving: one engine step and its phases (serving/server.py)
+    "serve": ("engine host loop", ("step_num", "host_ns")),
+    "serve_schedule": ("scheduler", ()),
+    "serve_admit": ("scheduler", ("queued",)),
+    "serve_prefill": ("engine host loop", ("n", "width")),
+    "serve_prefill_chunk": ("engine host loop",
+                            ("rid", "slot", "start", "nvalid", "last")),
+    "serve_chunk_fetch": ("engine host loop", ("rid",)),
+    "serve_first_token": ("engine host loop", ("rid",)),
+    "serve_decode": ("KV pool", ("slots", "live_tokens", "read_tokens")),
+    "serve_decode_args": ("engine host loop", ()),
+    "serve_decode_dispatch": ("engine host loop", ()),
+    "serve_decode_fetch": ("engine host loop", ()),
+    "serve_emit": ("engine host loop", ("slots",)),
+    "serve_post": ("engine host loop", ()),
+    "serve_kv_export": ("KV pool", ("rid",)),
+    "serve_kv_import": ("KV pool", ("rid",)),
+    # ---- serving: the request lifecycle, one identifier (marks)
+    "serve_req_submit": ("scheduler", ("rid", "prompt_len", "max_new")),
+    "serve_req_admit": ("scheduler", ("rid", "slot", "cached_tokens")),
+    "serve_req_first_token": ("scheduler", ("rid",)),
+    "serve_req_finish": ("scheduler", ("rid", "status", "tokens")),
+    "serve_req_preempt": ("scheduler", ("rid",)),
+    # ---- training: the step span and one span per StepClock segment
+    "train": ("trainer", ("step_num", "host_ns")),
+    "train_dispatch": ("trainer", ()),
+    "train_loss_fetch": ("trainer", ()),
+    "train_guard_fetch": ("trainer", ()),
+    "train_data_wait": ("data", ()),
+    "train_h2d": ("trainer", ()),
+    "train_metrics_fetch": ("trainer", ()),
+    "train_logging": ("trainer", ()),
+    "train_eval": ("trainer", ()),
+    "train_checkpoint_stall": ("trainer", ()),
+}
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region for traces (host-side; device ops inside still fuse).
-    Mirrored into the host tracer so a region shows up both in the XLA
-    profile and the Chrome-trace dump."""
-    from dla_tpu.telemetry.trace import get_tracer
-    with jax.profiler.TraceAnnotation(name):
-        with get_tracer().span(name, cat="annotate"):
-            yield
+class annotate:
+    """Named host region on the profiler's clock (device ops inside still
+    fuse), mirrored into the host tracer so it shows up both in the XLA
+    profile and the Chrome-trace dump. ``args`` become the event's stats
+    in the xplane: integers and short strings already in hand, nothing
+    computed for the span's sake. Costs an atomic load and two Python
+    calls when no trace is active."""
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, name: str, **args):
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+        self._span = get_tracer().span(name, cat="annotate", **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
+class step_annotation(annotate):
+    """Per-step span. ``name`` distinguishes loops sharing a trace ("train"
+    vs the serving engine's "serve"). ``host_ns`` is ``perf_counter_ns``
+    at the span's start: the one stat that ties every ``perf_counter``
+    reading (the host tracer's, ``StepClock``'s, a harness's token times)
+    to the xplane's clock, by the nearest step's pair. The host tracer's
+    mirror is the constant ``<name>_step`` (one Perfetto track row per
+    loop) with the step number in args."""
+    __slots__ = ()
+
+    def __init__(self, step: int, name: str = "train", **args):
+        self._ann = jax.profiler.StepTraceAnnotation(
+            name, step_num=step, host_ns=time.perf_counter_ns(), **args)
+        self._span = get_tracer().span(f"{name}_step", cat=name,
+                                       step=int(step))
+
+
+def mark(name: str, **args) -> None:
+    """Point event on the profiler's clock: a zero-length annotation."""
+    with jax.profiler.TraceAnnotation(name, **args):
+        pass

@@ -13,6 +13,7 @@ completed step.
 import json
 import math
 import threading
+import time
 import urllib.request
 
 import jax
@@ -478,17 +479,45 @@ def test_collector_off_switch_disables_cleanly(mesh8, tmp_path):
         assert "train/param_norm" not in snap  # collector off too
 
 
+class _StallClock:
+    """``now`` for a trainer's StepClock under test: every reading
+    advances one millisecond, so compute, data wait and logging cost the
+    same however loaded the machine is, and the real seconds spent
+    inside ``Trainer.save`` (the only wall time the test below is about)
+    are added on top."""
+
+    def __init__(self, trainer):
+        self.t = 0.0
+        trainer.clock.now = self
+        save = trainer.save
+
+        def timed_save(*args, **kwargs):
+            t0 = time.perf_counter()
+            try:
+                return save(*args, **kwargs)
+            finally:
+                self.t += time.perf_counter() - t0
+        trainer.save = timed_save
+
+    def __call__(self):
+        self.t += 0.001
+        return self.t
+
+
 def test_goodput_falls_under_injected_checkpoint_stall(mesh8, tmp_path,
                                                        monkeypatch):
     """Pin (a): an io_error injected via DLA_FAULT_PLAN makes the
     background checkpoint writer retry with backoff; the NEXT save's
     backpressure wait shows up as checkpoint_stall and drags goodput
-    down vs the fault-free run."""
+    down vs the fault-free run. Asserted on an injected clock
+    (_StallClock): under six test workers the steps' own wall time
+    swamped a 0.4 s stall."""
     with jax.sharding.set_mesh(mesh8):
         monkeypatch.delenv(ENV_VAR, raising=False)
         clean = _make_trainer(mesh8, tmp_path / "clean", max_steps=6,
                               save_every=2,
                               resilience={"async_checkpointing": True})
+        _StallClock(clean)
         it = BatchIter()
         clean.fit(it, rng=jax.random.key(0), data_state=it.state_dict)
         clean.checkpointer.wait()
@@ -498,6 +527,7 @@ def test_goodput_falls_under_injected_checkpoint_stall(mesh8, tmp_path,
             mesh8, tmp_path / "stalled", max_steps=6, save_every=2,
             resilience={"async_checkpointing": True, "save_retries": 3,
                         "retry_backoff_s": 0.4})
+        _StallClock(tr)
         it2 = BatchIter()
         tr.fit(it2, rng=jax.random.key(0), data_state=it2.state_dict)
         tr.checkpointer.wait()
