@@ -156,18 +156,27 @@ def filter_logits_per_row(
 ) -> jnp.ndarray:
     """Temperature/top-k/top-p filtering with PER-ROW traced parameters.
 
-    One descending argsort serves both filters: top-k keeps sorted rank
+    One descending sort serves both filters: top-k keeps sorted rank
     < k, top-p then keeps the smallest prefix of the top-k-renormalized
     distribution reaching p (the same ``(cum - probs) < p`` rule — and the
     same k-then-p composition — as the static ``top_k_mask``/``top_p_mask``
     pipeline). Traced k and p mean every request in a decode batch can
     carry its own knobs without retracing — the decode compile count stays
     pinned at 1.
+
+    Both sorts carry their payload: the first yields the sorted values
+    with the permutation (a stable ascending sort, reversed — the order
+    of ``argsort(x)[..., ::-1]``, ties included), the second, keyed on
+    that permutation, brings the keep mask back to vocabulary order. A
+    ``take_along_axis`` over [B, V] instead is a scalar-indexed gather
+    of B*V elements, several times a sort's cost on the TPU.
     """
     x = logits.astype(jnp.float32) / jnp.maximum(temps, 1e-6)[:, None]
     v = x.shape[-1]
-    sort_idx = jnp.argsort(x, axis=-1)[..., ::-1]
-    sorted_x = jnp.take_along_axis(x, sort_idx, axis=-1)
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    sorted_x, sort_idx = jax.lax.sort((x, iota), dimension=1,
+                                      is_stable=True, num_keys=1)
+    sorted_x, sort_idx = sorted_x[:, ::-1], sort_idx[:, ::-1]
     ranks = jnp.arange(v, dtype=jnp.int32)[None, :]
     keep_k = (ranks < top_ks[:, None]) | (top_ks[:, None] <= 0)
     sorted_probs = jax.nn.softmax(jnp.where(keep_k, sorted_x, NEG_INF),
@@ -175,8 +184,8 @@ def filter_logits_per_row(
     cum = jnp.cumsum(sorted_probs, axis=-1)
     keep_p = (cum - sorted_probs) < top_ps[:, None]
     keep_sorted = keep_p & keep_k
-    inv = jnp.argsort(sort_idx, axis=-1)
-    keep = jnp.take_along_axis(keep_sorted, inv, axis=-1)
+    _, keep = jax.lax.sort((sort_idx, keep_sorted), dimension=1,
+                           is_stable=False, num_keys=1)
     return jnp.where(keep, x, NEG_INF)
 
 
@@ -198,19 +207,32 @@ def sample_token_per_row(
     filtered/tempered one — so greedy logps match a recomputed forward
     pass and the values are usable as behavior-policy logps downstream.
 
+    The vocabulary-wide filter and draw run only for a batch that holds
+    a sampling row: one ``lax.cond`` on ``any(temps > 0)``, a traced
+    predicate (no host sync, one program), whose other branch is the
+    arg-max alone. Callers zero the temperature of rows that are not
+    running, keep this call out of ``vmap`` (there a cond lowers to a
+    select and both branches run) and inside a ``jit`` (bound eagerly,
+    the cond compiles at every call).
+
     Returns ``(tokens [B] int32, logps [B] float32)``.
     """
     raw = logits.astype(jnp.float32)
     logp_all = jax.nn.log_softmax(raw, axis=-1)
-    filt = filter_logits_per_row(raw, temps, top_ps, top_ks)
 
-    def draw(seed, position, row):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), position)
-        return jax.random.categorical(key, row)
+    greedy = jnp.argmax(raw, axis=-1).astype(jnp.int32)
 
-    sampled = jax.vmap(draw)(seeds, positions, filt)
-    greedy = jnp.argmax(raw, axis=-1)
-    tok = jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
+    def draw_rows():
+        filt = filter_logits_per_row(raw, temps, top_ps, top_ks)
+
+        def draw(seed, position, row):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), position)
+            return jax.random.categorical(key, row)
+
+        sampled = jax.vmap(draw)(seeds, positions, filt)
+        return jnp.where(temps <= 0.0, greedy, sampled.astype(jnp.int32))
+
+    tok = jax.lax.cond(jnp.any(temps > 0.0), draw_rows, lambda: greedy)
     logp = jnp.take_along_axis(logp_all, tok[:, None], axis=-1)[:, 0]
     return tok, logp
 

@@ -45,6 +45,10 @@ class ServingMetrics:
         self.requests_cancelled = r.counter("serving/requests_cancelled")
         self.preemptions = r.counter("serving/preemptions")
         self.decode_steps = r.counter("serving/decode_steps")
+        # decode steps with a running slot of temperature > 0: the steps
+        # whose sampler filtered and drew instead of taking the arg-max
+        self.decode_steps_sampled = r.counter(
+            "serving/decode_steps_sampled")
         self.prefill_batches = r.counter("serving/prefill_batches")
         self.tokens_generated = r.counter("serving/tokens_generated")
         self.prefix_lookups = r.counter("serving/prefix_cache/lookups")
@@ -99,6 +103,8 @@ class ServingMetrics:
                 self.requests_cancelled.value),
             "serving/preemptions": float(self.preemptions.value),
             "serving/decode_steps": float(self.decode_steps.value),
+            "serving/decode_steps_sampled": float(
+                self.decode_steps_sampled.value),
             "serving/prefill_batches": float(self.prefill_batches.value),
             "serving/tokens_generated": float(self.tokens_generated.value),
             "serving/prefix_cache/lookups": float(

@@ -85,6 +85,11 @@ from dla_tpu.telemetry.xla_introspect import (
 from dla_tpu.utils.profiling import (
     ProfileWindow, annotate, mark, step_annotation)
 
+#: ``_sample_host``'s sampler: one program per prefill-batch shape, shared
+#: by every engine of the process (called eagerly, the sampler's
+#: ``lax.cond`` would compile anew at every call)
+_sample_rows_jit = jax.jit(sample_token_per_row)
+
 
 @dataclasses.dataclass(frozen=True)
 class ServingConfig:
@@ -607,8 +612,11 @@ class ServingEngine:
                 "lengths": lengths}
         logits, k_cols, v_cols = self.model.decode_step_paged(
             params, view, tokens, adapters=adapters)
+        # a free slot keeps its last request's temperature: zeroed, so
+        # only running rows decide whether the step filters and draws
         new_tok, logp = sample_token_per_row(
-            seeds, gen_pos, logits, temps, top_ps, top_ks)
+            seeds, gen_pos, logits, jnp.where(active, temps, 0.0),
+            top_ps, top_ks)
         new_tok = jnp.where(active, new_tok, 0)
         logp = jnp.where(active, logp, 0.0)
         # scatter this step's KV column: physical (page, offset) of each
@@ -649,6 +657,7 @@ class ServingEngine:
         b = geom.num_slots
         sw = geom.slot_window
         col_ids = jnp.arange(sw, dtype=jnp.int32)[None, :]
+        temps = jnp.where(active, temps, 0.0)   # as in _decode_fn
 
         def draft_step(carry, i):
             cur, valid_c, pos_c, kp, vp = carry
@@ -723,7 +732,8 @@ class ServingEngine:
         logits, k_cols, v_cols = self.model.decode_block_paged(
             params, view, block, adapters=adapters)
         toks, logps = sample_token_block(
-            seeds, gen_pos, logits, temps, top_ps, top_ks)
+            seeds, gen_pos, logits, jnp.where(active, temps, 0.0),
+            top_ps, top_ks)                     # as in _decode_fn
         toks = jnp.where(active[:, None], toks, 0)
         logps = jnp.where(active[:, None], logps, 0.0)
         accept = toks[:, :self._spec_k] == proposals
@@ -1820,7 +1830,7 @@ class ServingEngine:
     def _sample_host(self, logits: np.ndarray, reqs: List[Request]):
         """Sample each request's next token from its prefill logits row —
         the EXACT per-row rule the decode step runs (same fold_in(seed,
-        token-index) keying, same filters), eager jax once per prefill
+        token-index) keying, same filters), one jitted call per prefill
         batch, off the hot loop. The token index is len(generated), so
         an eviction/replay re-prefill resumes the same stream. Returns
         (tokens, logps) host arrays."""
@@ -1837,25 +1847,34 @@ class ServingEngine:
         temps = np.array([sp.effective_temperature for sp in sps], np.float32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
         top_ps = np.array([sp.top_p for sp in sps], np.float32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
         top_ks = np.array([sp.top_k for sp in sps], np.int32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
-        toks, lps = sample_token_per_row(
+        toks, lps = _sample_rows_jit(
             jnp.asarray(seeds), jnp.asarray(gpos), jnp.asarray(logits),
             jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks))
         # dla: disable=host-sync-in-hot-loop -- prefill sample fetch: one D2H per admitted batch
         return np.asarray(toks), np.asarray(lps)
 
-    def _decode_span(self, active_slots: List[int]) -> annotate:
+    def _decode_span(self, active_slots: List[int],
+                     sampling_slots: int) -> annotate:
         """The decode phase's span. Its arguments are the KV read as the
         host knows it on entry: running slots, the tokens they hold, and
         the columns the step's gathers read (every slot's whole window,
         once per forward of the round, whatever the fill: a constant of
-        the geometry until the read is bounded by the fill)."""
+        the geometry until the read is bounded by the fill); and how
+        many of the running slots sample."""
         geom = self.cache.geom
         return annotate(
             "serve_decode", slots=len(active_slots),
             # dla: disable=host-sync-in-hot-loop -- host numpy mirror of the slot lengths, no device fetch
             live_tokens=int(self.cache.lengths[active_slots].sum()),
             read_tokens=(self._spec_k + 1) * geom.num_slots
-            * geom.slot_window)
+            * geom.slot_window,
+            sampling_slots=sampling_slots)
+
+    def _sampling_slots(self, active_slots: List[int]) -> int:
+        """Running slots whose request samples (temperature > 0): with
+        none, the decode program's sampler takes its arg-max branch."""
+        # dla: disable=host-sync-in-hot-loop -- host numpy mirror of the slots' temperatures, no device fetch
+        return int(np.count_nonzero(self.samp_temp[active_slots] > 0.0))
 
     def _decode_args(self, active_slots: List[int]) -> tuple:
         """Everything a decode dispatch takes after the pool, built and
@@ -1882,7 +1901,8 @@ class ServingEngine:
     def _decode_step(self) -> List[Tuple[int, int]]:
         c = self.cache
         active_slots = sorted(self.scheduler.running)
-        with self._decode_span(active_slots):
+        sampling_slots = self._sampling_slots(active_slots)
+        with self._decode_span(active_slots, sampling_slots):
             args = self._decode_args(active_slots)
             if self._fault_device_error:
                 # injected BEFORE dispatch: no KV column was written, no
@@ -1909,6 +1929,8 @@ class ServingEngine:
                     "injected non-finite logits (fault plan engine_step)")
             t_done = self.now()
             self.metrics.decode_steps.inc()
+            if sampling_slots:
+                self.metrics.decode_steps_sampled.inc()
             emitted: List[Tuple[int, int]] = []
             with annotate("serve_emit", slots=len(active_slots)):
                 for slot in active_slots:
@@ -1934,7 +1956,8 @@ class ServingEngine:
         c = self.cache
         k = self._spec_k
         active_slots = sorted(self.scheduler.running)
-        with self._decode_span(active_slots):
+        sampling_slots = self._sampling_slots(active_slots)
+        with self._decode_span(active_slots, sampling_slots):
             (btab, valid, pos, lengths, tokens, active_d, temps, top_ps,
              top_ks, seeds, gpos, adapters) = self._decode_args(active_slots)
             if self._fault_device_error:
@@ -1969,6 +1992,8 @@ class ServingEngine:
                     "injected non-finite logits (fault plan engine_step)")
             t_done = self.now()
             self.metrics.decode_steps.inc()
+            if sampling_slots:
+                self.metrics.decode_steps_sampled.inc()
             emitted: List[Tuple[int, int]] = []
             with annotate("serve_emit", slots=len(active_slots)):
                 for slot in active_slots:

@@ -233,6 +233,9 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/preemptions", "counter", "evictions",
        "page-pool OOM evictions", "step"),
     _s("serving/decode_steps", "counter", "steps", "", "step"),
+    _s("serving/decode_steps_sampled", "counter", "steps",
+       "decode steps with a running slot of temperature > 0 (the "
+       "sampler's filter-and-draw branch)", "step"),
     _s("serving/prefill_batches", "counter", "batches", "", "step"),
     _s("serving/tokens_generated", "counter", "tokens", "", "step"),
     _s("serving/ttft_ms", "histogram", "ms",

@@ -145,7 +145,8 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
                             ("rid", "slot", "start", "nvalid", "last")),
     "serve_chunk_fetch": ("engine host loop", ("rid",)),
     "serve_first_token": ("engine host loop", ("rid",)),
-    "serve_decode": ("KV pool", ("slots", "live_tokens", "read_tokens")),
+    "serve_decode": ("KV pool", ("slots", "live_tokens", "read_tokens",
+                                 "sampling_slots")),
     "serve_decode_args": ("engine host loop", ()),
     "serve_decode_dispatch": ("engine host loop", ()),
     "serve_decode_fetch": ("engine host loop", ()),

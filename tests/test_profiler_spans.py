@@ -170,6 +170,8 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
         assert phase[1]["read_tokens"] == reads
         assert phase[1]["live_tokens"] == held
         assert 1 <= phase[1]["slots"] <= geom.num_slots
+        assert phase[1]["sampling_slots"] == 0      # greedy requests
+    assert eng.metrics.decode_steps_sampled.value == 0
 
     # each request: submit -> admit -> first_token -> finish, in time order
     for rid in rids:
