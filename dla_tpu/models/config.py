@@ -142,6 +142,41 @@ class ModelConfig:
     moe_group_size: int = 512
     moe_aux_weight: float = 0.01      # switch load-balance loss weight
     moe_z_weight: float = 0.001       # router z-loss weight
+    # width of one routed (and one shared) expert where it differs from
+    # the dense width (HF ``moe_intermediate_size``); 0 = intermediate_size
+    moe_intermediate_size: int = 0
+    # shared experts: a dense gated MLP of width num_shared_experts *
+    # expert width that every token passes beside its routed experts
+    num_shared_experts: int = 0
+    # multiplies the renormalised top-k router weights (HF
+    # ``routed_scaling_factor``)
+    moe_routed_scale: float = 1.0
+    # Expert parallelism as one chip sees it: this process holds the
+    # routed experts [moe_first_expert, moe_first_expert +
+    # moe_experts_held) of num_experts (0 held = all of them). The router
+    # scores all num_experts; a choice that lands on an expert held
+    # elsewhere adds nothing here (its owner adds it: the per-shard body
+    # of the `expert` mesh axis, run without the exchange on one chip).
+    moe_first_expert: int = 0
+    moe_experts_held: int = 0
+    # Multi-head latent attention (DeepSeek-V2 / mistral4): kv_lora_rank
+    # > 0 makes the attention latent — queries through a q_lora_rank
+    # bottleneck (0 = a plain wq), keys and values expanded from one
+    # normalised kv_lora_rank latent per token, heads of qk_nope_head_dim
+    # unrotated + qk_rope_head_dim rotated dims (one rotated key shared
+    # by all heads) and v_head_dim values. The cached row is
+    # [latent | rotated key]: kv_lora_rank + qk_rope_head_dim numbers a
+    # token a layer. A property of the attention, not an `arch`: the
+    # block stays llama-shaped.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # rotary pairing: False = split halves (x[i], x[i + d/2]), the
+    # llama convention; True = adjacent dims (x[2i], x[2i+1]) (HF
+    # ``rope_interleave``)
+    rope_interleave: bool = False
     # LoRA (the reference's model.lora block, advertised but never wired —
     # reference base_model.py:45-49 dead code, SURVEY.md sec 2.5; here it
     # is functional). lora_r == 0 disables. Adapters are a separate
@@ -152,6 +187,21 @@ class ModelConfig:
     lora_targets: tuple = ("wq", "wk", "wv", "wo")
 
     def __post_init__(self):
+        if self.latent_attention:
+            if self.arch != "llama":
+                raise ValueError(
+                    "latent attention (kv_lora_rank > 0) is implemented "
+                    f"for the llama block only, not arch='{self.arch}'")
+            if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
+                   self.v_head_dim) <= 0 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs qk_nope_head_dim, v_head_dim "
+                    "and an even qk_rope_head_dim")
+            if (self.kv_cache_dtype != "bfloat16" or self.attention_bias
+                    or self.sliding_window or self.lora_r > 0):
+                raise ValueError(
+                    "latent attention runs without int8 KV, projection "
+                    "biases, a sliding window or LoRA adapters")
         if self.kv_cache_dtype not in ("bfloat16", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be 'bfloat16' or 'int8', got "
@@ -167,6 +217,12 @@ class ModelConfig:
                 raise ValueError(
                     f"MoE (num_experts={self.num_experts}) is implemented "
                     f"for the llama block only, not arch='{self.arch}'")
+            if not (0 <= self.moe_first_expert and self.moe_first_expert
+                    + self.experts_held_ <= self.num_experts):
+                raise ValueError(
+                    f"held experts [{self.moe_first_expert}, "
+                    f"{self.moe_first_expert + self.experts_held_}) lie "
+                    f"outside the {self.num_experts} the router scores")
             if self.lora_r > 0:
                 ffn = {"w_gate", "w_up", "w_down", "fc1", "fc2"}
                 bad = ffn & set(self.lora_targets)
@@ -177,12 +233,28 @@ class ModelConfig:
                         f"lora_targets to attention projections")
 
     @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def head_dim_(self) -> int:
+        if self.latent_attention:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def expert_width_(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def experts_held_(self) -> int:
+        return self.moe_experts_held or self.num_experts
 
     @property
     def rotary_dim_(self) -> int:
         """Rotated slice of each head; even, as rotate_half requires."""
+        if self.latent_attention:
+            return self.qk_rope_head_dim
         rd = int(self.head_dim_ * self.rotary_pct)
         rd -= rd % 2
         if rd <= 0:
@@ -333,6 +405,31 @@ register_model("tiny-moe", ModelConfig(
     vocab_size=512, hidden_size=64, intermediate_size=128,
     num_layers=2, num_heads=4, num_kv_heads=2, max_seq_length=256,
     num_experts=4, num_experts_per_token=2,
+    param_dtype="float32", dtype="float32", remat="none"))
+# latent attention + routed experts top-2 of 8 + one shared expert, YaRN
+# over a 16-token original context with the position-dependent query
+# scale: the mistral4 block at toy widths. moe_capacity_factor = E / k
+# gives every expert room for every token, so the capacity dispatch of
+# the full-sequence path drops nothing and apply() can be held to the
+# dropless reference.
+register_model("tiny-mla-moe", ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=192,
+    num_layers=2, num_heads=4, num_kv_heads=4, max_seq_length=256,
+    q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+    rope_scaling={"rope_type": "yarn", "factor": 4.0, "beta_fast": 32,
+                  "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                  "original_max_position_embeddings": 16,
+                  "llama_4_scaling_beta": 0.1},
+    num_experts=8, num_experts_per_token=2, moe_intermediate_size=32,
+    num_shared_experts=1, moe_capacity_factor=4.0,
+    param_dtype="float32", dtype="float32", remat="none"))
+# the same attention over a dense MLP: the latent pool without experts
+register_model("tiny-mla", ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=192,
+    num_layers=2, num_heads=4, num_kv_heads=4, max_seq_length=256,
+    q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
     param_dtype="float32", dtype="float32", remat="none"))
 
 # HF repo-id aliases so reference configs keep working verbatim

@@ -29,7 +29,10 @@ def _validated_rope_scaling(hf_cfg):
     YaRN dicts omitting original_max_position_embeddings get the
     checkpoint's max_position_embeddings injected — HF's own fallback,
     which ops/rotary cannot see from inside the op."""
-    rs = validate_rope_scaling(hf_cfg.get("rope_scaling"))
+    # newer configs (mistral4) carry the dict as ``rope_parameters``,
+    # with the base frequency inside it
+    rs = validate_rope_scaling(hf_cfg.get("rope_scaling")
+                               or hf_cfg.get("rope_parameters"))
     rope_type = rs and rs["rope_type"]  # normalized by validate
     if (rope_type == "yarn"
             and "original_max_position_embeddings" not in rs
@@ -68,6 +71,8 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
     if model_type == "phi":
         return _phi_config(hf_cfg, overrides)
     n_heads = int(hf_cfg["num_attention_heads"])
+    rope_theta = hf_cfg.get("rope_theta") or (
+        hf_cfg.get("rope_parameters") or {}).get("rope_theta", 10000.0)
     fields = dict(
         vocab_size=int(hf_cfg["vocab_size"]),
         hidden_size=int(hf_cfg["hidden_size"]),
@@ -76,7 +81,7 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
         num_heads=n_heads,
         num_kv_heads=int(hf_cfg.get("num_key_value_heads", n_heads)),
         head_dim=hf_cfg.get("head_dim"),
-        rope_theta=float(hf_cfg.get("rope_theta", 10000.0)),
+        rope_theta=float(rope_theta),
         rms_norm_eps=float(hf_cfg.get("rms_norm_eps", 1e-5)),
         tie_embeddings=bool(hf_cfg.get("tie_word_embeddings", False)),
         max_seq_length=int(hf_cfg.get("max_position_embeddings", 4096)),
@@ -115,6 +120,17 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
         if hf_cfg.get("sliding_window"):
             fields["sliding_window"] = int(hf_cfg["sliding_window"])
             fields["sliding_window_pattern"] = 2
+    if hf_cfg.get("kv_lora_rank"):
+        # latent attention (mistral4; the DeepSeek-V2 key names)
+        fields.update(
+            q_lora_rank=int(hf_cfg.get("q_lora_rank") or 0),
+            kv_lora_rank=int(hf_cfg["kv_lora_rank"]),
+            qk_nope_head_dim=int(hf_cfg["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(hf_cfg["qk_rope_head_dim"]),
+            v_head_dim=int(hf_cfg["v_head_dim"]),
+            rope_interleave=bool(hf_cfg.get("rope_interleave", False)))
+    if hf_cfg.get("n_routed_experts"):
+        fields.update(_routed_experts(hf_cfg))
     if model_type == "mixtral" or "num_local_experts" in hf_cfg:
         fields["num_experts"] = int(hf_cfg.get("num_local_experts", 8))
         fields["num_experts_per_token"] = int(
@@ -154,6 +170,41 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
                 "sliding_window here is all-layers")
     fields.update(overrides)
     return ModelConfig(**fields)
+
+
+def _routed_experts(hf_cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The DeepSeek-lineage expert keys (mistral4): ``n_routed_experts``
+    counts the experts whose weights THIS process holds; a file that
+    stands for one chip of an expert-parallel deployment states the
+    router's width as ``experts_published`` and the held ids as
+    ``experts_held`` = [first, last]. What ops/moe.py does not compute is
+    refused, not approximated."""
+    if int(hf_cfg.get("first_k_dense_replace") or 0):
+        raise NotImplementedError(
+            "leading dense layers (first_k_dense_replace > 0) need a "
+            "per-layer layer spec; every layer here is an expert layer")
+    if int(hf_cfg.get("n_group") or 1) != 1 or \
+            int(hf_cfg.get("topk_group") or 1) != 1:
+        raise NotImplementedError("group-limited routing (n_group > 1)")
+    if str(hf_cfg.get("scoring_func") or "softmax") != "softmax":
+        raise NotImplementedError(
+            "only the softmax router is implemented (no sigmoid scores, "
+            "no correction bias)")
+    if not hf_cfg.get("norm_topk_prob", True):
+        raise NotImplementedError(
+            "router weights are renormalised over the chosen experts "
+            "(norm_topk_prob false is not implemented)")
+    held = int(hf_cfg["n_routed_experts"])
+    published = int(hf_cfg.get("experts_published") or held)
+    first = int((hf_cfg.get("experts_held") or [0])[0])
+    return dict(
+        num_experts=published,
+        moe_experts_held=0 if held == published else held,
+        moe_first_expert=first,
+        num_experts_per_token=int(hf_cfg["num_experts_per_tok"]),
+        moe_intermediate_size=int(hf_cfg["moe_intermediate_size"]),
+        num_shared_experts=int(hf_cfg.get("n_shared_experts") or 0),
+        moe_routed_scale=float(hf_cfg.get("routed_scaling_factor") or 1.0))
 
 
 def _phi_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:

@@ -22,6 +22,7 @@ Replaces the reference's HF ``AutoModelForCausalLM`` wrapper
 """
 from __future__ import annotations
 
+import math
 import sys
 from typing import Any, Dict, Optional, Tuple
 
@@ -32,12 +33,17 @@ from jax.sharding import PartitionSpec as P
 from dla_tpu.models.config import ModelConfig
 from dla_tpu.parallel.mesh import auto_axes
 from dla_tpu.ops.attention import (
+    block_decode_attention,
     causal_attention,
     chunked_causal_attention,
     decode_attention,
 )
 from dla_tpu.ops.norms import layer_norm, rms_norm
-from dla_tpu.ops.rotary import apply_rotary, rotary_angles
+from dla_tpu.ops.rotary import (
+    apply_rotary,
+    position_query_scale,
+    rotary_angles,
+)
 
 Params = Dict[str, Any]
 
@@ -128,6 +134,16 @@ class Transformer:
         self._softmax_scale = (
             cfg.query_pre_attn_scalar ** -0.5
             if cfg.query_pre_attn_scalar else None)
+        if cfg.latent_attention:
+            # YaRN's temperature on a latent head (the DeepSeek-V2
+            # lineage's convention): qk_head_dim**-0.5 * m**2 with
+            # m = 0.1 * mscale_all_dim * ln(factor) + 1
+            rs = cfg.rope_scaling or {}
+            factor = float(rs.get("factor") or 1.0)
+            m_all = float(rs.get("mscale_all_dim") or 0.0)
+            m = 0.1 * m_all * math.log(factor) + 1.0 \
+                if m_all and factor > 1.0 else 1.0
+            self._softmax_scale = cfg.head_dim_ ** -0.5 * m * m
 
     # ------------------------------------------------------- storage layout
 
@@ -259,27 +275,59 @@ class Transformer:
                     (cfg.vocab_size,), self.pdtype)
             return params
         if cfg.num_experts > 0:
-            E = cfg.num_experts
+            # the router scores every expert; the weights are those of
+            # the experts held here (all of them unless the config says)
+            E, EH, F = cfg.num_experts, cfg.experts_held_, cfg.expert_width_
             mlp = {
                 "router": mat(jax.random.fold_in(rng, 7), (L, D, E), std),
-                "w_gate": mat(keys[5], (L, E, D, F), std),
-                "w_up": mat(keys[6], (L, E, D, F), std),
-                "w_down": mat(keys[7], (L, E, F, D), out_std),
+                "w_gate": mat(keys[5], (L, EH, D, F), std),
+                "w_up": mat(keys[6], (L, EH, D, F), std),
+                "w_down": mat(keys[7], (L, EH, F, D), out_std),
             }
+            if cfg.num_shared_experts:
+                FS = cfg.num_shared_experts * F
+                mlp["ws_gate"] = mat(
+                    jax.random.fold_in(rng, 11), (L, D, FS), std)
+                mlp["ws_up"] = mat(
+                    jax.random.fold_in(rng, 12), (L, D, FS), std)
+                mlp["ws_down"] = mat(
+                    jax.random.fold_in(rng, 13), (L, FS, D), out_std)
         else:
             mlp = {
                 "w_gate": mat(keys[5], (L, D, F), std),
                 "w_up": mat(keys[6], (L, D, F), std),
                 "w_down": mat(keys[7], (L, F, D), out_std),
             }
-        params: Params = {
-            "embed": {"embedding": mat(keys[0], (cfg.vocab_size, D), std)},
-            "layers": {
-                "attn_norm": jnp.ones((L, D), self.pdtype),
+        if cfg.latent_attention:
+            H, r = cfg.num_heads, cfg.kv_lora_rank
+            nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                              cfg.v_head_dim)
+            attn = {
+                "wkv_a": mat(keys[2], (L, D, r + rope), std),
+                "kv_norm": jnp.ones((L, r), self.pdtype),
+                # per head [k_nope | v], heads outermost
+                "wkv_b": mat(keys[3], (L, r, H * (nope + vd)), std),
+                "wo": mat(keys[4], (L, H * vd, D), out_std),
+            }
+            if cfg.q_lora_rank:
+                attn["wq_a"] = mat(keys[1], (L, D, cfg.q_lora_rank), std)
+                attn["q_norm"] = jnp.ones((L, cfg.q_lora_rank), self.pdtype)
+                attn["wq_b"] = mat(jax.random.fold_in(rng, 14),
+                                   (L, cfg.q_lora_rank, qdim), std)
+            else:
+                attn["wq"] = mat(keys[1], (L, D, qdim), std)
+        else:
+            attn = {
                 "wq": mat(keys[1], (L, D, qdim), std),
                 "wk": mat(keys[2], (L, D, kvdim), std),
                 "wv": mat(keys[3], (L, D, kvdim), std),
                 "wo": mat(keys[4], (L, qdim, D), out_std),
+            }
+        params: Params = {
+            "embed": {"embedding": mat(keys[0], (cfg.vocab_size, D), std)},
+            "layers": {
+                "attn_norm": jnp.ones((L, D), self.pdtype),
+                **attn,
                 "mlp_norm": jnp.ones((L, D), self.pdtype),
                 **mlp,
             },
@@ -470,20 +518,43 @@ class Transformer:
                 "w_up": P("stage", "expert", "fsdp", "model"),
                 "w_down": P("stage", "expert", "model", "fsdp"),
             }
+            if self.cfg.num_shared_experts:
+                mlp_specs["ws_gate"] = P("stage", "fsdp", "model")
+                mlp_specs["ws_up"] = P("stage", "fsdp", "model")
+                mlp_specs["ws_down"] = P("stage", "model", "fsdp")
         else:
             mlp_specs = {
                 "w_gate": P("stage", "fsdp", "model"),
                 "w_up": P("stage", "fsdp", "model"),
                 "w_down": P("stage", "model", "fsdp"),
             }
-        specs: Params = {
-            "embed": {"embedding": P("fsdp", None)},
-            "layers": {
-                "attn_norm": P("stage", None),
+        if self.cfg.latent_attention:
+            # the bottlenecks are replicated over `model`; the per-head
+            # up-projections shard their head dim like wq / wo
+            attn_specs = {
+                "wkv_a": P("stage", "fsdp", None),
+                "kv_norm": P("stage", None),
+                "wkv_b": P("stage", None, "model"),
+                "wo": P("stage", "model", "fsdp"),
+            }
+            if self.cfg.q_lora_rank:
+                attn_specs["wq_a"] = P("stage", "fsdp", None)
+                attn_specs["q_norm"] = P("stage", None)
+                attn_specs["wq_b"] = P("stage", None, "model")
+            else:
+                attn_specs["wq"] = P("stage", "fsdp", "model")
+        else:
+            attn_specs = {
                 "wq": P("stage", "fsdp", "model"),
                 "wk": P("stage", "fsdp", "model"),
                 "wv": P("stage", "fsdp", "model"),
                 "wo": P("stage", "model", "fsdp"),
+            }
+        specs: Params = {
+            "embed": {"embedding": P("fsdp", None)},
+            "layers": {
+                "attn_norm": P("stage", None),
+                **attn_specs,
                 "mlp_norm": P("stage", None),
                 **mlp_specs,
             },
@@ -514,9 +585,13 @@ class Transformer:
                dropout_key: Optional[jax.Array] = None,
                token_valid: Optional[jnp.ndarray] = None,  # [B, T] for MoE
                factored_mask: Optional[Tuple] = None,  # (valid, segments)
-               ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-        """One decoder block. Returns (output, (k, v)) — k/v before override,
-        for cache writes. ``layer`` may carry LoRA leaves (merged upstream)."""
+               dropless: bool = False,
+               ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...], Any]:
+        """One decoder block. Returns (output, cache rows, moe aux) — the
+        rows are what a cache stores per token, before any override: (k, v)
+        for dense attention, (latent row,) for latent attention.
+        ``layer`` may carry LoRA leaves (merged upstream). ``dropless``:
+        the serving entry points route experts with no capacity."""
         cfg = self.cfg
         dh = cfg.head_dim_
         rd = cfg.rotary_dim_
@@ -537,6 +612,24 @@ class Transformer:
                            cfg.rms_norm_eps)
         else:
             h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
+        if cfg.latent_attention:
+            with jax.named_scope("mla_attention"):
+                q, k, v, row = self._latent_expanded(
+                    layer, h, cos, sin, q_positions)
+                q = _constrain(
+                    q, P(("data", "fsdp"), "sequence", "model", None))
+                k = _constrain(
+                    k, P(("data", "fsdp"), "sequence", "model", None))
+                attn = self._attention(
+                    q, k, v, kv_segment_mask, q_positions, kv_positions,
+                    allow_flash, cp, flash_segs=flash_segs,
+                    factored_mask=factored_mask)
+                attn_out = proj("wo", attn.reshape(b, t, -1))
+            x = x + _constrain(attn_out, ACT_SPEC)
+            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+            mlp_out, moe_aux = self._mlp(layer, h, proj, token_valid,
+                                         dropless=dropless)
+            return x + _constrain(mlp_out, ACT_SPEC), (row,), moe_aux
         q = proj("wq", h).reshape(b, t, cfg.num_heads, dh)
         k = proj("wk", h).reshape(b, t, cfg.num_kv_heads, dh)
         v = proj("wv", h).reshape(b, t, cfg.num_kv_heads, dh)
@@ -568,27 +661,144 @@ class Transformer:
                                 cfg.rms_norm_eps)
         x = x + _constrain(attn_out, ACT_SPEC)
         h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-        mlp_out, moe_aux = self._mlp(layer, h, proj, token_valid)
+        mlp_out, moe_aux = self._mlp(layer, h, proj, token_valid,
+                                     dropless=dropless)
         if cfg.arch == "gemma2":
             mlp_out = rms_norm(mlp_out, layer["mlp_post_norm"],
                                cfg.rms_norm_eps)
         x = x + _constrain(mlp_out, ACT_SPEC)
         return x, new_kv, moe_aux
 
+    # ----------------------------------------------------- latent attention
+
+    def _latent_project(self, layer: Params, hn: jnp.ndarray, cos, sin,
+                        q_positions: jnp.ndarray):
+        """The projections both forms of latent attention share. hn
+        [B, T, D] (post attn_norm) -> q_nope [B, T, H, nope], q_rope
+        [B, T, H, rope] (rotated, and both carrying the position-dependent
+        query scale), ckv [B, T, r] (normalised latent), kr [B, T, 1,
+        rope] (the one rotated key all heads share). The cached row is
+        [ckv | kr]."""
+        cfg = self.cfg
+        b, t, _ = hn.shape
+        nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        if cfg.q_lora_rank:
+            cq = rms_norm(self._dense(layer, "wq_a", hn), layer["q_norm"],
+                          cfg.rms_norm_eps)
+            q = self._dense(layer, "wq_b", cq)
+        else:
+            q = self._dense(layer, "wq", hn)
+        q = q.reshape(b, t, cfg.num_heads, cfg.head_dim_)
+        kv = self._dense(layer, "wkv_a", hn)                # [B, T, r+rope]
+        ckv = rms_norm(kv[..., :r], layer["kv_norm"], cfg.rms_norm_eps)
+        kr = apply_rotary(kv[:, :, None, r:], cos, sin,
+                          interleave=cfg.rope_interleave)
+        q_nope = q[..., :nope]
+        q_rope = apply_rotary(q[..., nope:], cos, sin,
+                              interleave=cfg.rope_interleave)
+        q_scale = position_query_scale(q_positions, cfg.rope_scaling)
+        if q_scale is not None:
+            q_scale = q_scale[..., None, None].astype(q.dtype)
+            q_nope, q_rope = q_nope * q_scale, q_rope * q_scale
+        return q_nope, q_rope, ckv, kr
+
+    def _latent_expanded(self, layer: Params, hn: jnp.ndarray, cos, sin,
+                         q_positions: jnp.ndarray):
+        """Expanded form (full sequences): every token's latent goes up
+        through wkv_b to per-head keys and values. Returns q, k
+        [B, T, H, nope+rope], v [B, T, H, v] and the cache row
+        [B, T, 1, r+rope]."""
+        cfg = self.cfg
+        b, t, _ = hn.shape
+        h_, nope = cfg.num_heads, cfg.qk_nope_head_dim
+        q_nope, q_rope, ckv, kr = self._latent_project(
+            layer, hn, cos, sin, q_positions)
+        kvb = self._dense(layer, "wkv_b", ckv).reshape(
+            b, t, h_, nope + cfg.v_head_dim)
+        k = jnp.concatenate(
+            [kvb[..., :nope],
+             jnp.broadcast_to(kr, (b, t, h_, cfg.qk_rope_head_dim))], -1)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        row = jnp.concatenate([ckv[:, :, None, :], kr], -1)
+        return q, k, kvb[..., nope:], row
+
+    def _latent_absorbed(self, layer: Params, hn: jnp.ndarray, cos, sin,
+                         q_positions: jnp.ndarray, attend):
+        """Absorbed form (decode against cached rows): wkv_b's key half
+        moves onto the query and its value half onto the output, so the
+        cache is read as it is stored — one [r+rope] row a token, shared
+        by all heads (multi-query attention over the rows, the value
+        being the row's first r numbers). Same numbers as the expanded
+        form. ``attend(q, k_new, v_new) -> [B, T, H, r+rope]`` is the
+        path's attention over (cached rows, new rows). Returns
+        ([B, T, H * v], row)."""
+        cfg = self.cfg
+        b, t, _ = hn.shape
+        h_, nope, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        q_nope, q_rope, ckv, kr = self._latent_project(
+            layer, hn, cos, sin, q_positions)
+        wkv_b = self._weight(layer, "wkv_b").reshape(
+            r, h_, nope + cfg.v_head_dim)
+        q_lat = jnp.concatenate(
+            [jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :nope]),
+             q_rope], -1)                                # [B, T, H, r+rope]
+        row = jnp.concatenate([ckv[:, :, None, :], kr], -1)
+        # the row is key and value at once: the weighted sum over whole
+        # rows costs a quarter more multiplies than over their first r
+        # numbers and spares a sliced copy of the gathered window
+        u = attend(q_lat, row, row)[..., :r]
+        out = jnp.einsum("bthr,rhv->bthv", u, wkv_b[..., nope:])
+        return out.reshape(b, t, h_ * cfg.v_head_dim), row
+
+    def cache_rows(self) -> Tuple[Tuple[int, int], ...]:
+        """What one token stores per layer, as (heads, width) per pool:
+        keys and values of [KH, D] for dense attention, one [1, r+rope]
+        latent row for latent attention. A paged pool allocates one
+        [L, pages, page_size, heads, width] array per entry; the paged
+        steps below take and return tuples in this order."""
+        cfg = self.cfg
+        if cfg.latent_attention:
+            return ((1, cfg.kv_lora_rank + cfg.qk_rope_head_dim),)
+        return ((cfg.num_kv_heads, cfg.head_dim_),) * 2
+
+    # ------------------------------------------------------------------ mlp
+
     def _mlp(self, layer: Params, h: jnp.ndarray, proj,
-             token_valid: Optional[jnp.ndarray] = None):
+             token_valid: Optional[jnp.ndarray] = None,
+             dropless: bool = False):
         """Dense gated-SiLU MLP, or the routed MoE variant when the layer
-        carries a router (cfg.num_experts > 0). Returns (out, aux | None);
-        aux is the (load_balance, router_z, dropped_frac) triple from
-        ops.moe for the trainer to weight in. ``token_valid`` keeps pad
-        tokens from claiming expert capacity or skewing router stats."""
+        carries a router (cfg.num_experts > 0), plus the shared experts
+        where the layer has them. Returns (out, aux | None). Training
+        (capacity dispatch): aux is the (load_balance, router_z,
+        dropped_frac) triple from ops.moe for the trainer to weight in.
+        ``dropless`` (every serving entry point): no capacity, aux is the
+        int32 [2] (held experts hit, pairs landed) of this layer.
+        ``token_valid`` keeps pad tokens from claiming expert capacity or
+        skewing router stats."""
         if "router" in layer:
-            from dla_tpu.ops.moe import moe_mlp
-            out, aux = moe_mlp(
-                h, layer["router"], layer["w_gate"], layer["w_up"],
-                layer["w_down"], k=self.cfg.num_experts_per_token,
-                capacity_factor=self.cfg.moe_capacity_factor,
-                valid=token_valid, group_size=self.cfg.moe_group_size)
+            from dla_tpu.ops.moe import moe_mlp, moe_mlp_dropless
+            cfg = self.cfg
+            kw = dict(k=cfg.num_experts_per_token, valid=token_valid,
+                      first=cfg.moe_first_expert,
+                      routed_scale=cfg.moe_routed_scale)
+            if dropless:
+                # a scan that keeps the experts' weights stacked hands
+                # them over whole, with its block index (_paged_layers)
+                stack = layer.get("expert_stack", layer)
+                out, aux = moe_mlp_dropless(
+                    h, layer["router"], stack["w_gate"], stack["w_up"],
+                    stack["w_down"], layer=layer.get("layer_index"), **kw)
+            else:
+                out, aux = moe_mlp(
+                    h, layer["router"], layer["w_gate"], layer["w_up"],
+                    layer["w_down"],
+                    capacity_factor=cfg.moe_capacity_factor,
+                    group_size=cfg.moe_group_size, **kw)
+            if "ws_gate" in layer:
+                with jax.named_scope("moe_shared"):
+                    ff = jax.nn.silu(proj("ws_gate", h)) * proj("ws_up", h)
+                    out = out + proj("ws_down", _constrain(
+                        ff, P(("data", "fsdp"), "sequence", "model")))
             return out, aux
         if self.cfg.arch in ("gemma", "gemma2"):
             gate = jax.nn.gelu(proj("w_gate", h), approximate=True)
@@ -611,8 +821,10 @@ class Transformer:
         return (cfg.attention == "flash" and _flash_tileable(t)
                 and not cfg.attn_logit_softcap
                 and cfg.sliding_window_pattern == 1
-                and (cfg.query_pre_attn_scalar is None
-                     or cfg.query_pre_attn_scalar == cfg.head_dim_))
+                and (self._softmax_scale is None or math.isclose(
+                    self._softmax_scale, cfg.head_dim_ ** -0.5))
+                and (not cfg.latent_attention
+                     or cfg.v_head_dim == cfg.head_dim_))
 
     def _with_layer_windows(self, layers: Params,
                             storage: bool = False) -> Params:
@@ -1350,6 +1562,11 @@ class Transformer:
 
     def init_cache(self, batch: int, max_len: int) -> Params:
         cfg = self.cfg
+        if cfg.latent_attention:
+            raise NotImplementedError(
+                "latent attention decodes against the paged pool "
+                "(dla_tpu.serving.ServingEngine); the contiguous "
+                "decode_step cache stores per-head keys and values")
         shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim_)
         kv_dtype = jnp.int8 if self._kv_int8 else self.adtype
         cache = {
@@ -1383,11 +1600,13 @@ class Transformer:
 
     def prefill_external(self, params: Params, input_ids: jnp.ndarray,
                          attention_mask: jnp.ndarray,
-                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                         ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
         """The cache-layout-agnostic half of prefill: run the prompt
-        forward and hand back the raw KV columns instead of writing any
-        particular cache. Returns (last-real-token logits [B, V],
-        ks [L, B, T, KH, D], vs [L, B, T, KH, D]) in activation dtype.
+        forward and hand back the raw cache rows instead of writing any
+        particular cache. Returns (last-real-token logits [B, V], rows):
+        one [L, B, T, heads, width] array per ``cache_rows()`` entry
+        (keys and values; or the latent rows) in activation dtype.
+        Experts route droplessly: this is a serving entry point.
 
         ``prefill`` packs these into the contiguous cache; the serving
         engine (dla_tpu/serving) scatters them into its block-paged
@@ -1416,10 +1635,11 @@ class Transformer:
                                    positions, positions,
                                    allow_flash=flash_ok,
                                    token_valid=attention_mask,
-                                   factored_mask=pre_factored)
+                                   factored_mask=pre_factored,
+                                   dropless=True)
             return h, kv
 
-        x, (ks, vs) = jax.lax.scan(
+        x, rows = jax.lax.scan(
             body, x,
             self._with_layer_windows(self._flat_layers(params["layers"])))
         h = self._final_norm(params, x)
@@ -1428,7 +1648,7 @@ class Transformer:
         last_idx = jnp.maximum(lengths - 1, 0)
         last_h = jnp.take_along_axis(h, last_idx[:, None, None], axis=1)[:, 0]
         logits = self.unembed(params, last_h)
-        return logits, ks, vs
+        return logits, rows
 
     def prefill(self, params: Params, cache: Params,
                 input_ids: jnp.ndarray, attention_mask: jnp.ndarray,
@@ -1447,7 +1667,7 @@ class Transformer:
         long-context rollout path stays O(T) HBM like training).
         """
         b, t = input_ids.shape
-        logits, ks, vs = self.prefill_external(
+        logits, (ks, vs) = self.prefill_external(
             params, input_ids, attention_mask)
         lengths = attention_mask.astype(jnp.int32).sum(axis=1)
         max_len = cache["k"].shape[2]
@@ -1491,13 +1711,21 @@ class Transformer:
         return layer, k_cache, v_cache, k_s, v_s
 
     def _decode_layer(self, layer: Params, h_in: jnp.ndarray,
-                      cos, sin, attend):
+                      cos, sin, attend, q_positions=None,
+                      token_valid=None):
         """The per-layer decode computation SHARED by decode_step (one
         token) and decode_block (G tokens): norms, projections, MLP,
         and every arch branch — only the attention backend differs, and
         ``attend(q, k, v) -> [B, T, H, D]`` supplies it. Keeping this
         single ensures a new arch branch lands in both paths (the
-        'G == 1 is semantically decode_step' contract)."""
+        'G == 1 is semantically decode_step' contract). Returns (output,
+        (new cache rows, int32 [2] expert counters)): rows in
+        ``cache_rows()`` order; the counters are (held experts hit, pairs
+        landed) of this layer's dropless routing, zeros without experts.
+        ``q_positions`` [B, T]: latent attention's position-dependent
+        query scale reads them. ``token_valid`` [B, T]: rows that are
+        real (a running slot, a chunk's real token); the others' routed
+        pairs are neither computed nor counted."""
         cfg = self.cfg
         b, t, _ = h_in.shape
         dh = cfg.head_dim_
@@ -1525,26 +1753,38 @@ class Transformer:
                             cfg.rms_norm_eps)
         else:
             hn = rms_norm(h_in, layer["attn_norm"], cfg.rms_norm_eps)
-        q = proj("wq", hn).reshape(b, t, cfg.num_heads, dh)
-        k = proj("wk", hn).reshape(b, t, cfg.num_kv_heads, dh)
-        v = proj("wv", hn).reshape(b, t, cfg.num_kv_heads, dh)
-        q = apply_rotary(q, cos, sin, rotary_dim=cfg.rotary_dim_)
-        k = apply_rotary(k, cos, sin, rotary_dim=cfg.rotary_dim_)
-        attn = attend(q, k, v).reshape(b, t, cfg.num_heads * dh)
-        if cfg.arch == "phi":
-            ff = jax.nn.gelu(proj("fc1", hn), approximate=True)
-            return h_in + proj("wo", attn) + proj("fc2", ff), (k, v)
-        attn_out = proj("wo", attn)
+        no_experts = jnp.zeros((2,), jnp.int32)
+        if cfg.latent_attention:
+            with jax.named_scope("mla_attention"):
+                attn, row = self._latent_absorbed(
+                    layer, hn, cos, sin, q_positions, attend)
+                attn_out = proj("wo", attn)
+            rows = (row,)
+        else:
+            q = proj("wq", hn).reshape(b, t, cfg.num_heads, dh)
+            k = proj("wk", hn).reshape(b, t, cfg.num_kv_heads, dh)
+            v = proj("wv", hn).reshape(b, t, cfg.num_kv_heads, dh)
+            q = apply_rotary(q, cos, sin, rotary_dim=cfg.rotary_dim_)
+            k = apply_rotary(k, cos, sin, rotary_dim=cfg.rotary_dim_)
+            attn = attend(q, k, v).reshape(b, t, cfg.num_heads * dh)
+            rows = (k, v)
+            if cfg.arch == "phi":
+                ff = jax.nn.gelu(proj("fc1", hn), approximate=True)
+                return (h_in + proj("wo", attn) + proj("fc2", ff),
+                        (rows, no_experts))
+            attn_out = proj("wo", attn)
         if cfg.arch == "gemma2":
             attn_out = rms_norm(attn_out, layer["attn_post_norm"],
                                 cfg.rms_norm_eps)
         x1 = h_in + attn_out
         hn2 = rms_norm(x1, layer["mlp_norm"], cfg.rms_norm_eps)
-        mlp_out = self._mlp(layer, hn2, proj)[0]  # aux unused at decode
+        mlp_out, routed = self._mlp(layer, hn2, proj, token_valid,
+                                    dropless=True)
         if cfg.arch == "gemma2":
             mlp_out = rms_norm(mlp_out, layer["mlp_post_norm"],
                                cfg.rms_norm_eps)
-        return x1 + mlp_out, (k, v)
+        return x1 + mlp_out, (
+            rows, no_experts if routed is None else routed)
 
     def decode_step(self, params: Params, cache: Params,
                     tokens: jnp.ndarray,  # [B] the tokens just sampled
@@ -1689,7 +1929,7 @@ class Transformer:
               cache["k"], cache["v"])
         if self._kv_int8:
             xs = xs + (cache["k_scale"], cache["v_scale"])
-        x, (k_cols, v_cols) = jax.lax.scan(body2, x, xs)
+        x, ((k_cols, v_cols), _) = jax.lax.scan(body2, x, xs)
         h = self._final_norm(params, x)
         logits = self.unembed(params, h[:, 0])
 
@@ -1734,68 +1974,101 @@ class Transformer:
             new_cache["v"] = write_col(cache["v"], v_cols)
         return logits, new_cache
 
-    def decode_step_paged(self, params: Params, view: Params,
-                          tokens: jnp.ndarray,  # [B] the tokens just sampled
-                          adapters: Optional[Params] = None,
-                          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """One decode step against an EXTERNALLY-gathered KV view — the
-        cache-layout-agnostic sibling of ``decode_step``. The serving
-        engine's block-paged pool (dla_tpu/serving/kv_blocks.py) gathers
-        each sequence's pages into a [B, S] window via its block table and
-        hands the result here; this method never writes a cache — it
-        returns the step's fresh KV columns for the caller to scatter
-        back into whatever layout it owns.
-
-        ``view``:
-          k, v     [L, B, S, KH, D]  gathered cache (activation dtype)
-          valid    [B, S]            columns that may be attended
-          pos      [B, S]            logical position per column
-          lengths  [B]               true tokens so far = this query's pos
-
-        Returns (logits [B, V], k_cols [L, B, 1, KH, D], v_cols). Rows
-        whose view is garbage (freed serving slots) compute garbage that
-        the caller masks — static shapes, no recompilation as requests
-        come and go. int8 KV paging is not plumbed yet: serving pages
-        store the activation dtype."""
+    def _paged_layers(self, params: Params, view: Params, x: jnp.ndarray,
+                      positions: jnp.ndarray, adapters: Optional[Params],
+                      attention):
+        """The layer scan the three paged steps share: ``x`` [B, T, D]
+        embedded tokens at absolute ``positions`` [B, T] attend jointly
+        over the externally gathered window and their own fresh rows
+        through ``attention`` (``decode_attention`` for one token,
+        ``block_decode_attention`` for several). Returns (hidden after
+        the final norm [B, T, D], fresh rows — one [L, B, T, heads,
+        width] array per ``cache_rows()`` entry — and int32 [2] = (held
+        experts that received a token, (token, choice) pairs that landed
+        here) summed over layers)."""
         cfg = self.cfg
         if self._kv_int8:
             raise NotImplementedError(
-                "decode_step_paged serves activation-dtype pages; "
+                "the paged steps serve activation-dtype pages; "
                 "kv_cache_dtype=int8 is only wired into the contiguous "
                 "decode_step path")
-        positions = view["lengths"][:, None]               # [B, 1]
-        x = self._embed(params, tokens[:, None])
         cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,
                                  scaling=cfg.rope_scaling)
 
+        layers = dict(self._with_layer_windows(
+            self._flat_layers(params["layers"])))
+        stack = None
+        if "router" in layers:
+            # the routed experts' weights stay stacked outside the scan
+            # (ops.moe.moe_mlp_dropless, ``layer``): the scan carries the
+            # block index in their place
+            stack = {k: layers.pop(k) for k in ("w_gate", "w_up", "w_down")}
+            layers["layer_index"] = jnp.arange(cfg.num_layers,
+                                               dtype=jnp.int32)
+
         def body(carry, xs):
-            layer, k_cache, v_cache = xs
+            layer, *cached = xs
+            if stack is not None:
+                layer = {**layer, "expert_stack": stack}
 
             def attend(q, k, v):
-                return decode_attention(
-                    q, k_cache, v_cache, k, v,
+                # dense: (keys, values); latent: the one pool of rows is
+                # both (Transformer._latent_absorbed)
+                return attention(
+                    q, cached[0], cached[-1], k, v,
                     kv_valid=view["valid"],
                     q_positions=positions, kv_positions=view["pos"],
                     window=self._layer_window(layer),
                     softmax_scale=self._softmax_scale,
                     logit_softcap=cfg.attn_logit_softcap)
 
-            return self._decode_layer(layer, carry, cos, sin, attend)
+            return self._decode_layer(layer, carry, cos, sin, attend,
+                                      q_positions=positions,
+                                      token_valid=view.get("real"))
 
-        layers = self._with_layer_windows(self._flat_layers(params["layers"]))
-        xs = ({**layers, **self.slot_lora_xs(adapters)},
-              view["k"], view["v"])
-        x, (k_cols, v_cols) = jax.lax.scan(body, x, xs)
-        h = self._final_norm(params, x)
-        logits = self.unembed(params, h[:, 0])
-        return logits, k_cols, v_cols
+        xs = ({**layers, **self.slot_lora_xs(adapters)}, *view["kv"])
+        x, (rows, routed) = jax.lax.scan(body, x, xs)
+        return self._final_norm(params, x), rows, jnp.sum(routed, axis=0)
+
+    def decode_step_paged(self, params: Params, view: Params,
+                          tokens: jnp.ndarray,  # [B] the tokens just sampled
+                          adapters: Optional[Params] = None,
+                          ) -> Tuple[jnp.ndarray, Tuple, jnp.ndarray]:
+        """One decode step against an EXTERNALLY-gathered KV view — the
+        cache-layout-agnostic sibling of ``decode_step``. The serving
+        engine's block-paged pool (dla_tpu/serving/kv_blocks.py) gathers
+        each sequence's pages into a [B, S] window via its block table and
+        hands the result here; this method never writes a cache — it
+        returns the step's fresh rows for the caller to scatter back into
+        whatever layout it owns.
+
+        ``view``:
+          kv       tuple, one [L, B, S, heads, width] array per
+                   ``cache_rows()`` entry: the gathered cache (keys and
+                   values, or the latent rows; activation dtype)
+          valid    [B, S]            columns that may be attended
+          pos      [B, S]            logical position per column
+          lengths  [B]               true tokens so far = this query's pos
+          real     [B, T] optional   rows that are real (a running slot;
+                   a chunk's real token): routed experts skip the others
+
+        Returns (logits [B, V], rows — one [L, B, 1, heads, width] per
+        pool — and the step's int32 [2] expert counters, see
+        ``_paged_layers``). Rows whose view is garbage (freed serving
+        slots) compute garbage that the caller masks — static shapes, no
+        recompilation as requests come and go. int8 KV paging is not
+        plumbed yet: serving pages store the activation dtype."""
+        h, rows, routed = self._paged_layers(
+            params, view, self._embed(params, tokens[:, None]),
+            view["lengths"][:, None], adapters, decode_attention)
+        return self.unembed(params, h[:, 0]), rows, routed
 
     def prefill_step_paged(self, params: Params, view: Params,
                            tokens: jnp.ndarray,     # [B, C] chunk tokens
                            positions: jnp.ndarray,  # [B, C] absolute pos
                            last_index: jnp.ndarray,  # [B] last real token
                            adapters: Optional[Params] = None,
-                           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                           ) -> Tuple[jnp.ndarray, Tuple, jnp.ndarray]:
         """One fixed-width prefill CHUNK against an externally-gathered
         KV view — the chunked-prefill sibling of ``decode_step_paged``.
         The chunk's C queries attend jointly over (a) the already-
@@ -1805,48 +2078,19 @@ class Transformer:
         position (pad tokens carry later positions than every real
         query, so they mask themselves out). Returns
         (logits [B, V] — the next-token distribution after the token at
-        ``last_index``, only meaningful on the FINAL chunk —
-        k_cols/v_cols [L, B, C, KH, D] for the caller to scatter into
-        the pool; pad columns route to the trash page)."""
-        cfg = self.cfg
-        if self._kv_int8:
-            raise NotImplementedError(
-                "prefill_step_paged serves activation-dtype pages; "
-                "kv_cache_dtype=int8 is only wired into the contiguous "
-                "path")
-        b, c = tokens.shape
-        x = self._embed(params, tokens)
-        cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,
-                                 scaling=cfg.rope_scaling)
-        from dla_tpu.ops.attention import block_decode_attention
-
-        def body(carry, xs):
-            layer, k_cache, v_cache = xs
-
-            def attend(q, k, v):
-                return block_decode_attention(
-                    q, k_cache, v_cache, k, v,
-                    kv_valid=view["valid"],
-                    q_positions=positions, kv_positions=view["pos"],
-                    window=self._layer_window(layer),
-                    softmax_scale=self._softmax_scale,
-                    logit_softcap=cfg.attn_logit_softcap)
-
-            return self._decode_layer(layer, carry, cos, sin, attend)
-
-        layers = self._with_layer_windows(self._flat_layers(params["layers"]))
-        xs = ({**layers, **self.slot_lora_xs(adapters)},
-              view["k"], view["v"])
-        x, (k_cols, v_cols) = jax.lax.scan(body, x, xs)
-        h = self._final_norm(params, x)                     # [B, C, H]
-        last = h[jnp.arange(b), last_index]                 # [B, H]
-        logits = self.unembed(params, last)
-        return logits, k_cols, v_cols
+        ``last_index``, only meaningful on the FINAL chunk — the chunk's
+        rows [L, B, C, heads, width] per pool for the caller to scatter
+        (pad columns route to the trash page), and the expert counters)."""
+        h, rows, routed = self._paged_layers(
+            params, view, self._embed(params, tokens), positions, adapters,
+            block_decode_attention)                         # [B, C, H]
+        last = h[jnp.arange(tokens.shape[0]), last_index]   # [B, H]
+        return self.unembed(params, last), rows, routed
 
     def decode_block_paged(self, params: Params, view: Params,
                            tokens: jnp.ndarray,  # [B, G] token block
                            adapters: Optional[Params] = None,
-                           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                           ) -> Tuple[jnp.ndarray, Tuple, jnp.ndarray]:
         """Verify a G-token block against an externally-gathered KV view
         — the speculative-verify sibling of ``decode_step_paged``. Row
         b's block occupies absolute positions lengths[b]..lengths[b]+G-1;
@@ -1855,43 +2099,15 @@ class Transformer:
         columns must NOT be valid, the in-block keys supply them fresh)
         and (b) the block's own keys, causally by position. Returns
         (logits [B, G, V] — one next-token distribution per block
-        position — and k_cols/v_cols [L, B, G, KH, D] for the caller to
-        scatter; rejected columns are the caller's rollback problem)."""
-        cfg = self.cfg
-        if self._kv_int8:
-            raise NotImplementedError(
-                "decode_block_paged serves activation-dtype pages; "
-                "kv_cache_dtype=int8 is only wired into the contiguous "
-                "path")
-        b, g = tokens.shape
+        position — the block's rows [L, B, G, heads, width] per pool for
+        the caller to scatter (rejected columns are the caller's rollback
+        problem), and the expert counters)."""
         positions = view["lengths"][:, None] + \
-            jnp.arange(g, dtype=jnp.int32)[None, :]          # [B, G]
-        x = self._embed(params, tokens)
-        cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,
-                                 scaling=cfg.rope_scaling)
-        from dla_tpu.ops.attention import block_decode_attention
-
-        def body(carry, xs):
-            layer, k_cache, v_cache = xs
-
-            def attend(q, k, v):
-                return block_decode_attention(
-                    q, k_cache, v_cache, k, v,
-                    kv_valid=view["valid"],
-                    q_positions=positions, kv_positions=view["pos"],
-                    window=self._layer_window(layer),
-                    softmax_scale=self._softmax_scale,
-                    logit_softcap=cfg.attn_logit_softcap)
-
-            return self._decode_layer(layer, carry, cos, sin, attend)
-
-        layers = self._with_layer_windows(self._flat_layers(params["layers"]))
-        xs = ({**layers, **self.slot_lora_xs(adapters)},
-              view["k"], view["v"])
-        x, (k_cols, v_cols) = jax.lax.scan(body, x, xs)
-        h = self._final_norm(params, x)                      # [B, G, H]
-        logits = self.unembed(params, h)                     # [B, G, V]
-        return logits, k_cols, v_cols
+            jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]  # [B, G]
+        h, rows, routed = self._paged_layers(
+            params, view, self._embed(params, tokens), positions, adapters,
+            block_decode_attention)
+        return self.unembed(params, h), rows, routed                # [B,G,V]
 
     def start_decode(self, params: Params, input_ids: jnp.ndarray,
                      attention_mask: jnp.ndarray, max_new_tokens: int,
@@ -1931,7 +2147,6 @@ class Transformer:
                                  scaling=cfg.rope_scaling)
         col0 = cache["prompt_width"] + cache["step"]
         kv_pos = cache["pos"]
-        from dla_tpu.ops.attention import block_decode_attention
         if self._kv_int8:
             # block verify dequantizes via the XLA path (the Pallas
             # decode kernel is single-token); speculative decoding with
@@ -1966,7 +2181,7 @@ class Transformer:
               cache["k"], cache["v"])
         if self._kv_int8:
             xs = xs + (cache["k_scale"], cache["v_scale"])
-        x, (k_cols, v_cols) = jax.lax.scan(body, x, xs)
+        x, ((k_cols, v_cols), _) = jax.lax.scan(body, x, xs)
         h = self._final_norm(params, x)
         logits = self.unembed(params, h)                   # [B, G, V]
 

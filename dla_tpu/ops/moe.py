@@ -31,6 +31,15 @@ GSPMD shards the expert dim and inserts the all-to-alls:
 
 Static shapes throughout (C is computed from static T/E/k), scan/remat
 friendly, composes with fsdp/model sharding on the non-expert dims.
+
+That is the TRAINING path (``moe_mlp``). Serving routes droplessly
+(``moe_mlp_dropless``): a dropped token is a wrong answer there, and no
+reference can match a capacity that depends on the batch. Both take a
+share of the experts the router scores (``first`` .. ``first + E_held -
+1``): the per-shard body of expert parallelism, which on one chip runs
+without its exchange; choices landing on experts held elsewhere add
+nothing here. Shared experts are the caller's (a dense gated MLP beside
+the routed sum, ``Transformer._mlp``).
 """
 from __future__ import annotations
 
@@ -81,40 +90,136 @@ def moe_mlp(
     capacity_factor: float = 1.25,
     valid: Optional[jnp.ndarray] = None,   # [B, T] 1 = real token
     group_size: int = 512,
+    first: int = 0,
+    routed_scale: float = 1.0,
 ) -> Tuple[jnp.ndarray, MoEAux]:
-    """Routed gated-SiLU MLP. Returns ([B, T, D] output, aux losses)."""
+    """Routed gated-SiLU MLP with GShard capacity dispatch (the training
+    path). Returns ([B, T, D] output, aux losses). The weights may hold a
+    share of the experts the router scores: ids ``first .. first + E_held
+    - 1``; a choice landing elsewhere adds nothing here."""
     b, t, d = h.shape
     g = _fit_group(t, group_size)
     rows = b * (t // g)
     h2 = h.reshape(rows, g, d)
     v2 = None if valid is None else valid.reshape(rows, g)
     out, aux = _moe_rows(h2, router_w, w_gate, w_up, w_down, k=k,
-                         capacity_factor=capacity_factor, valid=v2)
+                         capacity_factor=capacity_factor, valid=v2,
+                         first=first, routed_scale=routed_scale)
     return out.reshape(b, t, d), aux
 
 
+def route_top_k(h: jnp.ndarray, router_w: jnp.ndarray, k: int,
+                routed_scale: float = 1.0):
+    """Router logits [..., E] in fp32 (fp32 accumulation of the
+    activation-dtype product), the k chosen experts and their weights:
+    softmax over the chosen logits, which IS the softmax over all E
+    renormalised over the chosen (Mixtral's and mistral4's
+    ``norm_topk_prob`` convention), times ``routed_scale``."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.einsum("...d,de->...e", h, router_w.astype(h.dtype),
+                            preferred_element_type=jnp.float32)
+        top_l, top_e = jax.lax.top_k(logits, min(k, router_w.shape[1]))
+        top_w = jax.nn.softmax(top_l, axis=-1)
+        if routed_scale != 1.0:
+            top_w = top_w * routed_scale
+    return logits, top_e, top_w
+
+
+def moe_mlp_dropless(
+    h: jnp.ndarray,              # [B, T, D] block input (post-norm)
+    router_w: jnp.ndarray,       # [D, E]    every expert the router scores
+    w_gate: jnp.ndarray,         # [E_held, D, F]
+    w_up: jnp.ndarray,           # [E_held, D, F]
+    w_down: jnp.ndarray,         # [E_held, F, D]
+    *,
+    k: int,
+    first: int = 0,
+    routed_scale: float = 1.0,
+    valid: Optional[jnp.ndarray] = None,   # [B, T] 1 = real token
+    layer: Optional[jnp.ndarray] = None,   # traced index into stacked w_*
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Routed gated-SiLU MLP with no capacity: every (token, choice) that
+    lands on a held expert is computed, whatever the imbalance — the
+    serving paths' routine (a dropped token is a wrong answer there, and
+    at a 512-token chunk over 128 experts GShard's capacity is 20).
+
+    The (token, choice) pairs are sorted by expert (pairs of experts held
+    elsewhere, and of pad tokens, sort last and belong to no group) and
+    go through ``jax.lax.ragged_dot``, XLA:TPU's grouped matmul: it walks
+    only the row tiles that hold a pair, so a decode step reads the
+    weights of the experts its rows chose and no others. Rows past the
+    last group come back undefined and are zeroed.
+
+    ``layer``: the weights are every layer's, stacked [L, E_held, D, F],
+    and this is block ``layer`` of a scan over them. The grouped matmul
+    then takes the whole stack as L * E_held groups of which only this
+    block's are not empty: a custom call cannot read a slice in place,
+    and slicing a block's experts out first copies all of their weights
+    every step (on a v5e, half of a decode step at 32 experts of 50 MB).
+
+    Returns ([B, T, D], int32 [2] = (held experts that received a pair,
+    pairs that landed here)): the counters behind ``experts_hit`` /
+    ``expert_assignments``."""
+    b, t, d = h.shape
+    e_held = w_gate.shape[-3]
+    x = h.reshape(b * t, d)
+    _, top_e, top_w = route_top_k(x, router_w, k, routed_scale)
+    kk = top_e.shape[-1]
+    with jax.named_scope("moe_experts"):
+        local = top_e - first
+        here = (local >= 0) & (local < e_held)
+        if valid is not None:
+            here = here & (valid.reshape(b * t, 1) > 0)
+        key = jnp.where(here, local, e_held).reshape(-1)     # [N*k]
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(e_held, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32)                          # [E_held]
+        landed = jnp.sum(group_sizes)
+        in_group = (jnp.arange(key.shape[0]) < landed)[:, None]
+        xs = jnp.take(x, order // kk, axis=0)                 # [N*k, D]
+        sizes = group_sizes
+        if layer is not None:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((w_gate.shape[0] * e_held,), jnp.int32),
+                group_sizes, (layer * e_held,))
+
+        def grouped(rows, w):
+            w = w.reshape((-1,) + w.shape[-2:]).astype(rows.dtype)
+            return jax.lax.ragged_dot(rows, w, sizes)
+
+        act = jnp.where(in_group, jax.nn.silu(grouped(xs, w_gate))
+                        * grouped(xs, w_up), 0)
+        ys = grouped(act, w_down)
+        w_sorted = jnp.take(top_w.reshape(-1), order)[:, None]
+        ys = jnp.where(in_group, ys * w_sorted.astype(ys.dtype), 0)
+        # back to (token, choice) order, then sum a token's k choices
+        out = jnp.take(ys, jnp.argsort(order), axis=0
+                       ).reshape(b * t, kk, d).sum(axis=1)
+        stats = jnp.stack([jnp.sum(group_sizes > 0, dtype=jnp.int32),
+                           landed])
+    return out.reshape(b, t, d), stats
+
+
 def _moe_rows(h, router_w, w_gate, w_up, w_down, *, k, capacity_factor,
-              valid):
+              valid, first=0, routed_scale=1.0):
     rows, g, d = h.shape
-    e = router_w.shape[1]
-    k = min(k, e)
-    cap = expert_capacity(g, e, k, capacity_factor)
+    e = w_gate.shape[0]                  # experts held (all of them: E)
+    k = min(k, router_w.shape[1])
+    cap = expert_capacity(g, router_w.shape[1], k, capacity_factor)
     v = (jnp.ones((rows, g), jnp.float32) if valid is None
          else valid.astype(jnp.float32))
 
-    logits = (h @ router_w.astype(h.dtype)).astype(jnp.float32)  # [R, G, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    # top-k experts per token; weights = softmax over the k chosen logits
-    top_w, top_e = jax.lax.top_k(logits, k)                # [R, G, k]
-    top_w = jax.nn.softmax(top_w, axis=-1)
+    logits, top_e, top_w = route_top_k(h, router_w, k, routed_scale)
+    probs = jax.nn.softmax(logits, axis=-1)                # [R, G, E]
 
     # slot assignment: position of this token among all (token, choice)
     # pairs routed to the same expert, counted in (choice-major, then
     # token) order so primary routes win capacity over secondary ones.
     # Padding tokens claim no slot at all (their one-hot is zeroed), so
     # they can never evict real tokens from an expert's capacity.
-    choice_onehot = (jax.nn.one_hot(top_e, e, dtype=jnp.int32)
+    # (an expert id outside the held range one-hots to all zeros)
+    choice_onehot = (jax.nn.one_hot(top_e - first, e, dtype=jnp.int32)
                      * v[:, :, None, None].astype(jnp.int32))  # [R,G,k,E]
     flat = choice_onehot.transpose(0, 2, 1, 3).reshape(rows, k * g, e)
     pos_flat = jnp.cumsum(flat, axis=1) - flat                 # [R, k*G, E]
@@ -148,6 +253,7 @@ def _moe_rows(h, router_w, w_gate, w_up, w_down, *, k, capacity_factor,
     # e * mean router prob of e, summed, scaled by E — minimized at
     # uniform) and z-loss on router logits
     n_real = jnp.maximum(jnp.sum(v), 1.0)
+    e = router_w.shape[1]
     primary = jax.nn.one_hot(top_e[..., 0], e, dtype=jnp.float32)
     frac = jnp.sum(primary * v[..., None], axis=(0, 1)) / n_real
     mean_prob = jnp.sum(probs * v[..., None], axis=(0, 1)) / n_real

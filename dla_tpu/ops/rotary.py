@@ -217,9 +217,32 @@ def rotary_angles(positions: jnp.ndarray, head_dim: int,
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def position_query_scale(positions: jnp.ndarray,
+                         scaling: Optional[Dict[str, Any]]
+                         ) -> Optional[jnp.ndarray]:
+    """The position-dependent query scale of ``llama_4_scaling_beta``
+    (mistral4's ``rope_parameters``): the query at position p is
+    multiplied by 1 + beta * ln(1 + floor(p / original context)), the
+    identity below the original context. positions [..., T] -> fp32
+    [..., T], or None where the dict has no such key."""
+    beta = float((scaling or {}).get("llama_4_scaling_beta") or 0.0)
+    if not beta:
+        return None
+    orig = float(scaling["original_max_position_embeddings"])
+    return 1.0 + beta * jnp.log1p(
+        jnp.floor(positions.astype(jnp.float32) / orig))
+
+
 def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
-                 rotary_dim: int = 0) -> jnp.ndarray:
+                 rotary_dim: int = 0, interleave: bool = False
+                 ) -> jnp.ndarray:
     """x [B, T, H, D] with (cos, sin) [B, T, rd/2] (or broadcastable).
+
+    ``interleave``: the rotated pairs are adjacent dims (x[2i], x[2i+1])
+    (HF ``rope_interleave``, the DeepSeek / mistral4 checkpoints' layout)
+    instead of (x[i], x[i + rd/2]). The result comes out in the
+    split-halves order either way — queries and keys share the
+    permutation, so every score is unchanged and nothing re-interleaves.
 
     Uses the split-halves convention (rotate_half), matching LLaMA /
     HF transformers so imported weights are numerically compatible.
@@ -236,7 +259,10 @@ def apply_rotary(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
     rd = rotary_dim or d
     rot, rest = x[..., :rd], x[..., rd:]
     d_half = rd // 2
-    x1, x2 = rot[..., :d_half], rot[..., d_half:]
+    if interleave:
+        x1, x2 = rot[..., 0::2], rot[..., 1::2]
+    else:
+        x1, x2 = rot[..., :d_half], rot[..., d_half:]
     cos = cos[..., None, :].astype(x.dtype)  # [B, T, 1, rd/2]
     sin = sin[..., None, :].astype(x.dtype)
     out1 = x1 * cos - x2 * sin

@@ -2,13 +2,14 @@
 sequence, so sequences of wildly different lengths never reserve
 worst-case contiguous cache.
 
-Layout: one preallocated pool ``k_pages``/``v_pages`` of shape
-[L, num_pages, page_size, KH, D]. Each decode SLOT (a row of the
-static-shape decode batch) owns a block table row — ``pages_per_slot``
-physical page ids — and the in-graph gather
+Layout: preallocated ``pools``, one [L, num_pages, page_size, heads,
+width] array per kind of row the model caches (``Transformer.cache_rows``:
+keys and values of [KH, D]; or one latent row of [1, r + rope]). Each
+decode SLOT (a row of the static-shape decode batch) owns a block table
+row — ``pages_per_slot`` physical page ids — and the in-graph gather
 
-    k_view = k_pages[:, block_table]           # [L, B, P/slot, ps, KH, D]
-             .reshape(L, B, S, KH, D)          # S = pages_per_slot * ps
+    view = pool[:, block_table]        # [L, B, P/slot, ps, heads, width]
+           .reshape(L, B, S, heads, width)     # S = pages_per_slot * ps
 
 rebuilds the contiguous [B, S] window ``Transformer.decode_step_paged``
 consumes. The gather is the whole trick: attention math stays
@@ -454,20 +455,24 @@ class PrefixCache:
 
 
 @jax.jit
-def copy_page(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
-              src, dst) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Device-side physical page copy — the copy-on-write primitive.
-    ``src``/``dst`` are traced scalars, so this compiles once per pool
-    shape no matter which pages get copied."""
-    return (k_pages.at[:, dst].set(k_pages[:, src]),
-            v_pages.at[:, dst].set(v_pages[:, src]))
+def copy_page(pools: Tuple[jnp.ndarray, ...], src, dst
+              ) -> Tuple[jnp.ndarray, ...]:
+    """Device-side physical page copy — the copy-on-write primitive —
+    in every pool. ``src``/``dst`` are traced scalars, so this compiles
+    once per pool shape no matter which pages get copied."""
+    return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
 
 class PagedKVCache:
     """Device pool + host metadata mirror for the serving decode batch.
 
     Device state (jitted steps read/write):
-      k_pages, v_pages  [L, num_pages, page_size, KH, D]
+      pools  a tuple of [L, num_pages, page_size, heads, width] arrays,
+             one per entry of ``model.cache_rows()``: keys and values of
+             [KH, D] for dense attention, one pool of [1, r + rope]
+             latent rows for latent attention. Every consumer (the
+             engine's gather / scatter, copy-on-write, migration) maps
+             over the tuple, so the row's shape is the model's business.
 
     Host mirror (authoritative, numpy — the scheduler mutates it and the
     engine ships it to device per step; decode-step updates are
@@ -484,10 +489,10 @@ class PagedKVCache:
         cfg = model.cfg
         self.geom = geom
         self.dtype = model.adtype
-        shape = (cfg.num_layers, geom.num_pages, geom.page_size,
-                 cfg.num_kv_heads, cfg.head_dim_)
-        self.k_pages = jnp.zeros(shape, self.dtype)
-        self.v_pages = jnp.zeros(shape, self.dtype)
+        self.pools: Tuple[jnp.ndarray, ...] = tuple(
+            jnp.zeros((cfg.num_layers, geom.num_pages, geom.page_size,
+                       heads, width), self.dtype)
+            for heads, width in model.cache_rows())
         s = geom.slot_window
         self.block_tables = np.zeros(
             (geom.num_slots, geom.pages_per_slot), np.int32)
@@ -570,6 +575,12 @@ class PagedKVCache:
         self.lengths[slot] = col + 1
         self.tokens[slot] = token
 
+    @property
+    def bytes_per_token(self) -> int:
+        """Bytes one cached token takes over every layer and pool."""
+        return sum(int(np.prod(p.shape[3:])) * p.shape[0]
+                   * p.dtype.itemsize for p in self.pools)
+
     def slot_page_index(self, slot: int) -> int:
         """Block-table index the NEXT decode write for ``slot`` needs
         (its write column / page_size)."""
@@ -581,7 +592,7 @@ class PagedKVCache:
         and repoint the table — the shared original stays pristine for
         its other readers and the index."""
         src = int(self.block_tables[slot, page_index])
-        self.k_pages, self.v_pages = copy_page(
-            self.k_pages, self.v_pages,
+        self.pools = copy_page(
+            self.pools,
             jnp.asarray(src, jnp.int32), jnp.asarray(new_page, jnp.int32))
         self.block_tables[slot, page_index] = new_page

@@ -49,6 +49,15 @@ class ServingMetrics:
         # whose sampler filtered and drew instead of taking the arg-max
         self.decode_steps_sampled = r.counter(
             "serving/decode_steps_sampled")
+        # dropless routing, summed over layers and decode steps: held
+        # experts that received a token, (token, choice) pairs that landed
+        # on a held expert (0 for a model without routed experts)
+        self.moe_experts_hit = r.counter("serving/moe/experts_hit")
+        self.moe_expert_assignments = r.counter(
+            "serving/moe/expert_assignments")
+        # bytes one cached token takes in the paged pool, every layer
+        # (dense: keys and values; latent attention: one latent row)
+        self.kv_bytes_per_token = r.gauge("serving/kv_bytes_per_token")
         self.prefill_batches = r.counter("serving/prefill_batches")
         self.tokens_generated = r.counter("serving/tokens_generated")
         self.prefix_lookups = r.counter("serving/prefix_cache/lookups")
@@ -105,6 +114,10 @@ class ServingMetrics:
             "serving/decode_steps": float(self.decode_steps.value),
             "serving/decode_steps_sampled": float(
                 self.decode_steps_sampled.value),
+            "serving/moe/experts_hit": float(self.moe_experts_hit.value),
+            "serving/moe/expert_assignments": float(
+                self.moe_expert_assignments.value),
+            "serving/kv_bytes_per_token": self.kv_bytes_per_token.value,
             "serving/prefill_batches": float(self.prefill_batches.value),
             "serving/tokens_generated": float(self.tokens_generated.value),
             "serving/prefix_cache/lookups": float(

@@ -52,7 +52,7 @@ TRANSPORTS = ("auto", "device", "host")
 #: Wire format version for ``MigrationTicket.to_bytes``. Bump on any
 #: header-field or payload-layout change; ``from_bytes`` rejects other
 #: versions with :class:`MigrationError` rather than misparsing.
-WIRE_VERSION = 1
+WIRE_VERSION = 2      # 2: one payload per pool of the cache (was k, v)
 
 _WIRE_MAGIC = b"DLAT"
 # magic(4) | version u16 | header-json length u32, little-endian
@@ -116,10 +116,12 @@ class MigrationConfig:
 class MigrationTicket:
     """A request's complete resumable state, engine-independent.
 
-    ``k_payload``/``v_payload`` are the gathered page contents, shape
-    ``[L, pages_per_slot, page_size, KH, D]`` — fixed per engine
-    geometry, with only the first ``n_pages`` rows real (the pad rows
-    hold trash-page contents and are never scattered onto real pages).
+    ``payloads`` are the gathered page contents, one array per pool of
+    the exporting engine (keys and values; or one pool of latent rows),
+    each ``[L, pages_per_slot, page_size, heads, width]`` — fixed per
+    engine geometry, with only the first ``n_pages`` rows real (the pad
+    rows hold trash-page contents and are never scattered onto real
+    pages).
     ``committed_len`` is the number of KV columns the payload covers:
     ``len(prompt) + len(generated) - 1`` — the last generated token is
     the next decode input and its column has not been written yet.
@@ -136,8 +138,7 @@ class MigrationTicket:
     committed_len: int
     page_size: int
     n_pages: int                        # real payload rows (committed)
-    k_payload: object                   # [L, P, page_size, KH, D]
-    v_payload: object
+    payloads: tuple                     # per pool [L, P, page_size, h, w]
     transport: str = "device"           # how the payload currently lives
     src_slot: Optional[int] = None      # fleet slot of the exporter
     # source-engine clocks, carried so TTFT is not double-counted and
@@ -156,8 +157,7 @@ class MigrationTicket:
 
     @property
     def payload_bytes(self) -> int:
-        k, v = self.k_payload, self.v_payload
-        return int(getattr(k, "nbytes", 0)) + int(getattr(v, "nbytes", 0))
+        return sum(int(getattr(x, "nbytes", 0)) for x in self.payloads)
 
     # ------------------------------------------------------- wire format
 
@@ -171,8 +171,8 @@ class MigrationTicket:
         metadata (arrival clocks, logprobs) survives via JSON's
         shortest-roundtrip float repr."""
         # dla: disable=host-sync-in-hot-loop -- designed wire export: one D2H per shipped ticket, counted by the caller on serving/federation/handoff_bytes
-        k = np.ascontiguousarray(np.asarray(self.k_payload))
-        v = np.ascontiguousarray(np.asarray(self.v_payload))
+        arrays = [np.ascontiguousarray(np.asarray(x))
+                  for x in self.payloads]
         sampling = (None if self.sampling is None
                     else dataclasses.asdict(self.sampling))
         meta = {
@@ -195,12 +195,12 @@ class MigrationTicket:
             "last_token_time": self.last_token_time,
             "trace_ctx": self.trace_ctx,
             "tenant": self.tenant,
-            "k_dtype": str(k.dtype), "k_shape": list(k.shape),
-            "v_dtype": str(v.dtype), "v_shape": list(v.shape),
+            "payloads": [{"dtype": str(a.dtype), "shape": list(a.shape)}
+                         for a in arrays],
         }
         header = json.dumps(meta, separators=(",", ":")).encode()
         return (_WIRE_HEAD.pack(_WIRE_MAGIC, WIRE_VERSION, len(header))
-                + header + k.tobytes() + v.tobytes())
+                + header + b"".join(a.tobytes() for a in arrays))
 
     @classmethod
     def from_bytes(cls, blob: bytes) -> "MigrationTicket":
@@ -229,24 +229,21 @@ class MigrationTicket:
         except ValueError as exc:
             raise MigrationError(
                 f"corrupt ticket header: {exc}") from exc
-        k_dtype = _wire_dtype(meta["k_dtype"])
-        v_dtype = _wire_dtype(meta["v_dtype"])
-        k_shape = tuple(int(d) for d in meta["k_shape"])
-        v_shape = tuple(int(d) for d in meta["v_shape"])
-        k_bytes = int(np.prod(k_shape, dtype=np.int64)) * k_dtype.itemsize
-        v_bytes = int(np.prod(v_shape, dtype=np.int64)) * v_dtype.itemsize
+        specs = [(_wire_dtype(p["dtype"]),
+                  tuple(int(d) for d in p["shape"]))
+                 for p in meta["payloads"]]
+        counts = [int(np.prod(shape, dtype=np.int64)) for _, shape in specs]
+        declared = sum(n * dt.itemsize for n, (dt, _) in zip(counts, specs))
         off = _WIRE_HEAD.size + hlen
-        if len(blob) != off + k_bytes + v_bytes:
+        if len(blob) != off + declared:
             raise MigrationError(
                 f"truncated ticket payload: header declares "
-                f"{k_bytes + v_bytes} payload bytes, have "
-                f"{len(blob) - off}")
-        k = np.frombuffer(blob, dtype=k_dtype, count=int(
-            np.prod(k_shape, dtype=np.int64)), offset=off
-        ).reshape(k_shape).copy()
-        v = np.frombuffer(blob, dtype=v_dtype, count=int(
-            np.prod(v_shape, dtype=np.int64)), offset=off + k_bytes
-        ).reshape(v_shape).copy()
+                f"{declared} payload bytes, have {len(blob) - off}")
+        arrays = []
+        for n, (dt, shape) in zip(counts, specs):
+            arrays.append(np.frombuffer(
+                blob, dtype=dt, count=n, offset=off).reshape(shape).copy())
+            off += n * dt.itemsize
         sampling = meta["sampling"]
         if sampling is not None:
             from dla_tpu.ops.sampling import SamplingParams
@@ -260,7 +257,7 @@ class MigrationTicket:
             deadline=meta["deadline"], priority=meta["priority"],
             committed_len=meta["committed_len"],
             page_size=meta["page_size"], n_pages=meta["n_pages"],
-            k_payload=k, v_payload=v, transport="host",
+            payloads=tuple(arrays), transport="host",
             src_slot=meta["src_slot"],
             admitted_time=meta["admitted_time"],
             first_token_time=meta["first_token_time"],
@@ -305,8 +302,8 @@ class KVMigrator:
         if dst_dev is None or src_dev is None or src_dev == dst_dev:
             return                      # shared device: zero-copy handoff
         try:
-            ticket.k_payload = jax.device_put(ticket.k_payload, dst_dev)
-            ticket.v_payload = jax.device_put(ticket.v_payload, dst_dev)
+            ticket.payloads = tuple(
+                jax.device_put(x, dst_dev) for x in ticket.payloads)
         except Exception as exc:  # noqa: BLE001 — no D2D path: bounce
             if mode == "device":
                 raise MigrationError(
@@ -335,7 +332,7 @@ class KVMigrator:
 
     @staticmethod
     def _pool_device(engine):
-        devs = getattr(engine.cache.k_pages, "devices", None)
+        devs = getattr(engine.cache.pools[0], "devices", None)
         if devs is None:
             return None
         try:
@@ -345,7 +342,7 @@ class KVMigrator:
 
     @staticmethod
     def _payload_device(ticket: MigrationTicket):
-        devs = getattr(ticket.k_payload, "devices", None)
+        devs = getattr(ticket.payloads[0], "devices", None)
         if devs is None:
             return None                 # host-resident payload
         try:
@@ -358,6 +355,5 @@ class KVMigrator:
         if ticket.transport == "host":
             return
         # dla: disable=host-sync-in-hot-loop -- designed migration host bounce: one D2H per migrated request, counted on serving/migration/host_bounce_bytes
-        ticket.k_payload = np.asarray(ticket.k_payload)
-        ticket.v_payload = np.asarray(ticket.v_payload)
+        ticket.payloads = tuple(np.asarray(x) for x in ticket.payloads)
         ticket.transport = "host"

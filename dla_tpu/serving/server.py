@@ -278,6 +278,7 @@ class ServingEngine:
             bucket_widths=self._bucket_widths(geom),
             prefix_cache=self.prefix_cache)
         self.metrics = ServingMetrics()
+        self.metrics.kv_bytes_per_token.set(self.cache.bytes_per_token)
         self._pc_mirrored = {"lookups": 0, "hit_tokens": 0,
                              "evictions": 0}
         # speculative round accounting lives in plain engine ints and is
@@ -501,22 +502,31 @@ class ServingEngine:
 
     # -------------------------------------------------------- jitted steps
 
-    def _prefill_fn(self, params, k_pages, v_pages, ids, mask, page_rows):
-        """Prefill a padded bucket batch and scatter its KV into the
-        pool. ids/mask [PB, W]; page_rows [PB, W/page_size] physical page
-        ids (dummy rows -> trash page 0). Returns (k_pages, v_pages,
+    def _gather(self, pools, block_tables):
+        """In-graph block-table gather of every pool: [L, pages, ps,
+        heads, width] -> the [L, B, S, heads, width] windows the model's
+        paged steps take as ``view["kv"]``."""
+        sw = self.cache.geom.slot_window
+        return tuple(
+            p[:, block_tables].reshape(
+                p.shape[0], block_tables.shape[0], sw, *p.shape[3:])
+            for p in pools)
+
+    def _prefill_fn(self, params, pools, ids, mask, page_rows):
+        """Prefill a padded bucket batch and scatter its rows into the
+        pools. ids/mask [PB, W]; page_rows [PB, W/page_size] physical page
+        ids (dummy rows -> trash page 0). Returns (pools,
         last-real-token logits [PB, V])."""
         self.prefill_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the serving compile-once tests
         ps = self.cfg.page_size
-        logits, ks, vs = self.model.prefill_external(params, ids, mask)
-        l, pb, w, kh, dh = ks.shape
-        ks = ks.reshape(l, pb, w // ps, ps, kh, dh)
-        vs = vs.reshape(l, pb, w // ps, ps, kh, dh)
-        k_pages = k_pages.at[:, page_rows].set(ks)
-        v_pages = v_pages.at[:, page_rows].set(vs)
-        return k_pages, v_pages, logits
+        logits, rows = self.model.prefill_external(params, ids, mask)
+        pools = tuple(
+            p.at[:, page_rows].set(r.reshape(
+                r.shape[0], r.shape[1], r.shape[2] // ps, ps, *r.shape[3:]))
+            for p, r in zip(pools, rows))
+        return pools, logits
 
-    def _prefill_chunk_fn(self, params, k_pages, v_pages, btab, valid,
+    def _prefill_chunk_fn(self, params, pools, btab, valid,
                           pos, ids, start, nvalid, adapters=None):
         """One FIXED-SHAPE prefill chunk for a single slot: gather the
         slot's pages (the already-computed prefix — cached hit pages and
@@ -526,52 +536,47 @@ class ServingEngine:
         ``pos`` [1, S]; ``ids`` [1, C]; ``start``/``nvalid`` traced
         scalars (chunk's absolute start column / real-token count), so
         every chunk of every request reuses ONE compile. Returns
-        (k_pages, v_pages, logits [1, V]) — logits are the next-token
+        (pools, logits [1, V]) — logits are the next-token
         distribution after the chunk's last real token, meaningful only
         on a request's final chunk (the only one whose logits the host
         fetches)."""
         self.prefill_chunk_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the serving compile-once tests
         geom = self.cache.geom
         ps = geom.page_size
-        l = self.model.cfg.num_layers
         c = self.cfg.prefill_chunk
-        k_view = k_pages[:, btab].reshape(
-            l, 1, geom.slot_window, *k_pages.shape[3:])
-        v_view = v_pages[:, btab].reshape(
-            l, 1, geom.slot_window, *v_pages.shape[3:])
-        view = {"k": k_view, "v": v_view, "valid": valid, "pos": pos}
+        real = jnp.arange(c) < nvalid
+        view = {"kv": self._gather(pools, btab), "valid": valid, "pos": pos,
+                "real": real[None, :]}
         # absolute chunk schedule: positions are fixed by `start`, so a
         # cache hit changes WHICH chunks run, never the math inside one
         positions = start + jnp.arange(c, dtype=jnp.int32)[None, :]
         last_index = jnp.maximum(nvalid - 1, 0)[None]
-        logits, k_cols, v_cols = self.model.prefill_step_paged(
+        logits, rows, _ = self.model.prefill_step_paged(
             params, view, ids, positions, last_index, adapters=adapters)
         # scatter the chunk's columns at their physical (page, offset);
         # pad columns (index >= nvalid) route to the trash page
         cols = start + jnp.arange(c, dtype=jnp.int32)
         page_ids = btab[0, cols // ps]
         offs = cols % ps
-        real = jnp.arange(c) < nvalid
         page_ids = jnp.where(real, page_ids, 0)
         offs = jnp.where(real, offs, 0)
-        k_pages = k_pages.at[:, page_ids, offs].set(k_cols[:, 0])
-        v_pages = v_pages.at[:, page_ids, offs].set(v_cols[:, 0])
-        return k_pages, v_pages, logits
+        pools = tuple(p.at[:, page_ids, offs].set(r[:, 0])
+                      for p, r in zip(pools, rows))
+        return pools, logits
 
-    def _export_kv_fn(self, k_pages, v_pages, page_ids):
+    def _export_kv_fn(self, pools, page_ids):
         """Gather one request's ordered pages out of the pool into a
         migration payload. ``page_ids`` [pages_per_slot] physical page
         ids with pad entries routed to trash page 0 — the shape is fixed
         by engine geometry, so every export of every request reuses ONE
-        compile. Returns (k_payload, v_payload)
-        [L, pages_per_slot, page_size, KH, D]; the payload stays on
-        device (the migrator decides whether it ever touches the host).
+        compile. Returns the payloads, one [L, pages_per_slot, page_size,
+        heads, width] array per pool; they stay on device (the migrator
+        decides whether they ever touch the host).
         """
         self.export_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the migration compile-once tests
-        return k_pages[:, page_ids], v_pages[:, page_ids]
+        return tuple(p[:, page_ids] for p in pools)
 
-    def _import_kv_fn(self, k_pages, v_pages, k_payload, v_payload,
-                      page_ids):
+    def _import_kv_fn(self, pools, payloads, page_ids):
         """Scatter a migration payload onto freshly allocated pages in
         ONE fixed-shape call — the install half of the KV handoff.
         ``page_ids`` [pages_per_slot] with pad entries routed to trash
@@ -580,11 +585,10 @@ class ServingEngine:
         trash-page convention). Same one-compile-per-engine contract as
         the export gather."""
         self.import_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the migration compile-once tests
-        k_pages = k_pages.at[:, page_ids].set(k_payload)
-        v_pages = v_pages.at[:, page_ids].set(v_payload)
-        return k_pages, v_pages
+        return tuple(p.at[:, page_ids].set(x)
+                     for p, x in zip(pools, payloads))
 
-    def _decode_fn(self, params, k_pages, v_pages, block_tables, valid,
+    def _decode_fn(self, params, pools, block_tables, valid,
                    pos, lengths, tokens, active, temps, top_ps, top_ks,
                    seeds, gen_pos, adapters=None):
         """One static-shape decode step over every slot: gather each
@@ -592,25 +596,22 @@ class ServingEngine:
         step, sample PER-ROW (each slot's traced temperature/top_p/top_k/
         seed, keyed by the slot's generated-token index), scatter the
         fresh KV column back. Free slots compute garbage routed to the
-        trash page. Returns the fresh KV pools plus a packed [2, B] int32
+        trash page. Returns the fresh pools plus a packed [4, B] int32
         array — row 0 the sampled tokens, row 1 their chosen-token
-        logprobs bitcast to int32 — so the host still performs exactly
+        logprobs bitcast to int32, rows 2 and 3 the step's expert
+        counters broadcast (held experts that received a token, and
+        (token, choice) pairs that landed here, summed over layers; 0
+        without experts) — so the host still performs exactly
         ONE D2H fetch per decode step (the execution-model invariant).
         The pack is integer because a small token id viewed as f32 is a
         subnormal, and the TPU flushes those to zero."""
         self.decode_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the serving compile-once tests
         geom = self.cache.geom
         ps = geom.page_size
-        l = self.model.cfg.num_layers
         b = geom.num_slots
-        # in-graph block-table gather: [L, B, pages/slot, ps, KH, D]
-        k_view = k_pages[:, block_tables].reshape(
-            l, b, geom.slot_window, *k_pages.shape[3:])
-        v_view = v_pages[:, block_tables].reshape(
-            l, b, geom.slot_window, *v_pages.shape[3:])
-        view = {"k": k_view, "v": v_view, "valid": valid, "pos": pos,
-                "lengths": lengths}
-        logits, k_cols, v_cols = self.model.decode_step_paged(
+        view = {"kv": self._gather(pools, block_tables), "valid": valid,
+                "pos": pos, "lengths": lengths, "real": active[:, None]}
+        logits, rows, routed = self.model.decode_step_paged(
             params, view, tokens, adapters=adapters)
         # a free slot keeps its last request's temperature: zeroed, so
         # only running rows decide whether the step filters and draws
@@ -627,13 +628,15 @@ class ServingEngine:
         offs = col % ps
         page_ids = jnp.where(active, page_ids, 0)
         offs = jnp.where(active, offs, 0)
-        k_pages = k_pages.at[:, page_ids, offs].set(k_cols[:, :, 0])
-        v_pages = v_pages.at[:, page_ids, offs].set(v_cols[:, :, 0])
+        pools = tuple(p.at[:, page_ids, offs].set(r[:, :, 0])
+                      for p, r in zip(pools, rows))
         packed = jnp.stack(
-            [new_tok, jax.lax.bitcast_convert_type(logp, jnp.int32)])
-        return k_pages, v_pages, packed
+            [new_tok, jax.lax.bitcast_convert_type(logp, jnp.int32),
+             jnp.broadcast_to(routed[0], (b,)),
+             jnp.broadcast_to(routed[1], (b,))])
+        return pools, packed
 
-    def _spec_draft_fn(self, draft_params, k_pages, v_pages, block_tables,
+    def _spec_draft_fn(self, draft_params, pools, block_tables,
                        valid, pos, lengths, tokens, active, temps,
                        top_ps, top_ks, seeds, gen_pos, adapters=None):
         """The speculative DRAFT phase: K sequential fixed-shape decode
@@ -647,26 +650,22 @@ class ServingEngine:
         proposes exactly the tokens the target will sample, and the
         token-matching verify accepts the whole block. Columns beyond
         the slot window or the allocated pages route to the trash page.
-        Returns (k_pages, v_pages, proposals [B, K]); the proposals stay
+        Returns (pools, proposals [B, K]); the proposals stay
         on device and flow straight into the verify dispatch — no D2H.
         """
         self.spec_draft_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the speculative compile-once tests
         geom = self.cache.geom
         ps = geom.page_size
-        l = self.model.cfg.num_layers
-        b = geom.num_slots
         sw = geom.slot_window
         col_ids = jnp.arange(sw, dtype=jnp.int32)[None, :]
         temps = jnp.where(active, temps, 0.0)   # as in _decode_fn
 
         def draft_step(carry, i):
-            cur, valid_c, pos_c, kp, vp = carry
-            k_view = kp[:, block_tables].reshape(l, b, sw, *kp.shape[3:])
-            v_view = vp[:, block_tables].reshape(l, b, sw, *vp.shape[3:])
+            cur, valid_c, pos_c, pools_c = carry
             lens_i = lengths + i
-            view = {"k": k_view, "v": v_view, "valid": valid_c,
-                    "pos": pos_c, "lengths": lens_i}
-            logits, k_cols, v_cols = self.model.decode_step_paged(
+            view = {"kv": self._gather(pools_c, block_tables),
+                    "valid": valid_c, "pos": pos_c, "lengths": lens_i}
+            logits, rows, _ = self.model.decode_step_paged(
                 draft_params, view, cur, adapters=adapters)
             nxt, _ = sample_token_per_row(
                 seeds, gen_pos + i, logits, temps, top_ps, top_ks)
@@ -680,19 +679,19 @@ class ServingEngine:
             offs = col % ps
             page_ids = jnp.where(in_win, page_ids, 0)
             offs = jnp.where(in_win, offs, 0)
-            kp = kp.at[:, page_ids, offs].set(k_cols[:, :, 0])
-            vp = vp.at[:, page_ids, offs].set(v_cols[:, :, 0])
+            pools_c = tuple(p.at[:, page_ids, offs].set(r[:, :, 0])
+                            for p, r in zip(pools_c, rows))
             written = (col_ids == col[:, None]) & in_win[:, None]
             valid_c = valid_c | written
             pos_c = jnp.where(written, col[:, None], pos_c)
-            return (nxt, valid_c, pos_c, kp, vp), nxt
+            return (nxt, valid_c, pos_c, pools_c), nxt
 
-        (_, _, _, k_pages, v_pages), props = jax.lax.scan(
-            draft_step, (tokens, valid, pos, k_pages, v_pages),
+        (_, _, _, pools), props = jax.lax.scan(
+            draft_step, (tokens, valid, pos, pools),
             jnp.arange(self._spec_k, dtype=jnp.int32))
-        return k_pages, v_pages, jnp.moveaxis(props, 0, 1)
+        return pools, jnp.moveaxis(props, 0, 1)
 
-    def _spec_verify_fn(self, params, k_pages, v_pages, block_tables,
+    def _spec_verify_fn(self, params, pools, block_tables,
                         valid, pos, lengths, tokens, proposals, active,
                         temps, top_ps, top_ks, seeds, gen_pos,
                         adapters=None):
@@ -718,18 +717,13 @@ class ServingEngine:
         self.spec_verify_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the speculative compile-once tests
         geom = self.cache.geom
         ps = geom.page_size
-        l = self.model.cfg.num_layers
         b = geom.num_slots
         sw = geom.slot_window
         g = self._spec_k + 1
-        k_view = k_pages[:, block_tables].reshape(
-            l, b, sw, *k_pages.shape[3:])
-        v_view = v_pages[:, block_tables].reshape(
-            l, b, sw, *v_pages.shape[3:])
-        view = {"k": k_view, "v": v_view, "valid": valid, "pos": pos,
-                "lengths": lengths}
+        view = {"kv": self._gather(pools, block_tables), "valid": valid,
+                "pos": pos, "lengths": lengths}
         block = jnp.concatenate([tokens[:, None], proposals], axis=1)
-        logits, k_cols, v_cols = self.model.decode_block_paged(
+        logits, rows, _ = self.model.decode_block_paged(
             params, view, block, adapters=adapters)
         toks, logps = sample_token_block(
             seeds, gen_pos, logits, jnp.where(active, temps, 0.0),
@@ -746,13 +740,13 @@ class ServingEngine:
         offs = cols % ps
         page_ids = jnp.where(in_win, page_ids, 0)
         offs = jnp.where(in_win, offs, 0)
-        k_pages = k_pages.at[:, page_ids, offs].set(k_cols)
-        v_pages = v_pages.at[:, page_ids, offs].set(v_cols)
+        pools = tuple(p.at[:, page_ids, offs].set(r)
+                      for p, r in zip(pools, rows))
         packed = jnp.stack([
             toks,
             jax.lax.bitcast_convert_type(logps, jnp.int32),
             jnp.broadcast_to(acc[:, None], (b, g))])
-        return k_pages, v_pages, packed
+        return pools, packed
 
     # ------------------------------------------------------------- intake
 
@@ -1095,8 +1089,7 @@ class ServingEngine:
         ids = np.zeros((geom.pages_per_slot,), np.int32)
         ids[:needed] = req.pages[:needed]
         with annotate("serve_kv_export", rid=req.rid):
-            k_payload, v_payload = self._export_kv(
-                self.cache.k_pages, self.cache.v_pages, self._dev(ids))
+            payloads = self._export_kv(self.cache.pools, self._dev(ids))
         return MigrationTicket(
             rid=req.rid,
             prompt_tokens=list(req.prompt_tokens),
@@ -1110,8 +1103,7 @@ class ServingEngine:
             committed_len=committed,
             page_size=self.cfg.page_size,
             n_pages=needed,
-            k_payload=k_payload,
-            v_payload=v_payload,
+            payloads=payloads,
             admitted_time=req.admitted_time,
             first_token_time=req.first_token_time,
             last_token_time=req.last_token_time,
@@ -1155,11 +1147,13 @@ class ServingEngine:
             return self._import_refuse(
                 f"ticket {ticket.rid}: n_pages {ticket.n_pages} != "
                 f"{needed} for {committed} committed columns")
-        kshape = tuple(getattr(ticket.k_payload, "shape", ()))
-        if len(kshape) < 2 or kshape[1] != geom.pages_per_slot:
+        shapes = [tuple(getattr(x, "shape", ())) for x in ticket.payloads]
+        want = [p.shape[:1] + (geom.pages_per_slot,) + p.shape[2:]
+                for p in self.cache.pools]
+        if shapes != want:
             return self._import_refuse(
-                f"ticket {ticket.rid}: payload geometry {kshape} does "
-                f"not match pages_per_slot {geom.pages_per_slot}")
+                f"ticket {ticket.rid}: payload geometry {shapes} does "
+                f"not match this engine's pools {want}")
         if len(ticket.prompt_tokens) + ticket.max_new_tokens \
                 > geom.slot_window:
             return self._import_refuse(
@@ -1187,9 +1181,8 @@ class ServingEngine:
         ids = np.zeros((geom.pages_per_slot,), np.int32)
         ids[:needed] = pages[:needed]
         with annotate("serve_kv_import", rid=ticket.rid):
-            self.cache.k_pages, self.cache.v_pages = self._import_kv(
-                self.cache.k_pages, self.cache.v_pages,
-                ticket.k_payload, ticket.v_payload, self._dev(ids))
+            self.cache.pools = self._import_kv(
+                self.cache.pools, tuple(ticket.payloads), self._dev(ids))
         req = Request(prompt_tokens=list(ticket.prompt_tokens),
                       max_new_tokens=int(ticket.max_new_tokens),
                       arrival_time=ticket.arrival_time,
@@ -1669,9 +1662,8 @@ class ServingEngine:
             mark("serve_req_admit", rid=req.rid, slot=req.slot,
                  cached_tokens=0)
         with annotate("serve_prefill", n=len(batch), width=width):
-            self.cache.k_pages, self.cache.v_pages, logits = self._prefill(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(page_rows))
+            self.cache.pools, logits = self._prefill(
+                self.params, self.cache.pools, jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(page_rows))
             # dla: disable=host-sync-in-hot-loop -- designed prefill D2H: one logits fetch per admitted batch, not per token
             logits_np = np.asarray(logits)
         t_done = self.now()
@@ -1768,8 +1760,8 @@ class ServingEngine:
         with annotate("serve_prefill_chunk", rid=req.rid, slot=slot,
                       start=start, nvalid=nvalid,
                       last=int(start + nvalid >= n)):
-            c.k_pages, c.v_pages, logits = self._prefill_chunk(
-                self.params, c.k_pages, c.v_pages,
+            c.pools, logits = self._prefill_chunk(
+                self.params, c.pools,
                 self._dev(c.block_tables[slot:slot + 1]),
                 self._dev(c.valid[slot:slot + 1]),
                 self._dev(c.pos[slot:slot + 1]),
@@ -1913,13 +1905,20 @@ class ServingEngine:
                 raise DeviceStepError(
                     "injected device error (fault plan engine_step)")
             with annotate("serve_decode_dispatch"):
-                c.k_pages, c.v_pages, packed = self._decode(
-                    self.params, c.k_pages, c.v_pages, *args)
+                c.pools, packed = self._decode(self.params, c.pools, *args)
             with annotate("serve_decode_fetch"):
                 # dla: disable=host-sync-in-hot-loop -- the designed single D2H per decode step (execution-model invariant)
                 packed_np = np.asarray(packed)
             toks_np = packed_np[0]
             logps_np = packed_np[1].view(np.float32)
+            if self.model.cfg.num_experts:
+                # rode the same fetch: which of the held experts this
+                # step's rows chose, summed over layers
+                hit, landed = int(packed_np[2, 0]), int(packed_np[3, 0])
+                mark("serve_moe_route", experts_hit=hit,
+                     expert_assignments=landed, slots=len(active_slots))
+                self.metrics.moe_experts_hit.inc(hit)
+                self.metrics.moe_expert_assignments.inc(landed)
             if self._fault_nan_logits:
                 # injected AFTER the fetch, where the real NaN guard below
                 # (_sample_host) and a device-side check would trip: the
@@ -1970,12 +1969,12 @@ class ServingEngine:
                 # draft and verify share one adapter view: the draft
                 # proposes under the SAME per-slot deltas the target
                 # verifies with, so per-tenant acceptance stays high
-                c.k_pages, c.v_pages, proposals = self._spec_draft(
-                    self.draft_params, c.k_pages, c.v_pages, btab, valid,
+                c.pools, proposals = self._spec_draft(
+                    self.draft_params, c.pools, btab, valid,
                     pos, lengths, tokens, active_d, temps, top_ps, top_ks,
                     seeds, gpos, adapters)
-                c.k_pages, c.v_pages, packed = self._spec_verify(
-                    self.params, c.k_pages, c.v_pages, btab, valid, pos,
+                c.pools, packed = self._spec_verify(
+                    self.params, c.pools, btab, valid, pos,
                     lengths, tokens, proposals, active_d, temps, top_ps,
                     top_ks, seeds, gpos, adapters)
             with annotate("serve_decode_fetch"):

@@ -236,6 +236,15 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/decode_steps_sampled", "counter", "steps",
        "decode steps with a running slot of temperature > 0 (the "
        "sampler's filter-and-draw branch)", "step"),
+    _s("serving/moe/experts_hit", "counter", "experts",
+       "held experts that received a token, summed over layers and "
+       "decode steps (dropless routing)", "step"),
+    _s("serving/moe/expert_assignments", "counter", "pairs",
+       "(token, choice) pairs that landed on a held expert, summed over "
+       "layers and decode steps", "step"),
+    _s("serving/kv_bytes_per_token", "gauge", "bytes",
+       "bytes one cached token takes in the paged pool over every layer "
+       "(keys and values, or one latent row)", "step"),
     _s("serving/prefill_batches", "counter", "batches", "", "step"),
     _s("serving/tokens_generated", "counter", "tokens", "", "step"),
     _s("serving/ttft_ms", "histogram", "ms",

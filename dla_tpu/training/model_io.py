@@ -74,7 +74,11 @@ def _arch_overrides(model_cfg: Dict[str, Any]) -> Dict[str, Any]:
                 "pipeline_stages",
                 "num_experts", "num_experts_per_token",
                 "moe_capacity_factor", "moe_group_size", "moe_aux_weight",
-                "moe_z_weight"):
+                "moe_z_weight", "moe_intermediate_size",
+                "num_shared_experts", "moe_routed_scale",
+                "moe_first_expert", "moe_experts_held",
+                "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "rope_interleave"):
         if key in model_cfg:
             out[key] = model_cfg[key]
     # reference model.lora block (config/distill_config.yaml:10-14; dead

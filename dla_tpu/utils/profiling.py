@@ -150,6 +150,10 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "serve_decode_args": ("engine host loop", ()),
     "serve_decode_dispatch": ("engine host loop", ()),
     "serve_decode_fetch": ("engine host loop", ()),
+    # mark after the fetch, models with routed experts only: what the
+    # step's dropless routing did, summed over layers
+    "serve_moe_route": ("experts", ("experts_hit", "expert_assignments",
+                                    "slots")),
     "serve_emit": ("engine host loop", ("slots",)),
     "serve_post": ("engine host loop", ()),
     "serve_kv_export": ("KV pool", ("rid",)),

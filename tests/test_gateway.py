@@ -47,6 +47,20 @@ def serve_setup():
     return model, params, gen
 
 
+@pytest.fixture(scope="module", params=["tiny", "tiny-mla"])
+def pool_setup(request):
+    """``serve_setup`` over both kinds of cache row (dense keys and
+    values; one latent row): a ticket carries one payload per pool."""
+    from dla_tpu.generation.engine import GenerationConfig
+    from dla_tpu.models.config import get_model_config
+    from dla_tpu.models.transformer import Transformer
+    model = Transformer(get_model_config(request.param))
+    params = model.init(jax.random.key(7))
+    gen = GenerationConfig(max_new_tokens=16, do_sample=False,
+                           eos_token_id=-1, pad_token_id=0)
+    return model, params, gen
+
+
 def _engine(serve_setup, **cfg_kw):
     model, params, gen = serve_setup
     kw = dict(page_size=PAGE, num_pages=64, num_slots=2,
@@ -132,8 +146,8 @@ def _mid_decode_ticket(serve_setup):
     return eng.export_request(rid)
 
 
-def test_ticket_wire_roundtrip_bit_identical(serve_setup):
-    ticket = _mid_decode_ticket(serve_setup)
+def test_ticket_wire_roundtrip_bit_identical(pool_setup):
+    ticket = _mid_decode_ticket(pool_setup)
     blob = ticket.to_bytes()
     back = MigrationTicket.from_bytes(blob)
     assert back.rid == ticket.rid
@@ -144,13 +158,14 @@ def test_ticket_wire_roundtrip_bit_identical(serve_setup):
     assert back.sampling == ticket.sampling
     assert back.committed_len == ticket.committed_len
     assert back.n_pages == ticket.n_pages
-    k0 = np.asarray(ticket.k_payload)
-    v0 = np.asarray(ticket.v_payload)
-    k1, v1 = np.asarray(back.k_payload), np.asarray(back.v_payload)
-    assert k1.dtype == k0.dtype and k1.shape == k0.shape
-    # bit-identity, not tolerance: the payload must survive the wire
-    assert k0.tobytes() == k1.tobytes()
-    assert v0.tobytes() == v1.tobytes()
+    model = pool_setup[0]
+    assert len(ticket.payloads) == len(back.payloads) \
+        == len(model.cache_rows())
+    for sent, got in zip(ticket.payloads, back.payloads):
+        sent, got = np.asarray(sent), np.asarray(got)
+        assert got.dtype == sent.dtype and got.shape == sent.shape
+        # bit-identity, not tolerance: the payload must survive the wire
+        assert sent.tobytes() == got.tobytes()
     # serialization is pure: a second encode is byte-stable
     assert MigrationTicket.from_bytes(blob).to_bytes() == blob
 

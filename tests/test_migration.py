@@ -43,6 +43,22 @@ def serve_setup():
     return model, params, gen
 
 
+@pytest.fixture(scope="module", params=["tiny", "tiny-mla"])
+def pool_setup(request):
+    """``serve_setup`` over both kinds of cache row: keys and values of
+    [KH, D] a token (dense attention) and one latent row of [1, r + rope]
+    (latent attention). The pool, copy-on-write, export and import follow
+    the model's ``cache_rows()``; these tests hold both to one behaviour."""
+    from dla_tpu.generation.engine import GenerationConfig
+    from dla_tpu.models.config import get_model_config
+    from dla_tpu.models.transformer import Transformer
+    model = Transformer(get_model_config(request.param))
+    params = model.init(jax.random.key(7))
+    gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
+                           eos_token_id=-1, pad_token_id=0)
+    return model, params, gen
+
+
 def _engine(serve_setup, **cfg_kw):
     """One engine with the migration-test geometry; fault_plan="" (not
     None) pins it fault-free even when $DLA_FAULT_PLAN is set."""
@@ -123,21 +139,21 @@ def test_decode_role_gates_submit(serve_setup):
     None,
     SamplingParams(temperature=0.8, top_k=20, seed=1234),
 ], ids=["greedy", "seeded-sampled"])
-def test_migrate_mid_decode_resumes_bit_identical(serve_setup, sampling):
+def test_migrate_mid_decode_resumes_bit_identical(pool_setup, sampling):
     """Export after 2 streamed tokens, install on a fresh decode-role
     engine, finish there: the merged stream equals the single-engine
     run exactly — the scatter restored the exact committed KV columns
     and the ``fold_in(seed, k)`` sampling stream is engine-independent."""
     prompt = [3, 5, 7, 2, 9, 4, 6, 8, 11, 13]
-    ref = _engine(serve_setup)
+    ref = _engine(pool_setup)
     rid = ref.submit(prompt, MAX_NEW, sampling=sampling)
     _drain(ref)
     want = list(ref.result(rid).generated)
     assert len(want) == MAX_NEW
     ref.close()
 
-    src = _engine(serve_setup)
-    dst = _engine(serve_setup, role="decode")
+    src = _engine(pool_setup)
+    dst = _engine(pool_setup, role="decode")
     rid = src.submit(prompt, MAX_NEW, sampling=sampling)
     _run_to(src, rid, 2)
     streamed = list(src.result(rid).generated)
@@ -164,15 +180,15 @@ def test_migrate_mid_decode_resumes_bit_identical(serve_setup, sampling):
     dst.close()
 
 
-def test_migrate_cow_shared_pages_keeps_refcounts(serve_setup):
+def test_migrate_cow_shared_pages_keeps_refcounts(pool_setup):
     """Two same-prompt requests share prefix pages on the source (COW
     via the prefix cache). Migrating one must not disturb the stayer:
     export is read-only, release decrefs only the mover's references,
     and the target registers its fresh copies into its own cache at
     refcount 1 + indexed."""
     prompt = [3, 5, 7, 2, 9, 4, 6, 8]           # 2 full pages
-    src = _engine(serve_setup)
-    dst = _engine(serve_setup, role="decode")
+    src = _engine(pool_setup)
+    dst = _engine(pool_setup, role="decode")
     warm = src.submit(prompt, MAX_NEW)           # registers the prefix
     _drain(src)
     del warm
@@ -229,12 +245,12 @@ def test_export_refuses_eviction_holes_and_counts(serve_setup):
     dst.close()
 
 
-def test_import_and_export_compile_exactly_once(serve_setup):
+def test_import_and_export_compile_exactly_once(pool_setup):
     """The gather/scatter pair is fixed-shape (pad page ids route to
     the trash page): migrating requests of different lengths must not
     recompile either side."""
-    src = _engine(serve_setup)
-    dst = _engine(serve_setup, role="decode")
+    src = _engine(pool_setup)
+    dst = _engine(pool_setup, role="decode")
     mig = KVMigrator(MigrationConfig())
     for i, plen in enumerate((5, 9, 13)):        # 2, 3, 4 pages committed
         prompt = [3 + i] * plen
@@ -252,9 +268,9 @@ def test_import_and_export_compile_exactly_once(serve_setup):
     dst.close()
 
 
-def test_host_transport_bounces_and_counts_bytes(serve_setup):
-    src = _engine(serve_setup)
-    dst = _engine(serve_setup, role="decode")
+def test_host_transport_bounces_and_counts_bytes(pool_setup):
+    src = _engine(pool_setup)
+    dst = _engine(pool_setup, role="decode")
     rid = src.submit([1, 2, 3, 4, 5, 6, 7, 8], MAX_NEW)
     _run_to(src, rid, 2)
     KVMigrator(MigrationConfig("host")).migrate(src, rid, dst)
@@ -270,11 +286,11 @@ def test_host_transport_bounces_and_counts_bytes(serve_setup):
 # restore fast path: alias cached pages instead of re-prefilling
 # ---------------------------------------------------------------------------
 
-def test_restore_aliases_cached_pages_without_prefill(serve_setup):
+def test_restore_aliases_cached_pages_without_prefill(pool_setup):
     """When the prefix cache holds EVERY committed page, restore adopts
     straight into decode — zero prefill chunks — and still reproduces
     the original continuation bit-for-bit."""
-    eng = _engine(serve_setup)
+    eng = _engine(pool_setup)
     prompt = [3, 5, 7, 2, 9, 4, 6, 8]            # page-aligned prompt
     rid = eng.submit(prompt, MAX_NEW)
     _drain(eng)

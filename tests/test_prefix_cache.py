@@ -235,6 +235,15 @@ def model_and_params():
     return model, model.init(jax.random.key(7))
 
 
+@pytest.fixture(scope="module", params=["tiny", "tiny-mla"])
+def pool_model_and_params(request):
+    """``model_and_params`` over both kinds of cache row (dense keys and
+    values; one latent row): register / lookup, copy-on-write and
+    eviction follow ``Transformer.cache_rows()``."""
+    model = Transformer(get_model_config(request.param))
+    return model, model.init(jax.random.key(7))
+
+
 @pytest.fixture(scope="module")
 def shared_prefix_prompts():
     rs = np.random.RandomState(11)
@@ -266,12 +275,12 @@ def _serve(eng, prompts):
 
 
 def test_prefix_cache_saves_half_of_prefill_bit_identically(
-        model_and_params, shared_prefix_prompts):
+        pool_model_and_params, shared_prefix_prompts):
     """The acceptance gate: 8 families x 16 requests, prefill token
     compute drops >= 50%, greedy outputs are bit-identical cache on vs
     off, and both engines pin their compile counts (one decode, one
     chunk fn, zero monolithic prefills)."""
-    model, params = model_and_params
+    model, params = pool_model_and_params
     prompts = shared_prefix_prompts
     total = sum(len(p) for p in prompts)
 
@@ -298,12 +307,12 @@ def test_prefix_cache_saves_half_of_prefill_bit_identically(
 
 
 def test_full_prompt_hit_skips_prefill_and_cow_protects_pages(
-        model_and_params):
+        pool_model_and_params):
     """Identical prompts: the second is an exact-full-prompt hit (zero
     chunks run — stored logits + aliased tail page), and the THIRD still
     matches, proving the second request's first decode write went to a
     copy, not the cached tail page."""
-    model, params = model_and_params
+    model, params = pool_model_and_params
     rs = np.random.RandomState(5)
     prompt = [int(t) for t in rs.randint(3, 500, (10,))]
 
@@ -319,11 +328,11 @@ def test_full_prompt_hit_skips_prefill_and_cow_protects_pages(
 
 
 def test_eviction_under_cache_pressure_recomputes_identically(
-        model_and_params, shared_prefix_prompts):
+        pool_model_and_params, shared_prefix_prompts):
     """A pool too small to retain every family's chain forces cached-
     page eviction; outputs must still match the cache-off run (evicted
     prefixes recompute, stale chains never resurface)."""
-    model, params = model_and_params
+    model, params = pool_model_and_params
     prompts = shared_prefix_prompts
     # 24 pages: 4 slots x 4 pages in flight leaves ~7 cacheable pages —
     # far fewer than 8 families x 3 pages of prefix
@@ -366,10 +375,10 @@ def test_token_budget_defers_chunk_while_decodes_fill_it(
     eng.scheduler.assert_consistent()
 
 
-def test_chunked_matches_monolithic_prefill(model_and_params):
+def test_chunked_matches_monolithic_prefill(pool_model_and_params):
     """Chunked prefill (no cache) reproduces the monolithic engine's
     greedy tokens exactly — the chunk path is a pure re-schedule."""
-    model, params = model_and_params
+    model, params = pool_model_and_params
     rs = np.random.RandomState(13)
     prompts = [[int(t) for t in rs.randint(3, 500, (n,))]
                for n in (5, 9, 12, 7)]

@@ -95,12 +95,19 @@ ENGINES = {
     # capacity 7 pages: both prompts admit, cannot both grow to 12 tokens
     "preempting": dict(page_size=2, num_pages=8, num_slots=2,
                        max_model_len=12, prefill_chunk=2),
+    # latent attention + routed experts (the tiny-mla-moe preset): the
+    # decode phase also marks what its dropless routing did
+    "experts": dict(page_size=4, num_pages=32, num_slots=2, max_model_len=32,
+                    prefill_chunk=4),
 }
 
 
 @pytest.mark.parametrize("kind", sorted(ENGINES))
 def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
     model, params = model_and_params
+    if kind == "experts":
+        model = Transformer(get_model_config("tiny-mla-moe"))
+        params = model.init(jax.random.key(7))
     gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
                            eos_token_id=-1, pad_token_id=0)
     eng = ServingEngine(model, params, gen, ServingConfig(**ENGINES[kind]))
@@ -136,6 +143,10 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
             "serve_req_finish"}
     want |= ({"serve_prefill_chunk", "serve_chunk_fetch"}
              if ENGINES[kind].get("prefill_chunk") else {"serve_prefill"})
+    if kind == "experts":
+        want.add("serve_moe_route")
+    else:
+        assert not rec.named("serve_moe_route")     # no routed experts
     if kind == "preempting":
         want.add("serve_req_preempt")
         assert eng.metrics.preemptions.value == len(
@@ -163,15 +174,33 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
     reads = (eng._spec_k + 1) * geom.num_slots * geom.slot_window
     phases = rec.named("serve_decode")
     assert len(phases) == len(live) > 0
+    inside_decode = ["serve_decode_args", "serve_decode_dispatch",
+                     "serve_decode_fetch", "serve_emit"]
+    if kind == "experts":       # the mark follows the step's one fetch
+        inside_decode.insert(3, "serve_moe_route")
     for phase, held in zip(phases, live):
-        assert [k[0] for k in rec.children(phase)] == [
-            "serve_decode_args", "serve_decode_dispatch",
-            "serve_decode_fetch", "serve_emit"]
+        assert [k[0] for k in rec.children(phase)] == inside_decode
         assert phase[1]["read_tokens"] == reads
         assert phase[1]["live_tokens"] == held
         assert 1 <= phase[1]["slots"] <= geom.num_slots
         assert phase[1]["sampling_slots"] == 0      # greedy requests
     assert eng.metrics.decode_steps_sampled.value == 0
+    if kind == "experts":
+        cfg = model.cfg
+        routes = [r[1] for r in rec.named("serve_moe_route")]
+        assert len(routes) == len(phases)
+        for route, phase in zip(routes, phases):
+            assert route["slots"] == phase[1]["slots"]
+            # every running row's choices land (all experts are held),
+            # summed over layers; no expert is hit without a pair
+            assert route["expert_assignments"] == (
+                route["slots"] * cfg.num_experts_per_token * cfg.num_layers)
+            assert 1 <= route["experts_hit"] <= route["expert_assignments"]
+        snap = eng.metrics.snapshot()
+        assert snap["serving/moe/experts_hit"] == sum(
+            r["experts_hit"] for r in routes)
+        assert snap["serving/moe/expert_assignments"] == sum(
+            r["expert_assignments"] for r in routes)
 
     # each request: submit -> admit -> first_token -> finish, in time order
     for rid in rids:

@@ -89,6 +89,9 @@ class _ModelStub:
     cfg = _Cfg()
     adtype = jnp.float32
 
+    def cache_rows(self):       # keys and values of [KH, D] per token
+        return ((1, 2), (1, 2))
+
 
 def _sched(page_size=4, num_pages=16, num_slots=2, pages_per_slot=4,
            **cfg_kw):
@@ -274,36 +277,53 @@ def test_serving_no_recompile_and_no_leaks_across_arrivals(
     assert eng.prefill_compiles == len(widths)
 
 
-def test_serving_eviction_recomputes_identically(model_and_params):
+@pytest.mark.parametrize("preset", ["tiny", "tiny-mla"])
+def test_serving_eviction_recomputes_identically(preset, model_and_params):
     """A pool sized to force mid-decode preemption: the evicted request
     re-prefills prompt+generated and still lands on the reference
-    tokens (greedy recompute is deterministic)."""
-    model, params = model_and_params
+    tokens (greedy recompute is deterministic). Over both kinds of cache
+    row: keys and values (``tiny``) and one latent row (``tiny-mla``)."""
+    if preset == "tiny":
+        model, params = model_and_params
+    else:
+        model = Transformer(get_model_config(preset))
+        params = model.init(jax.random.key(7))
     rs = np.random.RandomState(11)
     use = [list(rs.randint(3, 500, (4,))) for _ in range(2)]
     gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
                            eos_token_id=2, pad_token_id=0)
-    fn = jax.jit(build_generate_fn(model, gen))
-    ids = np.asarray(use, np.int32)
-    out = fn(params, jnp.asarray(ids), jnp.ones_like(jnp.asarray(ids)),
-             jax.random.key(0))
-    resp = np.asarray(out["response_tokens"])
-    rmask = np.asarray(out["response_mask"])
-    want = [[int(t) for t, m in zip(resp[i], rmask[i]) if m]
-            for i in range(len(use))]
+
+    def serve(num_pages):
+        eng = ServingEngine(model, params, gen,
+                            ServingConfig(page_size=2, num_pages=num_pages,
+                                          num_slots=2, max_model_len=12,
+                                          max_prefill_batch=2))
+        rids = [eng.submit(p, MAX_NEW) for p in use]
+        results = _drain(eng)
+        return eng, [results[rid] for rid in rids]
+
+    # the reference: the same engine with room for both requests, and for
+    # the dense model also the contiguous fixed-batch engine (latent
+    # attention decodes against the paged pool only)
+    roomy, unpressured = serve(num_pages=32)
+    assert roomy.metrics.preemptions.value == 0
+    want = [list(req.generated) for req in unpressured]
+    if preset == "tiny":
+        fn = jax.jit(build_generate_fn(model, gen))
+        ids = np.asarray(use, np.int32)
+        out = fn(params, jnp.asarray(ids), jnp.ones_like(jnp.asarray(ids)),
+                 jax.random.key(0))
+        resp = np.asarray(out["response_tokens"])
+        rmask = np.asarray(out["response_mask"])
+        assert want == [[int(t) for t, m in zip(resp[i], rmask[i]) if m]
+                        for i in range(len(use))]
     # capacity 7 pages: both 4-token prompts admit at 3 pages (2 prompt
     # + reserve) but cannot BOTH grow to 9 tokens (5 pages each) ->
     # someone gets preempted mid-decode
-    eng = ServingEngine(model, params, gen,
-                        ServingConfig(page_size=2, num_pages=8,
-                                      num_slots=2, max_model_len=12,
-                                      max_prefill_batch=2))
-    rids = [eng.submit(p, MAX_NEW) for p in use]
-    results = _drain(eng)
+    eng, results = serve(num_pages=8)
     assert eng.metrics.preemptions.value >= 1, (
         "config was meant to force at least one preemption")
-    for rid, expect in zip(rids, want):
-        req = results[rid]
+    for req, expect in zip(results, want):
         assert req.generated == expect, (
             f"eviction recompute diverged (evictions={req.evictions})")
     assert eng.cache.allocator.used_count == 0

@@ -91,6 +91,9 @@ class _ModelStub:
     cfg = _Cfg()
     adtype = jnp.float32
 
+    def cache_rows(self):       # keys and values of [KH, D] per token
+        return ((1, 2), (1, 2))
+
 
 def _sched(page_size=4, num_pages=16, num_slots=2, pages_per_slot=4):
     geom = PageGeometry(page_size=page_size, num_pages=num_pages,
