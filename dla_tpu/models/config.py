@@ -243,6 +243,17 @@ class ModelConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def latent_row_width_(self) -> int:
+        """Numbers one cached token stores per layer under latent
+        attention: kv_lora_rank + qk_rope_head_dim, rounded up to whole
+        128-lane rows once it fills one (320 -> 384; the pad lanes hold
+        zeros). A row that is no multiple of the TPU's lane width gets a
+        pages-minor default layout there, and every gather and write of
+        a page then relays the pool out (PERF.md, PR 29)."""
+        width = self.kv_lora_rank + self.qk_rope_head_dim
+        return width if width < 128 else -(-width // 128) * 128
+
+    @property
     def expert_width_(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 

@@ -43,6 +43,7 @@ from dla_tpu.ops.rotary import (
     apply_rotary,
     position_query_scale,
     rotary_angles,
+    yarn_mscale,
 )
 
 Params = Dict[str, Any]
@@ -139,10 +140,8 @@ class Transformer:
             # lineage's convention): qk_head_dim**-0.5 * m**2 with
             # m = 0.1 * mscale_all_dim * ln(factor) + 1
             rs = cfg.rope_scaling or {}
-            factor = float(rs.get("factor") or 1.0)
-            m_all = float(rs.get("mscale_all_dim") or 0.0)
-            m = 0.1 * m_all * math.log(factor) + 1.0 \
-                if m_all and factor > 1.0 else 1.0
+            m = yarn_mscale(float(rs.get("factor") or 1.0),
+                            float(rs.get("mscale_all_dim") or 0.0))
             self._softmax_scale = cfg.head_dim_ ** -0.5 * m * m
 
     # ------------------------------------------------------- storage layout
@@ -625,37 +624,36 @@ class Transformer:
                     allow_flash, cp, flash_segs=flash_segs,
                     factored_mask=factored_mask)
                 attn_out = proj("wo", attn.reshape(b, t, -1))
-            x = x + _constrain(attn_out, ACT_SPEC)
-            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-            mlp_out, moe_aux = self._mlp(layer, h, proj, token_valid,
-                                         dropless=dropless)
-            return x + _constrain(mlp_out, ACT_SPEC), (row,), moe_aux
-        q = proj("wq", h).reshape(b, t, cfg.num_heads, dh)
-        k = proj("wk", h).reshape(b, t, cfg.num_kv_heads, dh)
-        v = proj("wv", h).reshape(b, t, cfg.num_kv_heads, dh)
-        q = _constrain(q, P(("data", "fsdp"), "sequence", "model", None))
-        k = _constrain(k, P(("data", "fsdp"), "sequence", "model", None))
-        q = apply_rotary(q, cos, sin, rotary_dim=rd)
-        k = apply_rotary(k, cos, sin, rotary_dim=rd)
-        new_kv = (k, v)
-        if kv_override is not None:
-            k, v = kv_override
-        attn = self._attention(q, k, v, kv_segment_mask,
-                               q_positions, kv_positions, allow_flash, cp,
-                               flash_segs=flash_segs,
-                               window=self._layer_window(layer),
-                               factored_mask=factored_mask)
-        attn = attn.reshape(b, t, cfg.num_heads * dh)
+            new_kv = (row,)
+        else:
+            q = proj("wq", h).reshape(b, t, cfg.num_heads, dh)
+            k = proj("wk", h).reshape(b, t, cfg.num_kv_heads, dh)
+            v = proj("wv", h).reshape(b, t, cfg.num_kv_heads, dh)
+            q = _constrain(q, P(("data", "fsdp"), "sequence", "model", None))
+            k = _constrain(k, P(("data", "fsdp"), "sequence", "model", None))
+            q = apply_rotary(q, cos, sin, rotary_dim=rd)
+            k = apply_rotary(k, cos, sin, rotary_dim=rd)
+            new_kv = (k, v)
+            if kv_override is not None:
+                k, v = kv_override
+            attn = self._attention(q, k, v, kv_segment_mask,
+                                   q_positions, kv_positions, allow_flash,
+                                   cp, flash_segs=flash_segs,
+                                   window=self._layer_window(layer),
+                                   factored_mask=factored_mask)
+            attn = attn.reshape(b, t, cfg.num_heads * dh)
 
-        if cfg.arch == "phi":
-            # parallel residual: attention and MLP both read the shared h
-            attn_out = _constrain(proj("wo", attn), ACT_SPEC)
-            ff = _constrain(jax.nn.gelu(proj("fc1", h), approximate=True),
-                            P(("data", "fsdp"), "sequence", "model"))
-            mlp_out = _constrain(proj("fc2", ff), ACT_SPEC)
-            return x + attn_out + mlp_out, new_kv, None
+            if cfg.arch == "phi":
+                # parallel residual: attention and MLP both read the
+                # shared h
+                attn_out = _constrain(proj("wo", attn), ACT_SPEC)
+                ff = _constrain(
+                    jax.nn.gelu(proj("fc1", h), approximate=True),
+                    P(("data", "fsdp"), "sequence", "model"))
+                mlp_out = _constrain(proj("fc2", ff), ACT_SPEC)
+                return x + attn_out + mlp_out, new_kv, None
 
-        attn_out = proj("wo", attn)
+            attn_out = proj("wo", attn)
         if cfg.arch == "gemma2":  # post-attn norm BEFORE the residual add
             attn_out = rms_norm(attn_out, layer["attn_post_norm"],
                                 cfg.rms_norm_eps)
@@ -719,8 +717,19 @@ class Transformer:
             [kvb[..., :nope],
              jnp.broadcast_to(kr, (b, t, h_, cfg.qk_rope_head_dim))], -1)
         q = jnp.concatenate([q_nope, q_rope], -1)
-        row = jnp.concatenate([ckv[:, :, None, :], kr], -1)
-        return q, k, kvb[..., nope:], row
+        return q, k, kvb[..., nope:], self._latent_row(ckv, kr)
+
+    def _latent_row(self, ckv: jnp.ndarray, kr: jnp.ndarray,
+                    heads: int = 1) -> jnp.ndarray:
+        """[ckv | kr | zeros] along the last axis, ``heads`` in between:
+        the cached row (heads = 1), and the absorbed query laid out like
+        it, both ``cfg.latent_row_width_`` wide."""
+        cfg = self.cfg
+        parts = [ckv[:, :, None, :] if heads == 1 else ckv, kr]
+        pad = cfg.latent_row_width_ - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+        if pad:
+            parts.append(jnp.zeros(kr.shape[:-1] + (pad,), kr.dtype))
+        return jnp.concatenate(parts, -1)
 
     def _latent_absorbed(self, layer: Params, hn: jnp.ndarray, cos, sin,
                          q_positions: jnp.ndarray, attend):
@@ -730,8 +739,9 @@ class Transformer:
         by all heads (multi-query attention over the rows, the value
         being the row's first r numbers). Same numbers as the expanded
         form. ``attend(q, k_new, v_new) -> [B, T, H, r+rope]`` is the
-        path's attention over (cached rows, new rows). Returns
-        ([B, T, H * v], row)."""
+        path's attention over (cached rows, new rows); rows and the
+        absorbed query are ``latent_row_width_`` wide, zeros past r +
+        rope. Returns ([B, T, H * v], row)."""
         cfg = self.cfg
         b, t, _ = hn.shape
         h_, nope, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
@@ -739,26 +749,26 @@ class Transformer:
             layer, hn, cos, sin, q_positions)
         wkv_b = self._weight(layer, "wkv_b").reshape(
             r, h_, nope + cfg.v_head_dim)
-        q_lat = jnp.concatenate(
-            [jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :nope]),
-             q_rope], -1)                                # [B, T, H, r+rope]
-        row = jnp.concatenate([ckv[:, :, None, :], kr], -1)
+        q_lat = self._latent_row(
+            jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :nope]),
+            q_rope, heads=h_)                            # [B, T, H, row]
+        row = self._latent_row(ckv, kr)
         # the row is key and value at once: the weighted sum over whole
-        # rows costs a quarter more multiplies than over their first r
-        # numbers and spares a sliced copy of the gathered window
+        # rows costs half more multiplies than over their first r numbers
+        # and spares a sliced copy of the gathered window
         u = attend(q_lat, row, row)[..., :r]
         out = jnp.einsum("bthr,rhv->bthv", u, wkv_b[..., nope:])
         return out.reshape(b, t, h_ * cfg.v_head_dim), row
 
     def cache_rows(self) -> Tuple[Tuple[int, int], ...]:
         """What one token stores per layer, as (heads, width) per pool:
-        keys and values of [KH, D] for dense attention, one [1, r+rope]
-        latent row for latent attention. A paged pool allocates one
-        [L, pages, page_size, heads, width] array per entry; the paged
-        steps below take and return tuples in this order."""
+        keys and values of [KH, D] for dense attention, one lane-padded
+        [1, r + rope] latent row for latent attention. A paged pool
+        allocates one [L, pages, page_size, heads, width] array per
+        entry; the paged steps take and return tuples in this order."""
         cfg = self.cfg
         if cfg.latent_attention:
-            return ((1, cfg.kv_lora_rank + cfg.qk_rope_head_dim),)
+            return ((1, cfg.latent_row_width_),)
         return ((cfg.num_kv_heads, cfg.head_dim_),) * 2
 
     # ------------------------------------------------------------------ mlp
@@ -1977,15 +1987,28 @@ class Transformer:
     def _paged_layers(self, params: Params, view: Params, x: jnp.ndarray,
                       positions: jnp.ndarray, adapters: Optional[Params],
                       attention):
-        """The layer scan the three paged steps share: ``x`` [B, T, D]
-        embedded tokens at absolute ``positions`` [B, T] attend jointly
-        over the externally gathered window and their own fresh rows
-        through ``attention`` (``decode_attention`` for one token,
-        ``block_decode_attention`` for several). Returns (hidden after
-        the final norm [B, T, D], fresh rows — one [L, B, T, heads,
-        width] array per ``cache_rows()`` entry — and int32 [2] = (held
-        experts that received a token, (token, choice) pairs that landed
-        here) summed over layers)."""
+        """The layer scan the three paged steps share, over a block-paged
+        pool (dla_tpu/serving/kv_blocks.py). ``x`` [B, T, D] embedded
+        tokens at absolute ``positions`` [B, T]. Block l gathers each
+        row's pages out of ITS layer of every pool into the row's [S]
+        window, attends jointly over that window and the tokens' own
+        fresh rows through ``attention`` (``decode_attention`` for one
+        token, ``block_decode_attention`` for several), and writes the
+        fresh rows into its layer at ``write_pages`` / ``write_offs``.
+
+        Gather and write live inside the scan, a layer at a time, with
+        the pools riding the scan's carry (updated in place): gathering
+        every layer's window up front makes XLA transpose the whole pool
+        to pages-major and the gathered view back to layer-major, and a
+        write with the layer axis in its window relays the pool out again
+        (on a v5e, two thirds of a decode step and 3 GiB of temporaries);
+        pools scanned as inputs and outputs are copied slab by slab,
+        three times a layer (PERF.md, PR 29).
+
+        Returns (hidden after the final norm [B, T, D], the pools with
+        the fresh rows written, and int32 [2] = (held experts that
+        received a token, (token, choice) pairs that landed here) summed
+        over layers)."""
         cfg = self.cfg
         if self._kv_int8:
             raise NotImplementedError(
@@ -1994,22 +2017,26 @@ class Transformer:
                 "decode_step path")
         cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,
                                  scaling=cfg.rope_scaling)
+        tables = view["block_tables"]                    # [B, pages/slot]
+        b, window = view["valid"].shape
 
         layers = dict(self._with_layer_windows(
             self._flat_layers(params["layers"])))
+        layers["layer_index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         stack = None
         if "router" in layers:
             # the routed experts' weights stay stacked outside the scan
-            # (ops.moe.moe_mlp_dropless, ``layer``): the scan carries the
-            # block index in their place
+            # (ops.moe.moe_mlp_dropless, ``layer``): the block index
+            # stands in for them
             stack = {k: layers.pop(k) for k in ("w_gate", "w_up", "w_down")}
-            layers["layer_index"] = jnp.arange(cfg.num_layers,
-                                               dtype=jnp.int32)
 
-        def body(carry, xs):
-            layer, *cached = xs
+        def body(carry, layer):
+            h, pools = carry                  # pool [L, pages, page, h, w]
+            l = layer["layer_index"]
             if stack is not None:
                 layer = {**layer, "expert_stack": stack}
+            cached = [p[l, tables].reshape(b, window, *p.shape[3:])
+                      for p in pools]
 
             def attend(q, k, v):
                 # dense: (keys, values); latent: the one pool of rows is
@@ -2022,46 +2049,52 @@ class Transformer:
                     softmax_scale=self._softmax_scale,
                     logit_softcap=cfg.attn_logit_softcap)
 
-            return self._decode_layer(layer, carry, cos, sin, attend,
-                                      q_positions=positions,
-                                      token_valid=view.get("real"))
+            h, (rows, routed) = self._decode_layer(
+                layer, h, cos, sin, attend, q_positions=positions,
+                token_valid=view.get("real"))
+            pools = tuple(
+                p.at[l, view["write_pages"], view["write_offs"]].set(r)
+                for p, r in zip(pools, rows))
+            return (h, pools), routed
 
-        xs = ({**layers, **self.slot_lora_xs(adapters)}, *view["kv"])
-        x, (rows, routed) = jax.lax.scan(body, x, xs)
-        return self._final_norm(params, x), rows, jnp.sum(routed, axis=0)
+        (x, pools), routed = jax.lax.scan(
+            body, (x, tuple(view["pools"])),
+            {**layers, **self.slot_lora_xs(adapters)})
+        return self._final_norm(params, x), pools, jnp.sum(routed, axis=0)
 
     def decode_step_paged(self, params: Params, view: Params,
                           tokens: jnp.ndarray,  # [B] the tokens just sampled
                           adapters: Optional[Params] = None,
                           ) -> Tuple[jnp.ndarray, Tuple, jnp.ndarray]:
-        """One decode step against an EXTERNALLY-gathered KV view — the
-        cache-layout-agnostic sibling of ``decode_step``. The serving
-        engine's block-paged pool (dla_tpu/serving/kv_blocks.py) gathers
-        each sequence's pages into a [B, S] window via its block table and
-        hands the result here; this method never writes a cache — it
-        returns the step's fresh rows for the caller to scatter back into
-        whatever layout it owns.
+        """One decode step against a block-paged pool — the sibling of
+        ``decode_step`` for the serving engine. Each sequence's pages are
+        gathered into its [S] window by its block table, a layer at a
+        time; the step's fresh rows are written where the caller says.
 
         ``view``:
-          kv       tuple, one [L, B, S, heads, width] array per
-                   ``cache_rows()`` entry: the gathered cache (keys and
-                   values, or the latent rows; activation dtype)
-          valid    [B, S]            columns that may be attended
-          pos      [B, S]            logical position per column
-          lengths  [B]               true tokens so far = this query's pos
-          real     [B, T] optional   rows that are real (a running slot;
-                   a chunk's real token): routed experts skip the others
+          pools         tuple, one [L, pages, page, heads, width] array
+                        per ``cache_rows()`` entry (keys and values, or
+                        the latent rows; activation dtype)
+          block_tables  [B, pages/slot]  physical page ids per row
+          valid         [B, S]           columns that may be attended
+          pos           [B, S]           logical position per column
+          lengths       [B]              true tokens so far = this query's pos
+          write_pages, write_offs  [B, T]  physical (page, offset) each
+                        fresh row is written to (the trash page for rows
+                        the caller masks)
+          real          [B, T] optional  rows that are real (a running slot;
+                        a chunk's real token): routed experts skip the others
 
-        Returns (logits [B, V], rows — one [L, B, 1, heads, width] per
-        pool — and the step's int32 [2] expert counters, see
-        ``_paged_layers``). Rows whose view is garbage (freed serving
-        slots) compute garbage that the caller masks — static shapes, no
-        recompilation as requests come and go. int8 KV paging is not
-        plumbed yet: serving pages store the activation dtype."""
-        h, rows, routed = self._paged_layers(
+        Returns (logits [B, V], the pools with this step's rows written,
+        and the step's int32 [2] expert counters, see ``_paged_layers``).
+        Rows whose window is garbage (freed serving slots) compute garbage
+        that the caller masks — static shapes, no recompilation as
+        requests come and go. int8 KV paging is not plumbed yet: serving
+        pages store the activation dtype."""
+        h, pools, routed = self._paged_layers(
             params, view, self._embed(params, tokens[:, None]),
             view["lengths"][:, None], adapters, decode_attention)
-        return self.unembed(params, h[:, 0]), rows, routed
+        return self.unembed(params, h[:, 0]), pools, routed
 
     def prefill_step_paged(self, params: Params, view: Params,
                            tokens: jnp.ndarray,     # [B, C] chunk tokens
@@ -2069,45 +2102,43 @@ class Transformer:
                            last_index: jnp.ndarray,  # [B] last real token
                            adapters: Optional[Params] = None,
                            ) -> Tuple[jnp.ndarray, Tuple, jnp.ndarray]:
-        """One fixed-width prefill CHUNK against an externally-gathered
-        KV view — the chunked-prefill sibling of ``decode_step_paged``.
-        The chunk's C queries attend jointly over (a) the already-
-        computed prefix held in the paged pool, gathered into the view
-        with ``valid`` marking exactly the columns BEFORE this chunk,
-        and (b) the chunk's own fresh keys, causally by absolute
-        position (pad tokens carry later positions than every real
-        query, so they mask themselves out). Returns
+        """One fixed-width prefill CHUNK against the paged pool — the
+        chunked-prefill sibling of ``decode_step_paged``. The chunk's C
+        queries attend jointly over (a) the already-computed prefix held
+        in the pool, with ``valid`` marking exactly the columns BEFORE
+        this chunk, and (b) the chunk's own fresh keys, causally by
+        absolute position (pad tokens carry later positions than every
+        real query, so they mask themselves out). Returns
         (logits [B, V] — the next-token distribution after the token at
-        ``last_index``, only meaningful on the FINAL chunk — the chunk's
-        rows [L, B, C, heads, width] per pool for the caller to scatter
-        (pad columns route to the trash page), and the expert counters)."""
-        h, rows, routed = self._paged_layers(
+        ``last_index``, only meaningful on the FINAL chunk — the pools
+        with the chunk's rows written (pad columns to the trash page, by
+        the caller's ``write_pages``), and the expert counters)."""
+        h, pools, routed = self._paged_layers(
             params, view, self._embed(params, tokens), positions, adapters,
             block_decode_attention)                         # [B, C, H]
         last = h[jnp.arange(tokens.shape[0]), last_index]   # [B, H]
-        return self.unembed(params, last), rows, routed
+        return self.unembed(params, last), pools, routed
 
     def decode_block_paged(self, params: Params, view: Params,
                            tokens: jnp.ndarray,  # [B, G] token block
                            adapters: Optional[Params] = None,
                            ) -> Tuple[jnp.ndarray, Tuple, jnp.ndarray]:
-        """Verify a G-token block against an externally-gathered KV view
-        — the speculative-verify sibling of ``decode_step_paged``. Row
-        b's block occupies absolute positions lengths[b]..lengths[b]+G-1;
-        query g attends over (a) the committed prefix in the view
-        (``valid`` marks exactly the columns BEFORE the block — draft
-        columns must NOT be valid, the in-block keys supply them fresh)
-        and (b) the block's own keys, causally by position. Returns
-        (logits [B, G, V] — one next-token distribution per block
-        position — the block's rows [L, B, G, heads, width] per pool for
-        the caller to scatter (rejected columns are the caller's rollback
+        """Verify a G-token block against the paged pool — the
+        speculative-verify sibling of ``decode_step_paged``. Row b's
+        block occupies absolute positions lengths[b]..lengths[b]+G-1;
+        query g attends over (a) the committed prefix (``valid`` marks
+        exactly the columns BEFORE the block — draft columns must NOT be
+        valid, the in-block keys supply them fresh) and (b) the block's
+        own keys, causally by position. Returns (logits [B, G, V] — one
+        next-token distribution per block position — the pools with the
+        block's rows written (rejected columns are the caller's rollback
         problem), and the expert counters)."""
         positions = view["lengths"][:, None] + \
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]  # [B, G]
-        h, rows, routed = self._paged_layers(
+        h, pools, routed = self._paged_layers(
             params, view, self._embed(params, tokens), positions, adapters,
             block_decode_attention)
-        return self.unembed(params, h), rows, routed                # [B,G,V]
+        return self.unembed(params, h), pools, routed               # [B,G,V]
 
     def start_decode(self, params: Params, input_ids: jnp.ndarray,
                      attention_mask: jnp.ndarray, max_new_tokens: int,

@@ -204,9 +204,10 @@ def moe_mlp_dropless(
 def _moe_rows(h, router_w, w_gate, w_up, w_down, *, k, capacity_factor,
               valid, first=0, routed_scale=1.0):
     rows, g, d = h.shape
-    e = w_gate.shape[0]                  # experts held (all of them: E)
-    k = min(k, router_w.shape[1])
-    cap = expert_capacity(g, router_w.shape[1], k, capacity_factor)
+    e_all = router_w.shape[1]            # experts the router scores
+    e = w_gate.shape[0]                  # experts held here (usually all)
+    k = min(k, e_all)
+    cap = expert_capacity(g, e_all, k, capacity_factor)
     v = (jnp.ones((rows, g), jnp.float32) if valid is None
          else valid.astype(jnp.float32))
 
@@ -253,11 +254,10 @@ def _moe_rows(h, router_w, w_gate, w_up, w_down, *, k, capacity_factor,
     # e * mean router prob of e, summed, scaled by E — minimized at
     # uniform) and z-loss on router logits
     n_real = jnp.maximum(jnp.sum(v), 1.0)
-    e = router_w.shape[1]
-    primary = jax.nn.one_hot(top_e[..., 0], e, dtype=jnp.float32)
+    primary = jax.nn.one_hot(top_e[..., 0], e_all, dtype=jnp.float32)
     frac = jnp.sum(primary * v[..., None], axis=(0, 1)) / n_real
     mean_prob = jnp.sum(probs * v[..., None], axis=(0, 1)) / n_real
-    load_balance = e * jnp.sum(frac * mean_prob)
+    load_balance = e_all * jnp.sum(frac * mean_prob)
     router_z = jnp.sum(
         jax.nn.logsumexp(logits, axis=-1) ** 2 * v) / n_real
     dropped = 1.0 - jnp.sum(disp) / (k * n_real)

@@ -38,6 +38,14 @@ def validate_rope_scaling(scaling: Optional[Dict[str, Any]]
     return out
 
 
+def yarn_mscale(factor: float, m: float = 1.0) -> float:
+    """YaRN's attention temperature 0.1 * m * ln(factor) + 1 (1 at or
+    under factor 1): cos / sin carry mscale(m = mscale) / mscale(m =
+    mscale_all_dim); latent attention's softmax scale carries the square
+    of the latter (models/transformer.py)."""
+    return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+
 def _scale_inv_freq(inv_freq: jnp.ndarray, scaling: Dict[str, Any],
                     head_dim: int, theta: float
                     ) -> Tuple[jnp.ndarray, float]:
@@ -79,20 +87,15 @@ def _scale_inv_freq(inv_freq: jnp.ndarray, scaling: Dict[str, Any],
                 "max_position_embeddings when the dict omits it)")
         old_ctx = float(scaling["original_max_position_embeddings"])
 
-        def get_mscale(scale: float, m: float = 1.0) -> float:
-            if scale <= 1.0:
-                return 1.0
-            return 0.1 * m * math.log(scale) + 1.0
-
         attn = scaling.get("attention_factor")
         if attn is None:
             mscale = scaling.get("mscale")
             mscale_all = scaling.get("mscale_all_dim")
             if mscale and mscale_all:
-                attn = float(get_mscale(factor, mscale)
-                             / get_mscale(factor, mscale_all))
+                attn = float(yarn_mscale(factor, mscale)
+                             / yarn_mscale(factor, mscale_all))
             else:
-                attn = get_mscale(factor)
+                attn = yarn_mscale(factor)
         else:
             attn = float(attn)
 

@@ -4,17 +4,19 @@ worst-case contiguous cache.
 
 Layout: preallocated ``pools``, one [L, num_pages, page_size, heads,
 width] array per kind of row the model caches (``Transformer.cache_rows``:
-keys and values of [KH, D]; or one latent row of [1, r + rope]). Each
-decode SLOT (a row of the static-shape decode batch) owns a block table
-row — ``pages_per_slot`` physical page ids — and the in-graph gather
+keys and values of [KH, D]; or one latent row of [1, r + rope], lane-
+padded). Each decode SLOT (a row of the static-shape decode batch) owns a
+block table row — ``pages_per_slot`` physical page ids — and the model's
+paged steps (``Transformer._paged_layers``) gather, a layer at a time,
 
-    view = pool[:, block_table]        # [L, B, P/slot, ps, heads, width]
-           .reshape(L, B, S, heads, width)     # S = pages_per_slot * ps
+    view = slab[block_table]           # [B, P/slot, ps, heads, width]
+           .reshape(B, S, heads, width)        # S = pages_per_slot * ps
 
-rebuilds the contiguous [B, S] window ``Transformer.decode_step_paged``
-consumes. The gather is the whole trick: attention math stays
-layout-agnostic, the pool stays fixed-size, and page ownership is pure
-host-side bookkeeping (PageAllocator) that never touches the graph.
+out of that layer's slab of each pool, and write the step's fresh rows
+back at the (page, offset) the engine computed. The gather is the whole
+trick: attention math stays layout-agnostic, the pool stays fixed-size,
+and page ownership is pure host-side bookkeeping (PageAllocator) that
+never touches the graph.
 
 Physical page 0 is RESERVED as the trash page: free slots' block tables
 point at it, so the static-shape decode step can let inactive rows

@@ -502,16 +502,6 @@ class ServingEngine:
 
     # -------------------------------------------------------- jitted steps
 
-    def _gather(self, pools, block_tables):
-        """In-graph block-table gather of every pool: [L, pages, ps,
-        heads, width] -> the [L, B, S, heads, width] windows the model's
-        paged steps take as ``view["kv"]``."""
-        sw = self.cache.geom.slot_window
-        return tuple(
-            p[:, block_tables].reshape(
-                p.shape[0], block_tables.shape[0], sw, *p.shape[3:])
-            for p in pools)
-
     def _prefill_fn(self, params, pools, ids, mask, page_rows):
         """Prefill a padded bucket batch and scatter its rows into the
         pools. ids/mask [PB, W]; page_rows [PB, W/page_size] physical page
@@ -528,11 +518,12 @@ class ServingEngine:
 
     def _prefill_chunk_fn(self, params, pools, btab, valid,
                           pos, ids, start, nvalid, adapters=None):
-        """One FIXED-SHAPE prefill chunk for a single slot: gather the
-        slot's pages (the already-computed prefix — cached hit pages and
-        earlier chunks — with ``valid`` marking exactly the columns
-        before this chunk), run the chunk forward, scatter its C fresh
-        KV columns into the pool. ``btab`` [1, pages/slot]; ``valid``/
+        """One FIXED-SHAPE prefill chunk for a single slot: the model
+        gathers the slot's pages (the already-computed prefix — cached
+        hit pages and earlier chunks — with ``valid`` marking exactly the
+        columns before this chunk), runs the chunk forward and writes its
+        C fresh rows into the pool at the (page, offset) computed here.
+        ``btab`` [1, pages/slot]; ``valid``/
         ``pos`` [1, S]; ``ids`` [1, C]; ``start``/``nvalid`` traced
         scalars (chunk's absolute start column / real-token count), so
         every chunk of every request reuses ONE compile. Returns
@@ -541,27 +532,22 @@ class ServingEngine:
         on a request's final chunk (the only one whose logits the host
         fetches)."""
         self.prefill_chunk_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the serving compile-once tests
-        geom = self.cache.geom
-        ps = geom.page_size
+        ps = self.cache.geom.page_size
         c = self.cfg.prefill_chunk
         real = jnp.arange(c) < nvalid
-        view = {"kv": self._gather(pools, btab), "valid": valid, "pos": pos,
-                "real": real[None, :]}
+        # the chunk's columns land at their physical (page, offset); pad
+        # columns (index >= nvalid) route to the trash page
+        cols = start + jnp.arange(c, dtype=jnp.int32)
+        view = {"pools": pools, "block_tables": btab, "valid": valid,
+                "pos": pos, "real": real[None, :],
+                "write_pages": jnp.where(real, btab[0, cols // ps], 0)[None],
+                "write_offs": jnp.where(real, cols % ps, 0)[None]}
         # absolute chunk schedule: positions are fixed by `start`, so a
         # cache hit changes WHICH chunks run, never the math inside one
-        positions = start + jnp.arange(c, dtype=jnp.int32)[None, :]
+        positions = cols[None, :]
         last_index = jnp.maximum(nvalid - 1, 0)[None]
-        logits, rows, _ = self.model.prefill_step_paged(
+        logits, pools, _ = self.model.prefill_step_paged(
             params, view, ids, positions, last_index, adapters=adapters)
-        # scatter the chunk's columns at their physical (page, offset);
-        # pad columns (index >= nvalid) route to the trash page
-        cols = start + jnp.arange(c, dtype=jnp.int32)
-        page_ids = btab[0, cols // ps]
-        offs = cols % ps
-        page_ids = jnp.where(real, page_ids, 0)
-        offs = jnp.where(real, offs, 0)
-        pools = tuple(p.at[:, page_ids, offs].set(r[:, 0])
-                      for p, r in zip(pools, rows))
         return pools, logits
 
     def _export_kv_fn(self, pools, page_ids):
@@ -591,12 +577,13 @@ class ServingEngine:
     def _decode_fn(self, params, pools, block_tables, valid,
                    pos, lengths, tokens, active, temps, top_ps, top_ks,
                    seeds, gen_pos, adapters=None):
-        """One static-shape decode step over every slot: gather each
-        slot's pages into its [S] window, run the layout-agnostic decode
-        step, sample PER-ROW (each slot's traced temperature/top_p/top_k/
-        seed, keyed by the slot's generated-token index), scatter the
-        fresh KV column back. Free slots compute garbage routed to the
-        trash page. Returns the fresh pools plus a packed [4, B] int32
+        """One static-shape decode step over every slot: the model's
+        paged step gathers each slot's pages into its [S] window and
+        writes the fresh row at the (page, offset) computed here; the
+        result is sampled PER-ROW (each slot's traced temperature/top_p/
+        top_k/seed, keyed by the slot's generated-token index). Free
+        slots compute garbage routed to the trash page. Returns the
+        fresh pools plus a packed [4, B] int32
         array — row 0 the sampled tokens, row 1 their chosen-token
         logprobs bitcast to int32, rows 2 and 3 the step's expert
         counters broadcast (held experts that received a token, and
@@ -609,9 +596,16 @@ class ServingEngine:
         geom = self.cache.geom
         ps = geom.page_size
         b = geom.num_slots
-        view = {"kv": self._gather(pools, block_tables), "valid": valid,
-                "pos": pos, "lengths": lengths, "real": active[:, None]}
-        logits, rows, routed = self.model.decode_step_paged(
+        # this step's row: physical (page, offset) of each slot's write
+        # column; inactive slots write the trash page
+        page_ids = jnp.take_along_axis(
+            block_tables, (lengths // ps)[:, None], axis=1)[:, 0]
+        view = {"pools": pools, "block_tables": block_tables,
+                "valid": valid, "pos": pos, "lengths": lengths,
+                "real": active[:, None],
+                "write_pages": jnp.where(active, page_ids, 0)[:, None],
+                "write_offs": jnp.where(active, lengths % ps, 0)[:, None]}
+        logits, pools, routed = self.model.decode_step_paged(
             params, view, tokens, adapters=adapters)
         # a free slot keeps its last request's temperature: zeroed, so
         # only running rows decide whether the step filters and draws
@@ -620,16 +614,6 @@ class ServingEngine:
             top_ps, top_ks)
         new_tok = jnp.where(active, new_tok, 0)
         logp = jnp.where(active, logp, 0.0)
-        # scatter this step's KV column: physical (page, offset) of each
-        # slot's write column; inactive slots write the trash page
-        col = lengths
-        page_ids = jnp.take_along_axis(
-            block_tables, (col // ps)[:, None], axis=1)[:, 0]
-        offs = col % ps
-        page_ids = jnp.where(active, page_ids, 0)
-        offs = jnp.where(active, offs, 0)
-        pools = tuple(p.at[:, page_ids, offs].set(r[:, :, 0])
-                      for p, r in zip(pools, rows))
         packed = jnp.stack(
             [new_tok, jax.lax.bitcast_convert_type(logp, jnp.int32),
              jnp.broadcast_to(routed[0], (b,)),
@@ -662,25 +646,21 @@ class ServingEngine:
 
         def draft_step(carry, i):
             cur, valid_c, pos_c, pools_c = carry
-            lens_i = lengths + i
-            view = {"kv": self._gather(pools_c, block_tables),
-                    "valid": valid_c, "pos": pos_c, "lengths": lens_i}
-            logits, rows, _ = self.model.decode_step_paged(
-                draft_params, view, cur, adapters=adapters)
-            nxt, _ = sample_token_per_row(
-                seeds, gen_pos + i, logits, temps, top_ps, top_ks)
-            nxt = jnp.where(active, nxt, 0)
-            col = lens_i
+            col = lens_i = lengths + i
             in_win = (col < sw) & active
             page_ids = jnp.take_along_axis(
                 block_tables,
                 jnp.minimum(col // ps, geom.pages_per_slot - 1)[:, None],
                 axis=1)[:, 0]
-            offs = col % ps
-            page_ids = jnp.where(in_win, page_ids, 0)
-            offs = jnp.where(in_win, offs, 0)
-            pools_c = tuple(p.at[:, page_ids, offs].set(r[:, :, 0])
-                            for p, r in zip(pools_c, rows))
+            view = {"pools": pools_c, "block_tables": block_tables,
+                    "valid": valid_c, "pos": pos_c, "lengths": lens_i,
+                    "write_pages": jnp.where(in_win, page_ids, 0)[:, None],
+                    "write_offs": jnp.where(in_win, col % ps, 0)[:, None]}
+            logits, pools_c, _ = self.model.decode_step_paged(
+                draft_params, view, cur, adapters=adapters)
+            nxt, _ = sample_token_per_row(
+                seeds, gen_pos + i, logits, temps, top_ps, top_ks)
+            nxt = jnp.where(active, nxt, 0)
             written = (col_ids == col[:, None]) & in_win[:, None]
             valid_c = valid_c | written
             pos_c = jnp.where(written, col[:, None], pos_c)
@@ -720,10 +700,17 @@ class ServingEngine:
         b = geom.num_slots
         sw = geom.slot_window
         g = self._spec_k + 1
-        view = {"kv": self._gather(pools, block_tables), "valid": valid,
-                "pos": pos, "lengths": lengths}
+        cols = lengths[:, None] + jnp.arange(g, dtype=jnp.int32)[None, :]
+        in_win = (cols < sw) & active[:, None]
+        page_ids = jnp.take_along_axis(
+            block_tables,
+            jnp.minimum(cols // ps, geom.pages_per_slot - 1), axis=1)
+        view = {"pools": pools, "block_tables": block_tables,
+                "valid": valid, "pos": pos, "lengths": lengths,
+                "write_pages": jnp.where(in_win, page_ids, 0),
+                "write_offs": jnp.where(in_win, cols % ps, 0)}
         block = jnp.concatenate([tokens[:, None], proposals], axis=1)
-        logits, rows, _ = self.model.decode_block_paged(
+        logits, pools, _ = self.model.decode_block_paged(
             params, view, block, adapters=adapters)
         toks, logps = sample_token_block(
             seeds, gen_pos, logits, jnp.where(active, temps, 0.0),
@@ -732,16 +719,6 @@ class ServingEngine:
         logps = jnp.where(active[:, None], logps, 0.0)
         accept = toks[:, :self._spec_k] == proposals
         acc = accept_prefix_len(accept)                    # [B] 0..K
-        cols = lengths[:, None] + jnp.arange(g, dtype=jnp.int32)[None, :]
-        in_win = (cols < sw) & active[:, None]
-        page_ids = jnp.take_along_axis(
-            block_tables,
-            jnp.minimum(cols // ps, geom.pages_per_slot - 1), axis=1)
-        offs = cols % ps
-        page_ids = jnp.where(in_win, page_ids, 0)
-        offs = jnp.where(in_win, offs, 0)
-        pools = tuple(p.at[:, page_ids, offs].set(r)
-                      for p, r in zip(pools, rows))
         packed = jnp.stack([
             toks,
             jax.lax.bitcast_convert_type(logps, jnp.int32),
@@ -1663,7 +1640,8 @@ class ServingEngine:
                  cached_tokens=0)
         with annotate("serve_prefill", n=len(batch), width=width):
             self.cache.pools, logits = self._prefill(
-                self.params, self.cache.pools, jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(page_rows))
+                self.params, self.cache.pools, jnp.asarray(ids),
+                jnp.asarray(mask), jnp.asarray(page_rows))
             # dla: disable=host-sync-in-hot-loop -- designed prefill D2H: one logits fetch per admitted batch, not per token
             logits_np = np.asarray(logits)
         t_done = self.now()

@@ -1,10 +1,12 @@
 """MiB of latent cache the decode steps read for each token they handed
 out: the ``serve_decode`` spans' ``read_tokens`` (columns the step's gather
-reads) times the bytes of one cached latent row over every layer
+reads) times the bytes one cached token takes over every layer, over their
+``slots``, summed across the traced window. The bytes are the pool's own
+(the program's gauge ``serving/kv_bytes_per_token``: the stored row, lane
+padding included), or where the program has no such gauge the algorithm's
 (``costs_mla_moe.kv_bytes_per_token``: kv_lora_rank + qk_rope_head_dim
-numbers a layer), over their ``slots``, summed across the traced window.
-``kv_read_mib_per_token`` is the same quantity for a dense block's keys and
-values."""
+numbers a layer). ``kv_read_mib_per_token`` is the same quantity for a
+dense block's keys and values."""
 from perfbench.lib import costs_mla_moe, spans
 
 LAYER = "KV pool"
@@ -24,6 +26,6 @@ def read(ctx):
         return None
     width = {"bfloat16": 2, "float16": 2, "float32": 4}[
         ctx.config["serving"]["dtype"]]
-    return (sums["read_tokens"]
-            * costs_mla_moe.kv_bytes_per_token(ctx.config, width)
-            / sums["slots"] / 2 ** 20)
+    row = (ctx.counters.get("kv_bytes_per_token")
+           or costs_mla_moe.kv_bytes_per_token(ctx.config, width))
+    return sums["read_tokens"] * row / sums["slots"] / 2 ** 20

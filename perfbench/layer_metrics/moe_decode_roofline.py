@@ -11,7 +11,7 @@ from perfbench.lib import costs_mla_moe, xplane
 LAYER = "kernels"
 UNIT = "%"
 BETTER = "higher"
-MOVES = "serve_tok_s"
+MOVES = "itl_p99_ms"
 SOURCE = "device_trace"
 DRIVERS = ('serve_closed_loop_hf',)
 

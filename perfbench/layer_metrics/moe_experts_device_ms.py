@@ -11,7 +11,7 @@ from perfbench.lib import decode_scopes
 LAYER = "model step"
 UNIT = "ms"
 BETTER = "lower"
-MOVES = "serve_tok_s"
+MOVES = "itl_p99_ms"
 SOURCE = "device_trace"
 DRIVERS = ('serve_closed_loop_hf',)
 

@@ -7,7 +7,7 @@ counters ``serving/moe/expert_assignments`` and ``serving/moe/experts_hit``
 LAYER = "experts"
 UNIT = "count"
 BETTER = "higher"
-MOVES = "serve_tok_s"
+MOVES = "itl_p99_ms"
 SOURCE = "program_counter"
 DRIVERS = ('serve_closed_loop_hf',)
 

@@ -34,31 +34,27 @@ MAX_NEW = 4
 PAGE = 4
 
 
-@pytest.fixture(scope="module")
-def serve_setup():
+def _setup(preset):
     from dla_tpu.generation.engine import GenerationConfig
     from dla_tpu.models.config import get_model_config
     from dla_tpu.models.transformer import Transformer
-    cfg = get_model_config("tiny")
-    model = Transformer(cfg)
+    model = Transformer(get_model_config(preset))
     params = model.init(jax.random.key(7))
     gen = GenerationConfig(max_new_tokens=16, do_sample=False,
                            eos_token_id=-1, pad_token_id=0)
     return model, params, gen
+
+
+@pytest.fixture(scope="module")
+def serve_setup():
+    return _setup("tiny")
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny-mla"])
 def pool_setup(request):
     """``serve_setup`` over both kinds of cache row (dense keys and
     values; one latent row): a ticket carries one payload per pool."""
-    from dla_tpu.generation.engine import GenerationConfig
-    from dla_tpu.models.config import get_model_config
-    from dla_tpu.models.transformer import Transformer
-    model = Transformer(get_model_config(request.param))
-    params = model.init(jax.random.key(7))
-    gen = GenerationConfig(max_new_tokens=16, do_sample=False,
-                           eos_token_id=-1, pad_token_id=0)
-    return model, params, gen
+    return _setup(request.param)
 
 
 def _engine(serve_setup, **cfg_kw):

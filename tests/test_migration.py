@@ -30,17 +30,20 @@ MAX_NEW = 6
 PAGE = 4
 
 
-@pytest.fixture(scope="module")
-def serve_setup():
+def _setup(preset):
     from dla_tpu.generation.engine import GenerationConfig
     from dla_tpu.models.config import get_model_config
     from dla_tpu.models.transformer import Transformer
-    cfg = get_model_config("tiny")
-    model = Transformer(cfg)
+    model = Transformer(get_model_config(preset))
     params = model.init(jax.random.key(7))
     gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
                            eos_token_id=-1, pad_token_id=0)
     return model, params, gen
+
+
+@pytest.fixture(scope="module")
+def serve_setup():
+    return _setup("tiny")
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny-mla"])
@@ -49,14 +52,7 @@ def pool_setup(request):
     [KH, D] a token (dense attention) and one latent row of [1, r + rope]
     (latent attention). The pool, copy-on-write, export and import follow
     the model's ``cache_rows()``; these tests hold both to one behaviour."""
-    from dla_tpu.generation.engine import GenerationConfig
-    from dla_tpu.models.config import get_model_config
-    from dla_tpu.models.transformer import Transformer
-    model = Transformer(get_model_config(request.param))
-    params = model.init(jax.random.key(7))
-    gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
-                           eos_token_id=-1, pad_token_id=0)
-    return model, params, gen
+    return _setup(request.param)
 
 
 def _engine(serve_setup, **cfg_kw):
