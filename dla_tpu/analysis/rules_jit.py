@@ -280,9 +280,13 @@ class DonationMisuseRule(Rule):
             if site.call in site.fn.decorator_list:
                 donating[site.fn.name] = site.donate_positions
         for node in ast.walk(tree):
-            if isinstance(node, ast.Assign) and isinstance(node.value,
-                                                           ast.Call):
-                site = site_by_call.get(id(node.value))
+            if not isinstance(node, ast.Assign):
+                continue
+            # ``x = jax.jit(..)`` or ``x = jax.jit(..) if cond else None``
+            values = ([node.value.body, node.value.orelse]
+                      if isinstance(node.value, ast.IfExp) else [node.value])
+            for value in values:
+                site = site_by_call.get(id(value))
                 if site is None or not site.donate_positions:
                     continue
                 for t in node.targets:
@@ -329,13 +333,14 @@ class DonationMisuseRule(Rule):
     @staticmethod
     def _expr_key(node: ast.AST):
         """Stable key for a donated-arg expression we can track: a bare
-        name or a self-attribute."""
+        name or a dotted attribute chain on one (``self.state``,
+        ``c.pools``, ``self.cache.pools``)."""
+        attrs = []
+        while isinstance(node, ast.Attribute):
+            attrs.append(node.attr)
+            node = node.value
         if isinstance(node, ast.Name):
-            return node.id
-        if (isinstance(node, ast.Attribute)
-                and isinstance(node.value, ast.Name)
-                and node.value.id == "self"):
-            return f"self.{node.attr}"
+            return ".".join([node.id] + attrs[::-1])
         return None
 
     def _check_fn(self, sf, fn: ast.FunctionDef, donating
@@ -351,20 +356,13 @@ class DonationMisuseRule(Rule):
                        else None)
                 if key in donating:
                     events.append((node.lineno, "call", (node, key)))
-            if isinstance(node, ast.Name) and isinstance(node.ctx, ast.Load):
-                events.append((node.lineno, "load", node.id))
-            if (isinstance(node, ast.Attribute)
-                    and isinstance(node.ctx, ast.Load)
-                    and isinstance(node.value, ast.Name)
-                    and node.value.id == "self"):
-                events.append((node.lineno, "load", f"self.{node.attr}"))
-            if isinstance(node, ast.Name) and isinstance(node.ctx, ast.Store):
-                events.append((node.lineno, "store", node.id))
-            if (isinstance(node, ast.Attribute)
-                    and isinstance(node.ctx, ast.Store)
-                    and isinstance(node.value, ast.Name)
-                    and node.value.id == "self"):
-                events.append((node.lineno, "store", f"self.{node.attr}"))
+            if isinstance(node, (ast.Name, ast.Attribute)) \
+                    and isinstance(node.ctx, (ast.Load, ast.Store)):
+                expr = self._expr_key(node)
+                if expr is not None:
+                    kind = ("load" if isinstance(node.ctx, ast.Load)
+                            else "store")
+                    events.append((node.lineno, kind, expr))
         events.sort(key=lambda e: e[0])
 
         assigns = {id(n.value): n for n in ast.walk(fn)

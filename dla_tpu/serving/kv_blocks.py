@@ -37,6 +37,7 @@ used (refcount >= 1), or cached (refcount 0, content retained).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import OrderedDict
 from typing import (Callable, Dict, List, Optional, Sequence, Tuple)
 
@@ -456,12 +457,13 @@ class PrefixCache:
                 self.allocator.uncache(old.tail_page)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def copy_page(pools: Tuple[jnp.ndarray, ...], src, dst
               ) -> Tuple[jnp.ndarray, ...]:
     """Device-side physical page copy — the copy-on-write primitive —
     in every pool. ``src``/``dst`` are traced scalars, so this compiles
-    once per pool shape no matter which pages get copied."""
+    once per pool shape no matter which pages get copied. Consumes
+    ``pools`` (donated): rebind to what it returns."""
     return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
 
@@ -475,6 +477,19 @@ class PagedKVCache:
              latent rows for latent attention. Every consumer (the
              engine's gather / scatter, copy-on-write, migration) maps
              over the tuple, so the row's shape is the model's business.
+             This object alone owns the buffers: every jitted program
+             that returns the pools (decode, prefill, chunk, the
+             speculative pair, KV import, ``copy_page``) is given them
+             DONATED and updates them in place, so whoever gets pools
+             back has consumed the ones it gave and rebinds ``pools`` in
+             the same statement; the arrays that went in are deleted.
+             Nothing else may keep a pool array across a step (the
+             migration export is the one reader, and it returns new
+             arrays). A program that fails after its inputs were
+             consumed leaves ``pools`` dead (``pools_dead``): there is
+             no content left to save, the engine reports
+             ``DeviceStepError`` and its supervisor rebuilds a fresh
+             cache and replays.
 
     Host mirror (authoritative, numpy — the scheduler mutates it and the
     engine ships it to device per step; decode-step updates are
@@ -576,6 +591,12 @@ class PagedKVCache:
         self.pos[slot, col] = col
         self.lengths[slot] = col + 1
         self.tokens[slot] = token
+
+    @property
+    def pools_dead(self) -> bool:
+        """True once a failed dispatch consumed the pools and returned
+        nothing to rebind (see ``pools`` above)."""
+        return any(p.is_deleted() for p in self.pools)
 
     @property
     def bytes_per_token(self) -> int:

@@ -90,6 +90,11 @@ from dla_tpu.utils.profiling import (
 #: ``lax.cond`` would compile anew at every call)
 _sample_rows_jit = jax.jit(sample_token_per_row)
 
+#: what ``step()`` raises, and the KV handoff refuses with, once a failed
+#: dispatch has consumed the (donated) pools: see ``PagedKVCache.pools``
+_POOL_CONSUMED = ("pool consumed by a failed dispatch: this engine's KV "
+                  "is gone, rebuild it and replay")
+
 
 @dataclasses.dataclass(frozen=True)
 class ServingConfig:
@@ -402,15 +407,21 @@ class ServingEngine:
         self.spec_verify_compiles = 0
         self.export_compiles = 0
         self.import_compiles = 0
-        self._decode = jax.jit(self._decode_fn)
-        self._prefill = jax.jit(self._prefill_fn)
-        self._prefill_chunk = jax.jit(self._prefill_chunk_fn)
-        self._spec_draft = (jax.jit(self._spec_draft_fn)
+        # every program that returns the pools consumes the ones it was
+        # given (donate_argnums names ``pools`` in the bound method's
+        # signature), so XLA updates them in place and no pool-sized copy
+        # leaves a step; each call site rebinds ``cache.pools`` in the same
+        # statement. The export gather only reads: it does not donate.
+        self._decode = jax.jit(self._decode_fn, donate_argnums=1)
+        self._prefill = jax.jit(self._prefill_fn, donate_argnums=1)
+        self._prefill_chunk = jax.jit(self._prefill_chunk_fn,
+                                      donate_argnums=1)
+        self._spec_draft = (jax.jit(self._spec_draft_fn, donate_argnums=1)
                             if self._spec_k else None)
-        self._spec_verify = (jax.jit(self._spec_verify_fn)
+        self._spec_verify = (jax.jit(self._spec_verify_fn, donate_argnums=1)
                              if self._spec_k else None)
         self._export_kv = jax.jit(self._export_kv_fn)
-        self._import_kv = jax.jit(self._import_kv_fn)
+        self._import_kv = jax.jit(self._import_kv_fn, donate_argnums=0)
         # anomaly auto-triage over inter-token latency + unattributed
         # recompiles; captures land next to the other postmortems
         anomaly_cfg = AnomalyConfig.from_config(cfg.anomaly)
@@ -1041,6 +1052,8 @@ class ServingEngine:
         req = self._results.get(rid)
         if req is None:
             return self._export_refuse(f"unknown rid {rid}")
+        if self.cache.pools_dead:
+            return self._export_refuse(f"request {rid}: {_POOL_CONSUMED}")
         if req.state is not RequestState.DECODE or req.slot is None \
                 or self.scheduler.running.get(req.slot) is not req:
             return self._export_refuse(
@@ -1106,6 +1119,9 @@ class ServingEngine:
         (``MigrationError``, counted on failed_migrations) — the caller
         keeps the source copy running."""
         t_start = self.now()
+        if self.cache.pools_dead:
+            return self._import_refuse(
+                f"ticket {ticket.rid}: {_POOL_CONSUMED}")
         if ticket.page_size != self.cfg.page_size:
             return self._import_refuse(
                 f"page_size mismatch: ticket {ticket.page_size}, "
@@ -1238,7 +1254,14 @@ class ServingEngine:
         first so in-flight requests outrank new admissions for the pool;
         a fresh admission always carries its decode reserve, so it never
         needs a page in the same step. Returns the (rid, token) pairs
-        emitted this step, in slot order — the streaming surface."""
+        emitted this step, in slot order — the streaming surface.
+
+        Raises ``DeviceStepError`` on an engine whose pools a failed
+        dispatch consumed (``PagedKVCache.pools``): its KV is gone, so
+        the one way on is a rebuild and a replay, which is what a
+        ``Supervisor`` does with every device fault."""
+        if self.cache.pools_dead:
+            raise DeviceStepError(_POOL_CONSUMED)
         self.profile.on_step(self.engine_steps)
         if self.xla_introspect_enabled:
             # stamp compile events from this step's dispatches

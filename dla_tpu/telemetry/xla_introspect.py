@@ -15,7 +15,7 @@ the XLA layer underneath it. Two blind spots it removes:
 - **What did XLA actually lower?** At each compile the wrapper reads
   ``lowered.compile().cost_analysis()`` / ``memory_analysis()`` and
   publishes per-function analytic FLOPs, bytes accessed, and
-  argument/output/temp/generated-code memory as always-on
+  argument/output/temp/alias/generated-code memory as always-on
   ``telemetry/xla/<fn>/*`` gauges — the ``tools/scale_rehearsal.py``
   offline pattern promoted into the live registry — plus a roofline
   verdict (compute- vs bandwidth-bound) when given an
@@ -53,6 +53,9 @@ _MEMORY_FIELDS = (
     ("argument_size_in_bytes", "argument_bytes"),
     ("output_size_in_bytes", "output_bytes"),
     ("temp_size_in_bytes", "temp_bytes"),
+    # bytes of arguments the program updates in place (donated inputs
+    # XLA aliased to outputs): 0 means every output is a fresh buffer
+    ("alias_size_in_bytes", "alias_bytes"),
     ("generated_code_size_in_bytes", "generated_code_bytes"),
     ("peak_memory_in_bytes", "peak_bytes"),
 )

@@ -226,6 +226,48 @@ def test_donation_misuse_silent_on_same_statement_rebind(tmp_path):
     assert "donation-misuse" not in fired(r)
 
 
+_POOL_ENGINE = """
+    import jax
+
+    class Engine:
+        def __init__(self, spec):
+            self._decode = jax.jit(self._decode_fn, donate_argnums=1)
+            self._draft = (jax.jit(self._draft_fn, donate_argnums=1)
+                           if spec else None)
+
+        def _decode_fn(self, params, pools):
+            return pools, 0
+
+        def _draft_fn(self, params, pools):
+            return pools, 1
+
+        def step(self):
+            c = self.cache
+            {first}
+            {second}
+"""
+
+
+@pytest.mark.parametrize("first,second,fires", [
+    ("c.pools, out = self._decode(self.params, c.pools)",
+     "log(c.pools)", False),
+    ("fresh, out = self._decode(self.params, c.pools)",
+     "log(c.pools)", True),
+    ("self.cache.pools, out = self._draft(self.params, self.cache.pools)",
+     "log(self.cache.pools)", False),
+    ("fresh, out = self._draft(self.params, self.cache.pools)",
+     "log(self.cache.pools)", True),
+], ids=["rebind", "read-after", "rebind-conditional-jit",
+        "read-after-conditional-jit"])
+def test_donation_misuse_follows_bound_method_jits_and_attribute_chains(
+        tmp_path, first, second, fires):
+    """The serving engine's form: ``self._fn = jax.jit(self._fn_body,
+    donate_argnums=..)`` (also behind ``.. if cond else None``) called
+    with ``c.pools`` / ``self.cache.pools`` at the donated position."""
+    r = lint_src(tmp_path, _POOL_ENGINE.format(first=first, second=second))
+    assert ("donation-misuse" in fired(r)) is fires
+
+
 # ----------------------------------------------------------- pallas-tiling
 
 def test_pallas_tiling_fires_off_tile_and_missing_interpret(tmp_path):
