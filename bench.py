@@ -374,7 +374,7 @@ def run_serving_bench() -> dict:
     srv = {"num_requests": 32, "arrival_rate": 32.0, "new_tokens": 64,
            "prompt_len_min": 32, "prompt_len_max": 128,
            "page_size": 16, "num_pages": 512, "num_slots": 8,
-           "max_model_len": 256, "max_prefill_batch": 4}
+           "max_model_len": 256}
     model = Transformer(cfg)
     params = model.init(jax.random.key(0))
     row = measure_serving(model, params, srv)
@@ -490,7 +490,7 @@ def run_rollout_bench() -> dict:
     eng = RolloutEngine(
         model, params, gen,
         ServingConfig(page_size=4, num_pages=96, num_slots=num_slots,
-                      max_model_len=48, max_prefill_batch=2))
+                      max_model_len=48))
     out = eng.generate(ids, mask, derive_rollout_seeds(11, rows),
                        max_new=max_new)
     snap = eng.metrics.snapshot()
@@ -567,7 +567,7 @@ def run_rollout_fleet_bench() -> dict:
         mask[i, :n] = 1
     seeds = derive_rollout_seeds(11, rows)
     scfg = ServingConfig(page_size=4, num_pages=96, num_slots=4,
-                         max_model_len=48, max_prefill_batch=2,
+                         max_model_len=48,
                          fault_plan="")
     delay_s, branch = 0.05, 2
 
@@ -603,7 +603,7 @@ def run_rollout_fleet_bench() -> dict:
     chaos = SamplerFleet(
         model, params, gen,
         ServingConfig(page_size=4, num_pages=96, num_slots=4,
-                      max_model_len=48, max_prefill_batch=2,
+                      max_model_len=48,
                       fault_plan="sampler=1:rollout_step=1:lost"),
         SamplerFleetConfig(samplers=2, lease_ttl_s=0.3))
     steps_lost = 0
@@ -690,21 +690,13 @@ def run_serving_spec_bench() -> dict:
     def run_arm(spec_on: bool):
         eng = ServingEngine(model, params, gen, ServingConfig(
             page_size=4, num_pages=128, num_slots=num_slots,
-            max_model_len=96, max_prefill_batch=2,
+            max_model_len=96,
             speculative=spec if spec_on else None))
-        # compile warmup off the clock: every prefill bucket the mix
-        # hits at BOTH prefill batch shapes (3 requests = one batch of 2
-        # + one of 1 — the eager sampling ops compile per batch shape),
-        # plus one decode round per slot population — the 2-token budget
-        # is what forces the draft+verify pair (or plain decode) to
-        # trace, and the first arm must not eat compiles the second arm
-        # gets from the process-wide op cache
-        slot_w = eng.cache.geom.slot_window
-        for width in sorted({eng.scheduler.bucket_width(len(p))
-                             for p in prompts}):
-            plen = min(width, slot_w - 2)
-            for _ in range(3):
-                eng.submit([3 + (i % 251) for i in range(plen)], 2)
+        # compile warmup off the clock: the one chunk program plus one
+        # decode round — the 2-token budget is what forces the
+        # draft+verify pair (or plain decode) to trace
+        eng.submit([3 + (i % 251) for i in range(min(
+            eng.cfg.prefill_chunk, eng.cache.geom.slot_window - 2))], 2)
         eng.run_until_drained()
         # the measured window is small (~100 ms on CPU), so wall-clock
         # noise swamps a single pass: repeat the identical mix and take
@@ -784,7 +776,7 @@ def run_serving_tenant_bench() -> dict:
     params = model.init(jax.random.key(0))
     srv = {"new_tokens": 8, "arrival_rate": 1000.0, "seed": 7,
            "page_size": 4, "num_pages": 96, "num_slots": 4,
-           "max_model_len": 48, "max_prefill_batch": 2,
+           "max_model_len": 48,
            "chunked_prefill": {"chunk": 8},
            "tenancy": {"tenants": 4, "requests_per_tenant": 3}}
     row = measure_multi_tenant(model, params, srv)
@@ -866,7 +858,7 @@ def run_serving_fleet_bench() -> dict:
         # fault_plan="" pins members fault-free under $DLA_FAULT_PLAN
         return ServingEngine(model, params, gen, ServingConfig(
             page_size=4, num_pages=96, num_slots=2, max_model_len=48,
-            max_prefill_batch=2, prefill_chunk=chunk, prefix_cache=True,
+            prefill_chunk=chunk, prefix_cache=True,
             fault_plan=""))
 
     def warm(eng):
@@ -1012,7 +1004,7 @@ def run_serving_disagg_bench() -> dict:
         # fault_plan="" pins members fault-free under $DLA_FAULT_PLAN
         return ServingEngine(model, params, gen, ServingConfig(
             page_size=4, num_pages=96, num_slots=2, max_model_len=48,
-            max_prefill_batch=2, prefill_chunk=chunk, prefix_cache=True,
+            prefill_chunk=chunk, prefix_cache=True,
             fault_plan="", role=role))
 
     def warm(eng):
@@ -1140,7 +1132,7 @@ def run_serving_resilience_bench() -> dict:
     def factory():
         eng = ServingEngine(model, params, gen, ServingConfig(
             page_size=4, num_pages=64, num_slots=2, max_model_len=32,
-            max_prefill_batch=2, fault_plan=plan,
+            fault_plan=plan,
             shed={"max_queue_depth": 6}))
         engines.append(eng)
         return eng
@@ -1228,7 +1220,7 @@ def run_serving_gateway_bench() -> dict:
     gen = GenerationConfig(max_new_tokens=new_tokens, do_sample=False,
                            eos_token_id=-1)
     kw = dict(page_size=4, num_pages=64, num_slots=2, max_model_len=32,
-              max_prefill_batch=2, prefill_chunk=4, prefix_cache=True,
+              prefill_chunk=4, prefix_cache=True,
               fault_plan="")
 
     def make_engine():
@@ -1386,7 +1378,7 @@ def run_observability_bench() -> dict:
     gen = GenerationConfig(max_new_tokens=new_tokens, do_sample=False,
                            eos_token_id=-1)
     kw = dict(page_size=4, num_pages=64, num_slots=2, max_model_len=32,
-              max_prefill_batch=2, prefill_chunk=4, prefix_cache=True,
+              prefill_chunk=4, prefix_cache=True,
               fault_plan="")
     rs = np.random.RandomState(0)
     prompts = [[int(t) for t in rs.randint(3, 500, (6,))]
@@ -1453,7 +1445,7 @@ def run_observability_bench() -> dict:
         install_tracer(traced)
         drive_wire(gw.port, warm_prompts)   # traced-path + spool warm
         install_tracer(prev)
-        c0 = (eng.decode_compiles, eng.prefill_compiles)
+        c0 = (eng.decode_compiles, eng.prefill_chunk_compiles)
         best = {False: 0.0, True: 0.0}
         outs = {False: None, True: None}
         for _ in range(reps):
@@ -1469,7 +1461,7 @@ def run_observability_bench() -> dict:
         # summed over ALL measured drives of BOTH arms — tracing must
         # add zero compiles, so the pinned total is (0, 0)
         compiles = (eng.decode_compiles - c0[0],
-                    eng.prefill_compiles - c0[1])
+                    eng.prefill_chunk_compiles - c0[1])
     finally:
         install_tracer(prev)
         gw.close()

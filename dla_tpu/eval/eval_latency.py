@@ -177,13 +177,15 @@ def _serving_config(srv: Dict, **overrides):
 
     pc = srv.get("prefix_cache") or {}
     cp = srv.get("chunked_prefill") or {}
+    chunk = cp.get("chunk")
     kw = dict(
         page_size=int(srv.get("page_size", 16)),
         num_pages=int(srv.get("num_pages", 256)),
         num_slots=int(srv.get("num_slots", 8)),
         max_model_len=int(srv.get("max_model_len", 256)),
-        max_prefill_batch=int(srv.get("max_prefill_batch", 4)),
-        prefill_chunk=int(cp.get("chunk", 0)),
+        # unset, the engine works the chunk out from page_size and
+        # max_model_len
+        prefill_chunk=None if chunk is None else int(chunk),
         prefill_token_budget=int(cp.get("token_budget", 0)),
         prefix_cache=bool(pc.get("enabled", False)),
         cached_logits_capacity=int(pc.get("cached_logits_capacity", 128)),
@@ -194,6 +196,27 @@ def _serving_config(srv: Dict, **overrides):
         profile=srv.get("profile"))
     kw.update(overrides)
     return ServingConfig(**kw)
+
+
+def _warm_prompt(eng, new_tokens: int) -> List[int]:
+    """One chunk-wide prompt (cut to leave ``new_tokens`` of the slot
+    window): it reaches the one prefill program whatever the trace's
+    prompt lengths."""
+    plen = min(eng.cfg.prefill_chunk,
+               eng.cache.geom.slot_window - new_tokens)
+    return [3 + (i % 251) for i in range(plen)]
+
+
+def _warm(eng) -> None:
+    """Compile warm-up off the clock, on the engine that is measured:
+    the warm prompt's second token reaches the decode step or the
+    speculative pair (a 1-token request finishes at prefill). Then zero
+    the instrument panel: percentiles must measure serving, not XLA."""
+    from dla_tpu.serving.metrics import ServingMetrics
+
+    eng.submit(_warm_prompt(eng, 2), 2)
+    eng.run_until_drained()
+    eng.metrics = ServingMetrics()
 
 
 def _drive_open_loop(eng, prompts: List[List[int]], arrivals: np.ndarray,
@@ -248,18 +271,7 @@ def measure_serving(model, params, srv: Dict) -> Dict[str, float]:
                for _ in range(n)]
     arrivals = np.cumsum(rs.exponential(1.0 / rate, n))
 
-    # warm the compile caches — the decode step and EVERY prefill bucket
-    # the trace will hit — on the same engine instance, then zero the
-    # instrument panel: percentiles must measure serving, not XLA
-    slot_w = eng.cache.geom.slot_window
-    for width in sorted({eng.scheduler.bucket_width(len(p))
-                         for p in prompts}):
-        plen = min(width, slot_w - 1)   # leave room for the 1 new token
-        eng.submit([3 + (i % 251) for i in range(plen)], 1)
-    eng.run_until_drained()
-    from dla_tpu.serving.metrics import ServingMetrics
-    eng.metrics = ServingMetrics()
-
+    _warm(eng)
     dt, _ = _drive_open_loop(eng, prompts, arrivals, new_tokens)
     snap = eng.metrics.snapshot()
     return {
@@ -318,8 +330,8 @@ def measure_shared_prefix(model, params, srv: Dict) -> Dict[str, object]:
     arrivals = np.cumsum(rs.exponential(1.0 / rate, n))
     prompt_tokens = sum(len(p) for p in prompts)
     cp = srv.get("chunked_prefill") or {}
-    # chunked prefill is what MAKES hits reusable (absolute chunk
-    # schedule) — default a chunk on if the config didn't pick one
+    # hits are chunk-granular: unless the config picks a chunk, probe
+    # with a finer one than the engine's own default
     chunk = int(cp.get("chunk", 0)) or 2 * int(srv.get("page_size", 16))
 
     def run_arm(cache_on: bool):
@@ -604,7 +616,6 @@ def measure_speculative(model, params, srv: Dict) -> Dict[str, object]:
     decode rounds vs tokens, and whether the generated tokens are
     bit-identical (speculation must not change greedy output)."""
     from dla_tpu.serving import ServingEngine
-    from dla_tpu.serving.metrics import ServingMetrics
 
     sp = dict(srv.get("speculative") or {})
     sp.pop("enabled", None)
@@ -627,20 +638,7 @@ def measure_speculative(model, params, srv: Dict) -> Dict[str, object]:
     def run_arm(spec_on: bool):
         eng = ServingEngine(model, params, gen, _serving_config(
             srv, speculative=dict(sp, enabled=True) if spec_on else None))
-        # compile warmup off the clock: every prefill bucket the trace
-        # hits at BOTH prefill batch shapes (the eager sampling ops
-        # compile per batch shape, and the process-wide op cache would
-        # otherwise bill them all to the first arm), plus one decode
-        # round — a 2-token budget is what forces the (draft, verify)
-        # pair (or the plain decode step) to trace
-        slot_w = eng.cache.geom.slot_window
-        for width in sorted({eng.scheduler.bucket_width(len(p))
-                             for p in prompts}):
-            plen = min(width, slot_w - 2)
-            for _ in range(3):
-                eng.submit([3 + (i % 251) for i in range(plen)], 2)
-        eng.run_until_drained()
-        eng.metrics = ServingMetrics()
+        _warm(eng)
         dt, outs = _drive_open_loop(eng, prompts, arrivals, new_tokens)
         return dt, outs, eng.metrics.snapshot()
 
@@ -684,7 +682,6 @@ def measure_overload(model, params, srv: Dict) -> Dict[str, object]:
     both arms — shedding converts queue collapse into explicit, counted
     rejections, it never loses work silently."""
     from dla_tpu.serving import ServingEngine
-    from dla_tpu.serving.metrics import ServingMetrics
 
     ov = srv.get("overload") or {}
     n = int(srv.get("num_requests", 16))
@@ -714,13 +711,7 @@ def measure_overload(model, params, srv: Dict) -> Dict[str, object]:
     def run_arm(shed_on: bool):
         eng = ServingEngine(model, params, gen, _serving_config(
             srv, shed=shed if shed_on else None))
-        slot_w = eng.cache.geom.slot_window
-        for width in sorted({eng.scheduler.bucket_width(len(p))
-                             for p in prompts}):
-            plen = min(width, slot_w - 1)
-            eng.submit([3 + (i % 251) for i in range(plen)], 1)
-        eng.run_until_drained()
-        eng.metrics = ServingMetrics()
+        _warm(eng)
         dt, _ = _drive_open_loop(eng, prompts, arrivals, new_tokens)
         snap = eng.metrics.snapshot()
         submitted = snap["serving/requests_submitted"]
@@ -774,7 +765,6 @@ def measure_gateway(model, params, srv: Dict) -> Dict[str, object]:
     import threading
 
     from dla_tpu.serving import ServingEngine, ServingGateway
-    from dla_tpu.serving.metrics import ServingMetrics
     from dla_tpu.telemetry.trace import (Tracer, get_tracer,
                                          install_tracer)
 
@@ -793,18 +783,9 @@ def measure_gateway(model, params, srv: Dict) -> Dict[str, object]:
                for _ in range(n)]
     arrivals = np.cumsum(rs.exponential(1.0 / rate, n))
 
-    def warm(eng):
-        slot_w = eng.cache.geom.slot_window
-        for width in sorted({eng.scheduler.bucket_width(len(p))
-                             for p in prompts}):
-            eng.submit([3 + (i % 251)
-                        for i in range(min(width, slot_w - 1))], 1)
-        eng.run_until_drained()
-        eng.metrics = ServingMetrics()
-
     # ---- arm A: in-process (the measure_serving drive) --------------
     eng = ServingEngine(model, params, gen, _serving_config(srv))
-    warm(eng)
+    _warm(eng)
     dt_in, out_in = _drive_open_loop(eng, prompts, arrivals, new_tokens)
     snap = eng.metrics.snapshot()
 
@@ -851,13 +832,9 @@ def measure_gateway(model, params, srv: Dict) -> Dict[str, object]:
         finally:
             conn.close()
 
-    # warm every prefill bucket THROUGH the wire, off the clock (arm A
-    # was warmed the same way in-process)
-    slot_w = eng.cache.geom.slot_window
-    for width in sorted({eng.scheduler.bucket_width(len(p))
-                         for p in prompts}):
-        http_generate([3 + (i % 251)
-                       for i in range(min(width, slot_w - 1))])
+    # warm the gateway's engine THROUGH the wire, off the clock (arm A,
+    # the same configuration, was warmed in-process)
+    http_generate(_warm_prompt(eng, new_tokens))
 
     out_wire: List[List[int]] = [None] * n
     stamps: List[List[float]] = [[] for _ in range(n)]

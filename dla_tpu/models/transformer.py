@@ -1618,9 +1618,8 @@ class Transformer:
         (keys and values; or the latent rows) in activation dtype.
         Experts route droplessly: this is a serving entry point.
 
-        ``prefill`` packs these into the contiguous cache; the serving
-        engine (dla_tpu/serving) scatters them into its block-paged
-        pool — one forward, two cache layouts."""
+        ``prefill`` packs these into the contiguous cache (the paged
+        serving engine prefills through ``prefill_step_paged``)."""
         cfg = self.cfg
         b, t = input_ids.shape
         positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))

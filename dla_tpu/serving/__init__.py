@@ -9,8 +9,8 @@ Modules:
   kv_blocks  block-paged KV cache: fixed-size page pool + host-side
              allocator + the in-graph block-table gather/scatter
   scheduler  request lifecycle state machine (WAITING -> PREFILL ->
-             DECODE -> FINISHED/EVICTED), FCFS + longest-prefix
-             bucketing, eviction-on-OOM
+             DECODE -> FINISHED/EVICTED), strict-FCFS chunked
+             admission, eviction-on-OOM
   server     the host engine loop driving jitted prefill/decode steps
   metrics    queue depth, TTFT, inter-token latency, page occupancy,
              preemption counters

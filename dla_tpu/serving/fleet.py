@@ -377,10 +377,6 @@ class FleetRouter:
             # a factory may disaggregate on its own (per-slot engine
             # configs) — honor the engine's declared role in that case
             role = getattr(sup.engine.cfg, "role", "mixed")
-        if role == "prefill" and sup.engine.cfg.prefill_chunk <= 0:
-            raise ValueError(
-                f"fleet slot {slot} is prefill-role but its engine has "
-                "prefill_chunk=0: chunked prefill is the whole job")
         member = _Member(slot, sup, role)
         self._slots[slot] = member
         self.metrics.ensure_slot_gauge(slot, functools.partial(

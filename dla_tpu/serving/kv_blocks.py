@@ -478,7 +478,7 @@ class PagedKVCache:
              engine's gather / scatter, copy-on-write, migration) maps
              over the tuple, so the row's shape is the model's business.
              This object alone owns the buffers: every jitted program
-             that returns the pools (decode, prefill, chunk, the
+             that returns the pools (decode, prefill chunk, the
              speculative pair, KV import, ``copy_page``) is given them
              DONATED and updates them in place, so whoever gets pools
              back has consumed the ones it gave and rebinds ``pools`` in
@@ -521,25 +521,9 @@ class PagedKVCache:
 
     # ---------------------------------------------------- slot lifecycle
 
-    def open_slot(self, slot: int, pages: List[int], prompt_len: int,
-                  padded_len: int, first_token: int) -> None:
-        """Bind ``pages`` to ``slot`` and set prompt metadata: columns
-        [0, prompt_len) valid at positions 0..prompt_len-1 (prompts are
-        right-padded to ``padded_len``; pad columns hold garbage KV and
-        stay invalid). ``first_token`` is the token sampled from the
-        prefill logits — the first decode step's input."""
-        self.block_tables[slot] = 0
-        self.block_tables[slot, :len(pages)] = pages
-        self.valid[slot] = False
-        self.valid[slot, :prompt_len] = True
-        self.pos[slot] = 0
-        self.pos[slot, :padded_len] = np.arange(padded_len)
-        self.lengths[slot] = prompt_len
-        self.tokens[slot] = first_token
-
     def open_slot_prefill(self, slot: int, pages: List[int],
                           cached_len: int) -> None:
-        """Bind ``pages`` for a CHUNKED prefill: columns [0, cached_len)
+        """Bind ``pages`` for a prefill: columns [0, cached_len)
         are shared cache pages, already valid and attendable; later
         columns become valid as chunks scatter into them
         (``mark_computed``). ``lengths`` stays 0 — the slot joins the
@@ -558,7 +542,7 @@ class PagedKVCache:
 
     def begin_decode(self, slot: int, prompt_len: int,
                      first_token: int) -> None:
-        """Prefill complete (chunked or fully cached): the slot enters
+        """Prefill complete (computed or fully cached): the slot enters
         the decode batch at position ``prompt_len`` with ``first_token``
         as its next input."""
         self.valid[slot, :prompt_len] = True

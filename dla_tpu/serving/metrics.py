@@ -58,7 +58,6 @@ class ServingMetrics:
         # bytes one cached token takes in the paged pool, every layer
         # (dense: keys and values; latent attention: one latent row)
         self.kv_bytes_per_token = r.gauge("serving/kv_bytes_per_token")
-        self.prefill_batches = r.counter("serving/prefill_batches")
         self.tokens_generated = r.counter("serving/tokens_generated")
         self.prefix_lookups = r.counter("serving/prefix_cache/lookups")
         self.prefix_hit_tokens = r.counter(
@@ -118,7 +117,6 @@ class ServingMetrics:
             "serving/moe/expert_assignments": float(
                 self.moe_expert_assignments.value),
             "serving/kv_bytes_per_token": self.kv_bytes_per_token.value,
-            "serving/prefill_batches": float(self.prefill_batches.value),
             "serving/tokens_generated": float(self.tokens_generated.value),
             "serving/prefix_cache/lookups": float(
                 self.prefix_lookups.value),

@@ -512,8 +512,7 @@ class Supervisor:
         replay recomputes them)."""
         self._poll_burst()
         eng = self.engine
-        compile_mark = (eng.decode_compiles, eng.prefill_compiles,
-                        eng.prefill_chunk_compiles,
+        compile_mark = (eng.decode_compiles, eng.prefill_chunk_compiles,
                         eng.spec_draft_compiles, eng.spec_verify_compiles,
                         eng.export_compiles, eng.import_compiles)
         wd = self._watchdog
@@ -529,9 +528,9 @@ class Supervisor:
         wd.pause()
         self._commit(emitted)
         if self._hang.is_set():
-            if (eng.decode_compiles, eng.prefill_compiles,
-                    eng.prefill_chunk_compiles, eng.spec_draft_compiles,
-                    eng.spec_verify_compiles, eng.export_compiles,
+            if (eng.decode_compiles, eng.prefill_chunk_compiles,
+                    eng.spec_draft_compiles, eng.spec_verify_compiles,
+                    eng.export_compiles,
                     eng.import_compiles) != compile_mark:
                 # an XLA compile landed in this step: tracing/lowering
                 # legitimately blows any serving latency budget (and

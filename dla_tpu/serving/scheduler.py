@@ -12,17 +12,9 @@ States:  WAITING -> PREFILL -> DECODE -> FINISHED
                             restarts from the longest still-cached
                             chunk-aligned prefix, not from token 0)
 
-Admission policy (monolithic prefill): FCFS with LONGEST-PREFIX
-BUCKETING — the queue head fixes the prefill bucket (prompt width
-rounded up to a power-of-two page count), then a bounded lookahead pulls
-queued requests that pad to the same bucket into the same prefill batch.
-One compiled prefill per bucket width, full FCFS fairness for the head,
-and the lookahead bound keeps a stream of short prompts from starving a
-long one.
-
-Admission policy (chunked prefill, ``prefill_chunk > 0``): strict FCFS,
-one request prefilling at a time. The head takes a slot plus every page
-its prompt needs up front — aliasing already-cached prefix pages via the
+Admission policy: strict FCFS, one request prefilling at a time. The
+head takes a slot plus every page its prompt needs up front — aliasing
+already-cached prefix pages via the
 :class:`~dla_tpu.serving.kv_blocks.PrefixCache` (incref, no copy) and
 allocating only the rest — then the engine advances it one fixed-shape
 chunk per engine step, co-scheduled with the running decode batch under
@@ -120,10 +112,8 @@ class Request:
 
 @dataclasses.dataclass(frozen=True)
 class SchedulerConfig:
-    max_prefill_batch: int = 4     # requests per bucketed prefill call
-    lookahead: int = 16            # queue scan depth for bucket-mates
+    prefill_chunk: int             # chunk width in tokens (cache-hit grain)
     decode_reserve_pages: int = 1  # pages beyond the prompt required to admit
-    prefill_chunk: int = 0         # chunk width in tokens; 0 = monolithic
     prefill_token_budget: int = 0  # per-engine-step token cap; 0 = none
 
 
@@ -135,19 +125,14 @@ class Scheduler:
       2. ``ensure_decode_pages()``  grow running requests' block tables,
                                     copy-on-write shared write targets,
                                     preempting on OOM
-      3. ``next_prefill_batch()`` / ``admit_chunk_prefill()``
-                                    admission into free slots
+      3. ``admit_chunk_prefill()``  admission into a free slot
     """
 
     def __init__(self, cache: PagedKVCache, cfg: SchedulerConfig,
-                 bucket_widths: List[int],
                  prefix_cache: Optional[PrefixCache] = None):
         self.cache = cache
         self.cfg = cfg
         self.prefix_cache = prefix_cache
-        # ascending padded prompt widths (multiples of page_size); a
-        # prompt buckets to the smallest width that holds it
-        self.bucket_widths = sorted(bucket_widths)
         self.queue: Deque[Request] = deque()
         self.running: Dict[int, Request] = {}    # slot -> request
         self.prefilling: Dict[int, Request] = {} # slot -> mid-chunk req
@@ -186,69 +171,19 @@ class Scheduler:
         req.state = RequestState.WAITING
         self.queue.append(req)
 
-    def bucket_width(self, prefix_len: int) -> int:
-        for w in self.bucket_widths:
-            if prefix_len <= w:
-                return w
-        raise ValueError(
-            f"prefix length {prefix_len} exceeds the largest prefill "
-            f"bucket {self.bucket_widths[-1]}")
-
-    # ----------------------------------------- admission (monolithic)
-
-    def next_prefill_batch(self) -> List[Request]:
-        """FCFS + longest-prefix bucketing: the queue head fixes the
-        bucket; a bounded lookahead fills the batch with same-bucket
-        requests. Each admitted request gets a slot plus ALL its prompt
-        pages and the decode reserve — all-or-nothing, so a half-admitted
-        batch can't deadlock the pool. Admitted requests move to PREFILL
-        with pages+slot bound; the engine runs the actual forward."""
-        batch: List[Request] = []
-        if not self.queue or not self.free_slots:
-            return batch
-        head = self.queue[0]
-        width = self.bucket_width(len(head.prefix_tokens))
+    def admission_pages(self, prefix_len: int) -> int:
+        """Pages admission takes for a prefix of ``prefix_len`` tokens:
+        its own plus the decode reserve, capped at the block table's
+        width (a max-width prompt whose reserve would overflow the table
+        just starts reserve-less)."""
         geom = self.cache.geom
-        limit = min(self.cfg.max_prefill_batch, len(self.free_slots))
-        headroom = self._admission_headroom()
-        if headroom is not None:
-            limit = min(limit, headroom)
-        if limit <= 0:
-            return batch
-        scanned = 0
-        picked_ids = set()
-        for req in list(self.queue):
-            if len(batch) >= limit:
-                break
-            if scanned >= self.cfg.lookahead and batch:
-                break
-            scanned += 1
-            if self.bucket_width(len(req.prefix_tokens)) != width:
-                # bucketing never skips AHEAD of the head: only requests
-                # behind it may ride along, so FCFS holds for the head
-                continue
-            # cap at the block table's width: a max-width prompt whose
-            # reserve would overflow the table just starts reserve-less
-            n_pages = min(geom.pages_for(width)
-                          + self.cfg.decode_reserve_pages,
-                          geom.pages_per_slot)
-            pages = self.cache.allocator.alloc(n_pages)
-            if pages is None:
-                break  # backpressure: pool can't take another prefill
-            req.pages = pages
-            req.slot = self.free_slots.pop()
-            req.state = RequestState.PREFILL
-            picked_ids.add(req.rid)
-            batch.append(req)
-        if picked_ids:
-            self.queue = deque(
-                r for r in self.queue if r.rid not in picked_ids)
-        return batch
+        return min(geom.pages_for(prefix_len)
+                   + self.cfg.decode_reserve_pages, geom.pages_per_slot)
 
-    # -------------------------------------------- admission (chunked)
+    # ----------------------------------------------------------- admission
 
     def admit_chunk_prefill(self) -> Optional[Request]:
-        """Strict-FCFS chunked admission: at most one request is
+        """Strict-FCFS admission: at most one request is
         mid-prefill at a time (its chunks run one per engine step). The
         head gets a slot plus its FULL page demand up front — cached
         prefix pages alias (incref, no copy, no recompute), only the
@@ -265,7 +200,6 @@ class Scheduler:
         if self._admission_headroom() == 0:
             return None
         req = self.queue[0]
-        geom = self.cache.geom
         prefix = req.prefix_tokens
         n = len(prefix)
         hit_pages: List[int] = []
@@ -276,9 +210,8 @@ class Scheduler:
             # another's lookups (adapters change the KV contents)
             hit_pages, hit, logits = self.prefix_cache.lookup(
                 prefix, self.cfg.prefill_chunk, namespace=req.tenant)
-        total = min(geom.pages_for(n) + self.cfg.decode_reserve_pages,
-                    geom.pages_per_slot)
-        fresh = self.cache.allocator.alloc(total - len(hit_pages))
+        fresh = self.cache.allocator.alloc(
+            self.admission_pages(n) - len(hit_pages))
         if fresh is None:
             # backpressure: give the hit references back and wait
             for p in hit_pages:
@@ -296,8 +229,8 @@ class Scheduler:
         return req
 
     def activate(self, req: Request) -> None:
-        """PREFILL -> DECODE once the engine has run the prefill forward
-        (all chunks, for chunked prefill) and opened the slot."""
+        """PREFILL -> DECODE once the engine has run every chunk of the
+        prefill forward and the slot has begun decoding."""
         req.state = RequestState.DECODE
         self.prefilling.pop(req.slot, None)
         self.running[req.slot] = req

@@ -8,9 +8,10 @@ Execution model — the three invariants everything else hangs off:
    entering and leaving only change the *data* (block tables, validity,
    the active mask) — never a shape — so XLA compiles the decode step
    exactly once per engine lifetime (asserted by test).
-2. **Bucketed prefill.** Prompts pad to power-of-two page-count buckets,
-   so prefill compiles once per bucket width ever used, not per prompt
-   length.
+2. **Fixed-shape chunked prefill.** Every prompt prefills in chunks of
+   ``prefill_chunk`` tokens, one request and one chunk per engine step
+   beside the decode batch, so prefill compiles once per engine
+   lifetime too, whatever the prompt lengths.
 3. **Host-mirrored metadata.** Slot metadata (block tables, valid, pos,
    lengths, last tokens) is authoritative on the host as numpy; the
    jitted steps receive it as inputs and the host re-applies the
@@ -85,9 +86,9 @@ from dla_tpu.telemetry.xla_introspect import (
 from dla_tpu.utils.profiling import (
     ProfileWindow, annotate, mark, step_annotation)
 
-#: ``_sample_host``'s sampler: one program per prefill-batch shape, shared
-#: by every engine of the process (called eagerly, the sampler's
-#: ``lax.cond`` would compile anew at every call)
+#: ``_sample_host``'s sampler: one program over one logits row, shared by
+#: every engine of the process (called eagerly, the sampler's ``lax.cond``
+#: would compile anew at every call)
 _sample_rows_jit = jax.jit(sample_token_per_row)
 
 #: what ``step()`` raises, and the KV handoff refuses with, once a failed
@@ -103,21 +104,20 @@ class ServingConfig:
     num_pages: int = 64          # pool size (page 0 reserved for trash)
     num_slots: int = 4           # static decode batch rows
     max_model_len: int = 128     # per-slot logical window (prompt + new)
-    max_prefill_batch: int = 2
-    lookahead: int = 16
     decode_reserve_pages: int = 1
     seed: int = 0
-    # chunked prefill: tokens per fixed-shape prefill chunk (must be a
-    # multiple of page_size); 0 keeps PR-1's monolithic bucketed prefill
-    prefill_chunk: int = 0
+    # tokens per fixed-shape prefill chunk: a multiple of page_size, at
+    # most max_model_len. Unset, the engine takes
+    # min(max_model_len, 16 * page_size)
+    prefill_chunk: Optional[int] = None
     # co-scheduling cap: a prefill chunk is deferred while the running
     # decode batch plus the chunk would exceed this many tokens per
     # engine step (0 = no cap; a chunk always runs when nothing decodes,
     # so the budget can't livelock prefill)
     prefill_token_budget: int = 0
     # share full pages of identical token prefixes across requests via
-    # block-table aliasing (requires prefill_chunk > 0: cache hits are
-    # chunk-granular so the fixed chunk schedule stays compile-stable)
+    # block-table aliasing (cache hits are chunk-granular, so the fixed
+    # chunk schedule stays compile-stable)
     prefix_cache: bool = False
     # LRU cap on stored exact-full-prompt logits entries (each pins its
     # partial tail page in the cache)
@@ -172,9 +172,8 @@ class ServingConfig:
     # multi-tenant LoRA serving (serving.tenancy TenancyConfig fields as
     # a dict): a device-resident pool of per-tenant adapters gathered
     # per-slot inside the ONE compiled decode step, plus per-tenant
-    # quotas/SLOs/metrics. Requires prefill_chunk > 0 (tenant KV is
-    # namespaced in the prefix cache at chunk granularity, and the
-    # monolithic prefill path has no per-slot adapter plumbing).
+    # quotas/SLOs/metrics (tenant KV is namespaced in the prefix cache
+    # at chunk granularity).
     # None or {enabled: false} = single-tenant, PR-1 behavior.
     tenancy: Optional[Dict] = None
     # disaggregation role of this engine within a fleet:
@@ -182,7 +181,7 @@ class ServingConfig:
     #               standalone engine is always mixed)
     #   "prefill" — runs chunked prefill only; the fleet ships each
     #               finished prefix to a decode engine as a
-    #               MigrationTicket (requires prefill_chunk > 0)
+    #               MigrationTicket
     #   "decode"  — admission is handoff-only: submit() refuses, work
     #               arrives via import_request / restore
     role: str = "mixed"
@@ -210,37 +209,29 @@ class ServingEngine:
             raise ValueError(
                 f"max_model_len ({cfg.max_model_len}) must be a positive "
                 f"multiple of page_size ({cfg.page_size})")
-        if cfg.prefill_chunk:
-            if cfg.prefill_chunk % cfg.page_size:
-                raise ValueError(
-                    f"prefill_chunk ({cfg.prefill_chunk}) must be a "
-                    f"multiple of page_size ({cfg.page_size}): chunk "
-                    "boundaries must land on page boundaries so cached "
-                    "prefixes alias whole pages")
-            if cfg.prefill_chunk > cfg.max_model_len:
-                raise ValueError(
-                    f"prefill_chunk ({cfg.prefill_chunk}) exceeds "
-                    f"max_model_len ({cfg.max_model_len})")
-        elif cfg.prefix_cache:
+        if cfg.prefill_chunk is None:
+            cfg = dataclasses.replace(cfg, prefill_chunk=min(
+                cfg.max_model_len, 16 * cfg.page_size))
+        if cfg.prefill_chunk <= 0:
             raise ValueError(
-                "prefix_cache requires prefill_chunk > 0: cache hits "
-                "are chunk-granular, so the monolithic prefill path "
-                "cannot consume them")
+                f"prefill_chunk ({cfg.prefill_chunk}) must be positive: "
+                "the monolithic bucketed prefill it used to select is "
+                "gone, the engine prefills by chunks only")
+        if cfg.prefill_chunk % cfg.page_size:
+            raise ValueError(
+                f"prefill_chunk ({cfg.prefill_chunk}) must be a "
+                f"multiple of page_size ({cfg.page_size}): chunk "
+                "boundaries must land on page boundaries so cached "
+                "prefixes alias whole pages")
+        if cfg.prefill_chunk > cfg.max_model_len:
+            raise ValueError(
+                f"prefill_chunk ({cfg.prefill_chunk}) exceeds "
+                f"max_model_len ({cfg.max_model_len})")
         if cfg.role not in ("prefill", "decode", "mixed"):
             raise ValueError(
                 f"role must be 'prefill', 'decode' or 'mixed', got "
                 f"{cfg.role!r}")
-        if cfg.role == "prefill" and not cfg.prefill_chunk:
-            raise ValueError(
-                "role 'prefill' requires prefill_chunk > 0: a prefill "
-                "engine ships chunk-aligned prefixes, and only chunked "
-                "prefill lands page-aligned committed state to export")
         ten_cfg = TenancyConfig.from_config(cfg.tenancy)
-        if ten_cfg is not None and not cfg.prefill_chunk:
-            raise ValueError(
-                "tenancy requires prefill_chunk > 0: tenant KV is "
-                "namespaced in the prefix cache at chunk granularity and "
-                "only the chunked prefill path carries per-slot adapters")
         spec = dict(cfg.speculative or {})
         if spec and not spec.get("enabled", True):
             spec = {}
@@ -275,12 +266,9 @@ class ServingEngine:
                 logits_capacity=cfg.cached_logits_capacity)
         self.scheduler = Scheduler(
             self.cache,
-            SchedulerConfig(max_prefill_batch=cfg.max_prefill_batch,
-                            lookahead=cfg.lookahead,
-                            decode_reserve_pages=cfg.decode_reserve_pages,
+            SchedulerConfig(decode_reserve_pages=cfg.decode_reserve_pages,
                             prefill_chunk=cfg.prefill_chunk,
                             prefill_token_budget=cfg.prefill_token_budget),
-            bucket_widths=self._bucket_widths(geom),
             prefix_cache=self.prefix_cache)
         self.metrics = ServingMetrics()
         self.metrics.kv_bytes_per_token.set(self.cache.bytes_per_token)
@@ -401,7 +389,6 @@ class ServingEngine:
         # compile, so these ARE the compile counts the no-recompilation
         # test asserts on
         self.decode_compiles = 0
-        self.prefill_compiles = 0
         self.prefill_chunk_compiles = 0
         self.spec_draft_compiles = 0
         self.spec_verify_compiles = 0
@@ -413,7 +400,6 @@ class ServingEngine:
         # leaves a step; each call site rebinds ``cache.pools`` in the same
         # statement. The export gather only reads: it does not donate.
         self._decode = jax.jit(self._decode_fn, donate_argnums=1)
-        self._prefill = jax.jit(self._prefill_fn, donate_argnums=1)
         self._prefill_chunk = jax.jit(self._prefill_chunk_fn,
                                       donate_argnums=1)
         self._spec_draft = (jax.jit(self._spec_draft_fn, donate_argnums=1)
@@ -447,7 +433,6 @@ class ServingEngine:
             register_live_bytes_gauge(self.metrics.registry)
             max_entries = int(xi_cfg.get("max_entries", 16))
             named = [("decode", self._decode),
-                     ("prefill", self._prefill),
                      ("prefill_chunk", self._prefill_chunk)]
             if self._spec_k:
                 named += [("spec_draft", self._spec_draft),
@@ -461,9 +446,9 @@ class ServingEngine:
                     on_compile=self._on_recompile,
                     max_entries=max_entries)
                 for name, fn in named]
-            self._decode, self._prefill, self._prefill_chunk = wrapped[:3]
+            self._decode, self._prefill_chunk = wrapped[:2]
             if self._spec_k:
-                self._spec_draft, self._spec_verify = wrapped[3:5]
+                self._spec_draft, self._spec_verify = wrapped[2:4]
             self._export_kv, self._import_kv = wrapped[-2:]
         else:
             self.mfu_calc = None
@@ -487,17 +472,6 @@ class ServingEngine:
                 attributed=bool(event.get("attributed")))
 
     @staticmethod
-    def _bucket_widths(geom: PageGeometry) -> List[int]:
-        """Power-of-two page counts up to the slot window: one compiled
-        prefill per bucket ever used."""
-        widths, n = [], 1
-        while n < geom.pages_per_slot:
-            widths.append(n * geom.page_size)
-            n *= 2
-        widths.append(geom.slot_window)
-        return widths
-
-    @staticmethod
     def _dev(x: np.ndarray) -> jnp.ndarray:
         """Device-put host scheduler metadata BY VALUE.
 
@@ -512,20 +486,6 @@ class ServingEngine:
         return jnp.asarray(np.array(x))
 
     # -------------------------------------------------------- jitted steps
-
-    def _prefill_fn(self, params, pools, ids, mask, page_rows):
-        """Prefill a padded bucket batch and scatter its rows into the
-        pools. ids/mask [PB, W]; page_rows [PB, W/page_size] physical page
-        ids (dummy rows -> trash page 0). Returns (pools,
-        last-real-token logits [PB, V])."""
-        self.prefill_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the serving compile-once tests
-        ps = self.cfg.page_size
-        logits, rows = self.model.prefill_external(params, ids, mask)
-        pools = tuple(
-            p.at[:, page_rows].set(r.reshape(
-                r.shape[0], r.shape[1], r.shape[2] // ps, ps, *r.shape[3:]))
-            for p, r in zip(pools, rows))
-        return pools, logits
 
     def _prefill_chunk_fn(self, params, pools, btab, valid,
                           pos, ids, start, nvalid, adapters=None):
@@ -745,8 +705,8 @@ class ServingEngine:
                sampling: Optional[SamplingParams] = None,
                tenant: Optional[str] = None) -> int:
         """Queue a request; returns its id. Guards that the request can
-        EVER fit: its worst-case page demand (re-admission prefix padded
-        to a bucket, plus the decode reserve) within pool capacity.
+        EVER fit: the pages its longest re-admission prefix takes (plus
+        the decode reserve) within pool capacity.
 
         ``deadline_s`` is a per-request latency budget relative to
         arrival: past it the scheduler finishes the request with TIMEOUT
@@ -793,11 +753,8 @@ class ServingEngine:
         if deadline_s is not None:
             req.deadline = req.arrival_time + float(deadline_s)
         worst = len(req.prompt_tokens) + req.max_new_tokens
-        worst_pages = min(
-            geom.pages_for(self.scheduler.bucket_width(min(
-                worst, geom.slot_window)))
-            + self.cfg.decode_reserve_pages,
-            geom.pages_per_slot)
+        worst_pages = self.scheduler.admission_pages(
+            min(worst, geom.slot_window))
         if worst_pages > self.cache.allocator.capacity:
             raise ValueError(
                 f"request {req.rid} can never be served: needs up to "
@@ -1266,7 +1223,6 @@ class ServingEngine:
         if self.xla_introspect_enabled:
             # stamp compile events from this step's dispatches
             self._decode.step = self.engine_steps
-            self._prefill.step = self.engine_steps
             self._prefill_chunk.step = self.engine_steps
             if self._spec_k:
                 self._spec_draft.step = self.engine_steps
@@ -1285,24 +1241,17 @@ class ServingEngine:
                 self._ensure_decode_pages(span)
             with annotate("serve_admit",
                           queued=self.scheduler.queue_depth):
-                if self.cfg.prefill_chunk:
-                    self._admit_chunked(emitted)
-                else:
-                    self._admit(emitted)
-            if self.cfg.prefill_chunk:
-                self._chunk_step(emitted)
-            if self.cfg.prefill_chunk or self._spec_k:
-                # second page-safety pass. Chunked: requests admitted
-                # ABOVE (via cache hit or final chunk) decode THIS step,
-                # and their first write may land in a shared/indexed tail
-                # page — copy-on-write must run before the decode, not
-                # next step. One-shot prefill: an admission's decode
-                # reserve guarantees ONE column, but a speculative round
-                # commits up to span columns in the admission step itself
-                # — grow (or preempt) before the round, or commits could
-                # advance past allocated pages
-                with annotate("serve_schedule"):
-                    self._ensure_decode_pages(span)
+                self._admit(emitted)
+            self._chunk_step(emitted)
+            # second page-safety pass: requests admitted ABOVE (via cache
+            # hit or final chunk) decode THIS step. Their first write may
+            # land in a shared/indexed tail page — copy-on-write must run
+            # before the decode, not next step — and the decode reserve
+            # guarantees ONE column where a speculative round commits up
+            # to span: grow (or preempt) before the round, or commits
+            # could advance past allocated pages
+            with annotate("serve_schedule"):
+                self._ensure_decode_pages(span)
             if self.scheduler.running:
                 emitted.extend(self._spec_decode_step() if self._spec_k
                                else self._decode_step())
@@ -1635,68 +1584,7 @@ class ServingEngine:
         m.adapter_resident.set(st.resident_count)
 
     def _admit(self, emitted: List[Tuple[int, int]]) -> None:
-        """Drain as many bucketed prefill batches as slots/pages allow."""
-        while True:
-            batch = self.scheduler.next_prefill_batch()
-            if not batch:
-                return
-            self._run_prefill(batch, emitted)
-
-    def _run_prefill(self, batch: List[Request],
-                     emitted: List[Tuple[int, int]]) -> None:
-        geom = self.cache.geom
-        ps, pb = self.cfg.page_size, self.cfg.max_prefill_batch
-        width = self.scheduler.bucket_width(len(batch[0].prefix_tokens))
-        n_prompt_pages = geom.pages_for(width)
-        ids = np.zeros((pb, width), np.int32)
-        mask = np.zeros((pb, width), np.int32)
-        page_rows = np.zeros((pb, n_prompt_pages), np.int32)
-        for i, req in enumerate(batch):
-            toks = req.prefix_tokens
-            ids[i, :len(toks)] = toks
-            mask[i, :len(toks)] = 1
-            page_rows[i] = req.pages[:n_prompt_pages]
-        for i in range(len(batch), pb):
-            mask[i, 0] = 1   # dummy rows: one valid token, trash pages
-        for req in batch:
-            mark("serve_req_admit", rid=req.rid, slot=req.slot,
-                 cached_tokens=0)
-        with annotate("serve_prefill", n=len(batch), width=width):
-            self.cache.pools, logits = self._prefill(
-                self.params, self.cache.pools, jnp.asarray(ids),
-                jnp.asarray(mask), jnp.asarray(page_rows))
-            # dla: disable=host-sync-in-hot-loop -- designed prefill D2H: one logits fetch per admitted batch, not per token
-            logits_np = np.asarray(logits)
-        t_done = self.now()
-        self.metrics.prefill_batches.inc()
-        # one span for the batch, under its first request's id; every
-        # request's own first-token mark follows from _emit
-        with annotate("serve_first_token", rid=batch[0].rid):
-            first, first_lps = self._sample_host(
-                logits_np[:len(batch)], batch)
-            for i, req in enumerate(batch):
-                tok = int(first[i])
-                if req.admitted_time is None:
-                    # queue wait = arrival -> first admission (re-prefills
-                    # after eviction are decode-path stalls, not queue
-                    # time)
-                    req.admitted_time = t_done
-                    self.metrics.queue_wait_ms.record(
-                        (t_done - req.arrival_time) * 1000.0)
-                    if self.tracer.enabled:
-                        self.tracer.async_instant(
-                            "request", "admitted", req.rid, t=t_done,
-                            queue_wait_ms=(t_done - req.arrival_time)
-                            * 1000.0)
-                self.cache.open_slot(req.slot, req.pages,
-                                     len(req.prefix_tokens), width, tok)
-                self.scheduler.activate(req)
-                self._bind_slot_sampling(req)
-                self._emit(req, tok, t_done, emitted, first_of_prefill=True,
-                           logp=float(first_lps[i]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the prefill batch fetch above
-
-    def _admit_chunked(self, emitted: List[Tuple[int, int]]) -> None:
-        """Strict-FCFS chunked admission. Exact-full-prompt cache hits
+        """Strict-FCFS admission. Exact-full-prompt cache hits
         skip prefill entirely (stored logits -> first token now) and
         keep admitting behind them; a partial admission occupies the
         single mid-prefill seat and stops the loop."""
@@ -1778,9 +1666,8 @@ class ServingEngine:
         with annotate("serve_chunk_fetch", rid=req.rid):
             # dla: disable=host-sync-in-hot-loop -- designed prefill D2H: one logits fetch per REQUEST (final chunk only), not per chunk
             logits_np = np.asarray(logits)
-        t_done = self.now()
-        self.metrics.prefill_batches.inc()
-        self._first_token(req, logits_np, t_done, emitted, register=True)
+        self._first_token(req, logits_np, self.now(), emitted,
+                          register=True)
 
     def _first_token(self, req: Request, logits_np: np.ndarray, t: float,
                      emitted: List[Tuple[int, int]],
@@ -1790,8 +1677,7 @@ class ServingEngine:
         registration (``register``: the row was just computed, not served
         from the cache), activation."""
         with annotate("serve_first_token", rid=req.rid):
-            toks, lps = self._sample_host(logits_np, [req])
-            tok = int(toks[0])
+            tok, logp = self._sample_host(logits_np, req)
             self.cache.begin_decode(req.slot, len(req.prefix_tokens), tok)
             if register and self.prefix_cache is not None:
                 # first-writer-wins: later identical prompts alias these
@@ -1803,7 +1689,7 @@ class ServingEngine:
             self.scheduler.activate(req)
             self._bind_slot_sampling(req)
             self._emit(req, tok, t, emitted, first_of_prefill=True,
-                       logp=float(lps[0]))  # dla: disable=host-sync-in-hot-loop -- host numpy scalar; rode the logits row's one fetch
+                       logp=logp)
 
     def _mirror_cache_counters(self) -> None:
         """Mirror the PrefixCache's plain-int counters into the metrics
@@ -1820,31 +1706,34 @@ class ServingEngine:
         seen.update(lookups=pc.lookups, hit_tokens=pc.hit_tokens,
                     evictions=pc.evictions)
 
-    def _sample_host(self, logits: np.ndarray, reqs: List[Request]):
-        """Sample each request's next token from its prefill logits row —
-        the EXACT per-row rule the decode step runs (same fold_in(seed,
-        token-index) keying, same filters), one jitted call per prefill
-        batch, off the hot loop. The token index is len(generated), so
-        an eviction/replay re-prefill resumes the same stream. Returns
-        (tokens, logps) host arrays."""
+    def _sample_host(self, logits: np.ndarray, req: Request):
+        """Sample the request's next token from its one prefill logits
+        row ``logits`` [1, V] — the EXACT per-row rule the decode step
+        runs (same fold_in(seed, token-index) keying, same filters), off
+        the hot loop. The token index is len(generated), so an
+        eviction/replay re-prefill resumes the same stream. Returns
+        (token, logp)."""
         if np.isnan(logits).any():
             # real detection on the only logits the host ever sees: the
             # serving analog of the trainer's NaN guard. The supervisor
             # turns this into a rebuild-and-replay.
             raise NaNLogitsError("non-finite prefill logits")
-        sps = [self._effective_sampling(r) for r in reqs]
-        # python-list -> numpy marshalling of per-request sampling
-        # params (host-only, no device fetch on these lines)
-        seeds = np.array([sp.seed & 0xFFFFFFFF for sp in sps], np.uint32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
-        gpos = np.array([len(r.generated) for r in reqs], np.int32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
-        temps = np.array([sp.effective_temperature for sp in sps], np.float32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
-        top_ps = np.array([sp.top_p for sp in sps], np.float32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
-        top_ks = np.array([sp.top_k for sp in sps], np.int32)  # dla: disable=host-sync-in-hot-loop -- host list->numpy marshalling, no device fetch
+        sp = self._effective_sampling(req)
+
+        def row(value, dtype):
+            # through numpy: ``jnp.asarray`` of a Python list takes
+            # 0.14 ms longer, five times a first token (PERF.md, PR 31)
+            # dla: disable=host-sync-in-hot-loop -- host scalar -> numpy marshalling, no device fetch
+            return jnp.asarray(np.array([value], dtype))
         toks, lps = _sample_rows_jit(
-            jnp.asarray(seeds), jnp.asarray(gpos), jnp.asarray(logits),
-            jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks))
-        # dla: disable=host-sync-in-hot-loop -- prefill sample fetch: one D2H per admitted batch
-        return np.asarray(toks), np.asarray(lps)
+            row(sp.seed & 0xFFFFFFFF, np.uint32),
+            row(len(req.generated), np.int32), jnp.asarray(logits),
+            row(sp.effective_temperature, np.float32),
+            row(sp.top_p, np.float32), row(sp.top_k, np.int32))
+        # fetch whole, index on the host: ``toks[0]`` on the device array
+        # would run a slice and a squeeze program before each fetch
+        # dla: disable=host-sync-in-hot-loop -- prefill sample fetch: one D2H pair per admitted request
+        return int(np.asarray(toks)[0]), float(np.asarray(lps)[0])
 
     def _decode_span(self, active_slots: List[int],
                      sampling_slots: int) -> annotate:

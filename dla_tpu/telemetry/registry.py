@@ -245,7 +245,6 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/kv_bytes_per_token", "gauge", "bytes",
        "bytes one cached token takes in the paged pool over every layer "
        "(keys and values, or one latent row)", "step"),
-    _s("serving/prefill_batches", "counter", "batches", "", "step"),
     _s("serving/tokens_generated", "counter", "tokens", "", "step"),
     _s("serving/ttft_ms", "histogram", "ms",
        "time to first token (arrival -> first emit)", "step"),

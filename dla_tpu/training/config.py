@@ -349,7 +349,6 @@ class RolloutServingSchema:
     num_pages: Any = None
     num_slots: Any = None
     max_model_len: Any = None
-    max_prefill_batch: Any = None
     prefill_chunk: Any = None
     prefill_token_budget: Any = None
     prefix_cache: Any = None
@@ -615,8 +614,6 @@ class ServingLatencySchema:
     num_pages: Any = None
     num_slots: Any = None
     max_model_len: Any = None
-    max_prefill_batch: Any = None
-    lookahead: Any = None
     decode_reserve_pages: Any = None
     prefix_cache: Optional[PrefixCacheSchema] = None
     chunked_prefill: Optional[ChunkedPrefillSchema] = None

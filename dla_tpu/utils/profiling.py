@@ -140,7 +140,6 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "serve": ("engine host loop", ("step_num", "host_ns")),
     "serve_schedule": ("scheduler", ()),
     "serve_admit": ("scheduler", ("queued",)),
-    "serve_prefill": ("engine host loop", ("n", "width")),
     "serve_prefill_chunk": ("engine host loop",
                             ("rid", "slot", "start", "nvalid", "last")),
     "serve_chunk_fetch": ("engine host loop", ("rid",)),

@@ -50,7 +50,7 @@ def main() -> None:
     gen = GenerationConfig(max_new_tokens=16, do_sample=False,
                            eos_token_id=-1, pad_token_id=0)
     kw = dict(page_size=4, num_pages=64, num_slots=2, max_model_len=32,
-              max_prefill_batch=2, prefill_chunk=4, prefix_cache=True,
+              prefill_chunk=4, prefix_cache=True,
               fault_plan="")
 
     def factory(slot):

@@ -314,6 +314,26 @@ def test_config_schema_drift_silent_on_declared_keys(tmp_path):
     assert "config-schema-drift" not in fired(r)
 
 
+@pytest.mark.parametrize("block, key", [
+    ("latency:\n  serving:\n", "max_prefill_batch"),
+    ("latency:\n  serving:\n", "lookahead"),
+    ("ppo:\n  rollout:\n    serving:\n", "max_prefill_batch")],
+    ids=["latency.serving.max_prefill_batch", "latency.serving.lookahead",
+         "ppo.rollout.serving.max_prefill_batch"])
+def test_config_schema_drift_fires_on_a_removed_prefill_knob(
+        tmp_path, block, key):
+    """The two knobs of the removed bucketed prefill are no keys of the
+    schema: a YAML that still sets one is reported, not ignored."""
+    p = tmp_path / "config" / "exp.yaml"
+    p.parent.mkdir()
+    indent = " " * (2 * block.count("\n"))
+    p.write_text(f"experiment_name: t\n{block}{indent}page_size: 16\n"
+                 f"{indent}{key}: 2\n")
+    r = run_lint([p], rules=["config-schema-drift"], root=tmp_path)
+    hits = [f for f in r.active if f.rule == "config-schema-drift"]
+    assert len(hits) == 1 and f"serving.{key}`" in hits[0].message
+
+
 # ------------------------------------------------------- metric-name-drift
 
 def test_metric_name_drift_fires_on_undeclared_name(tmp_path):

@@ -56,7 +56,7 @@ def _factory(serve_setup):
     def factory(slot):
         return ServingEngine(model, params, gen, ServingConfig(
             page_size=PAGE, num_pages=64, num_slots=2, max_model_len=32,
-            max_prefill_batch=2, prefill_chunk=PAGE, prefix_cache=True,
+            prefill_chunk=PAGE, prefix_cache=True,
             fault_plan=""))
     return factory
 

@@ -60,7 +60,7 @@ def _engine(serve_setup, **cfg_kw):
     None) pins it fault-free even when $DLA_FAULT_PLAN is set."""
     model, params, gen = serve_setup
     kw = dict(page_size=PAGE, num_pages=64, num_slots=2,
-              max_model_len=32, max_prefill_batch=2, prefill_chunk=PAGE,
+              max_model_len=32, prefill_chunk=PAGE,
               prefix_cache=True, fault_plan="")
     kw.update(cfg_kw)
     return ServingEngine(model, params, gen, ServingConfig(**kw))

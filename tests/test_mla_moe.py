@@ -163,17 +163,21 @@ def test_engine_chunked_prefill_and_paged_decode_match_reference(ref, tiny):
             <= snap["serving/moe/expert_assignments"])
 
 
-def test_one_shot_prefill_matches_reference(ref, tiny):
-    """The engine without a chunk lane prefills through
-    ``prefill_external`` (expanded form) and decodes absorbed: both forms
-    write and read the same pool."""
+@pytest.mark.parametrize("chunk, length, chunks", [
+    (None, 13, 1), (8, 22, 3)], ids=["under_one_chunk", "three_chunks"])
+def test_prompt_of_n_chunks_matches_reference(ref, tiny, chunk, length,
+                                              chunks):
+    """The chunk lane is the one way in: a prompt shorter than the chunk
+    (``prefill_chunk`` unset: as wide as the window) and one that takes
+    three chunks, the last of them part full, both write the latent pool
+    the absorbed decode reads."""
     model, params = tiny
     rng = np.random.default_rng(3)
-    prompts = [[int(t) for t in rng.integers(3, 500, size=n)]
-               for n in (13, 22)]
-    results, _ = serve(model, params, prompts, max_new=6,
-                       prefill_chunk=None, prefix_cache=False)
-    assert_engine_matches(ref, model, params, prompts, results)
+    prompt = [int(t) for t in rng.integers(3, 500, size=length)]
+    results, snap = serve(model, params, [prompt], max_new=6,
+                          prefill_chunk=chunk)
+    assert snap["serving/prefill/chunks"] == chunks
+    assert_engine_matches(ref, model, params, [prompt], results)
 
 
 # ------------------------------------------------- (c) the shares add up

@@ -1,5 +1,5 @@
 """The KV pools are owned by ``PagedKVCache`` alone: every jitted serving
-program that returns the pools (decode, chunk, one-shot prefill, the
+program that returns the pools (decode, prefill chunk, the
 speculative pair, KV import, copy-on-write) is given them donated, so the
 arrays that went in are dead after the call and XLA updates the pool in
 place (``telemetry/xla/<fn>/alias_bytes`` = the pools' bytes: no
@@ -46,7 +46,7 @@ def setup(request):
 def _engine(setup, **cfg_kw):
     model, params, gen = setup
     kw = dict(page_size=PAGE, num_pages=64, num_slots=2, max_model_len=32,
-              max_prefill_batch=2, prefill_chunk=PAGE, prefix_cache=True,
+              prefill_chunk=PAGE, prefix_cache=True,
               fault_plan="")
     kw.update(cfg_kw)
     return ServingEngine(model, params, gen, ServingConfig(**kw))
@@ -84,10 +84,11 @@ def _chunk(setup):
     return eng, eng.step, "prefill_chunks"
 
 
-def _one_shot_prefill(setup):
-    eng = _engine(setup, prefill_chunk=0, prefix_cache=False)
+def _last_chunk(setup):
+    # prefill_chunk unset: one chunk as wide as the window holds the prompt
+    eng = _engine(setup, prefill_chunk=None, prefix_cache=False)
     eng.submit(PROMPT, 1)           # finishes at its first token: no decode
-    return eng, eng.step, "prefill_batches"
+    return eng, eng.step, "prefill_chunks"
 
 
 def _speculative_round(setup):
@@ -120,7 +121,7 @@ def _import_request(setup):
 
 
 PROGRAMS = {"decode": _decode, "prefill_chunk": _chunk,
-            "prefill": _one_shot_prefill, "speculative": _speculative_round,
+            "last_chunk": _last_chunk, "speculative": _speculative_round,
             "cow_page": _cow_page, "kv_import": _import_request}
 
 

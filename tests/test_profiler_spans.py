@@ -3,6 +3,7 @@ engine and the trainer emit exactly what the table lists, with exactly its
 arguments, nested and ordered as PERF.md section 3 says; a real profiler
 trace carries the arguments; StepClock's accounting covers the metrics
 fetch; the HLO scope map and the classifier read a lowered train step."""
+import dataclasses
 import glob
 import itertools
 import time
@@ -86,10 +87,11 @@ def _prompts(n=4, seed=3):
 ENGINES = {
     "chunked": dict(page_size=4, num_pages=32, num_slots=2, max_model_len=32,
                     prefill_chunk=4),
-    "one_shot": dict(page_size=4, num_pages=32, num_slots=2,
-                     max_model_len=32, max_prefill_batch=2),
+    # prefill_chunk unset: one chunk as wide as the window
+    "default_chunk": dict(page_size=4, num_pages=32, num_slots=2,
+                          max_model_len=32),
     "speculative": dict(page_size=4, num_pages=32, num_slots=2,
-                        max_model_len=32, max_prefill_batch=2,
+                        max_model_len=32,
                         speculative={"enabled": True, "k": 3,
                                      "draft": "self"}),
     # capacity 7 pages: both prompts admit, cannot both grow to 12 tokens
@@ -140,9 +142,7 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
             "serve_decode", "serve_decode_args", "serve_decode_dispatch",
             "serve_decode_fetch", "serve_emit", "serve_post",
             "serve_req_submit", "serve_req_admit", "serve_req_first_token",
-            "serve_req_finish"}
-    want |= ({"serve_prefill_chunk", "serve_chunk_fetch"}
-             if ENGINES[kind].get("prefill_chunk") else {"serve_prefill"})
+            "serve_req_finish", "serve_prefill_chunk", "serve_chunk_fetch"}
     if kind == "experts":
         want.add("serve_moe_route")
     else:
@@ -217,6 +217,17 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
         done = rec.named("serve_req_finish")
         row = next(r for r in done if r[1]["rid"] == rid)
         assert row[1]["status"] == "length" and row[1]["tokens"] == MAX_NEW
+
+
+def test_the_bucketed_prefill_left_no_span_and_no_knob():
+    """One prefill lane: its span is the chunk's, and no field of either
+    config selects or sizes another."""
+    from dla_tpu.serving import SchedulerConfig
+    assert "serve_prefill" not in SPANS and "serve_prefill_chunk" in SPANS
+    for cfg in (ServingConfig, SchedulerConfig):
+        names = {f.name for f in dataclasses.fields(cfg)}
+        assert not names & {"max_prefill_batch", "lookahead"}, cfg
+        assert "prefill_chunk" in names
 
 
 def _tiny_trainer(out_dir):

@@ -464,8 +464,7 @@ def serve_setup():
 def _engine(serve_setup, clock=None, **cfg_kw):
     from dla_tpu.serving import ServingConfig, ServingEngine
     model, params, gen = serve_setup
-    kw = dict(page_size=4, num_pages=32, num_slots=2, max_model_len=32,
-              max_prefill_batch=2)
+    kw = dict(page_size=4, num_pages=32, num_slots=2, max_model_len=32)
     kw.update(cfg_kw)
     extra = {"now": clock} if clock is not None else {}
     return ServingEngine(model, params, gen, ServingConfig(**kw), **extra)

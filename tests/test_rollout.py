@@ -51,7 +51,7 @@ def prompt_batch():
 
 def _serving_cfg(G=1, **kw):
     base = dict(page_size=4, num_pages=64, num_slots=3,
-                max_model_len=32, max_prefill_batch=2)
+                max_model_len=32)
     if G > 1:
         # G-groups share prompt pages through the prefix cache
         base.update(prefill_chunk=4, prefix_cache=True)
@@ -178,7 +178,8 @@ def test_refit_zero_recompiles_then_donation(model_and_params,
     roll = RolloutEngine(model, params, gen, _serving_cfg())
     out0 = roll.generate(ids, mask, seeds)
     assert roll.engine.decode_compiles == 1
-    pc = roll.engine.prefill_compiles
+    pc = roll.engine.prefill_chunk_compiles
+    assert pc == 1
 
     # refit the SAME params: identical outputs, zero recompiles
     refitter = WeightRefitter(roll, lambda: params)
@@ -190,7 +191,7 @@ def test_refit_zero_recompiles_then_donation(model_and_params,
     assert np.array_equal(np.asarray(out0["response_logps"]),
                           np.asarray(out1["response_logps"]))
     assert roll.engine.decode_compiles == 1
-    assert roll.engine.prefill_compiles == pc
+    assert roll.engine.prefill_chunk_compiles == pc
     assert roll.metrics.refits.value == 1
     assert roll.metrics.refit_ms.value >= 0
 
@@ -202,7 +203,7 @@ def test_refit_zero_recompiles_then_donation(model_and_params,
     assert not np.array_equal(np.asarray(out0["response_logps"]),
                               np.asarray(out2["response_logps"]))
     assert roll.engine.decode_compiles == 1
-    assert roll.engine.prefill_compiles == pc
+    assert roll.engine.prefill_chunk_compiles == pc
 
     # donated refit: the OLD (bumped) tree's buffers are freed eagerly;
     # the engine runs on the fresh tree and reproduces out0

@@ -297,9 +297,11 @@ def test_stale_temperature_of_a_free_slot_keeps_steps_greedy(
     extra = ({"speculative": {"enabled": True, "k": 2, "draft": "self"}}
              if speculative else {})
     eng = ServingEngine(model, params, gen, ServingConfig(
-        page_size=4, num_pages=32, num_slots=2, max_model_len=32,
-        max_prefill_batch=2, **extra))
-    sampled = eng.submit([5, 6, 7, 8], 4, sampling=SamplingParams(
+        page_size=4, num_pages=32, num_slots=2, max_model_len=32, **extra))
+    # admission takes one request a step: the sampled one needs more
+    # tokens than its first step can commit (1 + k + 1), so that the
+    # greedy one is admitted beside it and not into its freed slot
+    sampled = eng.submit([5, 6, 7, 8], 6, sampling=SamplingParams(
         temperature=0.9, top_p=0.9, seed=3))
     greedy = eng.submit([9, 10, 11, 12], MAX_NEW)
     held = []        # per decode phase: was the sampled request running?
@@ -317,7 +319,7 @@ def test_stale_temperature_of_a_free_slot_keeps_steps_greedy(
     stale_slot = int(np.flatnonzero(eng.samp_temp > 0)[0])
     eng.close()
 
-    assert len(eng.result(sampled).generated) == 4
+    assert len(eng.result(sampled).generated) == 6
     assert len(eng.result(greedy).generated) == MAX_NEW
     # the row still holds the finished request's temperature ...
     assert eng.samp_temp[stale_slot] == np.float32(0.9)

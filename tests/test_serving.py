@@ -1,5 +1,5 @@
 """Serving subsystem tests: page allocator invariants, scheduler state
-machine (admission, bucketing, eviction-recompute, no leaks), and the
+machine (admission, eviction-recompute, no leaks), and the
 load-bearing e2e guarantees — paged decode is TOKEN-IDENTICAL to the
 contiguous GenerationEngine path, and mid-decode arrivals never
 recompile the decode step."""
@@ -98,19 +98,18 @@ def _sched(page_size=4, num_pages=16, num_slots=2, pages_per_slot=4,
     geom = PageGeometry(page_size=page_size, num_pages=num_pages,
                         num_slots=num_slots, pages_per_slot=pages_per_slot)
     cache = PagedKVCache(_ModelStub(), geom)
-    widths = [page_size, 2 * page_size, geom.slot_window]
-    return Scheduler(cache, SchedulerConfig(**cfg_kw), widths), cache
+    cfg = SchedulerConfig(prefill_chunk=page_size, **cfg_kw)
+    return Scheduler(cache, cfg), cache
 
 
 def test_scheduler_admission_binds_slot_and_pages():
     sched, cache = _sched()
     req = Request(prompt_tokens=[1, 2, 3], max_new_tokens=4)
     sched.submit(req)
-    batch = sched.next_prefill_batch()
-    assert batch == [req]
+    assert sched.admit_chunk_prefill() is req
     assert req.state is RequestState.PREFILL
     assert req.slot is not None
-    # 3 tokens -> 4-wide bucket -> 1 prompt page + 1 decode reserve
+    # 3 tokens -> 1 prompt page + 1 decode reserve
     assert len(req.pages) == 2
     sched.activate(req)
     assert req.state is RequestState.DECODE
@@ -120,22 +119,6 @@ def test_scheduler_admission_binds_slot_and_pages():
     assert cache.allocator.used_count == 0
     assert len(sched.free_slots) == cache.geom.num_slots
     sched.assert_consistent()
-
-
-def test_scheduler_bucketing_head_fixes_bucket():
-    """The head's bucket decides the batch; a same-bucket request behind
-    a different-bucket one rides along, the different one waits."""
-    sched, _ = _sched(num_slots=4, max_prefill_batch=4)
-    short1 = Request(prompt_tokens=[1, 2], max_new_tokens=2)        # w=4
-    longer = Request(prompt_tokens=list(range(1, 7)), max_new_tokens=2)  # w=8
-    short2 = Request(prompt_tokens=[3], max_new_tokens=2)           # w=4
-    for r in (short1, longer, short2):
-        sched.submit(r)
-    batch = sched.next_prefill_batch()
-    assert [r.rid for r in batch] == [short1.rid, short2.rid]
-    assert list(sched.queue) == [longer]
-    batch2 = sched.next_prefill_batch()
-    assert batch2 == [longer]
 
 
 def test_scheduler_rejects_oversized_and_empty():
@@ -157,8 +140,9 @@ def test_scheduler_eviction_on_oom_requeues_and_frees():
     young = Request(prompt_tokens=[4, 5, 6], max_new_tokens=8)
     sched.submit(old)
     sched.submit(young)
-    for req in sched.next_prefill_batch():
-        cache.open_slot(req.slot, req.pages, 3, 4, 7)
+    for req in (old, young):
+        assert sched.admit_chunk_prefill() is req
+        cache.begin_decode(req.slot, 3, 7)
         sched.activate(req)
     sched.assert_consistent()
     old_slot = old.slot
@@ -234,8 +218,7 @@ def test_serving_matches_contiguous_engine(model_and_params,
     prompts, ref, gen = reference_tokens
     eng = ServingEngine(model, params, gen,
                         ServingConfig(page_size=4, num_pages=32,
-                                      num_slots=3, max_model_len=32,
-                                      max_prefill_batch=2))
+                                      num_slots=3, max_model_len=32))
     rids = [eng.submit(p, MAX_NEW) for p in prompts]
     results = _drain(eng)
     for i, rid in enumerate(rids):
@@ -252,8 +235,7 @@ def test_serving_no_recompile_and_no_leaks_across_arrivals(
     prompts, ref, gen = reference_tokens
     eng = ServingEngine(model, params, gen,
                         ServingConfig(page_size=4, num_pages=32,
-                                      num_slots=2, max_model_len=32,
-                                      max_prefill_batch=2))
+                                      num_slots=2, max_model_len=32))
     # wave 1: two requests saturate both slots
     rids = {eng.submit(p, MAX_NEW): i for i, p in enumerate(prompts[:2])}
     for _ in range(2):
@@ -272,9 +254,9 @@ def test_serving_no_recompile_and_no_leaks_across_arrivals(
         "guarantee broken")
     assert eng.cache.allocator.used_count == 0
     assert len(eng.scheduler.free_slots) == 2
-    # prefill compiles once per bucket width used, never per prompt
-    widths = {eng.scheduler.bucket_width(len(p)) for p in prompts}
-    assert eng.prefill_compiles == len(widths)
+    # one chunk shape: prefill compiles once, whatever the prompt lengths
+    assert len({len(p) for p in prompts}) > 1
+    assert eng.prefill_chunk_compiles == 1
 
 
 @pytest.mark.parametrize("preset", ["tiny", "tiny-mla"])
@@ -296,8 +278,7 @@ def test_serving_eviction_recomputes_identically(preset, model_and_params):
     def serve(num_pages):
         eng = ServingEngine(model, params, gen,
                             ServingConfig(page_size=2, num_pages=num_pages,
-                                          num_slots=2, max_model_len=12,
-                                          max_prefill_batch=2))
+                                          num_slots=2, max_model_len=12))
         rids = [eng.submit(p, MAX_NEW) for p in use]
         results = _drain(eng)
         return eng, [results[rid] for rid in rids]
@@ -350,6 +331,81 @@ def test_serving_metrics_surface(model_and_params, reference_tokens):
     assert snap["serving/page_occupancy"] == 0.0   # drained
 
 
+@pytest.mark.parametrize("page_size, max_model_len, want", [
+    (4, 32, 32),        # the whole window: a test prompt is one chunk
+    (2, 64, 32)])       # sixteen pages
+def test_unset_prefill_chunk_is_worked_out_from_the_geometry(
+        model_and_params, page_size, max_model_len, want):
+    model, params = model_and_params
+    gen = GenerationConfig(max_new_tokens=2, do_sample=False,
+                           eos_token_id=2, pad_token_id=0)
+    cfg = ServingConfig(page_size=page_size, num_pages=40, num_slots=2,
+                        max_model_len=max_model_len)
+    assert cfg.prefill_chunk is None
+    eng = ServingEngine(model, params, gen, cfg)
+    assert eng.cfg.prefill_chunk == want == min(max_model_len,
+                                                16 * page_size)
+    assert eng.scheduler.cfg.prefill_chunk == want
+    rid = eng.submit(list(range(3, 12)), 2)
+    _drain(eng)
+    assert len(eng.result(rid).generated) == 2
+    assert eng.metrics.prefill_chunks.value == 1    # 9 tokens, one chunk
+    assert eng.prefill_chunk_compiles == 1
+
+
+def test_burst_beyond_the_slots_drains_in_arrival_order(model_and_params,
+                                                        reference_tokens):
+    """Four prompts of four lengths at once on a default-config engine
+    with two slots: admission is strict FCFS (first tokens come out in
+    the order of arrival, whatever the lengths) and every stream is the
+    contiguous engine's."""
+    model, params = model_and_params
+    prompts, ref, gen = reference_tokens
+    eng = ServingEngine(model, params, gen,
+                        ServingConfig(page_size=4, num_pages=32,
+                                      num_slots=2, max_model_len=32))
+    rids = [eng.submit(p, MAX_NEW) for p in prompts]
+    first_seen = []
+    for _ in range(500):
+        if not eng.has_work():
+            break
+        for rid, _tok in eng.step():
+            if rid not in first_seen:
+                first_seen.append(rid)
+        eng.scheduler.assert_consistent()
+    assert first_seen == rids
+    admitted = [eng.result(r).admitted_time for r in rids]
+    assert admitted == sorted(admitted)
+    assert [eng.result(r).generated for r in rids] == ref
+    assert eng.prefill_chunk_compiles == eng.decode_compiles == 1
+
+
+def test_submit_counts_the_pages_admission_takes(model_and_params):
+    """A 20-token worst case takes 5 pages and the reserve, which a pool
+    of 7 holds (the power-of-two bucket of the removed lane, 32 tokens,
+    would have asked for 9 and refused it); 28 tokens take 8 and are
+    still refused. ``submit`` and admission share one count."""
+    model, params = model_and_params
+    gen = GenerationConfig(max_new_tokens=3, do_sample=False,
+                           eos_token_id=-1, pad_token_id=0)
+    eng = ServingEngine(model, params, gen,
+                        ServingConfig(page_size=4, num_pages=8,
+                                      num_slots=1, max_model_len=64))
+    assert eng.cache.allocator.capacity == 7
+    assert eng.scheduler.admission_pages(20) == 6
+    rid = eng.submit(list(range(3, 20)), 3)         # 17 + 3 = 20 tokens
+    eng.step()
+    assert len(eng.result(rid).pages) == eng.scheduler.admission_pages(17)
+    _drain(eng)
+    assert len(eng.result(rid).generated) == 3
+    assert eng.scheduler.admission_pages(28) == 8
+    with pytest.raises(ValueError, match="can never be served"):
+        eng.submit(list(range(3, 28)), 3)           # 25 + 3 = 28 tokens
+    # the block table's width caps the count: a window-filling request
+    # starts without its reserve
+    assert eng.scheduler.admission_pages(64) == 16
+
+
 def test_serving_rejects_request_that_can_never_fit(model_and_params):
     model, params = model_and_params
     gen = GenerationConfig(max_new_tokens=4, do_sample=False,
@@ -376,8 +432,7 @@ def test_serving_greedy_logprobs_match_teacher_forced_rescore(
     prompts, _, gen = reference_tokens
     eng = ServingEngine(model, params, gen,
                         ServingConfig(page_size=4, num_pages=32,
-                                      num_slots=3, max_model_len=32,
-                                      max_prefill_batch=2))
+                                      num_slots=3, max_model_len=32))
     rids = [eng.submit(p, MAX_NEW) for p in prompts]
     results = _drain(eng)
     for i, rid in enumerate(rids):

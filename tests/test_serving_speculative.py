@@ -44,8 +44,7 @@ def _prompts(n=4, seed=3):
 def _run(model, params, gen, prompts, sampling=None, **cfg_kw):
     """Run prompts to completion on a fresh engine; returns the engine
     (for counter assertions) and the per-prompt Request results."""
-    kw = dict(page_size=4, num_pages=32, num_slots=2, max_model_len=32,
-              max_prefill_batch=2)
+    kw = dict(page_size=4, num_pages=32, num_slots=2, max_model_len=32)
     kw.update(cfg_kw)
     eng = ServingEngine(model, params, gen, ServingConfig(**kw))
     sampling = sampling or [None] * len(prompts)
@@ -146,7 +145,7 @@ def test_spec_eviction_recomputes_identically(model_and_params):
     model, params = model_and_params
     rs = np.random.RandomState(11)
     use = [list(rs.randint(3, 500, (4,))) for _ in range(2)]
-    gen = GenerationConfig(max_new_tokens=5, do_sample=False,
+    gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
                            eos_token_id=2, pad_token_id=0)
     fn = jax.jit(build_generate_fn(model, gen))
     ids = np.asarray(use, np.int32)
@@ -156,12 +155,15 @@ def test_spec_eviction_recomputes_identically(model_and_params):
     rmask = np.asarray(out["response_mask"])
     want = [[int(t) for t, m in zip(resp[i], rmask[i]) if m]
             for i in range(len(use))]
+    # capacity 9 pages, one admission a step: the first request has
+    # grown to its whole window (6 pages) when the second is admitted at
+    # 3, and the second's first round wants a fourth -> it is preempted
+    # mid-decode with one token out and recomputes behind the first
     eng = ServingEngine(model, params, gen,
-                        ServingConfig(page_size=2, num_pages=8,
+                        ServingConfig(page_size=2, num_pages=10,
                                       num_slots=2, max_model_len=12,
-                                      max_prefill_batch=2,
                                       speculative=SPEC))
-    rids = [eng.submit(p, 5) for p in use]
+    rids = [eng.submit(p, MAX_NEW) for p in use]
     results = eng.run_until_drained(max_steps=500)
     assert eng.metrics.preemptions.value >= 1, (
         "config was meant to force at least one preemption")
@@ -188,7 +190,7 @@ def test_spec_counters_monotone_across_supervisor_restart(
     def factory():
         eng = ServingEngine(model, params, gen, ServingConfig(
             page_size=4, num_pages=32, num_slots=2, max_model_len=32,
-            max_prefill_batch=2, speculative=SPEC,
+            speculative=SPEC,
             fault_plan="engine_step=3:device_error"))
         engines.append(eng)
         return eng

@@ -588,8 +588,7 @@ def test_live_metrics_endpoint_round_trips(serve_setup):
     from dla_tpu.serving import ServingConfig, ServingEngine
     model, params, gen = serve_setup
     eng = ServingEngine(model, params, gen, ServingConfig(
-        page_size=4, num_pages=32, num_slots=2, max_model_len=32,
-        max_prefill_batch=2))
+        page_size=4, num_pages=32, num_slots=2, max_model_len=32))
     try:
         rs = np.random.RandomState(5)
         for _ in range(3):

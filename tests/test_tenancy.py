@@ -80,7 +80,7 @@ def _cfg(n=N_TENANTS, tenancy_extra=None, **over):
     tenancy = {"adapter_pool": {"max_adapters": n, "max_rank": RANK}}
     tenancy.update(tenancy_extra or {})
     base = dict(page_size=4, num_pages=64, num_slots=4, max_model_len=32,
-                max_prefill_batch=2, prefill_chunk=CHUNK, tenancy=tenancy)
+                prefill_chunk=CHUNK, tenancy=tenancy)
     base.update(over)
     return ServingConfig(**base)
 
@@ -133,7 +133,7 @@ def test_eight_tenant_batched_parity_greedy_and_seeded(model_and_params,
     ref = ServingEngine(model, model.merge_lora(params, adapters[
         tenants[0]]), _gen(), ServingConfig(
             page_size=4, num_pages=64, num_slots=4, max_model_len=32,
-            max_prefill_batch=2, prefill_chunk=CHUNK))
+            prefill_chunk=CHUNK))
     for t in tenants:
         ref.publish_params(model.merge_lora(params, adapters[t]))
         rg = ref.submit(prompts[t], MAX_NEW)
@@ -179,7 +179,7 @@ def test_hot_swap_changes_output_without_recompile(model_and_params,
     merged = ServingEngine(model, model.merge_lora(
         params, adapters["tenant2"]), _gen(), ServingConfig(
             page_size=4, num_pages=64, num_slots=4, max_model_len=32,
-            max_prefill_batch=2, prefill_chunk=CHUNK))
+            prefill_chunk=CHUNK))
     rid = merged.submit(prompt, MAX_NEW)
     want = _drain(merged)[rid]
     assert out2[r2].generated == want.generated
@@ -253,7 +253,7 @@ def test_eviction_recompute_keeps_tenant_parity(model_and_params,
     ref = ServingEngine(model, model.merge_lora(params, adapters[
         tenants[0]]), _gen(max_new_tokens=new), ServingConfig(
             page_size=2, num_pages=32, num_slots=2, max_model_len=12,
-            max_prefill_batch=2, prefill_chunk=4))
+            prefill_chunk=4))
     for t in tenants:
         ref.publish_params(model.merge_lora(params, adapters[t]))
         rid = ref.submit(prompts[t], new)
@@ -445,16 +445,6 @@ def test_publish_params_routes_adapter_trees_to_publish_adapter(
         plain.submit([5, 6, 7], MAX_NEW, tenant="tenant0")
     with pytest.raises(ValueError, match="unknown tenant"):
         eng.submit([5, 6, 7], MAX_NEW, tenant="never-published")
-
-
-def test_tenancy_requires_chunked_prefill(model_and_params):
-    model, params = model_and_params
-    with pytest.raises(ValueError, match="prefill_chunk"):
-        ServingEngine(model, params, _gen(), ServingConfig(
-            page_size=4, num_pages=64, num_slots=2, max_model_len=32,
-            prefill_chunk=0,
-            tenancy={"adapter_pool": {"max_adapters": 2,
-                                      "max_rank": RANK}}))
 
 
 # ---------------------------------------------------------------------------

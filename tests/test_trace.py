@@ -690,7 +690,6 @@ def test_serving_request_span_tree_matches_recorded_latencies(tmp_path):
     trace_path = tmp_path / "serve_trace.json"
     eng = ServingEngine(model, params, gen, ServingConfig(
         page_size=4, num_pages=32, num_slots=2, max_model_len=32,
-        max_prefill_batch=2,
         trace={"enabled": True, "path": str(trace_path)}))
     try:
         assert eng.tracer.enabled and get_tracer() is eng.tracer
@@ -757,7 +756,7 @@ def test_serving_timeout_and_drain_close_their_span_trees():
                            eos_token_id=2, pad_token_id=0)
     eng = ServingEngine(model, params, gen, ServingConfig(
         page_size=4, num_pages=32, num_slots=2, max_model_len=32,
-        max_prefill_batch=2, trace={"enabled": True}))
+        trace={"enabled": True}))
     try:
         rid = eng.submit([5, 6, 7], 5)
         eng.begin_drain()          # queued, no tokens -> cancelled

@@ -295,7 +295,7 @@ def test_fleet_reassign_span_children_of_original_dispatch():
     fleet = SamplerFleet(
         model, params, gen,
         ServingConfig(page_size=4, num_pages=64, num_slots=3,
-                      max_model_len=32, max_prefill_batch=2,
+                      max_model_len=32,
                       fault_plan="sampler=1:rollout_step=0:lost"),
         SamplerFleetConfig(samplers=2, lease_ttl_s=0.3))
     try:
