@@ -492,12 +492,19 @@ class PagedKVCache:
              cache and replays.
 
     Host mirror (authoritative, numpy — the scheduler mutates it and the
-    engine ships it to device per step; decode-step updates are
-    deterministic (+1 length, one valid column) so the host applies them
-    itself rather than fetching arrays back):
+    engine packs it into each dispatch's one argument array,
+    serving/step_args.py; decode-step updates are deterministic (+1
+    length, one valid column) so the host applies them itself rather than
+    fetching arrays back):
       block_tables  [num_slots, pages_per_slot] int32 physical page ids
-      valid         [num_slots, S] attendable columns
-      pos           [num_slots, S] logical position per column
+      valid         [num_slots, S] attendable columns: host bookkeeping,
+                    never sent. Every writer below keeps a slot's row a
+                    PREFIX (of length ``lengths`` while it decodes, of
+                    the computed columns while it prefills) and a
+                    column's logical position is its index, so the step
+                    programs compute the mask and the positions from
+                    ``lengths`` / the chunk's start
+                    (tests/test_step_args.py holds the writers to it)
       lengths       [num_slots]    true tokens so far
       tokens        [num_slots]    last sampled token (next step's input)
     """
@@ -514,7 +521,6 @@ class PagedKVCache:
         self.block_tables = np.zeros(
             (geom.num_slots, geom.pages_per_slot), np.int32)
         self.valid = np.zeros((geom.num_slots, s), bool)
-        self.pos = np.zeros((geom.num_slots, s), np.int32)
         self.lengths = np.zeros((geom.num_slots,), np.int32)
         self.tokens = np.zeros((geom.num_slots,), np.int32)
         self.allocator = PageAllocator(geom.num_pages)
@@ -532,7 +538,6 @@ class PagedKVCache:
         self.block_tables[slot, :len(pages)] = pages
         self.valid[slot] = False
         self.valid[slot, :cached_len] = True
-        self.pos[slot] = np.arange(self.geom.slot_window)
         self.lengths[slot] = 0
         self.tokens[slot] = 0
 
@@ -554,7 +559,6 @@ class PagedKVCache:
         scheduler, which owns the request -> pages mapping)."""
         self.block_tables[slot] = 0
         self.valid[slot] = False
-        self.pos[slot] = 0
         self.lengths[slot] = 0
         self.tokens[slot] = 0
 
@@ -572,7 +576,6 @@ class PagedKVCache:
         that the cursor simply never moved)."""
         col = int(self.lengths[slot])
         self.valid[slot, col] = True
-        self.pos[slot, col] = col
         self.lengths[slot] = col + 1
         self.tokens[slot] = token
 

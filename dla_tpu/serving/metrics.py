@@ -49,6 +49,10 @@ class ServingMetrics:
         # whose sampler filtered and drew instead of taking the arg-max
         self.decode_steps_sampled = r.counter(
             "serving/decode_steps_sampled")
+        # what the decode and chunk dispatches sent to the device: one
+        # packed array each (serving/step_args.py), and its bytes
+        self.step_arg_puts = r.counter("serving/step_arg_puts")
+        self.step_arg_bytes = r.counter("serving/step_arg_bytes")
         # dropless routing, summed over layers and decode steps: held
         # experts that received a token, (token, choice) pairs that landed
         # on a held expert (0 for a model without routed experts)
@@ -113,6 +117,8 @@ class ServingMetrics:
             "serving/decode_steps": float(self.decode_steps.value),
             "serving/decode_steps_sampled": float(
                 self.decode_steps_sampled.value),
+            "serving/step_arg_puts": float(self.step_arg_puts.value),
+            "serving/step_arg_bytes": float(self.step_arg_bytes.value),
             "serving/moe/experts_hit": float(self.moe_experts_hit.value),
             "serving/moe/expert_assignments": float(
                 self.moe_expert_assignments.value),

@@ -12,11 +12,13 @@ Execution model — the three invariants everything else hangs off:
    ``prefill_chunk`` tokens, one request and one chunk per engine step
    beside the decode batch, so prefill compiles once per engine
    lifetime too, whatever the prompt lengths.
-3. **Host-mirrored metadata.** Slot metadata (block tables, valid, pos,
-   lengths, last tokens) is authoritative on the host as numpy; the
-   jitted steps receive it as inputs and the host re-applies the
-   deterministic updates itself instead of fetching arrays back. Only
-   sampled tokens and prefill logits cross device->host per step.
+3. **Host-mirrored metadata.** Slot metadata (block tables, valid,
+   lengths, last tokens) is authoritative on the host as numpy; each
+   dispatch receives it packed into ONE int32 array, put once
+   (serving/step_args.py; the window mask and the positions are derived
+   in the program), and the host re-applies the deterministic updates
+   itself instead of fetching arrays back. Only sampled tokens and
+   prefill logits cross device->host per step.
 
 Backpressure: admission needs every prompt page plus a decode reserve up
 front; mid-decode page exhaustion preempts the youngest request (freed
@@ -63,6 +65,7 @@ from dla_tpu.serving.scheduler import (
     Scheduler,
     SchedulerConfig,
 )
+from dla_tpu.serving.step_args import PackedArgs
 from dla_tpu.serving.tenancy import (
     AdapterStore,
     TenancyConfig,
@@ -313,6 +316,23 @@ class ServingEngine:
         # sampling state): row 0 is the all-zeros base identity, so free
         # slots and base-model requests gather an exact +0.0 delta
         self.adapter_idx = np.zeros((ns,), np.int32)
+        # what a dispatch sends: ONE int32 array (serving/step_args.py).
+        # A decode step's is [num_slots, pages/slot + 8 or 9], a chunk's
+        # [pages/slot + chunk + 2 or 3]; the adapter pool row rides only
+        # with tenancy on. The window mask and positions are not in it:
+        # the programs derive them from ``lengths`` / ``start``.
+        tenancy = ((("adapter", 1, np.int32),) if ten_cfg is not None
+                   else ())
+        self._decode_layout = PackedArgs(
+            ("block_tables", geom.pages_per_slot, np.int32),
+            ("lengths", 1, np.int32), ("tokens", 1, np.int32),
+            ("active", 1, np.bool_), ("top_k", 1, np.int32),
+            ("seed", 1, np.uint32), ("gen_pos", 1, np.int32),
+            ("temp", 1, np.float32), ("top_p", 1, np.float32), *tenancy)
+        self._chunk_layout = PackedArgs(
+            ("block_tables", geom.pages_per_slot, np.int32),
+            ("ids", cfg.prefill_chunk, np.int32),
+            ("start", 1, np.int32), ("nvalid", 1, np.int32), *tenancy)
         self._draining = False
         self._old_handlers: Optional[dict] = None
         # engine-step counter drives the profiling window (the serving
@@ -471,46 +491,57 @@ class ServingEngine:
                 int(event.get("step") or self.engine_steps), event["fn"],
                 attributed=bool(event.get("attributed")))
 
-    @staticmethod
-    def _dev(x: np.ndarray) -> jnp.ndarray:
-        """Device-put host scheduler metadata BY VALUE.
+    def _put_step_args(self, packed: np.ndarray) -> jnp.ndarray:
+        """The one host-to-device put of a dispatch: ``packed`` is the
+        step's host state as ``PackedArgs.pack`` laid it out.
 
         jnp.asarray on suitably-aligned host numpy memory may alias it
-        zero-copy, and the engine mutates these arrays in place (e.g.
-        mark_computed flips `valid` bits right after a chunk dispatch)
-        while the async computation may not have executed yet — an
-        aliased buffer makes the jitted step read torn state. Copying
-        first pins the dispatched values.
-        """
-        # dla: disable=host-sync-in-hot-loop -- host->host copy of tiny scheduler metadata (no device fetch); the copy is the race fix
-        return jnp.asarray(np.array(x))
+        zero-copy, and the engine mutates its mirrors in place right
+        after a dispatch (mark_computed flips `valid` bits, advance_slot
+        moves `lengths`) while the async computation may not have
+        executed yet — an aliased mirror makes the jitted step read torn
+        state. ``pack`` fills a NEW array at every dispatch and nothing
+        writes it afterwards, which pins the dispatched values without a
+        second copy."""
+        self.metrics.step_arg_puts.inc()
+        self.metrics.step_arg_bytes.inc(packed.nbytes)
+        return jnp.asarray(packed)
 
     # -------------------------------------------------------- jitted steps
 
-    def _prefill_chunk_fn(self, params, pools, btab, valid,
-                          pos, ids, start, nvalid, adapters=None):
+    def _prefill_chunk_fn(self, params, pools, packed, adapters=None):
         """One FIXED-SHAPE prefill chunk for a single slot: the model
         gathers the slot's pages (the already-computed prefix — cached
-        hit pages and earlier chunks — with ``valid`` marking exactly the
-        columns before this chunk), runs the chunk forward and writes its
-        C fresh rows into the pool at the (page, offset) computed here.
-        ``btab`` [1, pages/slot]; ``valid``/
-        ``pos`` [1, S]; ``ids`` [1, C]; ``start``/``nvalid`` traced
-        scalars (chunk's absolute start column / real-token count), so
-        every chunk of every request reuses ONE compile. Returns
-        (pools, logits [1, V]) — logits are the next-token
-        distribution after the chunk's last real token, meaningful only
-        on a request's final chunk (the only one whose logits the host
-        fetches)."""
+        hit pages and earlier chunks), runs the chunk forward and writes
+        its C fresh rows into the pool at the (page, offset) computed
+        here. ``packed`` is the dispatch's one host array
+        (``_chunk_layout``): the slot's block-table row, the chunk's
+        ``ids`` [C], and ``start`` / ``nvalid`` (chunk's absolute start
+        column / real-token count), all traced, so every chunk of every
+        request reuses ONE compile. The columns the chunk may attend are
+        exactly those before it — ``PagedKVCache`` keeps a prefilling
+        slot's ``valid`` a prefix of length ``start`` — so the mask and
+        the positions are computed here, not sent. ``adapters`` are the
+        stacked pools alone (device arrays already); the slot's pool row
+        rides ``packed``. Returns (pools, logits [1, V]) — logits are the
+        next-token distribution after the chunk's last real token,
+        meaningful only on a request's final chunk (the only one whose
+        logits the host fetches)."""
         self.prefill_chunk_compiles += 1  # dla: disable=trace-side-effect -- deliberate trace-time compile counter, pinned by the serving compile-once tests
         ps = self.cache.geom.page_size
         c = self.cfg.prefill_chunk
+        f = self._chunk_layout.unpack(packed)
+        btab, start, nvalid = f["block_tables"][None], f["start"], f["nvalid"]
+        if adapters is not None:
+            adapters = {"idx": f["adapter"][None], **adapters}
+        window = jnp.arange(self.cache.geom.slot_window, dtype=jnp.int32)
         real = jnp.arange(c) < nvalid
         # the chunk's columns land at their physical (page, offset); pad
         # columns (index >= nvalid) route to the trash page
         cols = start + jnp.arange(c, dtype=jnp.int32)
-        view = {"pools": pools, "block_tables": btab, "valid": valid,
-                "pos": pos, "real": real[None, :],
+        view = {"pools": pools, "block_tables": btab,
+                "valid": (window < start)[None], "pos": window[None],
+                "real": real[None, :],
                 "write_pages": jnp.where(real, btab[0, cols // ps], 0)[None],
                 "write_offs": jnp.where(real, cols % ps, 0)[None]}
         # absolute chunk schedule: positions are fixed by `start`, so a
@@ -518,7 +549,8 @@ class ServingEngine:
         positions = cols[None, :]
         last_index = jnp.maximum(nvalid - 1, 0)[None]
         logits, pools, _ = self.model.prefill_step_paged(
-            params, view, ids, positions, last_index, adapters=adapters)
+            params, view, f["ids"][None], positions, last_index,
+            adapters=adapters)
         return pools, logits
 
     def _export_kv_fn(self, pools, page_ids):
@@ -545,15 +577,33 @@ class ServingEngine:
         return tuple(p.at[:, page_ids].set(x)
                      for p, x in zip(pools, payloads))
 
-    def _decode_fn(self, params, pools, block_tables, valid,
-                   pos, lengths, tokens, active, temps, top_ps, top_ks,
-                   seeds, gen_pos, adapters=None):
+    def _unpack_decode(self, packed, adapters):
+        """What the three decode programs do first: the fields of the
+        dispatch's one host array (``_decode_layout``), the window mask
+        and positions the host does not send, and the adapters argument
+        with its per-slot rows. ``PagedKVCache`` keeps a running slot's
+        ``valid`` mirror the prefix of length ``lengths`` and every
+        column's position its index, so ``valid`` and ``pos`` are a
+        compare on an iota here; a slot that is not running (free, or
+        mid-prefill with ``lengths`` 0) attends nothing and its row is
+        masked by ``active`` as before."""
+        f = self._decode_layout.unpack(packed)
+        window = jnp.arange(self.cache.geom.slot_window,
+                            dtype=jnp.int32)[None, :]
+        f["valid"] = window < f["lengths"][:, None]
+        f["pos"] = jnp.broadcast_to(window, f["valid"].shape)
+        if adapters is not None:
+            adapters = {"idx": f["adapter"], **adapters}
+        return f, adapters
+
+    def _decode_fn(self, params, pools, packed, adapters=None):
         """One static-shape decode step over every slot: the model's
         paged step gathers each slot's pages into its [S] window and
         writes the fresh row at the (page, offset) computed here; the
         result is sampled PER-ROW (each slot's traced temperature/top_p/
         top_k/seed, keyed by the slot's generated-token index). Free
-        slots compute garbage routed to the trash page. Returns the
+        slots compute garbage routed to the trash page. ``packed`` is the
+        step's host state in one array (``_unpack_decode``). Returns the
         fresh pools plus a packed [4, B] int32
         array — row 0 the sampled tokens, row 1 their chosen-token
         logprobs bitcast to int32, rows 2 and 3 the step's expert
@@ -567,22 +617,25 @@ class ServingEngine:
         geom = self.cache.geom
         ps = geom.page_size
         b = geom.num_slots
+        f, adapters = self._unpack_decode(packed, adapters)
+        block_tables, lengths, active = (
+            f["block_tables"], f["lengths"], f["active"])
         # this step's row: physical (page, offset) of each slot's write
         # column; inactive slots write the trash page
         page_ids = jnp.take_along_axis(
             block_tables, (lengths // ps)[:, None], axis=1)[:, 0]
         view = {"pools": pools, "block_tables": block_tables,
-                "valid": valid, "pos": pos, "lengths": lengths,
+                "valid": f["valid"], "pos": f["pos"], "lengths": lengths,
                 "real": active[:, None],
                 "write_pages": jnp.where(active, page_ids, 0)[:, None],
                 "write_offs": jnp.where(active, lengths % ps, 0)[:, None]}
         logits, pools, routed = self.model.decode_step_paged(
-            params, view, tokens, adapters=adapters)
+            params, view, f["tokens"], adapters=adapters)
         # a free slot keeps its last request's temperature: zeroed, so
         # only running rows decide whether the step filters and draws
         new_tok, logp = sample_token_per_row(
-            seeds, gen_pos, logits, jnp.where(active, temps, 0.0),
-            top_ps, top_ks)
+            f["seed"], f["gen_pos"], logits,
+            jnp.where(active, f["temp"], 0.0), f["top_p"], f["top_k"])
         new_tok = jnp.where(active, new_tok, 0)
         logp = jnp.where(active, logp, 0.0)
         packed = jnp.stack(
@@ -591,15 +644,14 @@ class ServingEngine:
              jnp.broadcast_to(routed[1], (b,))])
         return pools, packed
 
-    def _spec_draft_fn(self, draft_params, pools, block_tables,
-                       valid, pos, lengths, tokens, active, temps,
-                       top_ps, top_ks, seeds, gen_pos, adapters=None):
+    def _spec_draft_fn(self, draft_params, pools, packed, adapters=None):
         """The speculative DRAFT phase: K sequential fixed-shape decode
         steps with the draft tree over the shared paged pool. Step i
         feeds the previous proposal (the pending token at i=0), writes
         its KV column at ``lengths + i``, marks it valid in the TRACED
-        metadata copy only (the host mirrors are authoritative and never
-        see draft columns — that asymmetry is the free rollback), and
+        mask only (a column's position is its index, so ``pos`` needs no
+        update; the host mirrors are authoritative and never see draft
+        columns — that asymmetry is the free rollback), and
         samples proposal d_{i+1} on the request's own seeded stream at
         generated-token index ``gen_pos + i`` — so a perfect draft
         proposes exactly the tokens the target will sample, and the
@@ -612,11 +664,14 @@ class ServingEngine:
         geom = self.cache.geom
         ps = geom.page_size
         sw = geom.slot_window
+        f, adapters = self._unpack_decode(packed, adapters)
+        block_tables, lengths, active = (
+            f["block_tables"], f["lengths"], f["active"])
         col_ids = jnp.arange(sw, dtype=jnp.int32)[None, :]
-        temps = jnp.where(active, temps, 0.0)   # as in _decode_fn
+        temps = jnp.where(active, f["temp"], 0.0)   # as in _decode_fn
 
         def draft_step(carry, i):
-            cur, valid_c, pos_c, pools_c = carry
+            cur, valid_c, pools_c = carry
             col = lens_i = lengths + i
             in_win = (col < sw) & active
             page_ids = jnp.take_along_axis(
@@ -624,32 +679,32 @@ class ServingEngine:
                 jnp.minimum(col // ps, geom.pages_per_slot - 1)[:, None],
                 axis=1)[:, 0]
             view = {"pools": pools_c, "block_tables": block_tables,
-                    "valid": valid_c, "pos": pos_c, "lengths": lens_i,
+                    "valid": valid_c, "pos": f["pos"], "lengths": lens_i,
                     "write_pages": jnp.where(in_win, page_ids, 0)[:, None],
                     "write_offs": jnp.where(in_win, col % ps, 0)[:, None]}
             logits, pools_c, _ = self.model.decode_step_paged(
                 draft_params, view, cur, adapters=adapters)
             nxt, _ = sample_token_per_row(
-                seeds, gen_pos + i, logits, temps, top_ps, top_ks)
+                f["seed"], f["gen_pos"] + i, logits, temps, f["top_p"],
+                f["top_k"])
             nxt = jnp.where(active, nxt, 0)
-            written = (col_ids == col[:, None]) & in_win[:, None]
-            valid_c = valid_c | written
-            pos_c = jnp.where(written, col[:, None], pos_c)
-            return (nxt, valid_c, pos_c, pools_c), nxt
+            valid_c = valid_c | (
+                (col_ids == col[:, None]) & in_win[:, None])
+            return (nxt, valid_c, pools_c), nxt
 
-        (_, _, _, pools), props = jax.lax.scan(
-            draft_step, (tokens, valid, pos, pools),
+        (_, _, pools), props = jax.lax.scan(
+            draft_step, (f["tokens"], f["valid"], pools),
             jnp.arange(self._spec_k, dtype=jnp.int32))
         return pools, jnp.moveaxis(props, 0, 1)
 
-    def _spec_verify_fn(self, params, pools, block_tables,
-                        valid, pos, lengths, tokens, proposals, active,
-                        temps, top_ps, top_ks, seeds, gen_pos,
+    def _spec_verify_fn(self, params, pools, packed, proposals,
                         adapters=None):
         """The speculative VERIFY phase: one multi-token target forward
         over the block [pending, d_1 .. d_K] at columns
-        ``lengths .. lengths + K``. ``valid`` is the COMMITTED-ONLY host
-        mirror — the draft's columns must not be valid here, or the
+        ``lengths .. lengths + K``. ``valid`` is COMMITTED-ONLY, the
+        columns below ``lengths`` as the host has them (``_unpack_decode``
+        on the same ``packed`` the draft took) — the draft's columns must
+        not be valid here, or the
         block attention would double-count keys its in-block causal term
         already supplies. The target then samples its OWN next token at
         every block position on the request's fold_in(seed, gen_pos + i)
@@ -671,21 +726,25 @@ class ServingEngine:
         b = geom.num_slots
         sw = geom.slot_window
         g = self._spec_k + 1
+        f, adapters = self._unpack_decode(packed, adapters)
+        block_tables, lengths, active = (
+            f["block_tables"], f["lengths"], f["active"])
         cols = lengths[:, None] + jnp.arange(g, dtype=jnp.int32)[None, :]
         in_win = (cols < sw) & active[:, None]
         page_ids = jnp.take_along_axis(
             block_tables,
             jnp.minimum(cols // ps, geom.pages_per_slot - 1), axis=1)
         view = {"pools": pools, "block_tables": block_tables,
-                "valid": valid, "pos": pos, "lengths": lengths,
+                "valid": f["valid"], "pos": f["pos"], "lengths": lengths,
                 "write_pages": jnp.where(in_win, page_ids, 0),
                 "write_offs": jnp.where(in_win, cols % ps, 0)}
-        block = jnp.concatenate([tokens[:, None], proposals], axis=1)
+        block = jnp.concatenate([f["tokens"][:, None], proposals], axis=1)
         logits, pools, _ = self.model.decode_block_paged(
             params, view, block, adapters=adapters)
         toks, logps = sample_token_block(
-            seeds, gen_pos, logits, jnp.where(active, temps, 0.0),
-            top_ps, top_ks)                     # as in _decode_fn
+            f["seed"], f["gen_pos"], logits,
+            jnp.where(active, f["temp"], 0.0),   # as in _decode_fn
+            f["top_p"], f["top_k"])
         toks = jnp.where(active[:, None], toks, 0)
         logps = jnp.where(active[:, None], logps, 0.0)
         accept = toks[:, :self._spec_k] == proposals
@@ -1036,7 +1095,7 @@ class ServingEngine:
         ids = np.zeros((geom.pages_per_slot,), np.int32)
         ids[:needed] = req.pages[:needed]
         with annotate("serve_kv_export", rid=req.rid):
-            payloads = self._export_kv(self.cache.pools, self._dev(ids))
+            payloads = self._export_kv(self.cache.pools, jnp.asarray(ids))
         return MigrationTicket(
             rid=req.rid,
             prompt_tokens=list(req.prompt_tokens),
@@ -1132,7 +1191,8 @@ class ServingEngine:
         ids[:needed] = pages[:needed]
         with annotate("serve_kv_import", rid=ticket.rid):
             self.cache.pools = self._import_kv(
-                self.cache.pools, tuple(ticket.payloads), self._dev(ids))
+                self.cache.pools, tuple(ticket.payloads),
+                jnp.asarray(ids))
         req = Request(prompt_tokens=list(ticket.prompt_tokens),
                       max_new_tokens=int(ticket.max_new_tokens),
                       arrival_time=ticket.arrival_time,
@@ -1555,17 +1615,15 @@ class ServingEngine:
         if tenant is not None:
             self.adapter_store.release(tenant)
 
-    def _adapters_args(self, rows=None):
-        """The gathered-adapter argument for one jitted dispatch: the
-        per-slot pool rows (every slot, or ``rows`` for a single-slot
-        prefill chunk) plus the stacked A/B pools. None when tenancy is
-        off — an empty pytree, so the dispatch signature and jit
-        fingerprint are byte-identical to an adapter-free build."""
+    def _adapters_args(self):
+        """The adapter argument of a jitted dispatch: the stacked A/B
+        pools, device arrays already (each slot's pool row rides the
+        dispatch's packed array). None when tenancy is off — an empty
+        pytree, so the dispatch signature and jit fingerprint are
+        byte-identical to an adapter-free build."""
         if self.adapter_store is None:
             return None
-        idx = (self.adapter_idx if rows is None
-               else self.adapter_idx[rows])
-        return {"idx": self._dev(idx), **self.adapter_store.pools}
+        return self.adapter_store.pools
 
     def _mirror_adapter_counters(self) -> None:
         """Delta-mirror the AdapterStore's plain-int counters into the
@@ -1643,21 +1701,20 @@ class ServingEngine:
         n = len(prefix)
         start = req.prefill_pos
         nvalid = min(self.cfg.prefill_chunk, n - start)
-        ids = np.zeros((1, self.cfg.prefill_chunk), np.int32)
-        ids[0, :nvalid] = prefix[start:start + nvalid]
+        ids = np.zeros((self.cfg.prefill_chunk,), np.int32)
+        ids[:nvalid] = prefix[start:start + nvalid]
         c = self.cache
         with annotate("serve_prefill_chunk", rid=req.rid, slot=slot,
                       start=start, nvalid=nvalid,
-                      last=int(start + nvalid >= n)):
+                      last=int(start + nvalid >= n), puts=1,
+                      h2d_bytes=self._chunk_layout.nbytes()):
+            packed = self._chunk_layout.pack(
+                block_tables=c.block_tables[slot], ids=ids,
+                start=np.int32(start), nvalid=np.int32(nvalid),
+                adapter=self.adapter_idx[slot])
             c.pools, logits = self._prefill_chunk(
-                self.params, c.pools,
-                self._dev(c.block_tables[slot:slot + 1]),
-                self._dev(c.valid[slot:slot + 1]),
-                self._dev(c.pos[slot:slot + 1]),
-                jnp.asarray(ids),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(nvalid, jnp.int32),
-                self._adapters_args(slice(slot, slot + 1)))
+                self.params, c.pools, self._put_step_args(packed),
+                self._adapters_args())
         self.metrics.prefill_chunks.inc()
         c.mark_computed(slot, start, nvalid)
         req.prefill_pos = start + nvalid
@@ -1759,10 +1816,15 @@ class ServingEngine:
         return int(np.count_nonzero(self.samp_temp[active_slots] > 0.0))
 
     def _decode_args(self, active_slots: List[int]) -> tuple:
-        """Everything a decode dispatch takes after the pool, built and
-        put on the device inside ``serve_decode_args``."""
+        """Everything a decode dispatch takes after the pool: the step's
+        host state packed into one array (``_decode_layout``), built and
+        put on the device inside ``serve_decode_args``, and the adapter
+        pools. The span says what went up: ``puts`` 1, ``h2d_bytes`` the
+        array's."""
         c = self.cache
-        with annotate("serve_decode_args"):
+        with annotate("serve_decode_args", puts=1,
+                      h2d_bytes=self._decode_layout.nbytes(
+                          c.geom.num_slots)):
             active = np.zeros((c.geom.num_slots,), bool)
             active[active_slots] = True
             for slot in active_slots:
@@ -1773,12 +1835,13 @@ class ServingEngine:
                 # gen_pos + i in-graph)
                 self.gen_pos[slot] = len(
                     self.scheduler.running[slot].generated)
-            return (self._dev(c.block_tables), self._dev(c.valid),
-                    self._dev(c.pos), self._dev(c.lengths),
-                    self._dev(c.tokens), jnp.asarray(active),
-                    self._dev(self.samp_temp), self._dev(self.samp_top_p),
-                    self._dev(self.samp_top_k), self._dev(self.samp_seed),
-                    self._dev(self.gen_pos), self._adapters_args())
+            packed = self._decode_layout.pack(
+                c.geom.num_slots, block_tables=c.block_tables,
+                lengths=c.lengths, tokens=c.tokens, active=active,
+                top_k=self.samp_top_k, seed=self.samp_seed,
+                gen_pos=self.gen_pos, temp=self.samp_temp,
+                top_p=self.samp_top_p, adapter=self.adapter_idx)
+            return self._put_step_args(packed), self._adapters_args()
 
     def _decode_step(self) -> List[Tuple[int, int]]:
         c = self.cache
@@ -1838,17 +1901,16 @@ class ServingEngine:
         prefix — rejected draft columns exist solely in device pages
         that the next round's verify overwrites, so rollback is a no-op
         and an eviction/replay re-prefill never sees speculative
-        residue. Both dispatches read the same host-metadata snapshot;
-        the draft extends its own traced copy of ``valid``/``pos`` while
-        the verify attends committed-only (draft keys arrive via the
-        in-block causal term instead)."""
+        residue. Both dispatches read the same host-metadata snapshot
+        (one packed array, put once); the draft extends its own traced
+        copy of ``valid`` while the verify attends committed-only (draft
+        keys arrive via the in-block causal term instead)."""
         c = self.cache
         k = self._spec_k
         active_slots = sorted(self.scheduler.running)
         sampling_slots = self._sampling_slots(active_slots)
         with self._decode_span(active_slots, sampling_slots):
-            (btab, valid, pos, lengths, tokens, active_d, temps, top_ps,
-             top_ks, seeds, gpos, adapters) = self._decode_args(active_slots)
+            packed_args, adapters = self._decode_args(active_slots)
             if self._fault_device_error:
                 # injected BEFORE dispatch: no KV column written, no token
                 # sampled — the state a real dispatch failure leaves behind
@@ -1860,13 +1922,9 @@ class ServingEngine:
                 # proposes under the SAME per-slot deltas the target
                 # verifies with, so per-tenant acceptance stays high
                 c.pools, proposals = self._spec_draft(
-                    self.draft_params, c.pools, btab, valid,
-                    pos, lengths, tokens, active_d, temps, top_ps, top_ks,
-                    seeds, gpos, adapters)
+                    self.draft_params, c.pools, packed_args, adapters)
                 c.pools, packed = self._spec_verify(
-                    self.params, c.pools, btab, valid, pos,
-                    lengths, tokens, proposals, active_d, temps, top_ps,
-                    top_ks, seeds, gpos, adapters)
+                    self.params, c.pools, packed_args, proposals, adapters)
             with annotate("serve_decode_fetch"):
                 # dla: disable=host-sync-in-hot-loop -- the designed single D2H per speculative round (proposals never leave the device)
                 packed_np = np.asarray(packed)

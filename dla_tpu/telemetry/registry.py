@@ -236,6 +236,11 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/decode_steps_sampled", "counter", "steps",
        "decode steps with a running slot of temperature > 0 (the "
        "sampler's filter-and-draw branch)", "step"),
+    _s("serving/step_arg_puts", "counter", "puts",
+       "host-to-device puts of per-step arguments by the decode and "
+       "prefill-chunk dispatches (one packed array each)", "step"),
+    _s("serving/step_arg_bytes", "counter", "bytes",
+       "bytes those puts sent", "step"),
     _s("serving/moe/experts_hit", "counter", "experts",
        "held experts that received a token, summed over layers and "
        "decode steps (dropless routing)", "step"),

@@ -141,12 +141,14 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "serve_schedule": ("scheduler", ()),
     "serve_admit": ("scheduler", ("queued",)),
     "serve_prefill_chunk": ("engine host loop",
-                            ("rid", "slot", "start", "nvalid", "last")),
+                            ("rid", "slot", "start", "nvalid", "last",
+                             "puts", "h2d_bytes")),
     "serve_chunk_fetch": ("engine host loop", ("rid",)),
     "serve_first_token": ("engine host loop", ("rid",)),
     "serve_decode": ("KV pool", ("slots", "live_tokens", "read_tokens",
                                  "sampling_slots")),
-    "serve_decode_args": ("engine host loop", ()),
+    # ``puts`` / ``h2d_bytes``: what the dispatch sent to the device
+    "serve_decode_args": ("engine host loop", ("puts", "h2d_bytes")),
     "serve_decode_dispatch": ("engine host loop", ()),
     "serve_decode_fetch": ("engine host loop", ()),
     # mark after the fetch, models with routed experts only: what the

@@ -185,6 +185,17 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
         assert 1 <= phase[1]["slots"] <= geom.num_slots
         assert phase[1]["sampling_slots"] == 0      # greedy requests
     assert eng.metrics.decode_steps_sampled.value == 0
+    # what each dispatch sent to the device: one packed array, its bytes
+    # fixed by the geometry (a speculative round's two programs share it)
+    sent = {"serve_decode_args": eng._decode_layout.nbytes(geom.num_slots),
+            "serve_prefill_chunk": eng._chunk_layout.nbytes()}
+    rows = [r for r in rec.rows if r[0] in sent]
+    assert all(r[1]["puts"] == 1 and r[1]["h2d_bytes"] == sent[r[0]]
+               for r in rows)
+    snap = eng.metrics.snapshot()
+    assert snap["serving/step_arg_puts"] == len(rows)
+    assert snap["serving/step_arg_bytes"] == sum(
+        r[1]["h2d_bytes"] for r in rows)
     if kind == "experts":
         cfg = model.cfg
         routes = [r[1] for r in rec.named("serve_moe_route")]
@@ -344,6 +355,11 @@ def test_real_trace_carries_arguments_and_host_ns(model_and_params, trained,
     assert len(by_name["serve"]) == 3 and len(by_name["train"]) == 2
     chunk = dict(by_name["serve_prefill_chunk"][0].stats)
     assert chunk["rid"] == rid and chunk["nvalid"] >= 1
+    assert chunk["puts"] == 1
+    assert chunk["h2d_bytes"] == eng._chunk_layout.nbytes()
+    sent = dict(by_name["serve_decode_args"][0].stats)
+    assert sent["puts"] == 1 and sent["h2d_bytes"] == (
+        eng._decode_layout.nbytes(eng.cache.geom.num_slots))
     # host_ns is perf_counter_ns at the span's start: the pairs
     # (host_ns, start_ns) of two spans differ by the same offset, which
     # is what lays a perf_counter reading over the xplane's clock
