@@ -321,6 +321,7 @@ class GenerationEngine:
     tokenizes/detokenizes at the host boundary."""
 
     def __init__(self, model: Transformer, tokenizer, gen: GenerationConfig):
+        model.refuse_contiguous_cache()
         self.model = model
         self.tokenizer = tokenizer
         self.gen = dataclasses.replace(

@@ -8,7 +8,64 @@ so the same transformer code serves Llama-2 7B/13B/70B, Mistral-7B, phi-2
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+
+class LayerSpec(NamedTuple):
+    """What one layer is, as the model code and the cache manager read
+    it. ``mixer`` is the token mixer in front of the block's MLP:
+
+      attention            multi-head / grouped-query attention over the
+                           layer's own keys and values
+      latent_attention     multi-head latent attention (one latent row)
+      ssm                  selective state-space layer (Mamba-1); its
+                           scan output is also the memory the next
+                           ``gmu`` layers gate
+      diff_attention       differential attention (arXiv:2410.05258)
+                           over the layer's own keys and values
+      gmu                  gated memory unit (arXiv:2507.06607): gates the
+                           memory of the nearest ``ssm`` layer below
+      cross_diff_attention differential attention whose keys and values
+                           are those of the nearest ``paged``
+                           ``diff_attention`` layer below
+
+    ``cache`` is what the layer keeps for a sequence between steps:
+
+      paged         rows a token, for as long as the request lives
+      paged_window  rows a token, dropped once behind every future
+                    query's ``window``
+      state         a fixed-size recurrent state a sequence
+      shared        nothing of its own: it reads another layer's pages
+      none          nothing
+
+    ``window``: the layer attends keys in (pos - window, pos]; None =
+    every earlier key."""
+    mixer: str
+    cache: str
+    window: Optional[int] = None
+
+
+class CacheArray(NamedTuple):
+    """One device array a cache manager holds for a model
+    (``Transformer.cache_spec()``). By ``kind``:
+
+      paged         [layers, pages, page_size, *shape]: rows a token,
+                    addressed through a slot's block table
+      paged_window  the same over a pool of its own, whose pages go back
+                    to the allocator once behind ``window``
+      state         [layers, slots, *shape]: indexed by slot, not paged
+
+    """
+    kind: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Any
+    window: Optional[int] = None
+
+
+MIXERS = ("attention", "latent_attention", "ssm", "diff_attention", "gmu",
+          "cross_diff_attention")
+CACHE_KINDS = ("paged", "paged_window", "state", "shared", "none")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,8 +242,35 @@ class ModelConfig:
     lora_alpha: float = 32.0
     lora_dropout: float = 0.0
     lora_targets: tuple = ("wq", "wk", "wv", "wo")
+    # Per-layer spec, one (mixer, cache, window) entry a layer (see
+    # LayerSpec), for a model whose layers are not all of one kind. None
+    # = derived from the fields above (``layer_spec``): one entry
+    # repeated, or the alternating window of ``sliding_window_pattern``.
+    # A model that sets it runs the sequential block x + mixer(norm(x)),
+    # x + mlp(norm(x)) with a gated-SiLU MLP and no rotary embedding (its
+    # state-space layers carry the order), whatever ``arch`` says.
+    layers: Optional[Tuple[LayerSpec, ...]] = None
+    # "rms" | "layer": LayerNorm with weight and bias, in the blocks of a
+    # model with ``layers`` set and before the head
+    norm: str = "rms"
+    # selective state-space layers (Mamba-1): state size N, convolution
+    # width, inner width = ssm_expand * hidden_size, and the rank of the
+    # step-size projection (0 = ceil(hidden_size / 16))
+    ssm_state_size: int = 16
+    ssm_conv_width: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 0
 
     def __post_init__(self):
+        if self.layers is not None:
+            object.__setattr__(self, "layers", tuple(
+                LayerSpec(*e) for e in self.layers))
+            self._check_layers()
+        if self.norm not in ("rms", "layer") or (
+                self.norm == "layer" and self.layers is None):
+            raise ValueError(
+                f"norm={self.norm!r}: 'layer' is implemented for models "
+                "with a per-layer spec (`layers`); arch='phi' has its own")
         if self.latent_attention:
             if self.arch != "llama":
                 raise ValueError(
@@ -231,6 +315,74 @@ class ModelConfig:
                         f"LoRA targets {sorted(bad)} are dense-MLP "
                         f"matrices; with num_experts > 0 restrict "
                         f"lora_targets to attention projections")
+
+    def _check_layers(self) -> None:
+        if len(self.layers) != self.num_layers:
+            raise ValueError(
+                f"`layers` has {len(self.layers)} entries for "
+                f"num_layers={self.num_layers}")
+        full = memory = False
+        for l, spec in enumerate(self.layers):
+            if spec.mixer not in MIXERS or spec.cache not in CACHE_KINDS:
+                raise ValueError(f"layer {l}: unknown spec {spec}")
+            if spec.mixer in ("attention", "latent_attention"):
+                raise ValueError(
+                    f"layer {l}: mixer {spec.mixer!r} is run by the "
+                    "homogeneous stack (leave `layers` unset)")
+            want = {"ssm": ("state",), "gmu": ("none",),
+                    "cross_diff_attention": ("shared",),
+                    "diff_attention": ("paged", "paged_window")}[spec.mixer]
+            if spec.cache not in want or (
+                    (spec.cache == "paged_window") != bool(spec.window)):
+                raise ValueError(
+                    f"layer {l}: mixer {spec.mixer!r} with cache "
+                    f"{spec.cache!r} and window {spec.window}")
+            if spec.mixer == "gmu" and not memory:
+                raise ValueError(f"layer {l}: gmu with no ssm layer below")
+            if spec.mixer == "cross_diff_attention" and not full:
+                raise ValueError(
+                    f"layer {l}: cross attention with no paged "
+                    "diff_attention layer below")
+            memory = memory or spec.mixer == "ssm"
+            full = full or spec.cache == "paged"
+        if (self.kv_cache_dtype != "bfloat16" or self.lora_r > 0
+                or self.num_experts or self.latent_attention
+                or self.sliding_window):
+            raise ValueError(
+                "a model with a per-layer spec runs without int8 KV (its "
+                "pages and its recurrent state have no quantised form), "
+                "LoRA adapters, routed experts or latent attention, and "
+                "states its windows in `layers`, not in sliding_window")
+        if self.num_heads % 2 or self.num_kv_heads % 2 or (
+                self.num_heads // 2) % (self.num_kv_heads // 2):
+            raise ValueError(
+                "differential attention pairs heads: num_heads and "
+                "num_kv_heads must be even, query pairs a multiple of "
+                "key/value pairs")
+
+    @property
+    def layer_spec(self) -> Tuple[LayerSpec, ...]:
+        """One LayerSpec a layer: ``layers`` where the model states it,
+        else derived (one mixer, every layer paged, the window on the
+        layers ``sliding_window_pattern`` says: layer l slides iff
+        pattern is 1 or (l + 1) % pattern != 0, HF Gemma2's rule)."""
+        if self.layers is not None:
+            return self.layers
+        mixer = "latent_attention" if self.latent_attention else "attention"
+        pat = self.sliding_window_pattern
+        return tuple(
+            LayerSpec(mixer, "paged", self.sliding_window
+                      if self.sliding_window and (pat == 1 or (l + 1) % pat)
+                      else None)
+            for l in range(self.num_layers))
+
+    @property
+    def ssm_inner_(self) -> int:
+        return self.ssm_expand * self.hidden_size
+
+    @property
+    def ssm_dt_rank_(self) -> int:
+        return self.ssm_dt_rank or -(-self.hidden_size // 16)
 
     @property
     def latent_attention(self) -> bool:
@@ -280,6 +432,10 @@ class ModelConfig:
         d = {k: v for k, v in d.items() if k in fields}
         if "lora_targets" in d:
             d["lora_targets"] = tuple(d["lora_targets"])
+        if d.get("layers") is not None:
+            d["layers"] = tuple(
+                LayerSpec(**e) if isinstance(e, dict) else LayerSpec(*e)
+                for e in d["layers"])
         return cls(**d)
 
     def to_dict(self) -> Dict[str, Any]:
@@ -441,6 +597,44 @@ register_model("tiny-mla", ModelConfig(
     num_layers=2, num_heads=4, num_kv_heads=4, max_seq_length=256,
     q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
     qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+    param_dtype="float32", dtype="float32", remat="none"))
+
+
+
+def sambay_layers(num_layers: int, window: int,
+                  ssm_every: int = 2) -> Tuple[LayerSpec, ...]:
+    """The SambaY decoder-hybrid-decoder layout (arXiv:2507.06607, figure
+    1; HF ``phi4flash``'s ``mb_per_layer`` / ``yoco_mb`` / ``yoco_cross``):
+    with n layers, a self-decoder of n/2 layers alternating a state-space
+    mixer (every ``ssm_every``-th layer, from 0) with windowed
+    differential attention; layer n/2 a state-space layer whose scan
+    output is the cross-decoder's memory; layer n/2 + 1 full differential
+    attention, the one cache the cross-decoder reads; then gated memory
+    units (on the state-space positions) alternating with
+    cross-attention."""
+    half = num_layers // 2
+    out = []
+    for l in range(num_layers):
+        ssm_pos = l % ssm_every == 0
+        if l <= half:
+            out.append(LayerSpec("ssm", "state") if ssm_pos else
+                       LayerSpec("diff_attention", "paged_window", window))
+        elif l == half + 1:
+            out.append(LayerSpec("diff_attention", "paged"))
+        else:
+            out.append(LayerSpec("gmu", "none") if ssm_pos else
+                       LayerSpec("cross_diff_attention", "shared"))
+    return tuple(out)
+
+
+# the SambaY layout at toy widths: (M, W) x 3, M (the memory), F,
+# (G, X) x 2, so both repeating stretches run as scans; 4 query / 2
+# key-value heads of 16 (2 and 1 differential pairs), state size 4,
+# window 8
+register_model("tiny-sambay", ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128,
+    num_layers=12, num_heads=4, num_kv_heads=2, max_seq_length=256,
+    tie_embeddings=True, norm="layer", layers=sambay_layers(12, 8), ssm_state_size=4,
     param_dtype="float32", dtype="float32", remat="none"))
 
 # HF repo-id aliases so reference configs keep working verbatim

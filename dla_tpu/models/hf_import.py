@@ -64,12 +64,45 @@ def _validated_rope_scaling(hf_cfg):
     return rs
 
 
+def _phi4flash_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:
+    """Phi-4-mini-flash (SambaY, arXiv:2507.06607): the per-layer spec
+    from ``num_hidden_layers``, ``mb_per_layer`` and ``sliding_window``
+    (config.sambay_layers), LayerNorm with bias, a tied head, no rotary
+    embedding. The Mamba sizes are HF ``Phi4FlashConfig``'s defaults
+    where the file leaves them out (``mamba_d_state`` 16, ``mamba_d_conv``
+    4, ``mamba_expand`` 2, ``mamba_dt_rank`` ceil(hidden / 16))."""
+    from dla_tpu.models.config import sambay_layers
+    n_heads = int(hf_cfg["num_attention_heads"])
+    n_layers = int(hf_cfg["num_hidden_layers"])
+    dt_rank = hf_cfg.get("mamba_dt_rank", "auto")
+    fields = dict(
+        vocab_size=int(hf_cfg["vocab_size"]),
+        hidden_size=int(hf_cfg["hidden_size"]),
+        intermediate_size=int(hf_cfg["intermediate_size"]),
+        num_layers=n_layers, num_heads=n_heads,
+        num_kv_heads=int(hf_cfg.get("num_key_value_heads", n_heads)),
+        rms_norm_eps=float(hf_cfg.get("layer_norm_eps", 1e-5)),
+        tie_embeddings=bool(hf_cfg.get("tie_word_embeddings", True)),
+        max_seq_length=int(hf_cfg.get("max_position_embeddings", 4096)),
+        norm="layer",
+        layers=sambay_layers(n_layers, int(hf_cfg["sliding_window"]),
+                             int(hf_cfg.get("mb_per_layer", 2))),
+        ssm_state_size=int(hf_cfg.get("mamba_d_state", 16)),
+        ssm_conv_width=int(hf_cfg.get("mamba_d_conv", 4)),
+        ssm_expand=int(hf_cfg.get("mamba_expand", 2)),
+        ssm_dt_rank=0 if dt_rank == "auto" else int(dt_rank))
+    fields.update(overrides)
+    return ModelConfig(**fields)
+
+
 def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfig:
     """Map a Llama/Mistral/Qwen2- or Phi-style HF config.json to
     ModelConfig."""
     model_type = str(hf_cfg.get("model_type", "")).lower()
     if model_type == "phi":
         return _phi_config(hf_cfg, overrides)
+    if model_type == "phi4flash":
+        return _phi4flash_config(hf_cfg, overrides)
     n_heads = int(hf_cfg["num_attention_heads"])
     rope_theta = hf_cfg.get("rope_theta") or (
         hf_cfg.get("rope_parameters") or {}).get("rope_theta", 10000.0)

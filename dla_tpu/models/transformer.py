@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dla_tpu.models.config import ModelConfig
+from dla_tpu.models.config import CacheArray, ModelConfig
+from dla_tpu.models.hybrid import HybridStack
 from dla_tpu.parallel.mesh import auto_axes
 from dla_tpu.ops.attention import (
     block_decode_attention,
@@ -143,6 +144,14 @@ class Transformer:
             m = yarn_mscale(float(rs.get("factor") or 1.0),
                             float(rs.get("mscale_all_dim") or 0.0))
             self._softmax_scale = cfg.head_dim_ ** -0.5 * m * m
+        # a model whose layers are of several kinds (cfg.layers): its
+        # runs of layers live in models/hybrid.py. Its attention ops see
+        # differential pairs, rows twice a head wide, so the scale is
+        # stated, not read off the operands
+        self.hybrid: Optional[HybridStack] = None
+        if cfg.layers is not None:
+            self.hybrid = HybridStack(self)
+            self._softmax_scale = cfg.head_dim_ ** -0.5
 
     # ------------------------------------------------------- storage layout
 
@@ -243,6 +252,18 @@ class Transformer:
                     ).astype(self.pdtype)
 
         L, D, F = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
+        if self.hybrid is not None:
+            params = {
+                "embed": {"embedding": mat(keys[0], (cfg.vocab_size, D), std)},
+                "layers": self.hybrid.init(keys[1]),
+                "final_norm": jnp.ones((D,), self.pdtype),
+            }
+            if cfg.norm == "layer":
+                params["final_norm_bias"] = jnp.zeros((D,), self.pdtype)
+            if not cfg.tie_embeddings:
+                params["lm_head"] = mat(
+                    jax.random.fold_in(rng, 99), (D, cfg.vocab_size), std)
+            return params
         if cfg.arch == "phi":
             # parallel-residual block: one shared input LayerNorm, biased
             # projections, non-gated GELU MLP (fc1/fc2)
@@ -485,6 +506,15 @@ class Transformer:
         win (ZeRO-3 shard over fsdp, gathered at use like every other
         matrix) with zero TP-axis traffic on the embed path.
         """
+        if self.hybrid is not None:
+            specs = {"embed": {"embedding": P("fsdp", None)},
+                     "layers": self.hybrid.partition_specs(),
+                     "final_norm": P(None)}
+            if self.cfg.norm == "layer":
+                specs["final_norm_bias"] = P(None)
+            if not self.cfg.tie_embeddings:
+                specs["lm_head"] = P("fsdp", "model")
+            return specs
         if self.cfg.arch == "phi":
             specs = {
                 "embed": {"embedding": P("fsdp", None)},
@@ -771,6 +801,19 @@ class Transformer:
             return ((1, cfg.latent_row_width_),)
         return ((cfg.num_kv_heads, cfg.head_dim_),) * 2
 
+    def cache_spec(self) -> Tuple[CacheArray, ...]:
+        """Every array a cache manager holds for this model, in the
+        order the paged steps take and return them (``view["pools"]``).
+        A model of one kind of layer: one ``paged`` array of every layer
+        per ``cache_rows()`` entry. A model with a per-layer spec states
+        pages of two lifetimes and per-slot state
+        (``HybridStack.cache_spec``)."""
+        if self.hybrid is not None:
+            return self.hybrid.cache_spec()
+        return tuple(
+            CacheArray("paged", self.cfg.num_layers, row, self.adtype)
+            for row in self.cache_rows())
+
     # ------------------------------------------------------------------ mlp
 
     def _mlp(self, layer: Params, h: jnp.ndarray, proj,
@@ -847,8 +890,7 @@ class Transformer:
         cfg = self.cfg
         if not (cfg.sliding_window and cfg.sliding_window_pattern > 1):
             return layers
-        win = ((jnp.arange(cfg.num_layers) + 1)
-               % cfg.sliding_window_pattern != 0)
+        win = jnp.asarray([s.window is not None for s in cfg.layer_spec])
         if storage and self._interleaved_storage:
             win = win.reshape(self._storage_lead())
         return {**layers, "swa_on": win}
@@ -1156,6 +1198,10 @@ class Transformer:
         """
         cfg = self.cfg
         b, t = input_ids.shape
+        if self.hybrid is not None:
+            return self._hybrid_hidden_states(
+                params, input_ids, attention_mask, segment_ids, positions,
+                gapped_mask, lora), None
         # caller-supplied positions carry no contiguity guarantee — the
         # windowed ring must treat them like gapped-mask positions and
         # skip its scan truncation
@@ -1322,6 +1368,30 @@ class Transformer:
             moe_aux = type(auxs)(*(jnp.mean(a) for a in auxs))  # layer mean
         return self._final_norm(params, x), moe_aux
 
+    def _hybrid_hidden_states(self, params, input_ids, attention_mask,
+                              segment_ids, positions, gapped_mask, lora):
+        """The full-sequence forward of a model with a per-layer spec:
+        right-padded sequences from an empty state, on one device or a
+        data / fsdp / model mesh. Packing, gapped masks and caller's
+        positions would have to reset or skip the recurrent state, and
+        context or pipeline parallelism to hand it on: none is built."""
+        if (segment_ids is not None or positions is not None or gapped_mask
+                or lora is not None or _sequence_axis_size() > 1
+                or _stage_axis_size() > 1):
+            raise NotImplementedError(
+                "a model with state-space layers runs whole right-padded "
+                "sequences: no packing (segment_ids), gapped masks, "
+                "caller's positions, LoRA, context or pipeline parallelism")
+        b, t = input_ids.shape
+        positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+        kv_mask, real = None, jnp.ones((b, t), bool)
+        if attention_mask is not None:
+            real = attention_mask.astype(bool)
+            kv_mask = jnp.broadcast_to(real[:, None, :], (b, t, t))
+        x = _constrain(self._embed(params, input_ids), ACT_SPEC)
+        x = self.hybrid.forward(params["layers"], x, positions, kv_mask, real)
+        return self._final_norm(params, x)
+
     def _pipeline_forward(self, layers: Params, x: jnp.ndarray,
                           cos: jnp.ndarray, sin: jnp.ndarray,
                           kv_mask: Optional[jnp.ndarray],
@@ -1463,7 +1533,7 @@ class Transformer:
         return out.reshape(x.shape), moe_aux
 
     def _final_norm(self, params: Params, x: jnp.ndarray) -> jnp.ndarray:
-        if self.cfg.arch == "phi":
+        if self.cfg.arch == "phi" or self.cfg.norm == "layer":
             return layer_norm(x, params["final_norm"],
                               params["final_norm_bias"],
                               self.cfg.rms_norm_eps)
@@ -1570,8 +1640,19 @@ class Transformer:
         return (q.astype(jnp.float32) * scale[..., None]
                 ).astype(self.adtype)
 
+    def refuse_contiguous_cache(self) -> None:
+        """Raise for a model the contiguous cache cannot hold."""
+        if self.hybrid is not None:
+            raise ValueError(
+                "a model with a per-layer spec decodes against the paged "
+                "cache manager (dla_tpu.serving.ServingEngine): the "
+                "contiguous cache of init_cache / prefill / decode_step "
+                "(GenerationEngine) has one geometry for every layer and "
+                "no recurrent state")
+
     def init_cache(self, batch: int, max_len: int) -> Params:
         cfg = self.cfg
+        self.refuse_contiguous_cache()
         if cfg.latent_attention:
             raise NotImplementedError(
                 "latent attention decodes against the paged pool "
@@ -1621,6 +1702,7 @@ class Transformer:
         ``prefill`` packs these into the contiguous cache (the paged
         serving engine prefills through ``prefill_step_paged``)."""
         cfg = self.cfg
+        self.refuse_contiguous_cache()
         b, t = input_ids.shape
         positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
         flash_ok = self._flash_eligible(t)
@@ -2014,6 +2096,14 @@ class Transformer:
                 "the paged steps serve activation-dtype pages; "
                 "kv_cache_dtype=int8 is only wired into the contiguous "
                 "decode_step path")
+        if self.hybrid is not None:
+            if adapters is not None:
+                raise NotImplementedError(
+                    "per-slot adapters on a model with a per-layer spec")
+            x, pools = self.hybrid.paged(
+                params["layers"], view, x, positions, attention)
+            return (self._final_norm(params, x), pools,
+                    jnp.zeros((2,), jnp.int32))
         cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,
                                  scaling=cfg.rope_scaling)
         tables = view["block_tables"]                    # [B, pages/slot]

@@ -3,9 +3,12 @@ sequence, so sequences of wildly different lengths never reserve
 worst-case contiguous cache.
 
 Layout: preallocated ``pools``, one [L, num_pages, page_size, heads,
-width] array per kind of row the model caches (``Transformer.cache_rows``:
+width] array per kind of row the model caches (``Transformer.cache_spec``:
 keys and values of [KH, D]; or one latent row of [1, r + rope], lane-
-padded). Each decode SLOT (a row of the static-shape decode batch) owns a
+padded). A model whose layers are of several kinds states three kinds of
+array and the cache obeys (``PagedKVCache``): ``paged`` as above,
+``paged_window`` over a second pool whose pages go back to its allocator
+once they lie behind the window, and per-slot ``state``. Each decode SLOT (a row of the static-shape decode batch) owns a
 block table row — ``pages_per_slot`` physical page ids — and the model's
 paged steps (``Transformer._paged_layers``) gather, a layer at a time,
 
@@ -209,11 +212,43 @@ class PageGeometry:
     num_pages: int
     num_slots: int
     pages_per_slot: int
+    # the window pool of a model with ``paged_window`` layers (0 / 0 / 0:
+    # none): the attention window in tokens, the width of a slot's ring
+    # of window pages, and the pool's pages (page 0 is its trash page).
+    # ``for_model`` derives them; no configuration sets them
+    window: int = 0
+    window_ring: int = 0
+    num_window_pages: int = 0
+
+    @classmethod
+    def for_model(cls, model, *, page_size: int, num_pages: int,
+                  num_slots: int, pages_per_slot: int,
+                  prefill_chunk: int) -> "PageGeometry":
+        """The geometry a model's ``cache_spec()`` asks for. A slot holds
+        the window's pages and the chunk being written, and a page more
+        where the two do not line up: ceil((window + chunk) / page) + 1
+        entries, logical page j at entry j % ring; the pool owns a ring
+        for every slot, so a window page is never waited for."""
+        window = max((a.window or 0 for a in model.cache_spec()
+                      if a.kind == "paged_window"), default=0)
+        ring = (-(-(window + prefill_chunk) // page_size) + 1
+                if window else 0)
+        return cls(page_size=page_size, num_pages=num_pages,
+                   num_slots=num_slots, pages_per_slot=pages_per_slot,
+                   window=window, window_ring=ring,
+                   num_window_pages=num_slots * ring + 1 if ring else 0)
 
     @property
     def slot_window(self) -> int:
         """S: the per-slot logical window the gather materializes."""
         return self.pages_per_slot * self.page_size
+
+    @property
+    def window_gather_pages(self) -> int:
+        """Pages of the window pool a step reads for one slot: those
+        holding (pos - window, pos), a page more where the window's
+        start lies inside one."""
+        return -(-self.window // self.page_size) + 1 if self.window else 0
 
     def pages_for(self, n_tokens: int) -> int:
         """Pages needed to hold ``n_tokens`` (ceil)."""
@@ -471,12 +506,20 @@ class PagedKVCache:
     """Device pool + host metadata mirror for the serving decode batch.
 
     Device state (jitted steps read/write):
-      pools  a tuple of [L, num_pages, page_size, heads, width] arrays,
-             one per entry of ``model.cache_rows()``: keys and values of
-             [KH, D] for dense attention, one pool of [1, r + rope]
-             latent rows for latent attention. Every consumer (the
-             engine's gather / scatter, copy-on-write, migration) maps
-             over the tuple, so the row's shape is the model's business.
+      pools  a tuple of arrays, one per entry of ``model.cache_spec()``,
+             in its order. ``paged`` entries are [L, num_pages,
+             page_size, heads, width]: keys and values of [KH, D] for
+             dense attention, one pool of [1, r + rope] latent rows for
+             latent attention. Every consumer (the engine's gather /
+             scatter, copy-on-write, migration) maps over the tuple, so
+             the row's shape is the model's business. A model with a
+             per-layer spec adds ``paged_window`` entries ([layers,
+             num_window_pages, page_size, heads, width], addressed
+             through ``window_tables``) and ``state`` entries ([layers,
+             num_slots, *shape], indexed by slot; a request's first
+             prefill chunk starts from zeros whatever the slot held, so
+             nothing is zeroed on the host's side), all in the one
+             tuple, donated and rebound together.
              This object alone owns the buffers: every jitted program
              that returns the pools (decode, prefill chunk, the
              speculative pair, KV import, ``copy_page``) is given them
@@ -507,16 +550,29 @@ class PagedKVCache:
                     (tests/test_step_args.py holds the writers to it)
       lengths       [num_slots]    true tokens so far
       tokens        [num_slots]    last sampled token (next step's input)
+      window_tables [num_slots, window_ring] page ids of the window pool:
+                    a slot holds logical pages [window_first,
+                    window_next), page j at entry j % window_ring; the
+                    pass that advances a slot (``mark_computed``,
+                    ``advance_slot``) returns every page that lies
+                    wholly behind the next query's window to
+                    ``window_allocator``
     """
 
     def __init__(self, model, geom: PageGeometry):
-        cfg = model.cfg
         self.geom = geom
         self.dtype = model.adtype
+        self.spec = tuple(model.cache_spec())
         self.pools: Tuple[jnp.ndarray, ...] = tuple(
-            jnp.zeros((cfg.num_layers, geom.num_pages, geom.page_size,
-                       heads, width), self.dtype)
-            for heads, width in model.cache_rows())
+            jnp.zeros(self.array_shape(a, geom), a.dtype)
+            for a in self.spec)
+        self.window_tables = np.zeros(
+            (geom.num_slots, geom.window_ring), np.int32)
+        self.window_first = np.zeros((geom.num_slots,), np.int64)
+        self.window_next = np.zeros((geom.num_slots,), np.int64)
+        self.window_allocator = (PageAllocator(geom.num_window_pages)
+                                 if geom.window_ring else None)
+        self.window_pages_released = 0
         s = geom.slot_window
         self.block_tables = np.zeros(
             (geom.num_slots, geom.pages_per_slot), np.int32)
@@ -524,6 +580,15 @@ class PagedKVCache:
         self.lengths = np.zeros((geom.num_slots,), np.int32)
         self.tokens = np.zeros((geom.num_slots,), np.int32)
         self.allocator = PageAllocator(geom.num_pages)
+
+    @staticmethod
+    def array_shape(a, geom: PageGeometry) -> Tuple[int, ...]:
+        """The device array of one ``cache_spec()`` entry."""
+        if a.kind == "state":
+            return (a.layers, geom.num_slots) + tuple(a.shape)
+        pages = (geom.num_pages if a.kind == "paged"
+                 else geom.num_window_pages)
+        return (a.layers, pages, geom.page_size) + tuple(a.shape)
 
     # ---------------------------------------------------- slot lifecycle
 
@@ -544,6 +609,50 @@ class PagedKVCache:
     def mark_computed(self, slot: int, start: int, count: int) -> None:
         """A prefill chunk scattered columns [start, start+count)."""
         self.valid[slot, start:start + count] = True
+        if self.window_allocator is not None:
+            self._release_window(slot, start + count)
+
+    # ------------------------------------------------------ window pages
+
+    def ensure_window(self, slot: int, last_col: int) -> None:
+        """Window pages for every column up to ``last_col``, which the
+        coming dispatch writes. The pool owns a ring a slot, so this
+        cannot run dry while ``_release_window`` keeps up."""
+        ps, ring = self.geom.page_size, self.geom.window_ring
+        while self.window_next[slot] * ps <= last_col:
+            page = self.window_allocator.alloc(1)
+            j = int(self.window_next[slot])
+            if page is None or j - int(self.window_first[slot]) >= ring:
+                raise RuntimeError(
+                    f"slot {slot}: window ring of {ring} pages overrun at "
+                    f"logical page {j} (held from "
+                    f"{int(self.window_first[slot])})")
+            self.window_tables[slot, j % ring] = page[0]
+            self.window_next[slot] = j + 1
+
+    def _release_window(self, slot: int, next_query: int) -> None:
+        """Give back every window page no later query can see: the next
+        query stands at ``next_query`` and sees keys in (next_query -
+        window, next_query], so page j goes once its last column, (j +
+        1) * page - 1, is at or below next_query - window."""
+        ps, ring = self.geom.page_size, self.geom.window_ring
+        behind = (next_query - self.geom.window + 1) // ps   # pages < this
+        while self.window_first[slot] < min(behind, self.window_next[slot]):
+            entry = int(self.window_first[slot]) % ring
+            self.window_allocator.decref(
+                int(self.window_tables[slot, entry]))
+            self.window_tables[slot, entry] = 0
+            self.window_first[slot] += 1
+            self.window_pages_released += 1
+
+    def _close_window(self, slot: int) -> None:
+        ring = self.geom.window_ring
+        for j in range(int(self.window_first[slot]),
+                       int(self.window_next[slot])):
+            self.window_allocator.decref(
+                int(self.window_tables[slot, j % ring]))
+        self.window_tables[slot] = 0
+        self.window_first[slot] = self.window_next[slot] = 0
 
     def begin_decode(self, slot: int, prompt_len: int,
                      first_token: int) -> None:
@@ -561,6 +670,8 @@ class PagedKVCache:
         self.valid[slot] = False
         self.lengths[slot] = 0
         self.tokens[slot] = 0
+        if self.window_allocator is not None:
+            self._close_window(slot)
 
     def advance_slot(self, slot: int, token: int) -> None:
         """Apply one decode step's deterministic metadata update: the
@@ -578,6 +689,8 @@ class PagedKVCache:
         self.valid[slot, col] = True
         self.lengths[slot] = col + 1
         self.tokens[slot] = token
+        if self.window_allocator is not None:
+            self._release_window(slot, col + 1)
 
     @property
     def pools_dead(self) -> bool:
@@ -585,11 +698,36 @@ class PagedKVCache:
         nothing to rebind (see ``pools`` above)."""
         return any(p.is_deleted() for p in self.pools)
 
+    def _bytes(self, kind: str) -> int:
+        """Bytes of one unit (a token of a paged kind, a slot of
+        ``state``) over every layer of every array of ``kind``."""
+        return sum(int(np.prod(a.shape)) * a.layers
+                   * jnp.dtype(a.dtype).itemsize
+                   for a in self.spec if a.kind == kind)
+
     @property
     def bytes_per_token(self) -> int:
-        """Bytes one cached token takes over every layer and pool."""
-        return sum(int(np.prod(p.shape[3:])) * p.shape[0]
-                   * p.dtype.itemsize for p in self.pools)
+        """Bytes one cached token takes over every layer whose pages
+        live as long as the request (the ``paged`` arrays)."""
+        return self._bytes("paged")
+
+    @property
+    def window_bytes_per_token(self) -> int:
+        """Bytes a token takes in the window pool while inside the
+        window, over its layers."""
+        return self._bytes("paged_window")
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state a slot holds, whatever its length."""
+        return self._bytes("state")
+
+    @property
+    def occupancy(self) -> float:
+        """Pages owned over pages allocatable, both pools together."""
+        allocs = [a for a in (self.allocator, self.window_allocator) if a]
+        return (sum(a.used_count for a in allocs)
+                / max(1, sum(a.capacity for a in allocs)))
 
     def slot_page_index(self, slot: int) -> int:
         """Block-table index the NEXT decode write for ``slot`` needs

@@ -62,6 +62,20 @@ class ServingMetrics:
         # bytes one cached token takes in the paged pool, every layer
         # (dense: keys and values; latent attention: one latent row)
         self.kv_bytes_per_token = r.gauge("serving/kv_bytes_per_token")
+        # a model with a per-layer cache spec (0 / 1 for the others):
+        # bytes a token takes in the window pool while inside the window;
+        # bytes of recurrent state a slot holds; the layers that read the
+        # one ``paged`` attention layer's rows (itself included); the
+        # window pool's pages owned over pages it has; window pages given
+        # back to its allocator from behind the window
+        self.window_bytes_per_token = r.gauge(
+            "serving/window_bytes_per_token")
+        self.state_bytes_per_slot = r.gauge("serving/state_bytes_per_slot")
+        self.kv_shared_readers = r.gauge("serving/kv_shared_readers")
+        self.window_page_occupancy = r.gauge(
+            "serving/window_page_occupancy")
+        self.window_pages_released = r.counter(
+            "serving/window_pages_released")
         self.tokens_generated = r.counter("serving/tokens_generated")
         self.prefix_lookups = r.counter("serving/prefix_cache/lookups")
         self.prefix_hit_tokens = r.counter(
@@ -123,6 +137,16 @@ class ServingMetrics:
             "serving/moe/expert_assignments": float(
                 self.moe_expert_assignments.value),
             "serving/kv_bytes_per_token": self.kv_bytes_per_token.value,
+            "serving/window_bytes_per_token":
+                self.window_bytes_per_token.value,
+            "serving/state_bytes_per_slot": self.state_bytes_per_slot.value,
+            "serving/kv_shared_readers": self.kv_shared_readers.value,
+            "serving/window_page_occupancy":
+                self.window_page_occupancy.value,
+            "serving/window_page_occupancy_peak":
+                self.window_page_occupancy.peak,
+            "serving/window_pages_released": float(
+                self.window_pages_released.value),
             "serving/tokens_generated": float(self.tokens_generated.value),
             "serving/prefix_cache/lookups": float(
                 self.prefix_lookups.value),

@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import itertools
+import numpy as np
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
@@ -295,6 +296,12 @@ class Scheduler:
                             slot, len(req.pages) - 1] = page[0]
                         continue
                 elif self._ensure_writable(req, span):
+                    if self.cache.window_allocator is not None:
+                        # the window pool's page under the same write
+                        # range (it owns a ring a slot: never short)
+                        self.cache.ensure_window(
+                            slot, int(self.cache.lengths[slot])
+                            + self._write_need(req, span) - 1)
                     break
                 victim = self._youngest_running(exclude_rid=None)
                 if victim is None or victim.rid == req.rid:
@@ -465,3 +472,12 @@ class Scheduler:
             f"!= {alloc.capacity}")
         assert 0 not in refs and 0 not in alloc.cached_pages, (
             "trash page entered the allocator")
+        walloc = self.cache.window_allocator
+        if walloc is not None:
+            held = int((self.cache.window_next
+                        - self.cache.window_first).sum())
+            assert held == walloc.used_count == int(
+                np.count_nonzero(self.cache.window_tables)), (
+                f"window page drift: slots hold {held}, allocator says "
+                f"{walloc.used_count}")
+            assert held <= geom.window_ring * len(holders)

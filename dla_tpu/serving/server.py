@@ -253,14 +253,34 @@ class ServingEngine:
                 raise ValueError(
                     "speculative.draft must be 'int8' or 'self', got "
                     f"{self._spec_draft_kind!r}")
+        # a model that keeps recurrent state a slot (its cache spec has
+        # ``state`` arrays): what copies, shares or rolls back cached
+        # rows would have to snapshot that state too, and nothing does
+        # yet, so each such feature is refused here, by name
+        self._stateful = any(a.kind == "state" for a in model.cache_spec())
+        if self._stateful:
+            for on, what in (
+                    (cfg.prefix_cache, "ServingConfig.prefix_cache (a "
+                     "cached prefix is pages alone: the state at its end "
+                     "is not kept)"),
+                    (bool(spec), "speculative decoding (a rejected draft "
+                     "token has already moved the state)"),
+                    (cfg.role != "mixed", f"role={cfg.role!r} (KV export "
+                     "/ import carries pages, not state)"),
+                    (ten_cfg is not None, "tenancy (per-slot adapters)")):
+                if on:
+                    raise ValueError(
+                        "this model keeps recurrent state a slot, and "
+                        f"state snapshots are not built: no {what}")
         self.model = model
         self.params = params
         self.gen = gen
         self.cfg = cfg
         self.now = now
-        geom = PageGeometry(
-            page_size=cfg.page_size, num_pages=cfg.num_pages,
-            num_slots=cfg.num_slots, pages_per_slot=cfg.pages_per_slot)
+        geom = PageGeometry.for_model(
+            model, page_size=cfg.page_size, num_pages=cfg.num_pages,
+            num_slots=cfg.num_slots, pages_per_slot=cfg.pages_per_slot,
+            prefill_chunk=cfg.prefill_chunk)
         self.cache = PagedKVCache(model, geom)
         self.prefix_cache: Optional[PrefixCache] = None
         if cfg.prefix_cache:
@@ -275,6 +295,13 @@ class ServingEngine:
             prefix_cache=self.prefix_cache)
         self.metrics = ServingMetrics()
         self.metrics.kv_bytes_per_token.set(self.cache.bytes_per_token)
+        self.metrics.window_bytes_per_token.set(
+            self.cache.window_bytes_per_token)
+        self.metrics.state_bytes_per_slot.set(
+            self.cache.state_bytes_per_slot)
+        self.metrics.kv_shared_readers.set(
+            model.hybrid.shared_readers if model.hybrid else 1)
+        self._window_released_mirrored = 0
         self._pc_mirrored = {"lookups": 0, "hit_tokens": 0,
                              "evictions": 0}
         # speculative round accounting lives in plain engine ints and is
@@ -321,18 +348,24 @@ class ServingEngine:
         # [pages/slot + chunk + 2 or 3]; the adapter pool row rides only
         # with tenancy on. The window mask and positions are not in it:
         # the programs derive them from ``lengths`` / ``start``.
+        # A model with a window pool sends a second block-table row a
+        # slot (its ring of window pages), and one with per-slot state
+        # the chunk's slot: still one array, one put.
         tenancy = ((("adapter", 1, np.int32),) if ten_cfg is not None
                    else ())
+        window = ((("window_tables", geom.window_ring, np.int32),)
+                  if geom.window_ring else ())
         self._decode_layout = PackedArgs(
-            ("block_tables", geom.pages_per_slot, np.int32),
+            ("block_tables", geom.pages_per_slot, np.int32), *window,
             ("lengths", 1, np.int32), ("tokens", 1, np.int32),
             ("active", 1, np.bool_), ("top_k", 1, np.int32),
             ("seed", 1, np.uint32), ("gen_pos", 1, np.int32),
             ("temp", 1, np.float32), ("top_p", 1, np.float32), *tenancy)
         self._chunk_layout = PackedArgs(
-            ("block_tables", geom.pages_per_slot, np.int32),
+            ("block_tables", geom.pages_per_slot, np.int32), *window,
             ("ids", cfg.prefill_chunk, np.int32),
-            ("start", 1, np.int32), ("nvalid", 1, np.int32), *tenancy)
+            ("start", 1, np.int32), ("nvalid", 1, np.int32), *tenancy,
+            *((("slot", 1, np.int32),) if self._stateful else ()))
         self._draining = False
         self._old_handlers: Optional[dict] = None
         # engine-step counter drives the profiling window (the serving
@@ -544,6 +577,13 @@ class ServingEngine:
                 "real": real[None, :],
                 "write_pages": jnp.where(real, btab[0, cols // ps], 0)[None],
                 "write_offs": jnp.where(real, cols % ps, 0)[None]}
+        if "window_tables" in f:
+            view["window_tables"] = f["window_tables"][None]
+        if "slot" in f:
+            # the slot's recurrent state; a request's first chunk starts
+            # from zeros whatever the slot's last request left there
+            view["state_rows"] = f["slot"][None]
+            view["fresh"] = (start == 0)[None]
         # absolute chunk schedule: positions are fixed by `start`, so a
         # cache hit changes WHICH chunks run, never the math inside one
         positions = cols[None, :]
@@ -629,6 +669,8 @@ class ServingEngine:
                 "real": active[:, None],
                 "write_pages": jnp.where(active, page_ids, 0)[:, None],
                 "write_offs": jnp.where(active, lengths % ps, 0)[:, None]}
+        if "window_tables" in f:
+            view["window_tables"] = f["window_tables"]
         logits, pools, routed = self.model.decode_step_paged(
             params, view, f["tokens"], adapters=adapters)
         # a free slot keeps its last request's temperature: zeroed, so
@@ -1065,6 +1107,7 @@ class ServingEngine:
         resumable in place: unknown, queued/prefilling/terminal, or with
         an eviction hole — block-table pages no longer covering the
         committed columns."""
+        self._refuse_stateful("KV export")
         req = self._results.get(rid)
         if req is None:
             return self._export_refuse(f"unknown rid {rid}")
@@ -1115,6 +1158,13 @@ class ServingEngine:
             last_token_time=req.last_token_time,
             tenant=req.tenant)
 
+    def _refuse_stateful(self, what: str) -> None:
+        if self._stateful:
+            raise ValueError(
+                f"{what}: this model keeps recurrent state a slot, and a "
+                "ticket carries pages, not state (state snapshots are not "
+                "built)")
+
     def _export_refuse(self, msg: str):
         self._mig_stats["failed_migrations"] += 1
         raise MigrationError(msg)
@@ -1134,6 +1184,7 @@ class ServingEngine:
         mismatches, window overflows, slot/page exhaustion
         (``MigrationError``, counted on failed_migrations) — the caller
         keeps the source copy running."""
+        self._refuse_stateful("KV import")
         t_start = self.now()
         if self.cache.pools_dead:
             return self._import_refuse(
@@ -1340,7 +1391,14 @@ class ServingEngine:
         m = self.metrics
         m.queue_depth.set(self.scheduler.queue_depth)
         m.active_requests.set(self.scheduler.active_count)
-        m.page_occupancy.set(self.cache.allocator.occupancy)
+        m.page_occupancy.set(self.cache.occupancy)
+        walloc = self.cache.window_allocator
+        if walloc is not None:
+            m.window_page_occupancy.set(walloc.occupancy)
+            m.window_pages_released.inc(
+                self.cache.window_pages_released
+                - self._window_released_mirrored)
+            self._window_released_mirrored = self.cache.window_pages_released
         if self.slo is not None \
                 and self.engine_steps % self._slo_every == 0:
             self.slo.observe(m.snapshot(), step=self.engine_steps)
@@ -1708,10 +1766,13 @@ class ServingEngine:
                       start=start, nvalid=nvalid,
                       last=int(start + nvalid >= n), puts=1,
                       h2d_bytes=self._chunk_layout.nbytes()):
+            if c.window_allocator is not None:
+                c.ensure_window(slot, start + nvalid - 1)
             packed = self._chunk_layout.pack(
-                block_tables=c.block_tables[slot], ids=ids,
+                block_tables=c.block_tables[slot],
+                window_tables=c.window_tables[slot], ids=ids,
                 start=np.int32(start), nvalid=np.int32(nvalid),
-                adapter=self.adapter_idx[slot])
+                adapter=self.adapter_idx[slot], slot=np.int32(slot))
             c.pools, logits = self._prefill_chunk(
                 self.params, c.pools, self._put_step_args(packed),
                 self._adapters_args())
@@ -1807,7 +1868,14 @@ class ServingEngine:
             live_tokens=int(self.cache.lengths[active_slots].sum()),
             read_tokens=(self._spec_k + 1) * geom.num_slots
             * geom.slot_window,
-            sampling_slots=sampling_slots)
+            sampling_slots=sampling_slots,
+            # what the step touches beside the paged rows, constants of
+            # the geometry like ``read_tokens`` (0 for a model with
+            # neither): the slots whose recurrent state it reads and
+            # writes, and the columns one window layer's gather reads
+            state_slots=geom.num_slots if self._stateful else 0,
+            window_read_tokens=geom.num_slots * geom.window_gather_pages
+            * geom.page_size)
 
     def _sampling_slots(self, active_slots: List[int]) -> int:
         """Running slots whose request samples (temperature > 0): with
@@ -1837,7 +1905,7 @@ class ServingEngine:
                     self.scheduler.running[slot].generated)
             packed = self._decode_layout.pack(
                 c.geom.num_slots, block_tables=c.block_tables,
-                lengths=c.lengths, tokens=c.tokens, active=active,
+                window_tables=c.window_tables, lengths=c.lengths, tokens=c.tokens, active=active,
                 top_k=self.samp_top_k, seed=self.samp_seed,
                 gen_pos=self.gen_pos, temp=self.samp_temp,
                 top_p=self.samp_top_p, adapter=self.adapter_idx)

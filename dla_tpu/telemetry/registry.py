@@ -250,6 +250,20 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/kv_bytes_per_token", "gauge", "bytes",
        "bytes one cached token takes in the paged pool over every layer "
        "(keys and values, or one latent row)", "step"),
+    _s("serving/window_bytes_per_token", "gauge", "bytes",
+       "bytes a token takes in the window pool while inside the window, "
+       "over its layers (0 without window layers)", "step"),
+    _s("serving/state_bytes_per_slot", "gauge", "bytes",
+       "bytes of recurrent state a slot holds whatever its length (0 "
+       "without state-space layers)", "step"),
+    _s("serving/kv_shared_readers", "gauge", "layers",
+       "layers that read the one paged attention layer's rows, itself "
+       "included (1 for a model of one kind of layer)", "step"),
+    _s("serving/window_page_occupancy", "gauge", "fraction",
+       "window pool pages owned over pages it has", "step"),
+    _s("serving/window_pages_released", "counter", "pages",
+       "window pages given back to the allocator from behind the "
+       "window", "step"),
     _s("serving/tokens_generated", "counter", "tokens", "", "step"),
     _s("serving/ttft_ms", "histogram", "ms",
        "time to first token (arrival -> first emit)", "step"),

@@ -145,8 +145,12 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
                              "puts", "h2d_bytes")),
     "serve_chunk_fetch": ("engine host loop", ("rid",)),
     "serve_first_token": ("engine host loop", ("rid",)),
+    # ``state_slots`` / ``window_read_tokens``: the slots whose recurrent
+    # state the step reads and writes, and the columns one window layer's
+    # gather reads (0 for a model with neither)
     "serve_decode": ("KV pool", ("slots", "live_tokens", "read_tokens",
-                                 "sampling_slots")),
+                                 "sampling_slots", "state_slots",
+                                 "window_read_tokens")),
     # ``puts`` / ``h2d_bytes``: what the dispatch sent to the device
     "serve_decode_args": ("engine host loop", ("puts", "h2d_bytes")),
     "serve_decode_dispatch": ("engine host loop", ()),
@@ -176,6 +180,20 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "train_logging": ("trainer", ()),
     "train_eval": ("trainer", ()),
     "train_checkpoint_stall": ("trainer", ()),
+}
+
+
+#: ``jax.named_scope`` frames the model puts around its mixers and steps,
+#: read by the benchmark's scope readers from the compiled programs:
+#: scope -> layer as PERF.md section 3 names it.
+DEVICE_SCOPES: Dict[str, str] = {
+    "embed": "model step", "optimizer": "trainer",
+    "step_metrics": "trainer", "head_loss": "model step",
+    "mla_attention": "model step", "moe_router": "experts",
+    "moe_experts": "experts", "moe_shared": "experts",
+    "ssm_mixer": "model step", "gmu": "model step",
+    "swa_attention": "model step", "full_attention": "model step",
+    "cross_attention": "model step",
 }
 
 

@@ -271,6 +271,10 @@ def test_arch_overrides_cover_every_model_config_field():
         "max_seq_length",  # handled explicitly above the whitelist
         "flash_block_q", "flash_block_k",
         "lora_r", "lora_alpha", "lora_dropout", "lora_targets",  # lora block
+        # the per-layer spec and what it implies: the architecture's own
+        # shape, read from the model's config like the widths above
+        "layers", "norm", "ssm_state_size", "ssm_conv_width", "ssm_expand",
+        "ssm_dt_rank",
     }
     fields = {f.name for f in dataclasses.fields(ModelConfig)}
     candidates = fields - excluded

@@ -101,14 +101,20 @@ ENGINES = {
     # decode phase also marks what its dropless routing did
     "experts": dict(page_size=4, num_pages=32, num_slots=2, max_model_len=32,
                     prefill_chunk=4),
+    # layers of several kinds (the tiny-sambay preset): a window pool and
+    # per-slot state beside the paged rows; two block-table rows a slot
+    # in the one packed array
+    "state_space": dict(page_size=4, num_pages=32, num_slots=2,
+                        max_model_len=32, prefill_chunk=4),
 }
 
 
 @pytest.mark.parametrize("kind", sorted(ENGINES))
 def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
     model, params = model_and_params
-    if kind == "experts":
-        model = Transformer(get_model_config("tiny-mla-moe"))
+    if kind in ("experts", "state_space"):
+        model = Transformer(get_model_config(
+            "tiny-mla-moe" if kind == "experts" else "tiny-sambay"))
         params = model.init(jax.random.key(7))
     gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
                            eos_token_id=-1, pad_token_id=0)
@@ -184,6 +190,15 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
         assert phase[1]["live_tokens"] == held
         assert 1 <= phase[1]["slots"] <= geom.num_slots
         assert phase[1]["sampling_slots"] == 0      # greedy requests
+        # constants of the geometry, 0 for a model with neither
+        if kind == "state_space":
+            assert phase[1]["state_slots"] == geom.num_slots
+            assert phase[1]["window_read_tokens"] == (
+                geom.num_slots * geom.window_gather_pages * geom.page_size)
+            assert geom.window_gather_pages == 8 // 4 + 1
+        else:
+            assert phase[1]["state_slots"] == 0
+            assert phase[1]["window_read_tokens"] == 0
     assert eng.metrics.decode_steps_sampled.value == 0
     # what each dispatch sent to the device: one packed array, its bytes
     # fixed by the geometry (a speculative round's two programs share it)

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from dla_tpu.generation.engine import GenerationConfig, build_generate_fn
-from dla_tpu.models.config import get_model_config
+from dla_tpu.models.config import CacheArray, get_model_config
 from dla_tpu.models.transformer import Transformer
 from dla_tpu.serving import (
     PageAllocator,
@@ -89,8 +89,8 @@ class _ModelStub:
     cfg = _Cfg()
     adtype = jnp.float32
 
-    def cache_rows(self):       # keys and values of [KH, D] per token
-        return ((1, 2), (1, 2))
+    def cache_spec(self):       # keys and values of [KH, D] per token
+        return (CacheArray("paged", 1, (1, 2), jnp.float32),) * 2
 
 
 def _sched(page_size=4, num_pages=16, num_slots=2, pages_per_slot=4,

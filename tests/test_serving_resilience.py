@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dla_tpu.models.config import CacheArray
 from dla_tpu.resilience.faults import FaultPlan
 from dla_tpu.serving import (
     TERMINAL_STATES,
@@ -91,8 +92,8 @@ class _ModelStub:
     cfg = _Cfg()
     adtype = jnp.float32
 
-    def cache_rows(self):       # keys and values of [KH, D] per token
-        return ((1, 2), (1, 2))
+    def cache_spec(self):       # keys and values of [KH, D] per token
+        return (CacheArray("paged", 1, (1, 2), jnp.float32),) * 2
 
 
 def _sched(page_size=4, num_pages=16, num_slots=2, pages_per_slot=4):
@@ -183,7 +184,7 @@ def test_allocator_reclaim_cached_flushes_to_free_pool():
 @pytest.fixture(scope="module")
 def serve_setup():
     from dla_tpu.generation.engine import GenerationConfig
-    from dla_tpu.models.config import get_model_config
+    from dla_tpu.models.config import CacheArray, get_model_config
     from dla_tpu.models.transformer import Transformer
     cfg = get_model_config("tiny")
     model = Transformer(cfg)
