@@ -303,6 +303,33 @@ def phase_kernels(s: Smoke) -> None:
                     f"{h}q/{kh}kv x{d} {'int8' if int8 else 'bf16'} cache]",
                     lambda int8=int8: decode_vs_xla(int8), 2e-2)
 
+        # the same cache as pages of 16 scattered over a pool, slots at
+        # ragged fills: the paged kernel walks the block table
+        def paged_vs_xla(b=b, q1=q1, kc=kc, vc=vc, kn=kn, vn=vn) -> float:
+            from dla_tpu.ops.paged_attention import paged_decode_attention
+            page = 16
+            pps = cache // page
+            order = rs.permutation(b * pps) + 1      # page 0: the trash
+            tables = jnp.asarray(order.reshape(b, pps), jnp.int32)
+            lengths = jnp.asarray(
+                rs.randint(0, fill + 1, size=b), jnp.int32).at[0].set(fill)
+
+            def pool(x):
+                pages = x.reshape(b * pps, page, kh, d)
+                flat = jnp.zeros((b * pps + 1, page, kh, d), x.dtype)
+                return flat.at[tables.reshape(-1)].set(pages)[None]
+            out = paged_decode_attention(
+                q1[:, 0], pool(kc), pool(vc), tables, lengths, kn[:, 0],
+                vn[:, 0], layer=0, window=k["window"], interpret=interpret)
+            ref = decode_attention(
+                q1, kc, vc, kn, vn, kv_valid=pos < lengths[:, None],
+                q_positions=lengths[:, None], kv_positions=pos,
+                window=k["window"])
+            return rel_err(out, ref[:, 0])
+
+        verdict(f"paged_decode_attention [B{b} pages of 16, fills 0.."
+                f"{fill} of {cache} {h}q/{kh}kv x{d}]", paged_vs_xla, 2e-2)
+
     check(not failures, f"{len(failures)} kernel check(s) failed: "
           + "; ".join(failures))
 

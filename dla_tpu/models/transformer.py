@@ -22,8 +22,10 @@ Replaces the reference's HF ``AutoModelForCausalLM`` wrapper
 """
 from __future__ import annotations
 
+import importlib
 import math
 import sys
+import threading
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -33,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 from dla_tpu.models.config import CacheArray, ModelConfig
 from dla_tpu.models.hybrid import HybridStack
 from dla_tpu.parallel.mesh import auto_axes
+from dla_tpu.utils.compile_cache import cached_bytecode
 from dla_tpu.ops.attention import (
     block_decode_attention,
     causal_attention,
@@ -69,6 +72,21 @@ def _flash_tileable(t: int) -> bool:
     if jax.default_backend() == "cpu":
         return t % min(128, t) == 0
     return t >= 128 and t % 128 == 0
+
+
+def _tpu_backend() -> bool:
+    """Whether programs traced now are compiled for a TPU (the Pallas
+    kernels that have no XLA twin on other backends key off this)."""
+    return jax.default_backend() == "tpu"
+
+
+def _import_paged_kernel():
+    """The module that holds the paged decode kernel. It imports Pallas,
+    so nothing imports it at module level; its bytecode (and Pallas's) is
+    kept beside the compile cache: on the chip's host 0.4 s from there,
+    1.2 s from source (PERF.md, PR 35)."""
+    with cached_bytecode():
+        return importlib.import_module("dla_tpu.ops.paged_attention")
 
 
 def _flash_mesh():
@@ -152,6 +170,7 @@ class Transformer:
         if cfg.layers is not None:
             self.hybrid = HybridStack(self)
             self._softmax_scale = cfg.head_dim_ ** -0.5
+        self._start_paged_kernel_import()
 
     # ------------------------------------------------------- storage layout
 
@@ -238,6 +257,28 @@ class Transformer:
 
     def init(self, rng: jax.Array) -> Params:
         return self.to_storage_layout(self._init_canonical(rng))
+
+    def _start_paged_kernel_import(self) -> None:
+        """If this model's paged decode step will run the Pallas kernel
+        (``_paged_kernel_rows`` on a TPU backend), start importing the
+        kernel's module on a daemon thread. The import is 0.4 s of Python
+        from the bytecode cache (1.2 s from source: Pallas, most of it)
+        that the first decode trace would otherwise wait for; every entry
+        point builds the model before it makes the weights, so started
+        here it is over before an engine exists. A thread hides Python
+        only behind a wait (5.3 s for the 1.3 s import beside a main
+        thread that runs Python): with the import at 1.2 s it cost the
+        weights phase 0.7 to 1.0 s here and the end of ``init``'s trace
+        was the better place; at 0.4 s it costs that phase nothing that
+        shows and the set-up 0.35 s less than from there (PERF.md, PR 35:
+        the phases of each build). The in-line import in
+        ``paged_decode_kernel`` stays and is what correctness rests on:
+        the per-module import lock makes it wait for this one, never
+        race it."""
+        if self._paged_kernel_rows and _tpu_backend():
+            threading.Thread(
+                target=_import_paged_kernel,
+                name="dla-paged-kernel-import", daemon=True).start()
 
     def _init_canonical(self, rng: jax.Array) -> Params:
         cfg = self.cfg
@@ -800,6 +841,36 @@ class Transformer:
         if cfg.latent_attention:
             return ((1, cfg.latent_row_width_),)
         return ((cfg.num_kv_heads, cfg.head_dim_),) * 2
+
+    @property
+    def _paged_kernel_rows(self) -> bool:
+        """Whether this model's cached rows are ones the paged decode
+        kernel (ops/paged_attention.py) reads: the dense pair of keys
+        and values ``[K, D]`` with ``D`` a multiple of 128 lanes, the
+        kv heads of a token filling whole 32-bit words, a GQA group
+        inside the kernel's 8 sublanes. Latent rows (one pool), a
+        per-layer spec (models/hybrid.py's own gathers) and int8 pages
+        are not: they keep the gather."""
+        cfg = self.cfg
+        return (not cfg.latent_attention and self.hybrid is None
+                and not self._kv_int8
+                and cfg.head_dim_ % 128 == 0
+                and (cfg.num_kv_heads * self.adtype.itemsize) % 4 == 0
+                and cfg.num_heads // cfg.num_kv_heads <= 8)
+
+    def paged_decode_kernel(self):
+        """The module of the paged attention kernel if a
+        ``decode_step_paged`` traced now, under the ambient mesh, would
+        run it, else None (the gather). What is observed, no knob: the
+        rows (``_paged_kernel_rows``), a TPU backend (Mosaic compiles
+        for nothing else), and no multi-device auto mesh (a
+        ``pallas_call`` has no SPMD rule: GSPMD would replicate the
+        pools). Imports the module the first time, unless the
+        constructor's thread has by then."""
+        if (self._paged_kernel_rows and _tpu_backend()
+                and _flash_mesh() is None):
+            return _import_paged_kernel()
+        return None
 
     def cache_spec(self) -> Tuple[CacheArray, ...]:
         """Every array a cache manager holds for this model, in the
@@ -2070,12 +2141,28 @@ class Transformer:
                       attention):
         """The layer scan the three paged steps share, over a block-paged
         pool (dla_tpu/serving/kv_blocks.py). ``x`` [B, T, D] embedded
-        tokens at absolute ``positions`` [B, T]. Block l gathers each
-        row's pages out of ITS layer of every pool into the row's [S]
-        window, attends jointly over that window and the tokens' own
-        fresh rows through ``attention`` (``decode_attention`` for one
-        token, ``block_decode_attention`` for several), and writes the
-        fresh rows into its layer at ``write_pages`` / ``write_offs``.
+        tokens at absolute ``positions`` [B, T]. Block l attends jointly
+        over the row's cached columns in ITS layer of every pool and the
+        tokens' own fresh rows, and writes the fresh rows into its layer
+        at ``write_pages`` / ``write_offs``. The cached columns are read
+        one of two ways:
+
+        - the gather (every program but the one below): each row's pages
+          are gathered into the row's whole [S] window, whatever its
+          fill, and ``attention`` (``decode_attention`` for one token,
+          ``block_decode_attention`` for several) masks it by
+          ``view["valid"]`` / ``view["pos"]``;
+        - the paged kernel (ops/paged_attention.py), when the step is one
+          token a row (``attention is decode_attention``), the rows are
+          ``_paged_kernel_rows``, programs compile for a TPU and no
+          multi-device mesh is in force (``paged_decode_kernel``): it
+          walks ``block_tables`` and reads pages ``0 .. ceil(lengths /
+          page) - 1`` only. It takes the mask from ``view["lengths"]``
+          alone: column c at position c, valid iff c < lengths, which is
+          what ``PagedKVCache`` keeps for a running slot and what
+          ``valid`` / ``pos`` say in every caller
+          (``ServingEngine._unpack_decode``); those two are not read on
+          this path.
 
         Gather and write live inside the scan, a layer at a time, with
         the pools riding the scan's carry (updated in place): gathering
@@ -2084,7 +2171,8 @@ class Transformer:
         write with the layer axis in its window relays the pool out again
         (on a v5e, two thirds of a decode step and 3 GiB of temporaries);
         pools scanned as inputs and outputs are copied slab by slab,
-        three times a layer (PERF.md, PR 29).
+        three times a layer (PERF.md, PR 29). The kernel takes the whole
+        pools and the layer index for the same reason.
 
         Returns (hidden after the final norm [B, T, D], the pools with
         the fresh rows written, and int32 [2] = (held experts that
@@ -2119,15 +2207,26 @@ class Transformer:
             # stands in for them
             stack = {k: layers.pop(k) for k in ("w_gate", "w_up", "w_down")}
 
+        kernel = (self.paged_decode_kernel()
+                  if attention is decode_attention else None)
+
         def body(carry, layer):
             h, pools = carry                  # pool [L, pages, page, h, w]
             l = layer["layer_index"]
             if stack is not None:
                 layer = {**layer, "expert_stack": stack}
-            cached = [p[l, tables].reshape(b, window, *p.shape[3:])
-                      for p in pools]
+            if kernel is None:
+                cached = [p[l, tables].reshape(b, window, *p.shape[3:])
+                          for p in pools]
 
             def attend(q, k, v):
+                if kernel is not None:
+                    return kernel.paged_decode_attention(
+                        q[:, 0], pools[0], pools[1], tables,
+                        view["lengths"], k[:, 0], v[:, 0], layer=l,
+                        window=self._layer_window(layer),
+                        softmax_scale=self._softmax_scale,
+                        logit_softcap=cfg.attn_logit_softcap)[:, None]
                 # dense: (keys, values); latent: the one pool of rows is
                 # both (Transformer._latent_absorbed)
                 return attention(
@@ -2156,17 +2255,21 @@ class Transformer:
                           adapters: Optional[Params] = None,
                           ) -> Tuple[jnp.ndarray, Tuple, jnp.ndarray]:
         """One decode step against a block-paged pool — the sibling of
-        ``decode_step`` for the serving engine. Each sequence's pages are
-        gathered into its [S] window by its block table, a layer at a
-        time; the step's fresh rows are written where the caller says.
+        ``decode_step`` for the serving engine. Each sequence's cached
+        columns are read through its block table, a layer at a time: the
+        live pages alone by the paged kernel where ``_paged_layers`` says
+        it runs, else every page gathered into the [S] window; the
+        step's fresh rows are written where the caller says.
 
         ``view``:
           pools         tuple, one [L, pages, page, heads, width] array
                         per ``cache_rows()`` entry (keys and values, or
                         the latent rows; activation dtype)
           block_tables  [B, pages/slot]  physical page ids per row
-          valid         [B, S]           columns that may be attended
-          pos           [B, S]           logical position per column
+          valid         [B, S]           columns that may be attended:
+                        column c iff c < lengths (the kernel path reads
+                        ``lengths`` alone)
+          pos           [B, S]           logical position per column = c
           lengths       [B]              true tokens so far = this query's pos
           write_pages, write_offs  [B, T]  physical (page, offset) each
                         fresh row is written to (the trash page for rows

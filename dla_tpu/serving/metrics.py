@@ -49,6 +49,11 @@ class ServingMetrics:
         # whose sampler filtered and drew instead of taking the arg-max
         self.decode_steps_sampled = r.counter(
             "serving/decode_steps_sampled")
+        # decode steps dispatched to a program that holds the paged
+        # attention kernel (ops/paged_attention.py): the steps that read
+        # the running slots' live pages, not every slot's whole window
+        self.decode_steps_paged_kernel = r.counter(
+            "serving/decode_steps_paged_kernel")
         # what the decode and chunk dispatches sent to the device: one
         # packed array each (serving/step_args.py), and its bytes
         self.step_arg_puts = r.counter("serving/step_arg_puts")
@@ -131,6 +136,8 @@ class ServingMetrics:
             "serving/decode_steps": float(self.decode_steps.value),
             "serving/decode_steps_sampled": float(
                 self.decode_steps_sampled.value),
+            "serving/decode_steps_paged_kernel": float(
+                self.decode_steps_paged_kernel.value),
             "serving/step_arg_puts": float(self.step_arg_puts.value),
             "serving/step_arg_bytes": float(self.step_arg_bytes.value),
             "serving/moe/experts_hit": float(self.moe_experts_hit.value),

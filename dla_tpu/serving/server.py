@@ -258,6 +258,9 @@ class ServingEngine:
         # rows would have to snapshot that state too, and nothing does
         # yet, so each such feature is refused here, by name
         self._stateful = any(a.kind == "state" for a in model.cache_spec())
+        # whether the one-token step's program holds the paged attention
+        # kernel; asked of the model at the first decode span
+        self._kernel_decode: Optional[bool] = None
         if self._stateful:
             for on, what in (
                     (cfg.prefix_cache, "ServingConfig.prefix_cache (a "
@@ -638,7 +641,9 @@ class ServingEngine:
 
     def _decode_fn(self, params, pools, packed, adapters=None):
         """One static-shape decode step over every slot: the model's
-        paged step gathers each slot's pages into its [S] window and
+        paged step reads each slot's cached columns through its block
+        table (the live pages by the paged kernel, or the whole [S]
+        window gathered: ``Transformer._paged_layers``) and
         writes the fresh row at the (page, offset) computed here; the
         result is sampled PER-ROW (each slot's traced temperature/top_p/
         top_k/seed, keyed by the slot's generated-token index). Free
@@ -1857,22 +1862,40 @@ class ServingEngine:
                      sampling_slots: int) -> annotate:
         """The decode phase's span. Its arguments are the KV read as the
         host knows it on entry: running slots, the tokens they hold, and
-        the columns the step's gathers read (every slot's whole window,
-        once per forward of the round, whatever the fill: a constant of
-        the geometry until the read is bounded by the fill); and how
-        many of the running slots sample."""
+        the columns the step's program reads; and how many of the
+        running slots sample. ``read_tokens`` follows the program: where
+        the one-token step holds the paged attention kernel
+        (``Transformer.paged_decode_kernel``) it is the running slots'
+        live pages x the page size, from the host mirror of the lengths;
+        where it gathers (latent rows, a per-layer spec, a backend that
+        is no TPU, and the speculative verify forward whatever the
+        model) it is every slot's whole window, a constant of the
+        geometry."""
         geom = self.cache.geom
+        if self._kernel_decode is None:
+            # asked once, here: the first dispatch, which traces the
+            # program, follows in this same context
+            self._kernel_decode = self.model.paged_decode_kernel() is not None
+        # dla: disable=host-sync-in-hot-loop -- host numpy mirror of the slot lengths, no device fetch
+        lengths = self.cache.lengths[active_slots]
+        whole = geom.num_slots * geom.slot_window
+        if self._kernel_decode:
+            # the one-token forwards (the step, or a round's K drafts)
+            # read live pages; a round's verify forward gathers
+            live_pages = int((-(-lengths // geom.page_size)).sum())
+            read_tokens = max(self._spec_k, 1) * live_pages \
+                * geom.page_size + (whole if self._spec_k else 0)
+        else:
+            read_tokens = (self._spec_k + 1) * whole
         return annotate(
             "serve_decode", slots=len(active_slots),
-            # dla: disable=host-sync-in-hot-loop -- host numpy mirror of the slot lengths, no device fetch
-            live_tokens=int(self.cache.lengths[active_slots].sum()),
-            read_tokens=(self._spec_k + 1) * geom.num_slots
-            * geom.slot_window,
+            live_tokens=int(lengths.sum()),
+            read_tokens=read_tokens,
             sampling_slots=sampling_slots,
             # what the step touches beside the paged rows, constants of
-            # the geometry like ``read_tokens`` (0 for a model with
-            # neither): the slots whose recurrent state it reads and
-            # writes, and the columns one window layer's gather reads
+            # the geometry (0 for a model with neither): the slots whose
+            # recurrent state it reads and writes, and the columns one
+            # window layer's gather reads
             state_slots=geom.num_slots if self._stateful else 0,
             window_read_tokens=geom.num_slots * geom.window_gather_pages
             * geom.page_size)
@@ -1951,6 +1974,8 @@ class ServingEngine:
             self.metrics.decode_steps.inc()
             if sampling_slots:
                 self.metrics.decode_steps_sampled.inc()
+            if self._kernel_decode:
+                self.metrics.decode_steps_paged_kernel.inc()
             emitted: List[Tuple[int, int]] = []
             with annotate("serve_emit", slots=len(active_slots)):
                 for slot in active_slots:
@@ -2009,6 +2034,8 @@ class ServingEngine:
             self.metrics.decode_steps.inc()
             if sampling_slots:
                 self.metrics.decode_steps_sampled.inc()
+            if self._kernel_decode:
+                self.metrics.decode_steps_paged_kernel.inc()
             emitted: List[Tuple[int, int]] = []
             with annotate("serve_emit", slots=len(active_slots)):
                 for slot in active_slots:

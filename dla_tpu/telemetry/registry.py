@@ -236,6 +236,10 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/decode_steps_sampled", "counter", "steps",
        "decode steps with a running slot of temperature > 0 (the "
        "sampler's filter-and-draw branch)", "step"),
+    _s("serving/decode_steps_paged_kernel", "counter", "steps",
+       "decode steps dispatched to a program that holds the paged "
+       "attention kernel (reads the running slots' live pages, not "
+       "every slot's whole window)", "step"),
     _s("serving/step_arg_puts", "counter", "puts",
        "host-to-device puts of per-step arguments by the decode and "
        "prefill-chunk dispatches (one packed array each)", "step"),

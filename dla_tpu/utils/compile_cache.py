@@ -11,7 +11,9 @@ name, pid or timestamp: a cache that moves between runs never hits.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 from pathlib import Path
 
 import jax
@@ -35,3 +37,34 @@ def enable_compile_cache() -> str:
         return env_dir
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
     return str(DEFAULT_CACHE_DIR)
+
+
+@contextlib.contextmanager
+def cached_bytecode():
+    """Imports made inside keep their Python bytecode beside the XLA
+    cache (``<cache dir>/pycache``) and find it there the next time.
+
+    For the modules a process imports late and at a cost: the Pallas
+    stack behind a kernel is 129 modules, and where the interpreter
+    keeps no bytecode (``PYTHONDONTWRITEBYTECODE=1`` and no
+    ``__pycache__`` in the installation: most containers, this
+    repository's among them) 0.83 s of its 1.24 s import is
+    ``compile()`` of their sources, at every process start (PERF.md,
+    PR 35); from the cache the import is 0.2 s. Only while the
+    persistent compile cache is on and local: the same operator's
+    choice, the same directory, the same staleness rule as any
+    ``.pyc`` (source size and mtime). The interpreter's two switches
+    are process-wide, so another thread's imports during the block land
+    in the cache too; that is harmless, and both are restored."""
+    root = jax.config.jax_compilation_cache_dir
+    if (not root or not jax.config.jax_enable_compilation_cache
+            or "://" in root or sys.pycache_prefix is not None):
+        yield
+        return
+    before = sys.pycache_prefix, sys.dont_write_bytecode
+    sys.pycache_prefix = os.path.join(os.path.abspath(root), "pycache")
+    sys.dont_write_bytecode = False
+    try:
+        yield
+    finally:
+        sys.pycache_prefix, sys.dont_write_bytecode = before

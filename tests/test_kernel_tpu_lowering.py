@@ -75,6 +75,32 @@ def test_decode_attention_lowers_for_tpu(batch, cache_dtype):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("slots", [16, 64])
+@pytest.mark.parametrize("h,kh,d,softcap", [
+    (32, 8, 128, 0.0),      # mistral-7b: the dense serving cells
+    (16, 8, 256, 50.0),     # gemma-2 9b: two 128-lane copies a page
+    (16, 16, 256, 0.0),     # gemma 7b: no grouping
+    (32, 4, 128, 0.0),      # a group of 8
+], ids=["mistral", "gemma2", "gemma", "group8"])
+def test_paged_decode_attention_lowers_for_tpu(slots, h, kh, d, softcap):
+    """The paged kernel at the serving cells' geometry: the pools whole
+    ([layers, pages, 16, K, D], as stored), 2,048-token windows."""
+    from dla_tpu.ops.paged_attention import paged_decode_attention
+    pool = _sds((4, 128 * slots // 8, 16, kh, d))
+
+    def attend(q, kp, vp, tables, lengths, kn, vn, layer, window):
+        return paged_decode_attention(
+            q, kp, vp, tables, lengths, kn, vn, layer=layer, window=window,
+            logit_softcap=softcap, interpret=False)
+
+    text = _tpu_text(
+        attend, _sds((slots, h, d)), pool, pool,
+        _sds((slots, 128), jnp.int32), _sds((slots,), jnp.int32),
+        _sds((slots, kh, d)), _sds((slots, kh, d)), _sds((), jnp.int32),
+        _sds((), jnp.int32))
+    assert text.count("tpu_custom_call") == 1
+
+
 def test_sharded_train_forward_keeps_the_flash_kernel_on_tpu(
         mesh8, monkeypatch):
     """Under a multi-device mesh the model wraps the flash call in a
