@@ -15,9 +15,12 @@ class LayerSpec(NamedTuple):
     """What one layer is, as the model code and the cache manager read
     it. ``mixer`` is the token mixer in front of the block's MLP:
 
-      attention            multi-head / grouped-query attention over the
-                           layer's own keys and values
-      latent_attention     multi-head latent attention (one latent row)
+      attention            multi-head / grouped- / multi-query attention
+                           over the layer's own keys and values; inside
+                           ``ModelConfig.layers`` it is the plain form
+                           (no rotary, bias, softcap or window)
+      latent_attention     multi-head latent attention (one latent row;
+                           the homogeneous stack's alone)
       ssm                  selective state-space layer (Mamba-1); its
                            scan output is also the memory the next
                            ``gmu`` layers gate
@@ -26,12 +29,14 @@ class LayerSpec(NamedTuple):
       gmu                  gated memory unit (arXiv:2507.06607): gates the
                            memory of the nearest ``ssm`` layer below
       cross_diff_attention differential attention whose keys and values
-                           are those of the nearest ``paged``
+                           are those of the one ``paged``
                            ``diff_attention`` layer below
 
     ``cache`` is what the layer keeps for a sequence between steps:
 
-      paged         rows a token, for as long as the request lives
+      paged         rows a token, for as long as the request lives;
+                    a model may have any number of such layers, each
+                    with rows of its own
       paged_window  rows a token, dropped once behind every future
                     query's ``window``
       state         a fixed-size recurrent state a sequence
@@ -260,6 +265,10 @@ class ModelConfig:
     ssm_conv_width: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0
+    # whether the mixer RMS-norms dt, B and C between the projection that
+    # makes them and their use (HF JambaMambaMixer's dt_layernorm /
+    # b_layernorm / c_layernorm, eps = rms_norm_eps)
+    ssm_inner_norms: bool = False
 
     def __post_init__(self):
         if self.layers is not None:
@@ -321,15 +330,19 @@ class ModelConfig:
             raise ValueError(
                 f"`layers` has {len(self.layers)} entries for "
                 f"num_layers={self.num_layers}")
-        full = memory = False
+        mixers = {spec.mixer for spec in self.layers}
+        paged = [l for l, spec in enumerate(self.layers)
+                 if spec.cache == "paged"]
+        memory = False
         for l, spec in enumerate(self.layers):
             if spec.mixer not in MIXERS or spec.cache not in CACHE_KINDS:
                 raise ValueError(f"layer {l}: unknown spec {spec}")
-            if spec.mixer in ("attention", "latent_attention"):
+            if spec.mixer == "latent_attention":
                 raise ValueError(
                     f"layer {l}: mixer {spec.mixer!r} is run by the "
                     "homogeneous stack (leave `layers` unset)")
             want = {"ssm": ("state",), "gmu": ("none",),
+                    "attention": ("paged",),
                     "cross_diff_attention": ("shared",),
                     "diff_attention": ("paged", "paged_window")}[spec.mixer]
             if spec.cache not in want or (
@@ -339,12 +352,14 @@ class ModelConfig:
                     f"{spec.cache!r} and window {spec.window}")
             if spec.mixer == "gmu" and not memory:
                 raise ValueError(f"layer {l}: gmu with no ssm layer below")
-            if spec.mixer == "cross_diff_attention" and not full:
+            if spec.mixer == "cross_diff_attention" and not (
+                    len(paged) == 1 and paged[0] < l and
+                    self.layers[paged[0]].mixer == "diff_attention"):
                 raise ValueError(
-                    f"layer {l}: cross attention with no paged "
-                    "diff_attention layer below")
+                    f"layer {l}: cross attention reads the one paged "
+                    "diff_attention layer below it; the spec has paged "
+                    f"layers {paged}")
             memory = memory or spec.mixer == "ssm"
-            full = full or spec.cache == "paged"
         if (self.kv_cache_dtype != "bfloat16" or self.lora_r > 0
                 or self.num_experts or self.latent_attention
                 or self.sliding_window):
@@ -353,12 +368,23 @@ class ModelConfig:
                 "pages and its recurrent state have no quantised form), "
                 "LoRA adapters, routed experts or latent attention, and "
                 "states its windows in `layers`, not in sliding_window")
-        if self.num_heads % 2 or self.num_kv_heads % 2 or (
-                self.num_heads // 2) % (self.num_kv_heads // 2):
+        if "attention" in mixers and (
+                self.attention_bias or self.attn_logit_softcap):
+            raise ValueError(
+                "mixer 'attention' inside `layers` is the plain form: no "
+                "projection biases (attention_bias) and no "
+                "attn_logit_softcap")
+        if mixers & {"diff_attention", "cross_diff_attention"} and (
+                self.num_heads % 2 or self.num_kv_heads % 2 or (
+                    self.num_heads // 2) % (self.num_kv_heads // 2)):
             raise ValueError(
                 "differential attention pairs heads: num_heads and "
                 "num_kv_heads must be even, query pairs a multiple of "
                 "key/value pairs")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads {self.num_heads} is no multiple of "
+                f"num_kv_heads {self.num_kv_heads}")
 
     @property
     def layer_spec(self) -> Tuple[LayerSpec, ...]:
@@ -635,6 +661,29 @@ register_model("tiny-sambay", ModelConfig(
     vocab_size=512, hidden_size=64, intermediate_size=128,
     num_layers=12, num_heads=4, num_kv_heads=2, max_seq_length=256,
     tie_embeddings=True, norm="layer", layers=sambay_layers(12, 8), ssm_state_size=4,
+    param_dtype="float32", dtype="float32", remat="none"))
+
+
+def jamba_layers(num_layers: int, period: int,
+                 offset: int) -> Tuple[LayerSpec, ...]:
+    """The Jamba layout (arXiv:2403.19887; HF
+    ``JambaConfig.layers_block_type``): layer l is plain attention with
+    rows of its own iff ``l % period == offset``, else a state-space
+    layer."""
+    return tuple(
+        LayerSpec("attention", "paged") if l % period == offset
+        else LayerSpec("ssm", "state") for l in range(num_layers))
+
+
+# the Jamba layout at toy widths, one attention layer in 6 from layer 2:
+# M x 2, A, M x 5, A, M x 3, five runs as the published 28 layers cut (a
+# period over MAX_PERIOD is not looked for); 4 query heads over ONE key /
+# value head of 16, state size 4, dt / B / C normed
+register_model("tiny-jamba", ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=128,
+    num_layers=12, num_heads=4, num_kv_heads=1, max_seq_length=256,
+    tie_embeddings=True, rms_norm_eps=1e-6, layers=jamba_layers(12, 6, 2),
+    ssm_state_size=4, ssm_inner_norms=True,
     param_dtype="float32", dtype="float32", remat="none"))
 
 # HF repo-id aliases so reference configs keep working verbatim

@@ -64,6 +64,19 @@ def _validated_rope_scaling(hf_cfg):
     return rs
 
 
+def _mamba_fields(hf_cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The Mamba-1 sizes of a config.json, with the defaults the HF
+    config classes that carry them share (``mamba_d_state`` 16,
+    ``mamba_d_conv`` 4, ``mamba_expand`` 2, ``mamba_dt_rank`` "auto" =
+    ceil(hidden / 16))."""
+    dt_rank = hf_cfg.get("mamba_dt_rank", "auto")
+    return dict(
+        ssm_state_size=int(hf_cfg.get("mamba_d_state", 16)),
+        ssm_conv_width=int(hf_cfg.get("mamba_d_conv", 4)),
+        ssm_expand=int(hf_cfg.get("mamba_expand", 2)),
+        ssm_dt_rank=0 if dt_rank == "auto" else int(dt_rank))
+
+
 def _phi4flash_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:
     """Phi-4-mini-flash (SambaY, arXiv:2507.06607): the per-layer spec
     from ``num_hidden_layers``, ``mb_per_layer`` and ``sliding_window``
@@ -74,7 +87,6 @@ def _phi4flash_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:
     from dla_tpu.models.config import sambay_layers
     n_heads = int(hf_cfg["num_attention_heads"])
     n_layers = int(hf_cfg["num_hidden_layers"])
-    dt_rank = hf_cfg.get("mamba_dt_rank", "auto")
     fields = dict(
         vocab_size=int(hf_cfg["vocab_size"]),
         hidden_size=int(hf_cfg["hidden_size"]),
@@ -87,10 +99,50 @@ def _phi4flash_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:
         norm="layer",
         layers=sambay_layers(n_layers, int(hf_cfg["sliding_window"]),
                              int(hf_cfg.get("mb_per_layer", 2))),
-        ssm_state_size=int(hf_cfg.get("mamba_d_state", 16)),
-        ssm_conv_width=int(hf_cfg.get("mamba_d_conv", 4)),
-        ssm_expand=int(hf_cfg.get("mamba_expand", 2)),
-        ssm_dt_rank=0 if dt_rank == "auto" else int(dt_rank))
+        **_mamba_fields(hf_cfg))
+    fields.update(overrides)
+    return ModelConfig(**fields)
+
+
+def _jamba_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:
+    """Jamba (HF ``JambaConfig``): layer l is plain attention iff ``l %
+    attn_layer_period == attn_layer_offset`` (``layers_block_type``),
+    else Mamba-1 with RMSNorms on dt, B and C; RMSNorm, no rotary
+    embedding, a dense gated-SiLU MLP in every layer at ``num_experts``
+    1. The routed variant would put an expert MLP in the layers
+    ``expert_layer_period`` / ``_offset`` choose and a dense one in the
+    others: a per-layer FFN kind the spec'd block does not have, so it
+    refuses by name."""
+    from dla_tpu.models.config import jamba_layers
+    experts = int(hf_cfg.get("num_experts", 1))
+    if experts > 1:
+        raise ValueError(
+            f"model_type 'jamba' with num_experts={experts}: expert MLPs "
+            "in some layers and dense ones in the others is a per-layer "
+            "FFN kind the per-layer spec does not state; only "
+            "num_experts=1 (a dense MLP in every layer) is mapped")
+    if hf_cfg.get("mamba_proj_bias", False):
+        raise ValueError("model_type 'jamba' with mamba_proj_bias=true: "
+                         "the Mamba mixer's in / out projections have no "
+                         "bias here")
+    if hf_cfg.get("sliding_window"):
+        raise ValueError("model_type 'jamba' with a sliding_window: its "
+                         "attention layers are mapped as full attention")
+    n_heads = int(hf_cfg["num_attention_heads"])
+    n_layers = int(hf_cfg["num_hidden_layers"])
+    period = int(hf_cfg.get("attn_layer_period", 8))
+    offset = int(hf_cfg.get("attn_layer_offset", 4))
+    fields = dict(
+        vocab_size=int(hf_cfg["vocab_size"]),
+        hidden_size=int(hf_cfg["hidden_size"]),
+        intermediate_size=int(hf_cfg["intermediate_size"]),
+        num_layers=n_layers, num_heads=n_heads,
+        num_kv_heads=int(hf_cfg.get("num_key_value_heads", n_heads)),
+        rms_norm_eps=float(hf_cfg.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(hf_cfg.get("tie_word_embeddings", False)),
+        max_seq_length=int(hf_cfg.get("max_position_embeddings", 4096)),
+        layers=jamba_layers(n_layers, period, offset),
+        ssm_inner_norms=True, **_mamba_fields(hf_cfg))
     fields.update(overrides)
     return ModelConfig(**fields)
 
@@ -103,6 +155,8 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
         return _phi_config(hf_cfg, overrides)
     if model_type == "phi4flash":
         return _phi4flash_config(hf_cfg, overrides)
+    if model_type == "jamba":
+        return _jamba_config(hf_cfg, overrides)
     n_heads = int(hf_cfg["num_attention_heads"])
     rope_theta = hf_cfg.get("rope_theta") or (
         hf_cfg.get("rope_parameters") or {}).get("rope_theta", 10000.0)

@@ -1,7 +1,9 @@
 """The layer stack of a model whose layers are not all of one kind
-(``ModelConfig.layers``): state-space mixers, differential attention with
-a window or without, gated memory units and cross-attention onto another
-layer's cache, in one decoder (SambaY, arXiv:2507.06607).
+(``ModelConfig.layers``): state-space mixers (Mamba-1, with or without
+RMSNorms on dt, B and C), plain grouped- / multi-query attention,
+differential attention with a window or without, gated memory units and
+cross-attention onto another layer's cache, in one decoder (SambaY,
+arXiv:2507.06607; Jamba, arXiv:2403.19887).
 
 ``Transformer`` owns the embedding, the final norm, the head and the
 homogeneous scan; for a model with a per-layer spec it hands the layers
@@ -11,8 +13,11 @@ as one stacked dict a position of the period and run as one ``lax.scan``
 over its repeats; a stretch that does not repeat is a run of one, run
 inline. Two values cross layer boundaries inside a step: the memory ``m``
 (the scan output of the nearest state-space layer below, which the gated
-memory units gate) and the keys and values of the one ``paged``
-attention layer (which every cross-attention layer above it reads).
+memory units gate) and, in a spec that has cross-attention layers, the
+keys and values of its one ``paged`` attention layer (which every
+cross-attention layer above it reads). A spec without them may hold any
+number of ``paged`` layers: each keeps rows of its own, layer j of them
+at index j of the ``paged`` arrays.
 
 Every block is sequential: ``x + mixer(norm1(x))``, then ``x +
 mlp(norm2(x))`` with the gated-SiLU MLP; no rotary embedding anywhere
@@ -29,6 +34,12 @@ is taken on the outputs. Whole sequences attend that way; the paged
 steps go one further and lay the query out against a token's whole
 cached row (all pairs side by side, ``_wide_query``), so the pool is
 read as it is stored.
+
+Plain attention (mixer ``attention``) is the textbook form: no rotary,
+no bias, scale ``head_dim ** -0.5``, query heads grouped over the key /
+value heads. A cached row is a token's key (or value) heads side by
+side, ``num_kv_heads * head_dim`` numbers, the same count as the paired
+row above; with one key / value head the row is the key.
 """
 from __future__ import annotations
 
@@ -123,17 +134,22 @@ class HybridStack:
             raise ValueError(
                 f"one window pool, one window: the spec has {windows}")
         self.window = next(iter(windows), None)
-        self.shared_readers = sum(
-            s.cache in ("paged", "shared") for s in self.spec)
+        # layers that read the one shared layer's rows: itself and the
+        # cross layers (1 where every paged layer reads its own alone)
+        shared = sum(s.cache == "shared" for s in self.spec)
+        self.shared_readers = 1 + shared
         self._cache_spec = self._build_cache_spec()
         # cache kind -> positions of its arrays in cache_spec()
         self._slots: Dict[str, Tuple[int, ...]] = {}
         for i, entry in enumerate(self._cache_spec):
             self._slots[entry.kind] = self._slots.get(entry.kind, ()) + (i,)
+        # the shared layer's gathered rows cross layer boundaries through
+        # the carry's ``shared`` entry, outside every scan: it then has
+        # to stand outside them too
         for run in self.runs:
             kinds = [self.spec[run.start + j].cache
                      for j in range(run.period)]
-            if run.reps > 1 and "paged" in kinds:
+            if shared and run.reps > 1 and "paged" in kinds:
                 raise ValueError(
                     "the paged attention layer whose cache the cross "
                     "layers read has to stand alone in the spec, not "
@@ -186,6 +202,12 @@ class HybridStack:
                 dt_proj=((r, di), r ** -0.5),
                 dt_bias=((di,), "dt"), a_log=((di, n), "a_log"),
                 d_skip=((di,), "ones"), out_proj=((di, d), out_std))
+            if cfg.ssm_inner_norms:
+                shapes.update(dt_norm=((r,), "ones"), b_norm=((n,), "ones"),
+                              c_norm=((n,), "ones"))
+        elif mixer == "attention":
+            shapes.update(wq=((d, qdim), std), wk=((d, kvdim), std),
+                          wv=((d, kvdim), std), wo=((qdim, d), out_std))
         elif mixer == "diff_attention":
             shapes.update(
                 wq=((d, qdim), std), wq_bias=((qdim,), "zeros"),
@@ -269,18 +291,20 @@ class HybridStack:
 
     def _build_cache_spec(self) -> Tuple[CacheArray, ...]:
         """The arrays a cache manager holds for this model, in the order
-        the paged steps take and return them: keys and values of the one
-        ``paged`` layer, keys and values of the window layers, then the
+        the paged steps take and return them: keys and values of the
+        ``paged`` layers, keys and values of the window layers, then the
         state-space layers' state (float32) and convolution tail. A row
-        is a token's differential pairs side by side, one vector of
-        kv_heads / 2 x (2 * head_dim) numbers (10 x 128 = 1,280): the TPU
+        is a token's key / value heads side by side, one vector of
+        num_kv_heads x head_dim numbers, which is its differential pairs
+        side by side, kv_heads / 2 x (2 * head_dim) (10 x 128 = 1,280),
+        and one key of 128 under multi-query attention: the TPU
         tiles an array's two minor axes (16 x 128 for bfloat16), and a
         [page, 10, 128] page, with 10 in the second-minor place, gets a
         layout of the compiler's choosing at the program's boundary and
         a whole-pool copy to and from the one its scatter wants, every
         step; [page, 1280] tiles as it is."""
         cfg = self.cfg
-        row = (cfg.num_kv_heads // 2 * 2 * cfg.head_dim_,)
+        row = (cfg.num_kv_heads * cfg.head_dim_,)
         adtype = self.model.adtype
         out: List[CacheArray] = []
         for kind in ("paged", "paged_window"):
@@ -331,20 +355,30 @@ class HybridStack:
                 jnp.sum(real, axis=1, dtype=jnp.int32))
             xc = jax.nn.silu(conv)
             dbc = model._dense(layer, "x_proj", xc)
+
+            def inner(name, v):
+                # Jamba norms dt, B and C between x_proj and their use
+                return (rms_norm(v, layer[name], cfg.rms_norm_eps)
+                        if cfg.ssm_inner_norms else v)
             dt = jax.nn.softplus(
-                model._dense(layer, "dt_proj", dbc[..., :r]).astype(F32)
+                model._dense(layer, "dt_proj", inner(
+                    "dt_norm", dbc[..., :r])).astype(F32)
                 + layer["dt_bias"].astype(F32))
             dt = jnp.where(real[..., None], dt, 0.0)
             a = -jnp.exp(layer["a_log"].astype(F32)).T          # [N, d]
-            bm, cm = dbc[..., r:r + n], dbc[..., r + n:]
-            if h.shape[1] == 1:
-                y, state = selective_scan_step(
-                    xc[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
-                    layer["d_skip"], state)
-                y = y[:, None]
-            else:
-                y, state = selective_scan_chunk(
-                    xc, dt, a, bm, cm, layer["d_skip"], state)
+            bm = inner("b_norm", dbc[..., r:r + n])
+            cm = inner("c_norm", dbc[..., r + n:])
+            # the recurrence alone: projections and the convolution stay
+            # outside this scope
+            with jax.named_scope("ssm_scan"):
+                if h.shape[1] == 1:
+                    y, state = selective_scan_step(
+                        xc[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
+                        layer["d_skip"], state)
+                    y = y[:, None]
+                else:
+                    y, state = selective_scan_chunk(
+                        xc, dt, a, bm, cm, layer["d_skip"], state)
             y = y.astype(h.dtype)
             out = model._dense(layer, "out_proj", y * jax.nn.silu(z))
         return out, y, state, tail
@@ -353,6 +387,16 @@ class HybridStack:
         with jax.named_scope("gmu"):
             gate = jax.nn.silu(self.model._dense(layer, "gmu_in", h))
             return self.model._dense(layer, "gmu_out", memory * gate)
+
+    def _plain_qkv(self, layer: Params, h: jnp.ndarray):
+        """Plain attention's query [B, T, H, dh] and this token's key and
+        value heads [B, T, KH, dh]."""
+        cfg = self.cfg
+        b, t, _ = h.shape
+        proj = self._proj(layer)
+        kv = (b, t, cfg.num_kv_heads, cfg.head_dim_)
+        return (proj("wq", h).reshape(b, t, cfg.num_heads, cfg.head_dim_),
+                proj("wk", h).reshape(kv), proj("wv", h).reshape(kv))
 
     def _paired_query(self, layer: Params, h: jnp.ndarray) -> jnp.ndarray:
         """[B, T, H, 2 * dh]: head 2j as [q | 0], head 2j + 1 as [0 | q]."""
@@ -511,6 +555,11 @@ class HybridStack:
                 carry_ = {**carry_, "memory": memory}
             elif spec.mixer == "gmu":
                 out = self._gmu(layer, h, carry_["memory"])
+            elif spec.mixer == "attention":
+                with jax.named_scope(_ATTN_SCOPE[spec.cache]):
+                    q, k, v = self._plain_qkv(layer, h)
+                    out = self._proj(layer)("wo", attend(
+                        q, k, v, None).reshape(b, t, -1))
             else:
                 with jax.named_scope(_ATTN_SCOPE[spec.cache]):
                     q = self._paired_query(layer, h)
@@ -518,7 +567,7 @@ class HybridStack:
                         k, v = carry_["shared"]
                     else:
                         k, v = self._paired_kv(layer, h)
-                        if spec.cache == "paged":
+                        if spec.cache == "paged" and self.shared_readers > 1:
                             carry_ = {**carry_, "shared": (k, v)}
                     out = self._diff_output(
                         layer, attend(q, k, v, spec.window))
@@ -616,6 +665,24 @@ class HybridStack:
                 carry_ = {**carry_, "memory": memory}
             elif spec.mixer == "gmu":
                 out = self._gmu(layer, h, carry_["memory"])
+            elif spec.mixer == "attention":
+                # plain attention over the layer's own rows, arrays i of
+                # the paged kind; a gathered row splits into its key /
+                # value heads (one head: the row is the key)
+                with jax.named_scope(_ATTN_SCOPE[spec.cache]):
+                    ki, vi = slots["paged"]
+                    q, k, v = self._plain_qkv(layer, h)
+                    heads = (b, window_cols, cfg.num_kv_heads, cfg.head_dim_)
+                    att = attention(
+                        q, pools_[ki][i, tables].reshape(heads),
+                        pools_[vi][i, tables].reshape(heads), k, v,
+                        kv_valid=view["valid"], kv_positions=view["pos"],
+                        window=None, q_positions=positions,
+                        softmax_scale=cfg.head_dim_ ** -0.5)
+                    at = (i, view["write_pages"], view["write_offs"])
+                    pools_[ki] = pools_[ki].at[at].set(k.reshape(b, t, -1))
+                    pools_[vi] = pools_[vi].at[at].set(v.reshape(b, t, -1))
+                    out = self._proj(layer)("wo", att.reshape(b, t, -1))
             else:
                 with jax.named_scope(_ATTN_SCOPE[spec.cache]):
                     q = self._wide_query(layer, h)

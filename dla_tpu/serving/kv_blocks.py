@@ -711,6 +711,11 @@ class PagedKVCache:
         live as long as the request (the ``paged`` arrays)."""
         return self._bytes("paged")
 
+    def layers_of(self, kind: str) -> int:
+        """Layers that keep arrays of ``kind`` (0: none)."""
+        return max((a.layers for a in self.spec if a.kind == kind),
+                   default=0)
+
     @property
     def window_bytes_per_token(self) -> int:
         """Bytes a token takes in the window pool while inside the

@@ -67,10 +67,13 @@ class ServingMetrics:
         # bytes one cached token takes in the paged pool, every layer
         # (dense: keys and values; latent attention: one latent row)
         self.kv_bytes_per_token = r.gauge("serving/kv_bytes_per_token")
+        # layers that keep request-long pages of their own (every layer of
+        # a dense model; the ``paged`` layers of a per-layer spec)
+        self.kv_paged_layers = r.gauge("serving/kv_paged_layers")
         # a model with a per-layer cache spec (0 / 1 for the others):
         # bytes a token takes in the window pool while inside the window;
-        # bytes of recurrent state a slot holds; the layers that read the
-        # one ``paged`` attention layer's rows (itself included); the
+        # bytes of recurrent state a slot holds; the layers that read a
+        # shared ``paged`` attention layer's rows (itself included); the
         # window pool's pages owned over pages it has; window pages given
         # back to its allocator from behind the window
         self.window_bytes_per_token = r.gauge(
@@ -88,6 +91,9 @@ class ServingMetrics:
         self.prefix_evictions = r.counter(
             "serving/prefix_cache/evictions")
         self.prefill_chunks = r.counter("serving/prefill/chunks")
+        # real tokens x state-space layers the chunks ran: what the
+        # chunked selective scan worked through (0 without such layers)
+        self.prefill_scan_tokens = r.counter("serving/prefill/scan_tokens")
         self.prefill_tokens_saved = r.counter(
             "serving/prefill/tokens_saved")
         self.requests_shed = r.counter("serving/requests_shed")
@@ -144,6 +150,7 @@ class ServingMetrics:
             "serving/moe/expert_assignments": float(
                 self.moe_expert_assignments.value),
             "serving/kv_bytes_per_token": self.kv_bytes_per_token.value,
+            "serving/kv_paged_layers": self.kv_paged_layers.value,
             "serving/window_bytes_per_token":
                 self.window_bytes_per_token.value,
             "serving/state_bytes_per_slot": self.state_bytes_per_slot.value,
@@ -162,6 +169,8 @@ class ServingMetrics:
             "serving/prefix_cache/evictions": float(
                 self.prefix_evictions.value),
             "serving/prefill/chunks": float(self.prefill_chunks.value),
+            "serving/prefill/scan_tokens": float(
+                self.prefill_scan_tokens.value),
             "serving/prefill/tokens_saved": float(
                 self.prefill_tokens_saved.value),
             "serving/requests_shed": float(self.requests_shed.value),

@@ -298,6 +298,8 @@ class ServingEngine:
             prefix_cache=self.prefix_cache)
         self.metrics = ServingMetrics()
         self.metrics.kv_bytes_per_token.set(self.cache.bytes_per_token)
+        self.metrics.kv_paged_layers.set(self.cache.layers_of("paged"))
+        self._state_layers = self.cache.layers_of("state")
         self.metrics.window_bytes_per_token.set(
             self.cache.window_bytes_per_token)
         self.metrics.state_bytes_per_slot.set(
@@ -1767,10 +1769,13 @@ class ServingEngine:
         ids = np.zeros((self.cfg.prefill_chunk,), np.int32)
         ids[:nvalid] = prefix[start:start + nvalid]
         c = self.cache
+        # ``context``: tokens already cached for the slot when the chunk
+        # starts (what its attention layers read beside the chunk)
         with annotate("serve_prefill_chunk", rid=req.rid, slot=slot,
                       start=start, nvalid=nvalid,
                       last=int(start + nvalid >= n), puts=1,
-                      h2d_bytes=self._chunk_layout.nbytes()):
+                      h2d_bytes=self._chunk_layout.nbytes(),
+                      context=start):
             if c.window_allocator is not None:
                 c.ensure_window(slot, start + nvalid - 1)
             packed = self._chunk_layout.pack(
@@ -1782,6 +1787,7 @@ class ServingEngine:
                 self.params, c.pools, self._put_step_args(packed),
                 self._adapters_args())
         self.metrics.prefill_chunks.inc()
+        self.metrics.prefill_scan_tokens.inc(nvalid * self._state_layers)
         c.mark_computed(slot, start, nvalid)
         req.prefill_pos = start + nvalid
         if req.prefill_pos < n:

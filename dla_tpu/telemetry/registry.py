@@ -254,6 +254,10 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/kv_bytes_per_token", "gauge", "bytes",
        "bytes one cached token takes in the paged pool over every layer "
        "(keys and values, or one latent row)", "step"),
+    _s("serving/kv_paged_layers", "gauge", "layers",
+       "layers that keep request-long pages of their own (every layer of "
+       "a model of one kind; the `paged` layers of a per-layer spec)",
+       "step"),
     _s("serving/window_bytes_per_token", "gauge", "bytes",
        "bytes a token takes in the window pool while inside the window, "
        "over its layers (0 without window layers)", "step"),
@@ -261,8 +265,8 @@ CATALOG: Tuple[MetricSpec, ...] = (
        "bytes of recurrent state a slot holds whatever its length (0 "
        "without state-space layers)", "step"),
     _s("serving/kv_shared_readers", "gauge", "layers",
-       "layers that read the one paged attention layer's rows, itself "
-       "included (1 for a model of one kind of layer)", "step"),
+       "layers that read a shared paged attention layer's rows, itself "
+       "included (1 where every paged layer reads its own alone)", "step"),
     _s("serving/window_page_occupancy", "gauge", "fraction",
        "window pool pages owned over pages it has", "step"),
     _s("serving/window_pages_released", "counter", "pages",
@@ -283,6 +287,10 @@ CATALOG: Tuple[MetricSpec, ...] = (
        "cached pages reclaimed by the allocator (LRU)", "step"),
     _s("serving/prefill/chunks", "counter", "chunks",
        "chunked-prefill forward passes", "step"),
+    _s("serving/prefill/scan_tokens", "counter", "tokens",
+       "real tokens x state-space layers the prefill chunks ran (what "
+       "the chunked selective scan worked through; 0 without such "
+       "layers)", "step"),
     _s("serving/prefill/tokens_saved", "counter", "tokens",
        "prefill tokens skipped via cached prefixes", "step"),
     # -- serving resilience (serving.resilience): admission control,

@@ -142,7 +142,7 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "serve_admit": ("scheduler", ("queued",)),
     "serve_prefill_chunk": ("engine host loop",
                             ("rid", "slot", "start", "nvalid", "last",
-                             "puts", "h2d_bytes")),
+                             "puts", "h2d_bytes", "context")),
     "serve_chunk_fetch": ("engine host loop", ("rid",)),
     "serve_first_token": ("engine host loop", ("rid",)),
     # ``state_slots`` / ``window_read_tokens``: the slots whose recurrent
@@ -191,7 +191,8 @@ DEVICE_SCOPES: Dict[str, str] = {
     "step_metrics": "trainer", "head_loss": "model step",
     "mla_attention": "model step", "moe_router": "experts",
     "moe_experts": "experts", "moe_shared": "experts",
-    "ssm_mixer": "model step", "gmu": "model step",
+    "ssm_mixer": "model step", "ssm_scan": "model step",
+    "gmu": "model step",
     "swa_attention": "model step", "full_attention": "model step",
     "cross_attention": "model step",
 }

@@ -274,7 +274,7 @@ def test_arch_overrides_cover_every_model_config_field():
         # the per-layer spec and what it implies: the architecture's own
         # shape, read from the model's config like the widths above
         "layers", "norm", "ssm_state_size", "ssm_conv_width", "ssm_expand",
-        "ssm_dt_rank",
+        "ssm_dt_rank", "ssm_inner_norms",
     }
     fields = {f.name for f in dataclasses.fields(ModelConfig)}
     candidates = fields - excluded

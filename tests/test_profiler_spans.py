@@ -106,15 +106,20 @@ ENGINES = {
     # in the one packed array
     "state_space": dict(page_size=4, num_pages=32, num_slots=2,
                         max_model_len=32, prefill_chunk=4),
+    # state-space layers beside two plain multi-query attention layers
+    # with rows of their own (the tiny-jamba preset): no window pool
+    "ssm_attention": dict(page_size=4, num_pages=32, num_slots=2,
+                          max_model_len=32, prefill_chunk=4),
 }
+PRESETS = {"experts": "tiny-mla-moe", "state_space": "tiny-sambay",
+           "ssm_attention": "tiny-jamba"}
 
 
 @pytest.mark.parametrize("kind", sorted(ENGINES))
 def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
     model, params = model_and_params
-    if kind in ("experts", "state_space"):
-        model = Transformer(get_model_config(
-            "tiny-mla-moe" if kind == "experts" else "tiny-sambay"))
+    if kind in PRESETS:
+        model = Transformer(get_model_config(PRESETS[kind]))
         params = model.init(jax.random.key(7))
     gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
                            eos_token_id=-1, pad_token_id=0)
@@ -196,6 +201,9 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
             assert phase[1]["window_read_tokens"] == (
                 geom.num_slots * geom.window_gather_pages * geom.page_size)
             assert geom.window_gather_pages == 8 // 4 + 1
+        elif kind == "ssm_attention":
+            assert phase[1]["state_slots"] == geom.num_slots
+            assert phase[1]["window_read_tokens"] == 0
         else:
             assert phase[1]["state_slots"] == 0
             assert phase[1]["window_read_tokens"] == 0
@@ -211,6 +219,16 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
     assert snap["serving/step_arg_puts"] == len(rows)
     assert snap["serving/step_arg_bytes"] == sum(
         r[1]["h2d_bytes"] for r in rows)
+    # a chunk's ``context`` is what the slot held when it started; the
+    # scan counter is the chunks' real tokens x the state-space layers
+    chunks = rec.named("serve_prefill_chunk")
+    assert all(c[1]["context"] == c[1]["start"] for c in chunks)
+    state_layers = {"state_space": 4, "ssm_attention": 10}.get(kind, 0)
+    assert snap["serving/prefill/scan_tokens"] == state_layers * sum(
+        c[1]["nvalid"] for c in chunks)
+    assert snap["serving/kv_paged_layers"] == {
+        "state_space": 1, "ssm_attention": 2, "experts": 2}.get(
+            kind, model.cfg.num_layers)
     if kind == "experts":
         cfg = model.cfg
         routes = [r[1] for r in rec.named("serve_moe_route")]
