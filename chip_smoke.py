@@ -7,8 +7,10 @@ at Mistral-7B widths (hidden 4096, FFN 14336, 32q/8kv x 128, vocab 32000,
 window 4096) with depth as the only cut, random weights from a seed and
 data generated here from a seed:
 
-  kernels  the three Pallas kernels compiled (``interpret=False``) at
-           Mistral head shapes and compared with their XLA references
+  kernels  the Pallas kernels compiled (``interpret=False``) at Mistral
+           head shapes (the chunk's selective scan at the layer-spec
+           cells' T 512 / 256, d_inner 5,120, N 16) and compared with
+           their XLA references
   trainer  ``dla_tpu.training.train_sft.main`` on a YAML written here:
            flash attention, fused CE, remat, T = 2048, full fine-tuning;
            a few steps, a checkpoint, one ``--resume`` step. On a
@@ -80,7 +82,9 @@ CHIP = {
                 "matmul": [(64, 4096, 14336), (64, 14336, 4096),
                            (2048, 14336, 4096)],
                 "decode_batches": [8, 64], "decode_cache": 2048,
-                "decode_fill": 1500},
+                "decode_fill": 1500,
+                "scan_chunks": [512, 256], "scan_inner": 5120,
+                "scan_state": 16},
 }
 REHEARSAL = {
     "model": "tiny",
@@ -97,7 +101,8 @@ REHEARSAL = {
                 "seq": 256, "window": 64, "flash_batch": 1,
                 "matmul": [(16, 256, 384)],
                 "decode_batches": [2], "decode_cache": 256,
-                "decode_fill": 150},
+                "decode_fill": 150,
+                "scan_chunks": [32], "scan_inner": 256, "scan_state": 4},
 }
 
 
@@ -329,6 +334,30 @@ def phase_kernels(s: Smoke) -> None:
 
         verdict(f"paged_decode_attention [B{b} pages of 16, fills 0.."
                 f"{fill} of {cache} {h}q/{kh}kv x{d}]", paged_vs_xla, 2e-2)
+
+    # the chunk's selective scan at the two layer-spec serving cells'
+    # shapes, from a carried state that is not zero
+    def scan_vs_xla(t: int) -> float:
+        from dla_tpu.ops.selective_scan import selective_scan_chunk
+        from dla_tpu.ops.selective_scan_kernel import (
+            selective_scan_chunk_kernel,
+        )
+        di, n = k["scan_inner"], k["scan_state"]
+        args = (normal(1, t, di),
+                jnp.asarray(np.log1p(np.exp(rs.randn(1, t, di) - 1.0)),
+                            jnp.float32),
+                -jnp.exp(jnp.asarray(0.5 * rs.randn(n, di), jnp.float32)),
+                normal(1, t, n), normal(1, t, n),
+                jnp.asarray(rs.randn(di), jnp.float32),
+                jnp.asarray(rs.randn(1, n, di), jnp.float32))
+        got = selective_scan_chunk_kernel(*args, interpret=interpret)
+        want = selective_scan_chunk(*args)
+        return max(rel_err(g, w) for g, w in zip(got, want))
+
+    for t in k["scan_chunks"]:
+        verdict(f"selective_scan_chunk_kernel [T{t} d_inner "
+                f"{k['scan_inner']} N{k['scan_state']}]",
+                lambda t=t: scan_vs_xla(t), 1e-3)
 
     check(not failures, f"{len(failures)} kernel check(s) failed: "
           + "; ".join(failures))

@@ -339,12 +339,15 @@ class HybridStack:
             return out if bias is None else out + bias.astype(out.dtype)
         return proj
 
-    def _ssm(self, layer: Params, h: jnp.ndarray, state, tail, real):
+    def _ssm(self, layer: Params, h: jnp.ndarray, state, tail, real,
+             scan_kernel=None):
         """The Mamba-1 mixer over T tokens from (state, tail). ``real``
         [B, T] bool, a prefix of each row: the others move neither the
-        state nor the tail. Returns (mixer output [B, T, D], memory
-        [B, T, d_inner] = the scan's output before the gate, new state,
-        new tail)."""
+        state nor the tail. ``scan_kernel``: the module whose
+        ``selective_scan_chunk_kernel`` runs a chunk's recurrence
+        (``Transformer.scan_chunk_kernel``), or None for XLA's form.
+        Returns (mixer output [B, T, D], memory [B, T, d_inner] = the
+        scan's output before the gate, new state, new tail)."""
         cfg, model = self.cfg, self.model
         di, n, r = cfg.ssm_inner_, cfg.ssm_state_size, cfg.ssm_dt_rank_
         with jax.named_scope("ssm_mixer"):
@@ -377,7 +380,9 @@ class HybridStack:
                         layer["d_skip"], state)
                     y = y[:, None]
                 else:
-                    y, state = selective_scan_chunk(
+                    scan = (selective_scan_chunk if scan_kernel is None
+                            else scan_kernel.selective_scan_chunk_kernel)
+                    y, state = scan(
                         xc, dt, a, bm, cm, layer["d_skip"], state)
             y = y.astype(h.dtype)
             out = model._dense(layer, "out_proj", y * jax.nn.silu(z))
@@ -579,7 +584,7 @@ class HybridStack:
     # ---------------------------------------------------------- paged steps
 
     def paged(self, layers: Dict[str, Params], view: Dict, x: jnp.ndarray,
-              positions: jnp.ndarray, attention):
+              positions: jnp.ndarray, attention, scan_kernel=None):
         """The layers of one paged step (decode: T = 1 for every slot;
         prefill chunk: T tokens of one slot) over the arrays of
         ``cache_spec()``. Beside what ``Transformer._paged_layers`` reads,
@@ -594,6 +599,8 @@ class HybridStack:
                         chunk: this is how a slot's state is zeroed)
           real          [B, T] bool: rows that are real; the others write
                         the trash page and move no state
+
+        ``scan_kernel``: what runs a chunk's selective scans (``_ssm``).
 
         Returns (hidden before the final norm, the arrays updated)."""
         cfg = self.cfg
@@ -658,7 +665,7 @@ class HybridStack:
                     tail = jnp.where(fresh[:, None, None],
                                      jnp.zeros_like(tail), tail)
                 out, memory, state, tail = self._ssm(
-                    layer, h, state, tail, real)
+                    layer, h, state, tail, real, scan_kernel)
                 at = (i,) if rows is None else (i, rows)
                 pools_[si] = pools_[si].at[at].set(state)
                 pools_[ti] = pools_[ti].at[at].set(tail)

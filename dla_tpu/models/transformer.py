@@ -42,6 +42,7 @@ from dla_tpu.ops.attention import (
     chunked_causal_attention,
     decode_attention,
 )
+from dla_tpu.ops import selective_scan_kernel
 from dla_tpu.ops.norms import layer_norm, rms_norm
 from dla_tpu.ops.rotary import (
     apply_rotary,
@@ -279,6 +280,13 @@ class Transformer:
             threading.Thread(
                 target=_import_paged_kernel,
                 name="dla-paged-kernel-import", daemon=True).start()
+        if self._scan_kernel_layers and _tpu_backend():
+            # the same for the chunk's selective-scan kernel: its module
+            # names Pallas inside its functions only, ``pallas()`` is
+            # the import
+            threading.Thread(
+                target=selective_scan_kernel.pallas,
+                name="dla-scan-kernel-import", daemon=True).start()
 
     def _init_canonical(self, rng: jax.Array) -> Params:
         cfg = self.cfg
@@ -870,6 +878,36 @@ class Transformer:
         if (self._paged_kernel_rows and _tpu_backend()
                 and _flash_mesh() is None):
             return _import_paged_kernel()
+        return None
+
+    @property
+    def _scan_kernel_layers(self) -> bool:
+        """Whether this model has state-space layers of sizes the
+        chunk's selective-scan kernel (ops/selective_scan_kernel.py)
+        takes at some chunk length."""
+        cfg = self.cfg
+        return (self.hybrid is not None
+                and any(s.mixer == "ssm" for s in self.hybrid.spec)
+                and selective_scan_kernel.takes(
+                    selective_scan_kernel.TOKEN_ALIGN, cfg.ssm_inner_,
+                    cfg.ssm_state_size))
+
+    def scan_chunk_kernel(self, tokens: int):
+        """The selective-scan kernel's module, Pallas imported, if a
+        paged chunk program of ``tokens`` tokens traced now, under the
+        ambient mesh, would run it, else None (``selective_scan_chunk``,
+        XLA's form). What is observed, no knob: state-space layers of
+        sizes and a chunk of a length the kernel takes (``takes``), a
+        TPU backend (Mosaic compiles for nothing else) and no
+        multi-device auto mesh (a ``pallas_call`` has no SPMD rule).
+        ``HybridStack.forward`` never asks: the kernel has no VJP."""
+        cfg = self.cfg
+        if (self._scan_kernel_layers
+                and selective_scan_kernel.takes(
+                    tokens, cfg.ssm_inner_, cfg.ssm_state_size)
+                and _tpu_backend() and _flash_mesh() is None):
+            selective_scan_kernel.pallas()
+            return selective_scan_kernel
         return None
 
     def cache_spec(self) -> Tuple[CacheArray, ...]:
@@ -2189,7 +2227,8 @@ class Transformer:
                 raise NotImplementedError(
                     "per-slot adapters on a model with a per-layer spec")
             x, pools = self.hybrid.paged(
-                params["layers"], view, x, positions, attention)
+                params["layers"], view, x, positions, attention,
+                scan_kernel=self.scan_chunk_kernel(x.shape[1]))
             return (self._final_norm(params, x), pools,
                     jnp.zeros((2,), jnp.int32))
         cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,

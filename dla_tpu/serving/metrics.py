@@ -94,6 +94,10 @@ class ServingMetrics:
         # real tokens x state-space layers the chunks ran: what the
         # chunked selective scan worked through (0 without such layers)
         self.prefill_scan_tokens = r.counter("serving/prefill/scan_tokens")
+        # chunks dispatched to a program that holds the selective-scan
+        # kernel (ops/selective_scan_kernel.py)
+        self.prefill_scan_kernel_chunks = r.counter(
+            "serving/prefill/scan_kernel_chunks")
         self.prefill_tokens_saved = r.counter(
             "serving/prefill/tokens_saved")
         self.requests_shed = r.counter("serving/requests_shed")
@@ -171,6 +175,8 @@ class ServingMetrics:
             "serving/prefill/chunks": float(self.prefill_chunks.value),
             "serving/prefill/scan_tokens": float(
                 self.prefill_scan_tokens.value),
+            "serving/prefill/scan_kernel_chunks": float(
+                self.prefill_scan_kernel_chunks.value),
             "serving/prefill/tokens_saved": float(
                 self.prefill_tokens_saved.value),
             "serving/requests_shed": float(self.requests_shed.value),

@@ -261,6 +261,7 @@ class ServingEngine:
         # whether the one-token step's program holds the paged attention
         # kernel; asked of the model at the first decode span
         self._kernel_decode: Optional[bool] = None
+        self._kernel_scan: Optional[bool] = None
         if self._stateful:
             for on, what in (
                     (cfg.prefix_cache, "ServingConfig.prefix_cache (a "
@@ -1771,6 +1772,11 @@ class ServingEngine:
         c = self.cache
         # ``context``: tokens already cached for the slot when the chunk
         # starts (what its attention layers read beside the chunk)
+        if self._kernel_scan is None:
+            # asked once, here: the first dispatch, which traces the
+            # program, follows in this same context
+            self._kernel_scan = self.model.scan_chunk_kernel(
+                self.cfg.prefill_chunk) is not None
         with annotate("serve_prefill_chunk", rid=req.rid, slot=slot,
                       start=start, nvalid=nvalid,
                       last=int(start + nvalid >= n), puts=1,
@@ -1788,6 +1794,8 @@ class ServingEngine:
                 self._adapters_args())
         self.metrics.prefill_chunks.inc()
         self.metrics.prefill_scan_tokens.inc(nvalid * self._state_layers)
+        if self._kernel_scan:
+            self.metrics.prefill_scan_kernel_chunks.inc()
         c.mark_computed(slot, start, nvalid)
         req.prefill_pos = start + nvalid
         if req.prefill_pos < n:

@@ -291,6 +291,10 @@ CATALOG: Tuple[MetricSpec, ...] = (
        "real tokens x state-space layers the prefill chunks ran (what "
        "the chunked selective scan worked through; 0 without such "
        "layers)", "step"),
+    _s("serving/prefill/scan_kernel_chunks", "counter", "chunks",
+       "prefill chunks dispatched to a program that holds the "
+       "selective-scan kernel (the state-space layers' state stays in "
+       "VMEM through the chunk)", "step"),
     _s("serving/prefill/tokens_saved", "counter", "tokens",
        "prefill tokens skipped via cached prefixes", "step"),
     # -- serving resilience (serving.resilience): admission control,
