@@ -107,7 +107,10 @@ REHEARSAL = {
 
 
 class Smoke:
-    """Output, phase bookkeeping and the compile ledger of one run."""
+    """Output and phase bookkeeping of one run. What compiled, how long
+    it took and what the persistent cache answered is the program's own
+    accounting (``telemetry.xla_introspect``, started by
+    ``enable_compile_cache()``): the one source of compile counts."""
 
     def __init__(self, rehearsal: bool, out_dir: Path):
         self.tag = "REHEARSAL " if rehearsal else ""
@@ -118,36 +121,40 @@ class Smoke:
         # length of the run (the tool's output directory is capped)
         self.work = out_dir / "work"
         self.results: Dict[str, bool] = {}
-        self.compiles: List[tuple] = []       # (fun_name, seconds)
-        self.cache_events = {"hits": 0, "misses": 0}
 
     def say(self, msg: str) -> None:
         print(f"{self.tag}[chip_smoke] {msg}", flush=True)
 
-    def listen(self) -> None:
-        """Record every backend compile JAX reports (a persistent-cache
-        retrieval counts as the compile it replaced)."""
-        import jax
+    def compiles(self, since_ns: int = 0) -> List[tuple]:
+        """(fun_name, seconds) of every backend compile JAX reported
+        since ``since_ns`` (``perf_counter_ns``); a persistent-cache
+        retrieval counts as the compile it replaced."""
+        from dla_tpu.telemetry import xla_introspect as xi
+        return [(ev.fun_name, ev.seconds)
+                for ev in xi.compile_events(since_ns)
+                if ev.event == xi.BACKEND_COMPILE_EVENT]
 
-        def on_duration(event, seconds, **kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.compiles.append((kw.get("fun_name", "?"), seconds))
+    @staticmethod
+    def spent(before: Dict[str, float]) -> str:
+        """What the process compiled since ``before`` (an earlier
+        ``compile_accounting()``), in words."""
+        from dla_tpu.telemetry.xla_introspect import compile_accounting
+        d = {k: v - before[k] for k, v in compile_accounting().items()}
+        return (f"{d['backend_compiles']} compiles "
+                f"{d['backend_compile_s']:.1f}s, lowering "
+                f"{d['lower_s']:.1f}s; persistent cache "
+                f"{d['cache_hits']} hits, {d['cache_misses']} misses")
 
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.cache_events["hits"] += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self.cache_events["misses"] += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def compile_seconds(self, fun_name: str, since: int = 0) -> List[float]:
-        return [s for n, s in self.compiles[since:] if n == fun_name]
+    def compile_seconds(self, fun_name: str, since_ns: int = 0
+                        ) -> List[float]:
+        return [s for n, s in self.compiles(since_ns) if n == fun_name]
 
     def run(self, name: str, phase: Callable[["Smoke"], None]) -> None:
         import jax
-        t0, mark = time.perf_counter(), len(self.compiles)
+
+        from dla_tpu.telemetry.xla_introspect import compile_accounting
+        t0, mark = time.perf_counter(), time.perf_counter_ns()
+        before = compile_accounting()
         try:
             phase(self)
             ok, why = True, ""
@@ -155,11 +162,10 @@ class Smoke:
             traceback.print_exc()
             ok, why = False, f" {type(exc).__name__}: {str(exc)[:500]}"
         self.results[name] = ok
-        spent = sum(s for _, s in self.compiles[mark:])
         self.say(f"{'PASS' if ok else 'FAIL'} {name} "
-                 f"({time.perf_counter() - t0:.1f}s, {spent:.1f}s of it "
-                 f"in {len(self.compiles) - mark} compiles){why}")
-        slow = sorted((c for c in self.compiles[mark:] if c[1] >= 1.0),
+                 f"({time.perf_counter() - t0:.1f}s; "
+                 f"{self.spent(before)}){why}")
+        slow = sorted((c for c in self.compiles(mark) if c[1] >= 1.0),
                       key=lambda c: -c[1])
         for fun, secs in slow[:12]:
             self.say(f"  compile {fun}: {secs:.1f}s")
@@ -440,7 +446,7 @@ def phase_trainer(s: Smoke) -> None:
 
     metrics = s.out_dir / "trainer_logs" / "metrics.jsonl"
     metrics.unlink(missing_ok=True)
-    mark = len(s.compiles)
+    mark = time.perf_counter_ns()
     train_sft.main(["--config", str(cfg_path)])
     cold = s.compile_seconds("jit(_train_step)", mark)
     losses = _step_losses(metrics)
@@ -482,7 +488,7 @@ def phase_trainer(s: Smoke) -> None:
               f"a device held less than its shard of the state: {peaks}")
 
     gc.collect()        # the first run's state must leave the chip first
-    mark = len(s.compiles)
+    mark = time.perf_counter_ns()
     train_sft.main(["--config", str(cfg_path), "--resume", "--set",
                     f"optimization.max_train_steps={steps + 1}"])
     warm = s.compile_seconds("jit(_train_step)", mark)
@@ -665,7 +671,6 @@ def main(argv=None) -> int:
     cache_dir = Path(enable_compile_cache())
     entries = len(list(cache_dir.glob("*"))) if cache_dir.is_dir() else 0
     jax.config.update("jax_dump_ir_to", str(s.work / "ir"))
-    s.listen()
     try:
         import libtpu
         libtpu_version = libtpu.__version__
@@ -685,7 +690,9 @@ def main(argv=None) -> int:
         "native, rebuilt from dla_tpu/native/src/dla_data.cpp"
         if native.available() else "pure Python (no native toolchain)"))
 
-    t0 = time.perf_counter()
+    from dla_tpu.telemetry.xla_introspect import compile_accounting
+    t0, start_ns = time.perf_counter(), time.perf_counter_ns()
+    before = compile_accounting()
     try:
         for name in phases:
             s.run(name, PHASES[name])
@@ -693,16 +700,17 @@ def main(argv=None) -> int:
         jax.config.update("jax_dump_ir_to", None)
         shutil.rmtree(s.work, ignore_errors=True)
     ok = all(s.results.values())
-    s.say(f"compiles: {len(s.compiles)} taking "
-          f"{sum(c[1] for c in s.compiles):.1f}s; persistent cache "
-          f"{s.cache_events['hits']} hits, {s.cache_events['misses']} "
-          f"misses; wall {time.perf_counter() - t0:.1f}s")
+    acct = compile_accounting()
+    s.say(f"in all: {s.spent(before)}; "
+          f"wall {time.perf_counter() - t0:.1f}s")
     report = {"ok": ok, "device": device, "phases": s.results,
               "rehearsal": args.rehearsal,
               "compiles": [{"fn": n, "seconds": round(t, 3)}
-                           for n, t in s.compiles if t >= 1.0],
+                           for n, t in s.compiles(start_ns) if t >= 1.0],
               "cache": {"dir": str(cache_dir), "entries_at_start": entries,
-                        **s.cache_events}}
+                        "hits": acct["cache_hits"] - before["cache_hits"],
+                        "misses": (acct["cache_misses"]
+                                   - before["cache_misses"])}}
     (s.out_dir / "report.json").write_text(json.dumps(report, indent=1))
     print(s.tag + json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1

@@ -26,7 +26,7 @@ import importlib
 import math
 import sys
 import threading
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ from dla_tpu.models.config import CacheArray, ModelConfig
 from dla_tpu.models.hybrid import HybridStack
 from dla_tpu.parallel.mesh import auto_axes
 from dla_tpu.utils.compile_cache import cached_bytecode
+from dla_tpu.utils.profiling import startup_span
 from dla_tpu.ops.attention import (
     block_decode_attention,
     causal_attention,
@@ -86,7 +87,9 @@ def _import_paged_kernel():
     so nothing imports it at module level; its bytecode (and Pallas's) is
     kept beside the compile cache: on the chip's host 0.4 s from there,
     1.2 s from source (PERF.md, PR 35)."""
-    with cached_bytecode():
+    with startup_span("startup_kernel_import",
+                      module="dla_tpu.ops.paged_attention"), \
+            cached_bytecode():
         return importlib.import_module("dla_tpu.ops.paged_attention")
 
 
@@ -141,6 +144,12 @@ class Transformer:
     """Functional model: a namespace of pure functions bound to a config."""
 
     def __init__(self, cfg: ModelConfig):
+        with startup_span("startup_model_build", layers=cfg.num_layers,
+                          kernel_imports="") as span:
+            self._build(cfg)
+            span.set(kernel_imports=",".join(self._start_kernel_imports()))
+
+    def _build(self, cfg: ModelConfig) -> None:
         self.cfg = cfg
         self.adtype = jnp.dtype(cfg.dtype)
         self.pdtype = jnp.dtype(cfg.param_dtype)
@@ -171,7 +180,6 @@ class Transformer:
         if cfg.layers is not None:
             self.hybrid = HybridStack(self)
             self._softmax_scale = cfg.head_dim_ ** -0.5
-        self._start_paged_kernel_import()
 
     # ------------------------------------------------------- storage layout
 
@@ -259,10 +267,11 @@ class Transformer:
     def init(self, rng: jax.Array) -> Params:
         return self.to_storage_layout(self._init_canonical(rng))
 
-    def _start_paged_kernel_import(self) -> None:
+    def _start_kernel_imports(self) -> List[str]:
         """If this model's paged decode step will run the Pallas kernel
         (``_paged_kernel_rows`` on a TPU backend), start importing the
-        kernel's module on a daemon thread. The import is 0.4 s of Python
+        kernel's module on a daemon thread; returns the names of the
+        threads started. The import is 0.4 s of Python
         from the bytecode cache (1.2 s from source: Pallas, most of it)
         that the first decode trace would otherwise wait for; every entry
         point builds the model before it makes the weights, so started
@@ -276,10 +285,12 @@ class Transformer:
         ``paged_decode_kernel`` stays and is what correctness rests on:
         the per-module import lock makes it wait for this one, never
         race it."""
+        started = []
         if self._paged_kernel_rows and _tpu_backend():
             threading.Thread(
                 target=_import_paged_kernel,
                 name="dla-paged-kernel-import", daemon=True).start()
+            started.append("dla-paged-kernel-import")
         if self._scan_kernel_layers and _tpu_backend():
             # the same for the chunk's selective-scan kernel: its module
             # names Pallas inside its functions only, ``pallas()`` is
@@ -287,6 +298,8 @@ class Transformer:
             threading.Thread(
                 target=selective_scan_kernel.pallas,
                 name="dla-scan-kernel-import", daemon=True).start()
+            started.append("dla-scan-kernel-import")
+        return started
 
     def _init_canonical(self, rng: jax.Array) -> Params:
         cfg = self.cfg
@@ -1182,11 +1195,13 @@ class Transformer:
         group; GQA grouping survives because the model axis divides
         num_kv_heads in any valid TP layout. ``segs`` is the
         pre-broadcast (qseg, kseg) pair from broadcast_segment_ids."""
-        from dla_tpu.ops.flash_attention import (
-            DEFAULT_BLOCK_K,
-            DEFAULT_BLOCK_Q,
-            flash_causal_attention,
-        )
+        with startup_span("startup_kernel_import",
+                          module="dla_tpu.ops.flash_attention"):
+            from dla_tpu.ops.flash_attention import (
+                DEFAULT_BLOCK_K,
+                DEFAULT_BLOCK_Q,
+                flash_causal_attention,
+            )
         kw = dict(window=self.cfg.sliding_window or None,
                   block_q=self.cfg.flash_block_q or DEFAULT_BLOCK_Q,
                   block_k=self.cfg.flash_block_k or DEFAULT_BLOCK_K)
@@ -1372,10 +1387,12 @@ class Transformer:
             # scan-over-layers: inside the body the [B,T,block_k] expansion
             # would be rebuilt per layer (and re-rebuilt per layer in the
             # remat'd backward)
-            from dla_tpu.ops.flash_attention import (
-                DEFAULT_BLOCK_K,
-                broadcast_segment_ids,
-            )
+            with startup_span("startup_kernel_import",
+                              module="dla_tpu.ops.flash_attention"):
+                from dla_tpu.ops.flash_attention import (
+                    DEFAULT_BLOCK_K,
+                    broadcast_segment_ids,
+                )
             flash_segs = broadcast_segment_ids(
                 segment_ids,
                 block_k=self.cfg.flash_block_k or DEFAULT_BLOCK_K)

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from dla_tpu.utils.compile_cache import cached_bytecode
+from dla_tpu.utils.profiling import startup_span
 
 F32 = jnp.float32
 LANES = 128
@@ -79,7 +80,9 @@ def pallas():
     """Pallas and its TPU dialect, imported on first use: about a second
     from source on the chip's host, 0.4 s from the bytecode kept beside
     the compile cache (PERF.md, PR 35)."""
-    with cached_bytecode():
+    with startup_span("startup_kernel_import",
+                      module="jax.experimental.pallas.tpu"), \
+            cached_bytecode():
         from jax.experimental import pallas as pl
         from jax.experimental.pallas import tpu as pltpu
     return pl, pltpu

@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dla_tpu.utils.profiling import startup_span
+
 
 class PageAllocator:
     """Host-side refcounted free-list allocator over the fixed page pool.
@@ -563,9 +565,10 @@ class PagedKVCache:
         self.geom = geom
         self.dtype = model.adtype
         self.spec = tuple(model.cache_spec())
-        self.pools: Tuple[jnp.ndarray, ...] = tuple(
-            jnp.zeros(self.array_shape(a, geom), a.dtype)
-            for a in self.spec)
+        with startup_span("startup_pool_alloc", arrays=len(self.spec)):
+            self.pools: Tuple[jnp.ndarray, ...] = tuple(
+                jnp.zeros(self.array_shape(a, geom), a.dtype)
+                for a in self.spec)
         self.window_tables = np.zeros(
             (geom.num_slots, geom.window_ring), np.int32)
         self.window_first = np.zeros((geom.num_slots,), np.int64)

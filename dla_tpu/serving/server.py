@@ -87,7 +87,8 @@ from dla_tpu.telemetry.xla_introspect import (
     register_live_bytes_gauge,
 )
 from dla_tpu.utils.profiling import (
-    ProfileWindow, annotate, mark, step_annotation)
+    ProfileWindow, annotate, mark, report_startup, startup_span,
+    step_annotation)
 
 #: ``_sample_host``'s sampler: one program over one logits row, shared by
 #: every engine of the process (called eagerly, the sampler's ``lax.cond``
@@ -208,6 +209,12 @@ class ServingEngine:
     def __init__(self, model: Transformer, params, gen: GenerationConfig,
                  cfg: ServingConfig,
                  now: Callable[[], float] = time.perf_counter):
+        with startup_span("startup_engine_build", slots=int(cfg.num_slots),
+                          pages=int(cfg.num_pages)):
+            self._build(model, params, gen, cfg, now)
+
+    def _build(self, model: Transformer, params, gen: GenerationConfig,
+               cfg: ServingConfig, now: Callable[[], float]) -> None:
         if cfg.page_size < 1 or cfg.max_model_len % cfg.page_size:
             raise ValueError(
                 f"max_model_len ({cfg.max_model_len}) must be a positive "
@@ -377,6 +384,7 @@ class ServingEngine:
         # engine-step counter drives the profiling window (the serving
         # analog of the trainer's step number)
         self.engine_steps = 0
+        self._startup_reported = False
         self.profile = ProfileWindow(cfg.profile)
         # host tracer: an engine-local one from cfg.trace (built on the
         # engine's OWN clock so request timestamps pass straight in and
@@ -1376,6 +1384,11 @@ class ServingEngine:
                                else self._decode_step())
             with annotate("serve_post"):
                 self._post_step()
+        if emitted and not self._startup_reported:
+            # the first token out: this replica serves. Where the time
+            # since the process started went, once (gauges and one line)
+            self._startup_reported = True
+            report_startup(self.metrics.registry)
         return emitted
 
     def _ensure_decode_pages(self, span: int) -> None:

@@ -14,7 +14,9 @@ through (docs/OBSERVABILITY.md).
 - aggregate       — pod-wide per-host step-time/goodput + straggler,
                     and gossip-fed fleet-wide metrics federation
 - slo             — rolling-window SLOs with burn-rate alerting
-- xla_introspect  — retrace attribution + compiled-fn cost/memory gauges
+- xla_introspect  — retrace attribution + compiled-fn cost/memory gauges,
+                    the lower / compile split, process-wide compile
+                    accounting (persistent-cache hits and misses)
 - anomaly         — rolling median/MAD triage with one-shot capture
 """
 from dla_tpu.telemetry.registry import (

@@ -35,13 +35,24 @@ program a second time and report the step as healthy.
 Fingerprints deliberately cover structure + shape + dtype, not values:
 traced scalars (the guard EMA, fault injectors) change value every step
 and must never re-key the cache — mirroring jit's own cache key.
+
+- **What did compiling cost, and did the persistent cache answer?** The
+  wrapper times its ``lower()`` and its ``compile()`` apart (start-up
+  spans ``xla_lower`` / ``xla_compile``, gauges ``lower_s`` /
+  ``compile_s`` / ``cache_hit``), and one ``jax.monitoring`` listener
+  (:func:`install_compile_accounting`) counts the persistent cache's
+  hits and misses and keeps the lowering and backend-compile durations
+  of EVERY jitted function of the process, wrapped or not.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional, Tuple
+import threading
+import time
+from collections import OrderedDict, deque
+from typing import (Any, Callable, Deque, Dict, List, NamedTuple,
+                    Optional, Tuple)
 
 import jax
 
@@ -228,6 +239,150 @@ def compiled_scopes(module_pattern: str) -> Dict[str, str]:
     return out
 
 
+# ------------------------------------------------ process-wide accounting
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+TO_MLIR_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_COUNTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+#: the most events kept: the newest (a long-lived process asks what a
+#: phase compiled, ``compile_events(since_ns)``; a start is far smaller)
+COMPILE_EVENT_CAP = 4096
+
+
+class CompileEvent(NamedTuple):
+    """One duration JAX reported: ``t_ns`` is ``perf_counter_ns`` when it
+    fired (the end of the ``seconds`` it measures), on ``thread``."""
+    event: str
+    t_ns: int
+    seconds: float
+    fun_name: str
+    thread: str
+
+
+class _Accounting:
+    """What the listener adds up. JAX fires every one of these events
+    synchronously on the thread that lowers or compiles, so the counts
+    are kept per thread as well: a wrapper reads whether ITS compile hit
+    the cache from its own thread's counts, and a compile on another
+    thread (a sampler's, a fleet member's) cannot be mistaken for it."""
+
+    def __init__(self):
+        self.installed = False
+        self.reset()
+
+    def reset(self) -> None:
+        self.totals = {"cache_requests": 0, "cache_hits": 0,
+                       "cache_misses": 0, "backend_compiles": 0,
+                       "lower_s": 0.0, "backend_compile_s": 0.0}
+        self.events: Deque[CompileEvent] = deque(maxlen=COMPILE_EVENT_CAP)
+        self.dropped = 0
+        self.local = threading.local()
+
+    def thread_counts(self) -> Dict[str, int]:
+        counts = getattr(self.local, "counts", None)
+        if counts is None:
+            counts = self.local.counts = {
+                "cache_requests": 0, "cache_hits": 0, "cache_misses": 0,
+                "depth": 0}
+        return counts
+
+    # JAX's ``log_elapsed_time`` reports a scalar when a timed region
+    # opens and a duration when it closes. Tracing nests (a callee's
+    # ``jaxpr_trace_duration`` lies inside its caller's, and inside a
+    # lowering that traces a helper), so a plain sum counts seconds
+    # twice: only a region that opened at depth 0 is counted and kept.
+    def on_open(self, event: str, value: float, **kw) -> None:
+        if event == TRACE_EVENT or event == TO_MLIR_EVENT:
+            self.thread_counts()["depth"] += 1
+
+    def on_duration(self, event: str, seconds: float, **kw) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            self.totals["backend_compiles"] += 1
+            self.totals["backend_compile_s"] += seconds
+        elif event == TRACE_EVENT or event == TO_MLIR_EVENT:
+            counts = self.thread_counts()
+            counts["depth"] = max(counts["depth"] - 1, 0)
+            if counts["depth"]:
+                return
+            self.totals["lower_s"] += seconds
+        else:
+            return
+        self._keep(event, float(seconds), str(kw.get("fun_name", "?")))
+
+    def on_event(self, event: str, **kw) -> None:
+        key = _CACHE_COUNTS.get(event)
+        if key is None:
+            return
+        self.totals[key] += 1
+        self.thread_counts()[key] += 1
+        if key != "cache_requests":
+            # a hit or a miss is kept with its time too (0 seconds, no
+            # name: it fires inside the backend compile it belongs to),
+            # so a reader can count those of one phase
+            self._keep(event, 0.0, "")
+
+    def _keep(self, event: str, seconds: float, fun_name: str) -> None:
+        if len(self.events) == self.events.maxlen:
+            self.dropped += 1       # the oldest makes room
+        self.events.append(CompileEvent(
+            event, time.perf_counter_ns(), seconds, fun_name,
+            threading.current_thread().name))
+
+
+_ACCOUNTING = _Accounting()
+
+
+def install_compile_accounting() -> None:
+    """Register the one ``jax.monitoring`` listener of the process;
+    ``utils.compile_cache.enable_compile_cache()`` calls it, as does an
+    :class:`IntrospectedFunction` before its first compile. Once only,
+    however often it is called."""
+    if _ACCOUNTING.installed:
+        return
+    _ACCOUNTING.installed = True
+    jax.monitoring.register_scalar_listener(_ACCOUNTING.on_open)
+    jax.monitoring.register_event_duration_secs_listener(
+        _ACCOUNTING.on_duration)
+    jax.monitoring.register_event_listener(_ACCOUNTING.on_event)
+
+
+def compile_accounting() -> Dict[str, float]:
+    """The totals so far: persistent-cache ``cache_requests`` /
+    ``cache_hits`` / ``cache_misses``, ``backend_compiles`` and their
+    ``backend_compile_s`` (a retrieval counts as the compile it
+    replaced), ``lower_s`` (trace + MLIR lowering, outermost regions
+    only) and ``events_dropped``."""
+    return dict(_ACCOUNTING.totals, events_dropped=_ACCOUNTING.dropped)
+
+
+def compile_events(since_ns: int = 0) -> List[CompileEvent]:
+    """The outermost trace / lowering regions, every backend compile and
+    every persistent-cache hit or miss (``seconds`` 0) JAX reported
+    since ``since_ns`` (``perf_counter_ns``), every jitted function of
+    the process: the newest ``COMPILE_EVENT_CAP`` of them."""
+    return [ev for ev in list(_ACCOUNTING.events) if ev.t_ns >= since_ns]
+
+
+def reset_compile_accounting() -> None:
+    """Zero the totals and forget the events (tests); the listener, once
+    installed, stays."""
+    _ACCOUNTING.reset()
+
+
+def publish_compile_accounting(registry: MetricRegistry) -> None:
+    """``telemetry/xla/cache_hits``, ``/cache_misses``,
+    ``/backend_compile_s``, ``/lower_s`` on ``registry``, as of now."""
+    for key in ("cache_hits", "cache_misses", "backend_compile_s",
+                "lower_s"):
+        set_gauge(registry, f"telemetry/xla/{key}",
+                  _ACCOUNTING.totals[key])
+
+
 @dataclasses.dataclass
 class _Entry:
     """One compiled specialization: the AOT executable + its analysis."""
@@ -290,19 +445,39 @@ class IntrospectedFunction:
         return entry.compiled(*args)
 
     def _compile(self, fp, args) -> _Entry:
+        # here, not at the top: utils.profiling imports this package
+        from dla_tpu.utils.profiling import startup_span
+        install_compile_accounting()
         is_recompile = self.compiles > 0
-        if is_recompile:
-            self._emit_compile_event(fp, aot=True)
-        compiled = self.jitted.lower(*args).compile()
+        n = self.compiles + 1
+        # the one trace and the lowering: paid at every process start,
+        # whatever the persistent cache holds
+        with startup_span("xla_lower", fn=self.name, n_compiles=n) as low:
+            lowered = self.jitted.lower(*args)
+        # the backend compile, or its retrieval from the persistent
+        # cache: which, by this thread's counts before and after
+        counts = _ACCOUNTING.thread_counts()
+        asked, hits = counts["cache_requests"], counts["cache_hits"]
+        with startup_span("xla_compile", fn=self.name, n_compiles=n,
+                          cache_hit=-1) as comp:
+            compiled = lowered.compile()
+            cache_hit = (-1 if counts["cache_requests"] == asked
+                         else int(counts["cache_hits"] > hits))
+            comp.set(cache_hit=cache_hit)
+        timing = {"lower_s": low.seconds, "compile_s": comp.seconds,
+                  "cache_hit": cache_hit}
         _LATEST_COMPILED[self.name] = compiled    # for compiled_scopes()
+        if is_recompile:
+            self._emit_compile_event(fp, aot=True, timing=timing)
         self.compiles += 1
         if not is_recompile and self.recorder is not None:
             # first compile is expected, not a recompile: ring event only
             # (last_event stays None so the caller reads it as attributed)
             self.recorder.record("compile", step=self.step, fn=self.name,
                                  first=True, attributed=True,
-                                 n_compiles=1, aot=True)
-        stats = dict(normalize_cost_analysis(
+                                 n_compiles=1, aot=True, **timing)
+        stats = dict(timing)
+        stats.update(normalize_cost_analysis(
             _safe_cost_analysis(compiled)))
         stats.update(memory_stats(compiled))
         if self.mfu_calc is not None and stats.get("flops"):
@@ -328,7 +503,9 @@ class IntrospectedFunction:
             self.step = step
         self._emit_compile_event(self._last_fp, aot=False)
 
-    def _emit_compile_event(self, new_fp, aot: bool) -> None:
+    def _emit_compile_event(self, new_fp, aot: bool,
+                            timing: Optional[Dict[str, float]] = None
+                            ) -> None:
         changes = diff_fingerprints(self._last_fp, new_fp)
         event = {
             "fn": self.name,
@@ -336,6 +513,7 @@ class IntrospectedFunction:
             "changed": changes,
             "n_compiles": self.compiles + 1,
             "aot": aot,
+            **(timing or {}),
         }
         self.recompiles += 1
         self.last_event = event
@@ -354,8 +532,8 @@ class IntrospectedFunction:
         if self.registry is None:
             return
         for key, value in stats.items():
-            _get_gauge(self.registry,
-                       f"telemetry/xla/{self.name}/{key}").set(value)
+            set_gauge(self.registry,
+                      f"telemetry/xla/{self.name}/{key}", value)
 
 
 def _safe_cost_analysis(compiled: Any) -> Any:
@@ -379,8 +557,11 @@ def _get_counter(registry: MetricRegistry, name: str) -> Counter:
     return inst
 
 
-def _get_gauge(registry: MetricRegistry, name: str) -> Gauge:
+def set_gauge(registry: MetricRegistry, name: str, value: float) -> Gauge:
+    """Set gauge ``name`` on ``registry``, registering it the first time
+    (the ``telemetry/xla/`` family is a dynamic prefix of the CATALOG)."""
     inst = registry._instruments.get(name)
     if inst is None:
         inst = registry.gauge(name)
+    inst.set(value)
     return inst

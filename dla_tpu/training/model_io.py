@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 from pathlib import Path
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 
@@ -27,6 +27,7 @@ from dla_tpu.data.tokenizers import ByteTokenizer, Tokenizer, load_tokenizer
 from dla_tpu.models.config import ModelConfig, get_model_config
 from dla_tpu.models.reward import RewardModel
 from dla_tpu.models.transformer import Transformer
+from dla_tpu.utils.profiling import startup_span
 
 
 @dataclasses.dataclass
@@ -97,6 +98,14 @@ def load_causal_lm(name_or_path: str, model_cfg: Dict[str, Any],
                    rng: jax.Array) -> ModelBundle:
     """Resolve a causal LM (policy/teacher/student):
     dla_tpu checkpoint > local HF weight dir > registry preset."""
+    with startup_span("startup_weights", source="") as span:
+        source, bundle = _load_causal_lm(name_or_path, model_cfg, rng)
+        span.set(source=source)
+    return bundle
+
+
+def _load_causal_lm(name_or_path: str, model_cfg: Dict[str, Any],
+                    rng: jax.Array) -> Tuple[str, ModelBundle]:
     overrides = _arch_overrides(model_cfg)
     if is_checkpoint_path(name_or_path):
         params, aux = load_tree_numpy(name_or_path, prefix="params")
@@ -112,7 +121,8 @@ def load_causal_lm(name_or_path: str, model_cfg: Dict[str, Any],
         # cross-topology via to_canonical_layout) reshapes here
         params = model.to_storage_layout(params)
         tok = _tokenizer_for(name_or_path, model_cfg, aux)
-        return ModelBundle(model, params, model.partition_specs(), tok, cfg)
+        return "checkpoint", ModelBundle(
+            model, params, model.partition_specs(), tok, cfg)
 
     hf = _try_hf_dir(name_or_path, overrides)
     if hf is not None:
@@ -122,7 +132,8 @@ def load_causal_lm(name_or_path: str, model_cfg: Dict[str, Any],
         # models store block-major (free reshape, no-op otherwise)
         params = model.to_storage_layout(params)
         tok = _tokenizer_for(name_or_path, model_cfg)
-        return ModelBundle(model, params, model.partition_specs(), tok, cfg)
+        return "hf_dir", ModelBundle(
+            model, params, model.partition_specs(), tok, cfg)
 
     cfg = get_model_config(name_or_path, **overrides)
     model = Transformer(cfg)
@@ -131,7 +142,8 @@ def load_causal_lm(name_or_path: str, model_cfg: Dict[str, Any],
         cfg = dataclasses.replace(cfg, vocab_size=int(tok.vocab_size))
         model = Transformer(cfg)
     params = model.init(rng)
-    return ModelBundle(model, params, model.partition_specs(), tok, cfg)
+    return "preset", ModelBundle(
+        model, params, model.partition_specs(), tok, cfg)
 
 
 def build_reward_model(model_cfg: Dict[str, Any], rng: jax.Array) -> ModelBundle:

@@ -87,7 +87,8 @@ from dla_tpu.training.optim import build_optimizer
 from dla_tpu.training.utils import check_batch_identity
 from dla_tpu.utils.logging import MetricsLogger, RunningMean, log_rank_zero
 from dla_tpu.utils.profiling import (
-    ProfileWindow, annotate, apply_debug_flags, step_annotation)
+    ProfileWindow, annotate, apply_debug_flags, report_startup,
+    startup_span, step_annotation)
 
 Pytree = Any
 LossFn = Callable[[Pytree, Pytree, Dict[str, jnp.ndarray], jax.Array],
@@ -117,6 +118,12 @@ class Trainer:
         frozen_specs: Optional[Pytree] = None,
         eval_fn: Optional[LossFn] = None,
     ):
+        with startup_span("startup_trainer_build"):
+            self._build(config, mesh, loss_fn, params, param_specs,
+                        frozen, frozen_specs, eval_fn)
+
+    def _build(self, config, mesh, loss_fn, params, param_specs, frozen,
+               frozen_specs, eval_fn) -> None:
         self.config = config
         self.mesh = mesh
         self.loss_fn = loss_fn
@@ -176,11 +183,13 @@ class Trainer:
         # counts) are replicated.
         self.opt_state_shardings = _match_opt_shardings(
             self.optimizer, self.params, self.param_shardings, mesh)
-        self.opt_state = jax.jit(
-            self.optimizer.init,
-            out_shardings=self.opt_state_shardings)(self.params)
+        with startup_span("startup_state_init"):
+            self.opt_state = jax.jit(
+                self.optimizer.init,
+                out_shardings=self.opt_state_shardings)(self.params)
 
         self.step = 0
+        self._startup_reported = False
         self._jit_train_step = None
         self._jit_eval_step = None
 
@@ -628,6 +637,12 @@ class Trainer:
             # compute is compile time, not goodput
             self.clock.mark_compile()
             self._attribute_compile(step_fn)
+        if not self._startup_reported:
+            # the first step is through (``fit`` and ``step_on_batch``
+            # both pass here): where the time since the process started
+            # went, once (gauges and one line)
+            self._startup_reported = True
+            report_startup(self.registry, log_rank_zero)
         with annotate("train_guard_fetch"):
             ok = (not self.guard.cfg.enabled
                   # dla: disable=host-sync-in-hot-loop -- guard flag rides the same materialization as the loss fetch above

@@ -24,7 +24,13 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 def enable_compile_cache() -> str:
     """Point the persistent compile cache at its directory and return
-    that directory. Safe to call more than once."""
+    that directory; start the process's compile accounting (one
+    ``jax.monitoring`` listener: cache hits and misses, lowering and
+    compile seconds of every jitted function). Safe to call more than
+    once."""
+    # here, not at the top: the model and kernel modules import this one
+    from dla_tpu.telemetry.xla_introspect import install_compile_accounting
+    install_compile_accounting()
     # An executable carries the op_name of each of its instructions (named
     # scopes, JAX's jvp / transpose / remat frames), and a trace's device
     # time is read through them (telemetry.xla_introspect.hlo_scopes). By

@@ -21,6 +21,14 @@ and "Race detection / sanitizers"). TPU-native replacement:
   in the same xplane as the device ops. ``SPANS`` is the one table of
   what the engine and the trainer emit (docs/OBSERVABILITY.md).
 
+- **Start-up spans kept in memory**: ``startup_span`` is an ``annotate``
+  that also leaves one record in a bounded process-wide list
+  (``startup_spans()``), because no profiler runs while a process
+  starts: the constructors, the lazy kernel imports and every lowering
+  and compile of an ``IntrospectedFunction``. ``startup_summary()``
+  reduces them, with the compile accounting of
+  ``telemetry.xla_introspect``, to the one line an operator reads.
+
 - **Live profiler server**: ``hardware.profiler_port: 9999`` starts
   ``jax.profiler.start_server`` for on-demand capture from TensorBoard
   while a long run is in flight.
@@ -35,11 +43,13 @@ and "Race detection / sanitizers"). TPU-native replacement:
 """
 from __future__ import annotations
 
+import threading
 import time
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
+from dla_tpu.telemetry import xla_introspect
 from dla_tpu.telemetry.trace import get_tracer
 
 _SERVER = None  # keep a ref so the profiler server outlives the call
@@ -180,6 +190,24 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "train_logging": ("trainer", ()),
     "train_eval": ("trainer", ()),
     "train_checkpoint_stall": ("trainer", ()),
+    # ---- start-up: once a process or once a compile, never inside a
+    # steady-state step; each also leaves a record in ``startup_spans()``.
+    # An argument whose value is known only when the span ends opens
+    # with a placeholder ("" / -1) that ``startup_span.set`` overwrites
+    # in the record (the profiler's event keeps the placeholder)
+    "startup_model_build": ("start-up", ("layers", "kernel_imports")),
+    "startup_kernel_import": ("start-up", ("module",)),
+    "startup_weights": ("start-up", ("source",)),
+    "startup_engine_build": ("start-up", ("slots", "pages")),
+    "startup_pool_alloc": ("start-up", ("arrays",)),
+    "startup_trainer_build": ("start-up", ()),
+    "startup_state_init": ("start-up", ()),
+    # the two halves of ``IntrospectedFunction._compile``: trace +
+    # lowering (paid at every start, cache hit or not), then the backend
+    # compile or its retrieval; ``cache_hit`` 1 / 0, -1 = the persistent
+    # cache was not asked
+    "xla_lower": ("start-up", ("fn", "n_compiles")),
+    "xla_compile": ("start-up", ("fn", "n_compiles", "cache_hit")),
 }
 
 
@@ -243,3 +271,182 @@ def mark(name: str, **args) -> None:
     """Point event on the profiler's clock: a zero-length annotation."""
     with jax.profiler.TraceAnnotation(name, **args):
         pass
+
+
+# ------------------------------------------------------- start-up records
+
+#: the most records the process keeps; later ones are counted, not kept
+STARTUP_SPAN_CAP = 512
+#: threads that exist to import a kernel's module beside the main thread
+IMPORT_THREAD_SUFFIX = "-kernel-import"
+
+
+class _StartupLog:
+    """The process's start-up records, how many the cap turned away, and
+    whether the one log line has been said."""
+    records: List[Dict[str, Any]] = []
+    dropped = 0
+    reported = False
+
+
+class startup_span(annotate):
+    """An :class:`annotate` that ALWAYS leaves one record in the
+    process-wide list :func:`startup_spans` returns: ``name``, ``thread``
+    (its name), ``start_ns`` / ``end_ns`` from ``time.perf_counter_ns()``
+    (the clock of every step span's ``host_ns``, so a start-up record
+    and the device timeline of a traced window share one axis) and
+    ``args``. The profiler sees set-up in no run (it is started after
+    it), and the host tracer is off unless installed: the record is what
+    says where a start went.
+
+    For code that runs once a process or once a compile (a constructor,
+    a lazy import, a lowering): NEVER inside a steady-state step. The
+    list is capped at ``STARTUP_SPAN_CAP`` records (later ones only
+    count as dropped), and ``tests/test_profiler_spans.py`` holds
+    ``step()`` and ``step_on_batch`` to adding none. No lock: a record is
+    built by its own thread and published by one ``list.append``."""
+    __slots__ = ("_rec",)
+
+    def __init__(self, name: str, **args):
+        super().__init__(name, **args)
+        self._rec = {"name": name,
+                     "thread": threading.current_thread().name,
+                     "start_ns": 0, "end_ns": 0, "args": args}
+
+    def __enter__(self):
+        super().__enter__()
+        self._rec["start_ns"] = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self._rec["end_ns"] = time.perf_counter_ns()
+        if len(_StartupLog.records) < STARTUP_SPAN_CAP:
+            _StartupLog.records.append(self._rec)
+        else:
+            _StartupLog.dropped += 1
+        return super().__exit__(*exc)
+
+    def set(self, **args) -> None:
+        """Overwrite arguments whose value the span's end decides (which
+        imports a build started, whether a compile hit the cache): in the
+        record only, and only names the span opened with."""
+        unknown = set(args) - set(self._rec["args"])
+        if unknown:
+            raise KeyError(f"{self._rec['name']} opened without "
+                           f"{sorted(unknown)}")
+        self._rec["args"].update(args)
+
+    @property
+    def seconds(self) -> float:
+        return (self._rec["end_ns"] - self._rec["start_ns"]) * 1e-9
+
+
+def startup_spans() -> List[Dict[str, Any]]:
+    """A copy of the records so far, in order of their ends."""
+    return [dict(r, args=dict(r["args"]))
+            for r in list(_StartupLog.records)]
+
+
+def startup_spans_dropped() -> int:
+    return _StartupLog.dropped
+
+
+def reset_startup_spans() -> None:
+    """Forget every record (tests: one pytest process builds hundreds of
+    models and engines)."""
+    del _StartupLog.records[:]
+    _StartupLog.dropped = 0
+    _StartupLog.reported = False
+
+
+def self_seconds(records: List[Dict[str, Any]]) -> List[float]:
+    """Each record's own seconds: its duration less what the records
+    nested inside it ON ITS THREAD cover, in the order given."""
+    own = [(r["end_ns"] - r["start_ns"]) * 1e-9 for r in records]
+    by_thread: Dict[str, List[int]] = {}
+    for i, r in enumerate(records):
+        by_thread.setdefault(r["thread"], []).append(i)
+    for idx in by_thread.values():
+        idx.sort(key=lambda i: (records[i]["start_ns"],
+                                -records[i]["end_ns"]))
+        stack: List[int] = []
+        for i in idx:
+            while stack and records[stack[-1]]["end_ns"] <= \
+                    records[i]["start_ns"]:
+                stack.pop()
+            if stack:
+                own[stack[-1]] -= (records[i]["end_ns"]
+                                   - records[i]["start_ns"]) * 1e-9
+            stack.append(i)
+    return [max(x, 0.0) for x in own]
+
+
+def startup_summary() -> Dict[str, Any]:
+    """Where this process's start went, from the records and the compile
+    accounting: self seconds by span name on the threads that build and
+    compile (``spans_s``), the kernel imports that ran beside them on a
+    thread of their own (``background_import_s``: hidden unless the
+    main thread then waited, which shows as its own
+    ``startup_kernel_import``), the persistent cache's hits and misses,
+    lowering and backend-compile seconds of every jitted function of the
+    process, and the three costliest functions."""
+    records = startup_spans()
+    own = self_seconds(records)
+    spans_s: Dict[str, float] = {}
+    background = 0.0
+    for rec, secs in zip(records, own):
+        if rec["thread"].endswith(IMPORT_THREAD_SUFFIX):
+            background += secs
+        else:
+            spans_s[rec["name"]] = spans_s.get(rec["name"], 0.0) + secs
+    acct = xla_introspect.compile_accounting()
+    by_fun: Dict[str, float] = {}
+    for ev in xla_introspect.compile_events():
+        if ev.fun_name:         # a cache hit or miss carries no name
+            # JAX names a trace ``f`` and its lowering and compile ``jit(f)``
+            name = ev.fun_name.removeprefix("jit(").removesuffix(")")
+            by_fun[name] = by_fun.get(name, 0.0) + ev.seconds
+    costliest = sorted(by_fun.items(), key=lambda kv: -kv[1])[:3]
+    return {"spans_s": spans_s, "background_import_s": background,
+            "cache_hits": acct["cache_hits"],
+            "cache_misses": acct["cache_misses"],
+            "lower_s": acct["lower_s"],
+            "backend_compile_s": acct["backend_compile_s"],
+            "costliest": costliest,
+            "records_dropped": startup_spans_dropped()}
+
+
+def startup_line(summary: Dict[str, Any]) -> str:
+    """The one log line of :func:`startup_summary`."""
+    spans = " ".join(f"{name} {secs:.2f}" for name, secs in sorted(
+        summary["spans_s"].items(), key=lambda kv: -kv[1])
+        if secs >= 0.005)
+    funs = ", ".join(f"{name} {secs:.2f}"
+                     for name, secs in summary["costliest"])
+    return (f"start-up (self s): {spans}; background kernel imports "
+            f"{summary['background_import_s']:.2f}; persistent cache "
+            f"{summary['cache_hits']} hits / {summary['cache_misses']} "
+            f"misses; all jitted functions: lowering "
+            f"{summary['lower_s']:.2f}, backend compile "
+            f"{summary['backend_compile_s']:.2f} (most: {funs})")
+
+
+def report_startup(registry, log=print) -> Dict[str, Any]:
+    """What an engine does after its first step that handed out a token
+    and a trainer after its first completed step: set
+    ``telemetry/xla/startup/<span name>_s`` (self seconds) and the
+    process-wide compile gauges on ``registry``, and log the one line,
+    the first time in this process (a fleet's members, a rebuilt engine
+    and a trainer beside them share one start). Returns the summary."""
+    summary = startup_summary()
+    for name, secs in summary["spans_s"].items():
+        xla_introspect.set_gauge(
+            registry, f"telemetry/xla/startup/{name}_s", secs)
+    xla_introspect.set_gauge(
+        registry, "telemetry/xla/startup/background_import_s",
+        summary["background_import_s"])
+    xla_introspect.publish_compile_accounting(registry)
+    if not _StartupLog.reported:
+        _StartupLog.reported = True
+        log("[dla_tpu] " + startup_line(summary))
+    return summary

@@ -17,10 +17,12 @@ from dla_tpu.models.config import get_model_config
 from dla_tpu.models.transformer import Transformer
 from dla_tpu.parallel.mesh import mesh_from_config
 from dla_tpu.serving import ServingConfig, ServingEngine
+from dla_tpu.telemetry import is_catalog_name
 from dla_tpu.telemetry.stepclock import SEGMENTS, StepClock
 from dla_tpu.telemetry.xla_introspect import compiled_scopes, hlo_scopes
 from dla_tpu.training.train_sft import make_sft_loss
 from dla_tpu.training.trainer import Trainer
+from dla_tpu.utils import profiling
 from dla_tpu.utils.profiling import SPANS
 
 MAX_NEW = 8
@@ -342,11 +344,234 @@ def test_trainer_emits_the_span_table(trained, monkeypatch):
 def test_every_span_of_the_table_has_a_layer_and_plain_arguments():
     assert len(SPANS) >= 25
     for name, (layer, args) in SPANS.items():
-        assert name.startswith(("serve", "train")), name
+        assert name.startswith(("serve", "train", "startup_", "xla_")), name
         assert layer and isinstance(args, tuple)
+        # the start-up layer is the start-up spans, and only they
+        assert (layer == "start-up") == name.startswith(
+            ("startup_", "xla_")), name
     # every StepClock segment has its profiler span; compute is `train`
     for seg in SEGMENTS:
         assert ("train" if seg == "compute" else f"train_{seg}") in SPANS
+
+
+# ------------------------------------------------------- start-up records
+
+def _held_to_the_table(records):
+    """Every start-up record is a row of SPANS, layer ``start-up``, with
+    exactly the row's arguments, plain values, and a sane interval."""
+    assert records
+    for r in records:
+        assert r["name"] in SPANS, r["name"]
+        layer, args = SPANS[r["name"]]
+        assert layer == "start-up" and tuple(r["args"]) == args, r
+        assert all(isinstance(v, (int, str)) and not isinstance(v, bool)
+                   for v in r["args"].values()), r
+        assert 0 < r["start_ns"] <= r["end_ns"] and r["thread"]
+
+
+def _inside(inner, outer):
+    return (outer["start_ns"] <= inner["start_ns"]
+            and inner["end_ns"] <= outer["end_ns"]
+            and inner["thread"] == outer["thread"])
+
+
+def _one(records, name, **args):
+    rows = [r for r in records if r["name"] == name
+            and all(r["args"].get(k) == v for k, v in args.items())]
+    assert len(rows) == 1, (name, args, rows)
+    return rows[0]
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny-jamba"])
+def test_engine_startup_records_and_none_from_a_steady_step(
+        monkeypatch, capsys, preset):
+    profiling.reset_startup_spans()
+    rec = Recorder().install(monkeypatch)
+    model = Transformer(get_model_config(preset))
+    params = model.init(jax.random.key(7))
+    gen = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
+                           eos_token_id=-1, pad_token_id=0)
+    eng = ServingEngine(model, params, gen,
+                        ServingConfig(**ENGINES["chunked"]))
+    eng.submit([5, 6, 7, 8, 9, 10], MAX_NEW)
+    assert capsys.readouterr().out == ""        # nothing said yet
+    for _ in range(50):
+        if eng.step():
+            break
+    records = profiling.startup_spans()
+    _held_to_the_table(records)
+    build = _one(records, "startup_model_build")
+    assert build["args"] == {"layers": model.cfg.num_layers,
+                             "kernel_imports": ""}     # no TPU here
+    engine = _one(records, "startup_engine_build")
+    assert engine["args"] == {"slots": 2, "pages": 32}
+    pools = _one(records, "startup_pool_alloc")
+    assert pools["args"]["arrays"] == len(eng.cache.spec)
+    assert _inside(pools, engine) and not _inside(engine, build)
+    for fn in ("decode", "prefill_chunk"):
+        low = _one(records, "xla_lower", fn=fn)
+        comp = _one(records, "xla_compile", fn=fn)
+        assert low["end_ns"] <= comp["start_ns"]
+        assert comp["args"]["cache_hit"] == -1  # the suite's cache is off
+        assert comp["args"]["n_compiles"] == low["args"]["n_compiles"] == 1
+        assert not _inside(low, engine)         # at the first dispatch
+    # the profiler's events are the same rows (a late argument keeps
+    # its placeholder there), and the compiles sit in their step span
+    emitted = [r for r in rec.rows if r[0].startswith(("startup_", "xla_"))]
+    assert sorted(r[0] for r in emitted) == sorted(
+        r["name"] for r in records)
+    for name, kwargs, _, _ in emitted:
+        assert tuple(kwargs) == SPANS[name][1], (name, kwargs)
+    steps = rec.named("serve")
+    for row in rec.named("xla_lower") + rec.named("xla_compile"):
+        assert any(s[2] < row[2] and row[3] < s[3] for s in steps)
+    # the first token out: gauges on the engine's registry and one line
+    snap = eng.metrics.registry.snapshot()
+    summary = profiling.startup_summary()
+    for name in ("startup_model_build", "startup_engine_build",
+                 "startup_pool_alloc", "xla_lower", "xla_compile"):
+        gauge = f"telemetry/xla/startup/{name}_s"
+        assert is_catalog_name(gauge)
+        assert snap[gauge] == pytest.approx(summary["spans_s"][name])
+    assert snap["telemetry/xla/startup/background_import_s"] == 0.0
+    assert snap["telemetry/xla/cache_misses"] == 0.0
+    assert snap["telemetry/xla/lower_s"] > 0.0
+    # self time: the engine's build does not count its pools twice
+    assert summary["spans_s"]["startup_engine_build"] == pytest.approx(
+        (engine["end_ns"] - engine["start_ns"]
+         - pools["end_ns"] + pools["start_ns"]) * 1e-9)
+    said = capsys.readouterr().out
+    assert said.count("[dla_tpu] start-up (self s):") == 1
+    assert "persistent cache 0 hits / 0 misses" in said
+    # a steady-state step adds no record and says nothing
+    for _ in range(3):
+        assert eng.step()
+    assert len(profiling.startup_spans()) == len(records)
+    assert capsys.readouterr().out == ""
+    assert eng.decode_compiles == eng.prefill_chunk_compiles == 1
+    eng.close()
+
+
+def test_trainer_startup_records_and_none_from_a_steady_step(
+        tmp_path, capsys):
+    profiling.reset_startup_spans()
+    mesh, trainer, batch = _tiny_trainer(tmp_path / "startup")
+    records = profiling.startup_spans()
+    _held_to_the_table(records)
+    build = _one(records, "startup_trainer_build")
+    state = _one(records, "startup_state_init")
+    assert _inside(state, build)
+    assert not _inside(build, _one(records, "startup_model_build"))
+    assert not any(r["name"].startswith("xla_") for r in records)
+    with jax.sharding.set_mesh(mesh):
+        trainer.step_on_batch(batch, jax.random.key(1))
+    records = profiling.startup_spans()
+    _held_to_the_table(records)
+    low = _one(records, "xla_lower", fn="train_step")
+    comp = _one(records, "xla_compile", fn="train_step")
+    assert build["end_ns"] <= low["start_ns"] <= low["end_ns"] \
+        <= comp["start_ns"]
+    snap = trainer.registry.snapshot()
+    for name in ("startup_trainer_build", "startup_state_init",
+                 "xla_lower", "xla_compile"):
+        assert snap[f"telemetry/xla/startup/{name}_s"] >= 0.0
+    assert snap["telemetry/xla/train_step/lower_s"] == pytest.approx(
+        (low["end_ns"] - low["start_ns"]) * 1e-9)
+    assert capsys.readouterr().out.count("start-up (self s):") == 1
+    with jax.sharding.set_mesh(mesh):
+        for i in range(3):
+            trainer.step_on_batch(batch, jax.random.key(i))
+    assert len(profiling.startup_spans()) == len(records)
+    assert "start-up" not in capsys.readouterr().out
+    assert trainer.train_step_compiles == 1
+
+
+def test_startup_records_are_capped_and_count_their_drops(monkeypatch):
+    profiling.reset_startup_spans()
+    monkeypatch.setattr(profiling, "STARTUP_SPAN_CAP", 4)
+    for i in range(7):
+        with profiling.startup_span("startup_kernel_import",
+                                    module=f"m{i}"):
+            pass
+    records = profiling.startup_spans()
+    assert [r["args"]["module"] for r in records] == ["m0", "m1", "m2", "m3"]
+    assert profiling.startup_spans_dropped() == 3
+    assert profiling.startup_summary()["records_dropped"] == 3
+    # a copy: a reader cannot edit the process's list
+    records[0]["args"]["module"] = "edited"
+    assert profiling.startup_spans()[0]["args"]["module"] == "m0"
+    profiling.reset_startup_spans()
+    assert profiling.startup_spans() == []
+    assert profiling.startup_spans_dropped() == 0
+
+
+def test_background_import_carries_its_threads_name_and_stays_apart():
+    """The constructor's import thread and the main thread's wait at
+    trace time are told apart by the thread's name alone; the summary
+    keeps the hidden seconds out of the foreground's."""
+    import threading
+    profiling.reset_startup_spans()
+    gate = threading.Event()
+
+    def background():
+        with profiling.startup_span("startup_kernel_import",
+                                    module="dla_tpu.ops.paged_attention"):
+            gate.wait(5.0)
+
+    thread = threading.Thread(target=background, daemon=True,
+                              name="dla-paged-kernel-import")
+    with profiling.startup_span("xla_lower", fn="decode", n_compiles=1):
+        thread.start()
+        with profiling.startup_span("startup_kernel_import",
+                                    module="dla_tpu.ops.paged_attention"):
+            time.sleep(0.02)
+            gate.set()
+            thread.join()
+    records = profiling.startup_spans()
+    by_thread = {r["thread"]: r for r in records
+                 if r["name"] == "startup_kernel_import"}
+    assert set(by_thread) == {"dla-paged-kernel-import", "MainThread"}
+    lower = _one(records, "xla_lower")
+    wait = by_thread["MainThread"]
+    assert _inside(wait, lower)
+    summary = profiling.startup_summary()
+    hidden = by_thread["dla-paged-kernel-import"]
+    assert summary["background_import_s"] == pytest.approx(
+        (hidden["end_ns"] - hidden["start_ns"]) * 1e-9)
+    assert summary["spans_s"]["startup_kernel_import"] == pytest.approx(
+        (wait["end_ns"] - wait["start_ns"]) * 1e-9)
+    # the lowering's self time leaves its nested wait out, and the other
+    # thread's import, which overlaps it, is not subtracted
+    assert summary["spans_s"]["xla_lower"] == pytest.approx(
+        (lower["end_ns"] - lower["start_ns"]
+         - wait["end_ns"] + wait["start_ns"]) * 1e-9)
+    assert "background kernel imports" in profiling.startup_line(summary)
+
+
+def test_a_late_argument_has_to_be_one_the_span_opened_with():
+    profiling.reset_startup_spans()
+    with profiling.startup_span("xla_compile", fn="f", n_compiles=1,
+                                cache_hit=-1) as span:
+        span.set(cache_hit=1)
+        with pytest.raises(KeyError):
+            span.set(hit=1)
+    assert profiling.startup_spans()[0]["args"] == {
+        "fn": "f", "n_compiles": 1, "cache_hit": 1}
+    assert span.seconds >= 0.0
+
+
+def test_weights_path_leaves_its_record():
+    from dla_tpu.training.model_io import load_causal_lm
+    profiling.reset_startup_spans()
+    bundle = load_causal_lm("tiny", {"tokenizer": "byte"},
+                            jax.random.key(0))
+    records = profiling.startup_spans()
+    _held_to_the_table(records)
+    weights = _one(records, "startup_weights")
+    assert weights["args"] == {"source": "preset"}
+    assert all(_inside(r, weights) for r in records
+               if r["name"] == "startup_model_build")
+    assert bundle.params is not None
 
 
 # ------------------------------------------------- a real profiler trace

@@ -302,3 +302,182 @@ def test_trainer_introspection_off_switch(mesh8, tmp_path):
         assert not isinstance(tr._jit_train_step, IntrospectedFunction)
         assert "telemetry/xla/train_step/flops" not in \
             tr.registry.snapshot()
+
+
+# ---------------------------------------------------------------------------
+# what compiling cost: the lower / compile split, persistent-cache hits,
+# and the process-wide accounting behind them
+# ---------------------------------------------------------------------------
+
+def test_lower_and_compile_seconds_gauges_and_recorder_event():
+    """The wrapper times its one lowering and its one compile apart and
+    says whether the persistent cache answered: on the per-fn gauges, on
+    both kinds of ``compile`` event, and as start-up records. The tests
+    run with the persistent cache off, so it was not asked: -1."""
+    from dla_tpu.utils import profiling
+    profiling.reset_startup_spans()
+    reg = MetricRegistry()
+    rec = FlightRecorder(capacity=8)
+    fn, ticks = _wrapped("decode", registry=reg, recorder=rec)
+    fn(np.ones((4, 8), np.float32))
+    fn(np.ones((4, 8), np.float32))
+    assert len(ticks) == 1 and fn.compiles == 1       # still one of each
+    snap = reg.snapshot()
+    for key in ("lower_s", "compile_s", "cache_hit"):
+        assert is_catalog_name(f"telemetry/xla/decode/{key}")
+    assert snap["telemetry/xla/decode/lower_s"] > 0.0
+    assert snap["telemetry/xla/decode/compile_s"] > 0.0
+    assert snap["telemetry/xla/decode/cache_hit"] == -1.0
+    first = [e for e in rec.events if e["kind"] == "compile"][0]
+    assert first["first"] and first["cache_hit"] == -1
+    assert first["lower_s"] == pytest.approx(fn.stats["lower_s"])
+    assert first["compile_s"] == pytest.approx(fn.stats["compile_s"])
+    # the induced recompile's event carries its own three numbers
+    fn(np.ones((4, 16), np.float32))
+    again = [e for e in rec.events if e["kind"] == "compile"][1]
+    assert again["attributed"] and again["n_compiles"] == 2
+    assert again["lower_s"] > 0.0 and again["compile_s"] > 0.0
+    assert again["cache_hit"] == -1 and fn.last_event["cache_hit"] == -1
+    # two records a compile, one after the other on this thread
+    records = [r for r in profiling.startup_spans()
+               if r["args"].get("fn") == "decode"]
+    assert [(r["name"], r["args"]["n_compiles"]) for r in records] == [
+        ("xla_lower", 1), ("xla_compile", 1),
+        ("xla_lower", 2), ("xla_compile", 2)]
+    assert records[0]["end_ns"] <= records[1]["start_ns"]
+    assert records[1]["args"]["cache_hit"] == -1
+    assert (records[0]["end_ns"] - records[0]["start_ns"]) * 1e-9 == \
+        pytest.approx(first["lower_s"])
+
+
+_CACHE_PROBE = """
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from dla_tpu.telemetry import xla_introspect as xi
+ticks = []
+def make():                 # two function objects, one program
+    def f(x):
+        ticks.append(1)
+        return jnp.sum(x * 2.0)
+    return f
+x, out = np.ones((4, 8), np.float32), []
+for _ in range(2):
+    fn = xi.IntrospectedFunction("fn", jax.jit(make()))
+    fn(x); fn(x)
+    out.append([fn.stats["cache_hit"], fn.compiles])
+print(json.dumps({"out": out, "ticks": len(ticks),
+                  "acct": xi.compile_accounting()}))
+"""
+
+
+def test_cache_hit_reads_0_then_1_over_a_fresh_cache_directory(tmp_path):
+    """A process of its own (the suite keeps the persistent cache off),
+    thresholds at 0 so that a tiny program is written: the first wrapper
+    misses and writes, the second, over the same program, is answered
+    from the directory. One trace and one compile each."""
+    import json
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.dirname(os.path.dirname(__file__)))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    done = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE, str(tmp_path / "cache")],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    got = json.loads(done.stdout.strip().splitlines()[-1])
+    assert got["out"] == [[0, 1], [1, 1]]
+    assert got["ticks"] == 2
+    acct = got["acct"]
+    assert (acct["cache_requests"], acct["cache_hits"],
+            acct["cache_misses"]) == (2, 1, 1)
+    assert acct["backend_compiles"] == 2 and acct["lower_s"] > 0.0
+
+
+def test_accounting_listener_installs_once(monkeypatch):
+    """However often ``enable_compile_cache()`` is called, and whoever
+    else asks: one listener of each kind, so nothing is counted twice."""
+    from jax._src import monitoring
+    from dla_tpu.telemetry import xla_introspect as xi
+    from dla_tpu.utils import compile_cache
+    before_dir = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent/unused")
+    try:
+        for _ in range(3):
+            compile_cache.enable_compile_cache()
+            xi.install_compile_accounting()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before_dir)
+    acct = xi._ACCOUNTING
+    assert monitoring.get_event_duration_listeners().count(
+        acct.on_duration) == 1
+    assert monitoring.get_event_listeners().count(acct.on_event) == 1
+    assert monitoring.get_scalar_listeners().count(acct.on_open) == 1
+
+
+def test_accounting_sees_every_jitted_function_and_counts_nesting_once():
+    """An unwrapped ``jax.jit`` is on the books too; a callee traced
+    inside its caller's trace adds no seconds of its own."""
+    from dla_tpu.telemetry import xla_introspect as xi
+    xi.install_compile_accounting()
+    xi.reset_compile_accounting()
+
+    @jax.jit
+    def inner_fn(x):
+        return jnp.tanh(x) * 3.0
+
+    @jax.jit
+    def outer_fn(x):
+        return jnp.sum(inner_fn(x) + 1.0)
+
+    import time
+    mark = time.perf_counter_ns()
+    outer_fn(np.ones((3, 5), np.float32))
+    events = xi.compile_events(mark)
+    names = [(e.event.rsplit("/", 1)[1], e.fun_name) for e in events]
+    assert ("backend_compile_duration", "jit(outer_fn)") in names
+    assert ("jaxpr_to_mlir_module_duration", "jit(outer_fn)") in names
+    assert any(k == "jaxpr_trace_duration" and f == "outer_fn"
+               for k, f in names)
+    # the callee's trace lies inside the caller's: not an event of its own
+    assert not any("inner_fn" in f for _, f in names)
+    assert all(e.thread == "MainThread" and e.t_ns >= mark for e in events)
+    acct = xi.compile_accounting()
+    lowered = sum(e.seconds for e in events
+                  if not e.event.endswith("backend_compile_duration"))
+    assert acct["lower_s"] == pytest.approx(lowered)
+    assert acct["backend_compiles"] >= 1
+    assert acct["cache_hits"] == acct["cache_misses"] == 0   # cache off
+    assert xi.compile_events(time.perf_counter_ns()) == []
+    reg = MetricRegistry()
+    xi.publish_compile_accounting(reg)
+    snap = reg.snapshot()
+    for key in ("cache_hits", "cache_misses", "backend_compile_s",
+                "lower_s"):
+        assert is_catalog_name(f"telemetry/xla/{key}")
+        assert snap[f"telemetry/xla/{key}"] == acct[key]
+
+
+def test_compile_event_list_honours_its_cap(monkeypatch):
+    """The newest events stay (a long-lived process asks what its latest
+    phase compiled: chip_smoke's trainer phase late in a pytest worker);
+    the totals count on."""
+    from dla_tpu.telemetry import xla_introspect as xi
+    monkeypatch.setattr(xi, "COMPILE_EVENT_CAP", 3)
+    xi.reset_compile_accounting()
+    acct = xi._ACCOUNTING
+    for i in range(5):
+        acct.on_duration(xi.BACKEND_COMPILE_EVENT, 0.5, fun_name=f"jit(f{i})")
+    assert [e.fun_name for e in xi.compile_events()] == [
+        "jit(f2)", "jit(f3)", "jit(f4)"]
+    got = xi.compile_accounting()
+    assert got["events_dropped"] == 2 and got["backend_compiles"] == 5
+    assert got["backend_compile_s"] == pytest.approx(2.5)  # totals go on
+    monkeypatch.undo()          # the next list is made at the real cap
+    xi.reset_compile_accounting()
+    assert xi.compile_events() == []
+    assert xi._ACCOUNTING.events.maxlen == xi.COMPILE_EVENT_CAP
