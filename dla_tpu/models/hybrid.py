@@ -39,7 +39,10 @@ Plain attention (mixer ``attention``) is the textbook form: no rotary,
 no bias, scale ``head_dim ** -0.5``, query heads grouped over the key /
 value heads. A cached row is a token's key (or value) heads side by
 side, ``num_kv_heads * head_dim`` numbers, the same count as the paired
-row above; with one key / value head the row is the key.
+row above; with one key / value head the row is the key. In a paged
+chunk program these layers read their cached rows in blocks up to the
+context (``ops.attention.blockwise_paged_attention``), not the slot's
+gathered window.
 """
 from __future__ import annotations
 
@@ -51,6 +54,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dla_tpu.models.config import CacheArray, LayerSpec
+from dla_tpu.ops.attention import blockwise_paged_attention, decode_attention
 from dla_tpu.ops.norms import layer_norm, rms_norm
 from dla_tpu.ops.selective_scan import (
     causal_conv_step,
@@ -62,6 +66,12 @@ Params = Dict[str, jnp.ndarray]
 F32 = jnp.float32
 #: a period longer than this is not looked for
 MAX_PERIOD = 4
+#: cached columns a block of the chunk programs' plain-attention walk
+#: reads (``chunk_attention_block_pages``). Settled on a v5e at 20 heads
+#: x 512 tokens: a block's float32 scores are then 40 MiB and XLA keeps
+#: them in VMEM (0.05 ms a block a layer; at 2,048 one layer's of two
+#: spill to HBM, 0.22; at 4,096 both, 0.70: PERF.md, PR 39)
+CHUNK_ATTENTION_BLOCK_COLUMNS = 1024
 #: the device scope of each mixer (utils/profiling.py DEVICE_SCOPES)
 _ATTN_SCOPE = {"paged_window": "swa_attention", "paged": "full_attention",
                "shared": "cross_attention"}
@@ -95,6 +105,17 @@ def layer_runs(spec: Tuple[LayerSpec, ...]) -> Tuple[Run, ...]:
         runs.append(best)
         i += best.period * best.reps
     return tuple(runs)
+
+
+def chunk_attention_block_pages(page_size: int, table_pages: int) -> int:
+    """Pages a block of ``blockwise_paged_attention`` in a chunk program
+    over pages of ``page_size`` and block tables of ``table_pages``
+    entries: ``CHUNK_ATTENTION_BLOCK_COLUMNS`` columns in whole pages, the
+    whole table where it is shorter. From the shapes alone, so that the
+    engine's counters can work it out again
+    (``HybridStack.chunk_attention_walk``)."""
+    return max(1, min(CHUNK_ATTENTION_BLOCK_COLUMNS // page_size,
+                      table_pages))
 
 
 def lambda_init(layer_index) -> jnp.ndarray:
@@ -138,6 +159,10 @@ class HybridStack:
         # cross layers (1 where every paged layer reads its own alone)
         shared = sum(s.cache == "shared" for s in self.spec)
         self.shared_readers = 1 + shared
+        # plain-attention layers over rows of their own: what a chunk
+        # program reads through the block walk (``paged``)
+        self.walked_layers = sum(
+            s.mixer == "attention" and s.cache == "paged" for s in self.spec)
         self._cache_spec = self._build_cache_spec()
         # cache kind -> positions of its arrays in cache_spec()
         self._slots: Dict[str, Tuple[int, ...]] = {}
@@ -154,6 +179,18 @@ class HybridStack:
                     "the paged attention layer whose cache the cross "
                     "layers read has to stand alone in the spec, not "
                     "inside a repeating stretch")
+
+    def chunk_attention_walk(self, page_size: int,
+                             table_pages: int) -> Tuple[int, int]:
+        """(cached columns a block, layers that walk) of the block walk
+        in this stack's paged chunk program over pages of ``page_size``
+        and block tables of ``table_pages`` entries (``paged``); (0, 0)
+        where no layer walks: the host's half of the counters
+        ``serving/prefill/attn_read_tokens`` / ``attn_window_tokens``."""
+        if not self.walked_layers:
+            return 0, 0
+        return (page_size * chunk_attention_block_pages(
+            page_size, table_pages), self.walked_layers)
 
     # ------------------------------------------------------------- storage
 
@@ -674,18 +711,31 @@ class HybridStack:
                 out = self._gmu(layer, h, carry_["memory"])
             elif spec.mixer == "attention":
                 # plain attention over the layer's own rows, arrays i of
-                # the paged kind; a gathered row splits into its key /
-                # value heads (one head: the row is the key)
+                # the paged kind. The one-token step of every slot
+                # gathers each row's whole window (a row splits into its
+                # key / value heads; one head: the row is the key); a
+                # chunk walks the cached columns in blocks up to the
+                # rows' context, ``q0`` (``blockwise_paged_attention``)
                 with jax.named_scope(_ATTN_SCOPE[spec.cache]):
                     ki, vi = slots["paged"]
                     q, k, v = self._plain_qkv(layer, h)
-                    heads = (b, window_cols, cfg.num_kv_heads, cfg.head_dim_)
-                    att = attention(
-                        q, pools_[ki][i, tables].reshape(heads),
-                        pools_[vi][i, tables].reshape(heads), k, v,
-                        kv_valid=view["valid"], kv_positions=view["pos"],
-                        window=None, q_positions=positions,
-                        softmax_scale=cfg.head_dim_ ** -0.5)
+                    if attention is decode_attention:
+                        heads = (b, window_cols, cfg.num_kv_heads,
+                                 cfg.head_dim_)
+                        att = attention(
+                            q, pools_[ki][i, tables].reshape(heads),
+                            pools_[vi][i, tables].reshape(heads), k, v,
+                            kv_valid=view["valid"],
+                            kv_positions=view["pos"], window=None,
+                            q_positions=positions,
+                            softmax_scale=cfg.head_dim_ ** -0.5)
+                    else:
+                        att = blockwise_paged_attention(
+                            q, pools_[ki], pools_[vi], i, tables, q0, k, v,
+                            q_positions=positions,
+                            block_pages=chunk_attention_block_pages(
+                                pools_[ki].shape[2], tables.shape[1]),
+                            softmax_scale=cfg.head_dim_ ** -0.5)
                     at = (i, view["write_pages"], view["write_offs"])
                     pools_[ki] = pools_[ki].at[at].set(k.reshape(b, t, -1))
                     pools_[vi] = pools_[vi].at[at].set(v.reshape(b, t, -1))

@@ -264,6 +264,106 @@ def block_decode_attention(
     return out.reshape(b, g, h, d)
 
 
+def blockwise_paged_attention(
+    q: jnp.ndarray,        # [B, T, H, D]  the chunk's queries
+    k_pool: jnp.ndarray,   # [L, pages, page, ...]  a row = K * D numbers
+    v_pool: jnp.ndarray,
+    layer,                 # scalar index into the pools' leading axis
+    block_tables: jnp.ndarray,  # [B, pages/slot]  physical page ids
+    context: jnp.ndarray,  # [B] int32  cached columns before the chunk
+    k_new: jnp.ndarray,    # [B, T, K, D]  the chunk's keys
+    v_new: jnp.ndarray,
+    *,
+    q_positions: jnp.ndarray,   # [B, T] absolute position per query
+    block_pages: int,
+    softmax_scale: Optional[float] = None,
+) -> jnp.ndarray:
+    """``block_decode_attention`` over each row's paged prefix, WITHOUT
+    gathering the row's window: the cached columns are read in blocks of
+    ``block_pages`` whole pages up to the live context, with an online
+    soft-max. Row b's column c lives at offset ``c % page`` of page
+    ``block_tables[b, c // page]``, sits at position c and is attended
+    iff ``c < context[b]`` (what ``PagedKVCache`` keeps for a prefilling
+    slot: ``valid`` a prefix of length ``start``, every query of the
+    chunk at or past it), so validity, causality and position are one
+    compare; no window, no soft-cap.
+
+    The walk is a ``lax.fori_loop`` whose trip count is computed in the
+    program, ``ceil(max(context) / block columns)``: a block past every
+    row's context is neither gathered nor scored, and a context of 0 runs
+    none. A block gathers its pages of ``layer`` alone (the reason
+    ``Transformer._paged_layers`` gathers a layer at a time holds here
+    too), scores [B, K, G, T, block] in float32, masks ``column <
+    context`` and folds into the running row maximum, row sum and
+    un-normalised float32 output. The chunk's own keys and values come
+    last (causal by absolute position: pad tokens carry later positions
+    than every real query), and the output is normalised once. Operands
+    are as ``block_decode_attention`` has them: the weights are cast to
+    the value dtype for the value product, which accumulates in float32.
+    The same mathematics, rounding apart.
+
+    One bound serves every row, so this is for programs of one slot or
+    few (a prefill chunk). The one-token step of many slots with as many
+    lengths keeps its whole-window gather (``decode_attention``): bounded
+    by the longest row it would read nearly everything, and what it wants
+    is the per-slot page walk of ops/paged_attention.py. Returns
+    [B, T, H, D]."""
+    b, t, h, d = q.shape
+    kheads = k_new.shape[2]
+    groups = h // kheads
+    page = k_pool.shape[2]
+    cols = block_pages * page
+    scale = softmax_scale if softmax_scale is not None else d ** -0.5
+    # whole blocks: pad entries name the trash page, and their columns
+    # lie past every context
+    n_blocks = -(-block_tables.shape[1] // block_pages)
+    tables = jnp.pad(block_tables, (
+        (0, 0), (0, n_blocks * block_pages - block_tables.shape[1])))
+    qg = q.reshape(b, t, kheads, groups, d).transpose(0, 2, 3, 1, 4)
+    context = context.astype(jnp.int32)
+
+    def fold(carry, scores, mask, values):
+        """One block of masked scores [B, K, G, T, S] and its values
+        [B, S, K, D] into (row maximum, row sum, output)."""
+        m, l, acc = carry
+        scores = jnp.where(mask, scores, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+        # a row with nothing to attend yet keeps m at NEG_INF: its
+        # weights are zeroed by the mask, not by the exponential
+        p = jnp.where(mask, jnp.exp(scores - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bkgts,bskd->bkgtd", p.astype(values.dtype), values,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    def block(j, carry):
+        pages = jax.lax.dynamic_slice_in_dim(
+            tables, j * block_pages, block_pages, axis=1)
+        k_blk = k_pool[layer, pages].reshape(b, cols, kheads, d)
+        v_blk = v_pool[layer, pages].reshape(b, cols, kheads, d)
+        scores = jnp.einsum("bkgtd,bskd->bkgts", qg, k_blk,
+                            preferred_element_type=jnp.float32) * scale
+        column = j * cols + jnp.arange(cols, dtype=jnp.int32)
+        mask = column[None, :] < context[:, None]                # [B, S]
+        return fold(carry, scores, mask[:, None, None, None, :], v_blk)
+
+    stats = (b, kheads, groups, t)
+    carry = (jnp.full(stats, NEG_INF, jnp.float32),
+             jnp.zeros(stats, jnp.float32),
+             jnp.zeros(stats + (d,), jnp.float32))
+    trips = jnp.minimum(-(-jnp.max(context) // cols), n_blocks)
+    carry = jax.lax.fori_loop(0, trips, block, carry)
+
+    self_scores = jnp.einsum("bkgtd,bskd->bkgts", qg, k_new,
+                             preferred_element_type=jnp.float32) * scale
+    smask = q_positions[:, :, None] >= q_positions[:, None, :]   # [B,T,T]
+    _, l, acc = fold(carry, self_scores, smask[:, None, None], v_new)
+    out = (acc / l[..., None]).astype(v_new.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+
+
 def decode_attention(
     q: jnp.ndarray,       # [B, 1, H, D]  the current token's query
     k_cache: jnp.ndarray,  # [B, S, K, D]  cache BEFORE this step's write

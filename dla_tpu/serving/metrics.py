@@ -98,6 +98,14 @@ class ServingMetrics:
         # kernel (ops/selective_scan_kernel.py)
         self.prefill_scan_kernel_chunks = r.counter(
             "serving/prefill/scan_kernel_chunks")
+        # cached columns the chunks' attention block walk read, and the
+        # whole windows a gather would have read, both x walking layers
+        # (ops/attention.py blockwise_paged_attention; 0 / 0 where the
+        # chunk program gathers)
+        self.prefill_attn_read_tokens = r.counter(
+            "serving/prefill/attn_read_tokens")
+        self.prefill_attn_window_tokens = r.counter(
+            "serving/prefill/attn_window_tokens")
         self.prefill_tokens_saved = r.counter(
             "serving/prefill/tokens_saved")
         self.requests_shed = r.counter("serving/requests_shed")
@@ -177,6 +185,10 @@ class ServingMetrics:
                 self.prefill_scan_tokens.value),
             "serving/prefill/scan_kernel_chunks": float(
                 self.prefill_scan_kernel_chunks.value),
+            "serving/prefill/attn_read_tokens": float(
+                self.prefill_attn_read_tokens.value),
+            "serving/prefill/attn_window_tokens": float(
+                self.prefill_attn_window_tokens.value),
             "serving/prefill/tokens_saved": float(
                 self.prefill_tokens_saved.value),
             "serving/requests_shed": float(self.requests_shed.value),

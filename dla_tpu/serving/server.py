@@ -308,6 +308,10 @@ class ServingEngine:
         self.metrics.kv_bytes_per_token.set(self.cache.bytes_per_token)
         self.metrics.kv_paged_layers.set(self.cache.layers_of("paged"))
         self._state_layers = self.cache.layers_of("state")
+        # (block columns, layers) of the chunk program's attention block
+        # walk, (0, 0) where it gathers: the host's half of the counters
+        self._attn_walk = (model.hybrid.chunk_attention_walk(
+            geom.page_size, geom.pages_per_slot) if model.hybrid else (0, 0))
         self.metrics.window_bytes_per_token.set(
             self.cache.window_bytes_per_token)
         self.metrics.state_bytes_per_slot.set(
@@ -1809,6 +1813,12 @@ class ServingEngine:
         self.metrics.prefill_scan_tokens.inc(nvalid * self._state_layers)
         if self._kernel_scan:
             self.metrics.prefill_scan_kernel_chunks.inc()
+        cols, layers = self._attn_walk
+        if layers:
+            self.metrics.prefill_attn_read_tokens.inc(
+                -(-start // cols) * cols * layers)
+            self.metrics.prefill_attn_window_tokens.inc(
+                c.geom.slot_window * layers)
         c.mark_computed(slot, start, nvalid)
         req.prefill_pos = start + nvalid
         if req.prefill_pos < n:

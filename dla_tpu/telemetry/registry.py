@@ -295,6 +295,15 @@ CATALOG: Tuple[MetricSpec, ...] = (
        "prefill chunks dispatched to a program that holds the "
        "selective-scan kernel (the state-space layers' state stays in "
        "VMEM through the chunk)", "step"),
+    _s("serving/prefill/attn_read_tokens", "counter", "tokens",
+       "cached columns the prefill chunks' attention block walk read: "
+       "ceil(context / block columns) x block columns x the layers "
+       "that walk (0 where the chunk program gathers whole windows)",
+       "step"),
+    _s("serving/prefill/attn_window_tokens", "counter", "tokens",
+       "slot window x the layers that walk, a chunk: what a whole-"
+       "window gather would have read; attn_read_tokens over this is "
+       "the share of the window read", "step"),
     _s("serving/prefill/tokens_saved", "counter", "tokens",
        "prefill tokens skipped via cached prefixes", "step"),
     # -- serving resilience (serving.resilience): admission control,

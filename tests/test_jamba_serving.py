@@ -33,9 +33,11 @@ from dla_tpu.models.config import (
     get_model_config,
     jamba_layers,
 )
+from dla_tpu.models import hybrid
 from dla_tpu.models.hf_import import hf_config_to_model_config
 from dla_tpu.models.hybrid import Run, layer_runs
 from dla_tpu.models.transformer import Transformer
+from dla_tpu.ops.attention import block_decode_attention
 from dla_tpu.serving import ServingConfig, ServingEngine
 from dla_tpu.telemetry.xla_introspect import compiled_scopes
 from dla_tpu.utils.profiling import DEVICE_SCOPES, SPANS
@@ -175,14 +177,31 @@ def test_apply_matches_the_reference(model_and_params, ref):
 
 # --------------------------------------------------- through the engine
 
+@pytest.fixture
+def blocks_of(monkeypatch):
+    """Steer the chunk program's attention walk to blocks of so many
+    cached columns: a test's stand-in for a long window (the program
+    works the size out from the shapes, and no option reaches it)."""
+    def set_columns(columns):
+        monkeypatch.setattr(hybrid, "CHUNK_ATTENTION_BLOCK_COLUMNS", columns)
+    return set_columns
+
+
+@pytest.mark.parametrize("block_columns", [None, CHUNK],
+                         ids=["one_block_a_window", "blocks_of_a_chunk"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_chunked_prefill_and_paged_decode_match_the_reference(
-        model_and_params, ref, dtype):
+        model_and_params, ref, dtype, block_columns, blocks_of):
     """Prompts of several chunks with a ragged last one, five requests of
     different lengths through three slots (so slots are freed and reused:
     a stale state or a stale row would show). A bfloat16 engine fails the
-    float32 limit and meets its own."""
+    float32 limit and meets its own. The chunks' attention layers walk
+    their cached rows in blocks: the whole window in one (the shapes'
+    own size here), or blocks of 8 columns, so that the 23-token prompt's
+    chunks run 0, 1 and 2 of them."""
     model, params = model_and_params
+    if block_columns:
+        blocks_of(block_columns)
     if dtype == "bfloat16":
         model = Transformer(dataclasses.replace(model.cfg, dtype="bfloat16"))
     eng = _engine(model, params)
@@ -439,6 +458,121 @@ def test_routed_experts_refuse_by_name():
     with pytest.raises(ValueError, match="num_experts=16"):
         hf_config_to_model_config({**_published_keys(), "num_experts": 16,
                                    "num_experts_per_tok": 2})
+
+
+# ------------------------------------- the chunk program's block walk
+
+def _gathering(q, k_pool, v_pool, layer, tables, context, k_new, v_new, *,
+               q_positions, block_pages, softmax_scale):
+    """What the chunk program's attention layers did before the walk:
+    ``block_decode_attention`` over the row's whole gathered window."""
+    b = q.shape[0]
+    window = tables.shape[1] * k_pool.shape[2]
+    heads = (b, window) + k_new.shape[2:]
+    cols = jnp.arange(window, dtype=jnp.int32)
+    return block_decode_attention(
+        q, k_pool[layer, tables].reshape(heads),
+        v_pool[layer, tables].reshape(heads), k_new, v_new,
+        kv_valid=cols[None] < context[:, None], q_positions=q_positions,
+        kv_positions=jnp.broadcast_to(cols, (b, window)),
+        softmax_scale=softmax_scale)
+
+
+def _prefilled(model, params, prompt, new, monkeypatch, gather):
+    """One request through an engine whose chunk program walks blocks of
+    8 columns, or gathers: (the pools after the prompt's last chunk, the
+    request's result)."""
+    monkeypatch.setattr(hybrid, "CHUNK_ATTENTION_BLOCK_COLUMNS", CHUNK)
+    if gather:
+        monkeypatch.setattr(hybrid, "blockwise_paged_attention", _gathering)
+    eng = _engine(model, params, num_slots=1)
+    rid = eng.submit(prompt, new)
+    while not eng.result(rid).generated:
+        eng.step()
+    pools = [np.array(p) for p in eng.cache.pools]
+    _drain(eng)
+    res = eng.result(rid)
+    eng.close()
+    monkeypatch.undo()
+    return pools, res
+
+
+def test_walked_chunks_write_the_pages_of_the_gathered_window(
+        model_and_params, monkeypatch):
+    """A prompt of four chunks (contexts 0, 8, 16, 24: 0 to 3 blocks)
+    through the walk and through ``block_decode_attention`` over the
+    gathered window: the same pages, the same state, and at float32 the
+    same greedy tokens with the same log-probabilities."""
+    model, params = model_and_params
+    prompt = _prompts([29], seed=13)[0]
+    walked, got = _prefilled(model, params, prompt, 24, monkeypatch, False)
+    gathered, want = _prefilled(model, params, prompt, 24, monkeypatch, True)
+    for a, b in zip(walked, gathered):
+        assert np.abs(a - b).max() < 1e-5
+    # the first attention layer's rows come from below every attention
+    assert np.array_equal(walked[0][0], gathered[0][0])
+    assert got.generated == want.generated
+    assert np.abs(np.asarray(got.generated_logprobs)
+                  - np.asarray(want.generated_logprobs)).max() < 1e-5
+
+
+@pytest.mark.parametrize("program", ["walk", "gather"])
+def test_chunk_program_holds_no_score_as_wide_as_the_window(
+        model_and_params, monkeypatch, program):
+    """The compiled chunk program of an engine with a 96-column window
+    (no other size of the model is 96 or 104) walking blocks of 8: no
+    float buffer is as wide as the window, or the window and the chunk.
+    The gathered form, compiled the same way, holds both: the search
+    finds what it looks for."""
+    model, params = model_and_params
+    monkeypatch.setattr(hybrid, "CHUNK_ATTENTION_BLOCK_COLUMNS", CHUNK)
+    if program == "gather":
+        monkeypatch.setattr(hybrid, "blockwise_paged_attention", _gathering)
+    eng = _engine(model, params, num_slots=1, max_model_len=96,
+                  num_pages=80)
+    window = eng.cache.geom.slot_window
+    assert window == 96
+    text = jax.jit(eng._prefill_chunk_fn, donate_argnums=1).lower(
+        params, eng.cache.pools,
+        jnp.zeros((eng._chunk_layout.width,), jnp.int32)).compile().as_text()
+    eng.close()
+    wide = re.findall(
+        rf"f32\[(?:\d+,)*(?:{window}|{window + CHUNK})(?:,\d+)*\]", text)
+    if program == "walk":
+        assert not wide, sorted(set(wide))
+        assert re.search(rf"f32\[(?:\d+,)*{CHUNK},{CHUNK}\]", text)
+    else:
+        assert wide
+
+
+@pytest.mark.parametrize("model_name, walks", [
+    ("tiny-jamba", True), ("tiny-sambay", False), ("tiny", False)])
+def test_attention_walk_counters(model_name, walks, blocks_of):
+    """``serving/prefill/attn_read_tokens`` over ``attn_window_tokens``:
+    the share of the window the chunks' block walk read, from the host's
+    ``start``; 0 / 0 for a model whose chunk program gathers."""
+    blocks_of(CHUNK)
+    model = Transformer(get_model_config(model_name))
+    params = model.init(jax.random.key(0))
+    eng = _engine(model, params, num_slots=1)
+    assert eng._attn_walk == ((CHUNK, 2) if walks else (0, 0))
+    eng.submit(_prompts([29])[0], 2)
+    eng.run_until_drained(max_steps=50)
+    snap = eng.metrics.snapshot()
+    assert snap["serving/prefill/chunks"] == 4
+    if walks:
+        # contexts 0, 8, 16, 24 in blocks of 8, two layers; window 64
+        assert snap["serving/prefill/attn_read_tokens"] == 2 * (8 + 16 + 24)
+        assert snap["serving/prefill/attn_window_tokens"] == 4 * 2 * 64
+    else:
+        assert snap["serving/prefill/attn_read_tokens"] == 0
+        assert snap["serving/prefill/attn_window_tokens"] == 0
+    eng.close()
+    if model.hybrid:
+        assert model.hybrid.chunk_attention_walk(PAGE, 16) == eng._attn_walk
+    # blocks are whole pages: at least one, at most the table
+    assert hybrid.chunk_attention_block_pages(16, 2112) == 1
+    assert hybrid.chunk_attention_block_pages(PAGE, 1) == 1
 
 
 # ---------------------------------------- spans, scopes and the programs

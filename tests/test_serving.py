@@ -329,6 +329,10 @@ def test_serving_metrics_surface(model_and_params, reference_tokens):
     assert snap["serving/ttft_ms_p50"] >= 0.0
     assert snap["serving/page_occupancy_peak"] > 0.0
     assert snap["serving/page_occupancy"] == 0.0   # drained
+    # a dense model's chunk program gathers: no block walk to count
+    assert snap["serving/prefill/chunks"] >= 2.0
+    assert snap["serving/prefill/attn_read_tokens"] == 0.0
+    assert snap["serving/prefill/attn_window_tokens"] == 0.0
 
 
 @pytest.mark.parametrize("page_size, max_model_len, want", [
