@@ -24,6 +24,9 @@ class LayerSpec(NamedTuple):
       ssm                  selective state-space layer (Mamba-1); its
                            scan output is also the memory the next
                            ``gmu`` layers gate
+      ssm2                 Mamba-2 state-space layer (one scalar decay a
+                           head, a state matrix a head, B and C shared by
+                           a group, a gated RMSNorm before ``out_proj``)
       diff_attention       differential attention (arXiv:2410.05258)
                            over the layer's own keys and values
       gmu                  gated memory unit (arXiv:2507.06607): gates the
@@ -68,8 +71,8 @@ class CacheArray(NamedTuple):
     window: Optional[int] = None
 
 
-MIXERS = ("attention", "latent_attention", "ssm", "diff_attention", "gmu",
-          "cross_diff_attention")
+MIXERS = ("attention", "latent_attention", "ssm", "ssm2", "diff_attention",
+          "gmu", "cross_diff_attention")
 CACHE_KINDS = ("paged", "paged_window", "state", "shared", "none")
 
 
@@ -269,6 +272,24 @@ class ModelConfig:
     # makes them and their use (HF JambaMambaMixer's dt_layernorm /
     # b_layernorm / c_layernorm, eps = rms_norm_eps)
     ssm_inner_norms: bool = False
+    # Mamba-2 layers (mixer ``ssm2``, arXiv:2405.21060): the inner width
+    # ssm_inner_ is ssm_heads heads of ssm_head_dim, each with one scalar
+    # decay and a float32 state [ssm_head_dim, ssm_state_size]; B and C are
+    # shared by ssm_groups groups of heads; ssm_chunk tokens are a block of
+    # the chunked (SSD) form a prefill chunk runs
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    # muP scalars (Granite 4.0): the embedding times embedding_multiplier,
+    # each residual branch times residual_multiplier, attention scores
+    # times attention_multiplier (0 = head_dim ** -0.5), the logits over
+    # logits_scaling. At 1 (0 for the scale) nothing is multiplied: the
+    # programs of every other model are the ones they were
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.layers is not None:
@@ -341,7 +362,7 @@ class ModelConfig:
                 raise ValueError(
                     f"layer {l}: mixer {spec.mixer!r} is run by the "
                     "homogeneous stack (leave `layers` unset)")
-            want = {"ssm": ("state",), "gmu": ("none",),
+            want = {"ssm": ("state",), "ssm2": ("state",), "gmu": ("none",),
                     "attention": ("paged",),
                     "cross_diff_attention": ("shared",),
                     "diff_attention": ("paged", "paged_window")}[spec.mixer]
@@ -361,13 +382,29 @@ class ModelConfig:
                     f"layers {paged}")
             memory = memory or spec.mixer == "ssm"
         if (self.kv_cache_dtype != "bfloat16" or self.lora_r > 0
-                or self.num_experts or self.latent_attention
-                or self.sliding_window):
+                or self.latent_attention or self.sliding_window):
             raise ValueError(
                 "a model with a per-layer spec runs without int8 KV (its "
                 "pages and its recurrent state have no quantised form), "
-                "LoRA adapters, routed experts or latent attention, and "
-                "states its windows in `layers`, not in sliding_window")
+                "LoRA adapters or latent attention, and states its windows "
+                "in `layers`, not in sliding_window")
+        # routed experts (num_experts > 0) sit in EVERY layer of a spec'd
+        # model: the spec states no per-layer FFN kind, so experts in
+        # every n-th layer beside dense MLPs have no form here
+        if {"ssm", "ssm2"} <= mixers:
+            raise ValueError(
+                "Mamba-1 (ssm) and Mamba-2 (ssm2) layers in one spec: the "
+                "cache states one shape of recurrent state")
+        if "ssm2" in mixers and (
+                self.ssm_heads <= 0
+                or self.ssm_heads * self.ssm_head_dim != self.ssm_inner_
+                or self.ssm_groups != 1 or "gmu" in mixers):
+            raise ValueError(
+                "an ssm2 layer needs ssm_heads x ssm_head_dim == "
+                f"ssm_expand x hidden_size ({self.ssm_heads} x "
+                f"{self.ssm_head_dim} vs {self.ssm_inner_}); ssm_groups > 1 "
+                f"(here {self.ssm_groups}) and gated memory units over a "
+                "Mamba-2 layer are not built")
         if "attention" in mixers and (
                 self.attention_bias or self.attn_logit_softcap):
             raise ValueError(
@@ -684,6 +721,32 @@ register_model("tiny-jamba", ModelConfig(
     num_layers=12, num_heads=4, num_kv_heads=1, max_seq_length=256,
     tie_embeddings=True, rms_norm_eps=1e-6, layers=jamba_layers(12, 6, 2),
     ssm_state_size=4, ssm_inner_norms=True,
+    param_dtype="float32", dtype="float32", remat="none"))
+
+
+def granite_hybrid_layers(num_layers: int, attention: Tuple[int, ...]
+                          ) -> Tuple[LayerSpec, ...]:
+    """The Granite 4.0-H layout (HF ``layer_types``): plain attention at
+    the layers in ``attention``, Mamba-2 everywhere else."""
+    return tuple(LayerSpec("attention", "paged") if l in attention
+                 else LayerSpec("ssm2", "state") for l in range(num_layers))
+
+
+# the Granite 4.0-H period at toy widths: M x 5, A, M x 4 (three runs),
+# 4 query / 2 key-value heads of 16 with a muP scale of its own, Mamba-2
+# of 8 heads of 16 over a state of 8 in SSD blocks of 4, top-3 of 8
+# routed experts of 16 beside a shared expert of 32, and the four muP
+# scalars away from 1
+register_model("tiny-granite-h", ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=16,
+    num_layers=10, num_heads=4, num_kv_heads=2, max_seq_length=256,
+    tie_embeddings=True, rms_norm_eps=1e-5,
+    layers=granite_hybrid_layers(10, (5,)),
+    ssm_state_size=8, ssm_heads=8, ssm_head_dim=16, ssm_chunk=4,
+    num_experts=8, num_experts_per_token=3, moe_intermediate_size=16,
+    num_shared_experts=2,
+    embedding_multiplier=12.0, residual_multiplier=0.22,
+    attention_multiplier=1.0, logits_scaling=16.0,
     param_dtype="float32", dtype="float32", remat="none"))
 
 # HF repo-id aliases so reference configs keep working verbatim

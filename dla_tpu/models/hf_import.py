@@ -147,10 +147,95 @@ def _jamba_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:
     return ModelConfig(**fields)
 
 
+def _granite_hybrid_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:
+    """Granite 4.0-H (HF ``GraniteMoeHybridConfig``): ``layer_types`` gives
+    each layer's mixer, Mamba-2 (``mamba``) or grouped-query attention with
+    no positional encoding (``attention``); every layer has routed experts
+    of width ``intermediate_size`` beside a shared expert of width
+    ``shared_intermediate_size`` (a gated MLP k expert widths wide is k
+    shared experts side by side: ``num_shared_experts`` k); the four muP
+    scalars; RMSNorm; the
+    embedding tied to the head. Held experts follow the mistral4 file
+    convention (``_routed_experts``): ``num_local_experts`` counts the
+    experts THIS process holds, ``experts_published`` the router's width,
+    ``experts_held`` = [first, last]. What is not computed here is refused
+    by name, not approximated."""
+    from dla_tpu.models.config import granite_hybrid_layers
+
+    def refuse(what: str):
+        raise ValueError(f"model_type 'granitemoehybrid' with {what}: not "
+                         "built here")
+
+    if int(hf_cfg.get("mamba_n_groups", 1)) != 1:
+        refuse(f"mamba_n_groups={hf_cfg['mamba_n_groups']} (B and C shared "
+               "by more than one group of heads)")
+    if hf_cfg.get("position_embedding_type") != "nope":
+        refuse(f"position_embedding_type="
+               f"{hf_cfg.get('position_embedding_type')!r} (only 'nope')")
+    if hf_cfg.get("time_step_limit") is not None:
+        refuse(f"time_step_limit={hf_cfg['time_step_limit']} (dt is not "
+               "clamped)")
+    if not hf_cfg.get("tie_word_embeddings", True):
+        refuse("untied embeddings (tie_word_embeddings false)")
+    for key, want in (("mamba_proj_bias", False), ("attention_bias", False),
+                      ("mamba_conv_bias", True), ("hidden_act", "silu"),
+                      ("normalization_function", "rmsnorm")):
+        if hf_cfg.get(key, want) != want:
+            refuse(f"{key}={hf_cfg[key]!r}")
+    kinds = list(hf_cfg["layer_types"])
+    if len(kinds) != int(hf_cfg["num_hidden_layers"]) or \
+            set(kinds) - {"mamba", "attention"}:
+        refuse(f"layer_types {sorted(set(kinds))} over "
+               f"{hf_cfg['num_hidden_layers']} layers")
+    held = int(hf_cfg.get("num_local_experts") or 0)
+    if held <= 0:
+        refuse("no routed experts (num_local_experts 0: a dense MLP)")
+    published = int(hf_cfg.get("experts_published") or held)
+    n_heads = int(hf_cfg["num_attention_heads"])
+    hidden = int(hf_cfg["hidden_size"])
+    expand = int(hf_cfg.get("mamba_expand", 2))
+    mamba_heads = int(hf_cfg["mamba_n_heads"])
+    d_head = hf_cfg.get("mamba_d_head", "auto")
+    width = int(hf_cfg["intermediate_size"])
+    shared = int(hf_cfg.get("shared_intermediate_size") or 0)
+    if shared % width:
+        refuse(f"shared_intermediate_size={shared}, no multiple of the "
+               f"expert width {width}")
+    fields = dict(
+        vocab_size=int(hf_cfg["vocab_size"]), hidden_size=hidden,
+        intermediate_size=width, num_layers=len(kinds), num_heads=n_heads,
+        num_kv_heads=int(hf_cfg.get("num_key_value_heads", n_heads)),
+        rms_norm_eps=float(hf_cfg.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=True,
+        max_seq_length=int(hf_cfg.get("max_position_embeddings", 4096)),
+        layers=granite_hybrid_layers(len(kinds), tuple(
+            l for l, k in enumerate(kinds) if k == "attention")),
+        ssm_state_size=int(hf_cfg.get("mamba_d_state", 256)),
+        ssm_conv_width=int(hf_cfg.get("mamba_d_conv", 4)),
+        ssm_expand=expand, ssm_heads=mamba_heads,
+        ssm_head_dim=(expand * hidden // mamba_heads if d_head == "auto"
+                      else int(d_head)),
+        ssm_chunk=int(hf_cfg.get("mamba_chunk_size", 256)),
+        num_experts=published,
+        moe_experts_held=0 if held == published else held,
+        moe_first_expert=int((hf_cfg.get("experts_held") or [0])[0]),
+        num_experts_per_token=int(hf_cfg["num_experts_per_tok"]),
+        moe_intermediate_size=width, num_shared_experts=shared // width,
+        embedding_multiplier=float(hf_cfg.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf_cfg.get("residual_multiplier", 1.0)),
+        attention_multiplier=float(hf_cfg.get("attention_multiplier")
+                                   or 0.0),
+        logits_scaling=float(hf_cfg.get("logits_scaling", 1.0)))
+    fields.update(overrides)
+    return ModelConfig(**fields)
+
+
 def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfig:
     """Map a Llama/Mistral/Qwen2- or Phi-style HF config.json to
     ModelConfig."""
     model_type = str(hf_cfg.get("model_type", "")).lower()
+    if model_type == "granitemoehybrid":
+        return _granite_hybrid_config(hf_cfg, overrides)
     if model_type == "phi":
         return _phi_config(hf_cfg, overrides)
     if model_type == "phi4flash":
@@ -219,6 +304,18 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
     if hf_cfg.get("n_routed_experts"):
         fields.update(_routed_experts(hf_cfg))
     if model_type == "mixtral" or "num_local_experts" in hf_cfg:
+        # num_local_experts alone does not make a Mixtral block: a file
+        # that also states layer kinds, Mamba sizes or muP scalars is
+        # another architecture, which would import here as a wrong model
+        # under the right name
+        foreign = sorted(k for k in hf_cfg if k == "layer_types"
+                         or k.startswith("mamba_")
+                         or k.endswith("_multiplier"))
+        if model_type != "mixtral" and foreign:
+            raise ValueError(
+                f"model_type {model_type!r}: num_local_experts beside "
+                f"{foreign} is not the Mixtral block; no importer here "
+                "maps this architecture")
         fields["num_experts"] = int(hf_cfg.get("num_local_experts", 8))
         fields["num_experts_per_token"] = int(
             hf_cfg.get("num_experts_per_tok", 2))

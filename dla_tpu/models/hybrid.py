@@ -1,9 +1,9 @@
 """The layer stack of a model whose layers are not all of one kind
 (``ModelConfig.layers``): state-space mixers (Mamba-1, with or without
-RMSNorms on dt, B and C), plain grouped- / multi-query attention,
+RMSNorms on dt, B and C; Mamba-2), plain grouped- / multi-query attention,
 differential attention with a window or without, gated memory units and
 cross-attention onto another layer's cache, in one decoder (SambaY,
-arXiv:2507.06607; Jamba, arXiv:2403.19887).
+arXiv:2507.06607; Jamba, arXiv:2403.19887; Granite 4.0-H).
 
 ``Transformer`` owns the embedding, the final norm, the head and the
 homogeneous scan; for a model with a per-layer spec it hands the layers
@@ -19,9 +19,19 @@ cross-attention layer above it reads). A spec without them may hold any
 number of ``paged`` layers: each keeps rows of its own, layer j of them
 at index j of the ``paged`` arrays.
 
-Every block is sequential: ``x + mixer(norm1(x))``, then ``x +
-mlp(norm2(x))`` with the gated-SiLU MLP; no rotary embedding anywhere
-(the state-space layers carry the order).
+Every block is sequential: ``x + r * mixer(norm1(x))``, then ``x + r *
+mlp(norm2(x))`` with ``r`` = ``residual_multiplier`` (1 unless the config
+says) and the gated-SiLU MLP, or routed experts beside a shared one in
+every layer (``num_experts`` > 0: dropless, ``ops.moe.moe_mlp_dropless``,
+the experts' weights handed over stacked with the layer's repeat index);
+no rotary embedding anywhere (the state-space layers carry the order).
+
+The Mamba-2 mixer (``ssm2``, arXiv:2405.21060) projects ``[z | x B C |
+dt]``, runs the causal convolution over ``x B C``, gives each head one
+scalar decay ``exp(dt * A)`` and a state ``[head_dim, N]``, and ends in
+``RMSNorm(y * silu(z))`` before ``out_proj``; its prefill chunks run the
+chunked (SSD) form, a decode step the recurrence
+(``ops.selective_scan.ssd_chunk`` / ``ssd_step``).
 
 Differential attention (arXiv:2410.05258) runs through the attention ops
 every other model uses, over stacked heads: query heads pair as (2j,
@@ -60,6 +70,8 @@ from dla_tpu.ops.selective_scan import (
     causal_conv_step,
     selective_scan_chunk,
     selective_scan_step,
+    ssd_chunk,
+    ssd_step,
 )
 
 Params = Dict[str, jnp.ndarray]
@@ -75,6 +87,8 @@ CHUNK_ATTENTION_BLOCK_COLUMNS = 1024
 #: the device scope of each mixer (utils/profiling.py DEVICE_SCOPES)
 _ATTN_SCOPE = {"paged_window": "swa_attention", "paged": "full_attention",
                "shared": "cross_attention"}
+#: the routed experts' leaves, which stay stacked outside a run's scan
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
 class Run(NamedTuple):
@@ -163,6 +177,14 @@ class HybridStack:
         # program reads through the block walk (``paged``)
         self.walked_layers = sum(
             s.mixer == "attention" and s.cache == "paged" for s in self.spec)
+        # the scan output the gated memory units read (Mamba-1 only)
+        self.memory = any(s.mixer == "ssm" for s in self.spec)
+        self.mamba2 = any(s.mixer == "ssm2" for s in self.spec)
+        # what a Mamba-2 layer's convolution runs over: x, B and C
+        self.conv_width2 = (cfg.ssm_inner_
+                            + 2 * cfg.ssm_groups * cfg.ssm_state_size)
+        self.attention_scale = (cfg.attention_multiplier
+                                or cfg.head_dim_ ** -0.5)
         self._cache_spec = self._build_cache_spec()
         # cache kind -> positions of its arrays in cache_spec()
         self._slots: Dict[str, Tuple[int, ...]] = {}
@@ -226,8 +248,19 @@ class HybridStack:
                                     "norm2": ((d,), "ones")}
         if cfg.norm == "layer":
             shapes["norm1_bias"] = shapes["norm2_bias"] = ((d,), "zeros")
-        shapes.update(w_gate=((d, f), std), w_up=((d, f), std),
-                      w_down=((f, d), out_std))
+        if cfg.num_experts:
+            # the router scores every expert; the weights are those held
+            e, fe = cfg.experts_held_, cfg.expert_width_
+            shapes.update(router=((d, cfg.num_experts), std),
+                          w_gate=((e, d, fe), std), w_up=((e, d, fe), std),
+                          w_down=((e, fe, d), out_std))
+            fs = cfg.num_shared_experts * fe
+            if fs:
+                shapes.update(ws_gate=((d, fs), std), ws_up=((d, fs), std),
+                              ws_down=((fs, d), out_std))
+        else:
+            shapes.update(w_gate=((d, f), std), w_up=((d, f), std),
+                          w_down=((f, d), out_std))
         lambdas = {f"lambda_{n_}": ((dh,), 0.1)
                    for n_ in ("q1", "k1", "q2", "k2")}
         if mixer == "ssm":
@@ -242,6 +275,15 @@ class HybridStack:
             if cfg.ssm_inner_norms:
                 shapes.update(dt_norm=((r,), "ones"), b_norm=((n,), "ones"),
                               c_norm=((n,), "ones"))
+        elif mixer == "ssm2":
+            nh, conv = cfg.ssm_heads, self.conv_width2
+            shapes.update(
+                in_proj=((d, di + conv + nh), std),
+                conv_w=((cfg.ssm_conv_width, conv), "conv"),
+                conv_b=((conv,), "zeros"),
+                dt_bias=((nh,), "dt"), a_log=((nh,), "a_log2"),
+                d_skip=((nh,), "ones"), ssm_norm=((di,), "ones"),
+                out_proj=((di, d), out_std))
         elif mixer == "attention":
             shapes.update(wq=((d, qdim), std), wk=((d, kvdim), std),
                           wv=((d, kvdim), std), wo=((qdim, d), out_std))
@@ -287,6 +329,8 @@ class HybridStack:
                     elif how == "a_log":     # A = -(1..N), S4D-real
                         v = jnp.broadcast_to(jnp.log(jnp.arange(
                             1, cfg.ssm_state_size + 1, dtype=F32)), full)
+                    elif how == "a_log2":    # A = -U[1, 16], a head each
+                        v = jnp.log(jax.random.uniform(k, full, F32, 1, 16))
                     elif how == "dt":
                         # softplus^-1 of a step size log-uniform in
                         # [1e-3, 1e-1] (the Mamba initialisation)
@@ -304,14 +348,20 @@ class HybridStack:
         """Matrices shard their input dim over ``fsdp`` and their output
         dim over ``model`` (down-projections the other way round);
         vectors and the small state-space leaves are replicated."""
-        down = {"w_down", "wo", "out_proj", "gmu_out"}
+        down = {"w_down", "wo", "out_proj", "gmu_out", "ws_down"}
         specs: Dict[str, Dict[str, P]] = {}
         for run in self.runs:
             for j in range(run.period):
                 block = {}
                 for name, (shape, _) in self._block_shapes(
                         self.spec[run.start + j].mixer).items():
-                    if len(shape) == 2 and name not in (
+                    if len(shape) == 3:      # [experts, in, out]
+                        block[name] = (P(None, "expert", "model", "fsdp")
+                                       if name in down
+                                       else P(None, "expert", "fsdp", "model"))
+                    elif name == "router":
+                        block[name] = P(None, "fsdp", None)
+                    elif len(shape) == 2 and name not in (
                             "conv_w", "a_log", "x_proj", "dt_proj"):
                         block[name] = (P(None, "model", "fsdp")
                                        if name in down
@@ -349,7 +399,17 @@ class HybridStack:
                 out += [CacheArray(
                     kind, self._cache_layers[kind], row, adtype,
                     self.window if kind == "paged_window" else None)] * 2
-        if self._cache_layers.get("state"):
+        if self._cache_layers.get("state") and self.mamba2:
+            # a Mamba-2 layer: [heads, head_dim, N] float32 (N = 128 on
+            # the lanes), and the tail of x B C
+            n = self._cache_layers["state"]
+            out.append(CacheArray(
+                "state", n, (cfg.ssm_heads, cfg.ssm_head_dim,
+                             cfg.ssm_state_size), F32, None))
+            out.append(CacheArray(
+                "state", n, (cfg.ssm_conv_width - 1, self.conv_width2),
+                adtype, None))
+        elif self._cache_layers.get("state"):
             n = self._cache_layers["state"]
             out.append(CacheArray(
                 "state", n, (cfg.ssm_state_size, cfg.ssm_inner_), F32, None))
@@ -424,6 +484,42 @@ class HybridStack:
             y = y.astype(h.dtype)
             out = model._dense(layer, "out_proj", y * jax.nn.silu(z))
         return out, y, state, tail
+
+    def _ssm2(self, layer: Params, h: jnp.ndarray, state, tail, real):
+        """The Mamba-2 mixer over T tokens from (state [B, H, P, N]
+        float32, tail [B, K-1, d_inner + 2N]); ``real`` as in ``_ssm``.
+        Returns (mixer output [B, T, D], new state, new tail)."""
+        cfg, model = self.cfg, self.model
+        di, n, nh = cfg.ssm_inner_, cfg.ssm_state_size, cfg.ssm_heads
+        b, t, _ = h.shape
+        with jax.named_scope("ssm_mixer"):
+            zxd = model._dense(layer, "in_proj", h)
+            z, xbc, dt = zxd[..., :di], zxd[..., di:-nh], zxd[..., -nh:]
+            conv, tail = causal_conv_step(
+                xbc, tail, layer["conv_w"], layer["conv_b"],
+                jnp.sum(real, axis=1, dtype=jnp.int32))
+            xbc = jax.nn.silu(conv)
+            x = xbc[..., :di].reshape(b, t, nh, cfg.ssm_head_dim)
+            bm, cm = xbc[..., di:di + n], xbc[..., di + n:]
+            dt = jax.nn.softplus(dt.astype(F32)
+                                 + layer["dt_bias"].astype(F32))
+            dt = jnp.where(real[..., None], dt, 0.0)
+            a = -jnp.exp(layer["a_log"].astype(F32))
+            with jax.named_scope("ssm_scan"):
+                if t == 1:
+                    y, state = ssd_step(x[:, 0], dt[:, 0], a, bm[:, 0],
+                                        cm[:, 0], layer["d_skip"], state)
+                    y = y[:, None]
+                else:
+                    y, state = ssd_chunk(x, dt, a, bm, cm, layer["d_skip"],
+                                         state, block=cfg.ssm_chunk)
+            # the gated RMSNorm over the whole inner width (one group)
+            g = y.reshape(b, t, di) * jax.nn.silu(z.astype(F32))
+            g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                                  + cfg.rms_norm_eps)
+            out = model._dense(layer, "out_proj", g.astype(h.dtype)
+                               * layer["ssm_norm"].astype(h.dtype))
+        return out, state, tail
 
     def _gmu(self, layer: Params, h: jnp.ndarray, memory: jnp.ndarray):
         with jax.named_scope("gmu"):
@@ -511,10 +607,22 @@ class HybridStack:
         return self._proj(layer)("wo", pair.astype(self.model.adtype).reshape(
             b, t, cfg.num_heads * cfg.head_dim_))
 
-    def _mlp(self, layer: Params, x: jnp.ndarray) -> jnp.ndarray:
+    def _residual(self, x: jnp.ndarray, out: jnp.ndarray) -> jnp.ndarray:
+        r = self.cfg.residual_multiplier
+        return x + out if r == 1.0 else x + out * jnp.asarray(r, out.dtype)
+
+    def _mlp(self, layer: Params, x: jnp.ndarray, real, carry: Dict):
+        """``x + r * mlp(norm2(x))`` and the carry: routed experts (every
+        layer has them or none does) route droplessly over the rows
+        ``real`` and add their int32 [2] counters to ``carry["routed"]``."""
         h = self._norm(layer, "norm2", x)
-        out, _ = self.model._mlp(layer, h, self._proj(layer))
-        return x + out
+        if not self.cfg.num_experts:
+            out, _ = self.model._mlp(layer, h, self._proj(layer))
+            return self._residual(x, out), carry
+        out, routed = self.model._mlp(layer, h, self._proj(layer),
+                                      token_valid=real, dropless=True)
+        return self._residual(x, out), {
+            **carry, "routed": carry["routed"] + routed}
 
     def _with_depth(self, layers: Dict[str, Params], run: Run
                     ) -> List[Params]:
@@ -543,12 +651,23 @@ class HybridStack:
         for run in self.runs:
             stacks = self._with_depth(layers, run)
             shared = carry.get("shared")
+            # routed experts stay stacked outside the scan and go to the
+            # grouped matmul whole, with the repeat as the block index
+            # (ops.moe.moe_mlp_dropless ``layer``): slicing a repeat's
+            # experts out would copy all of them every step
+            experts = [{k: s.pop(k) for k in _EXPERT_LEAVES}
+                       if "router" in s else None for s in stacks]
 
-            def body(x_, carry_, xs, run=run, shared=shared):
+            def body(x_, carry_, xs, run=run, shared=shared,
+                     experts=experts):
                 if shared is not None:
                     carry_ = {**carry_, "shared": shared}
                 for j in range(run.period):
-                    x_, carry_ = block(run.start + j, xs[j], x_, carry_)
+                    layer = xs[j]
+                    if experts[j] is not None:
+                        layer = {**layer, "expert_stack": experts[j],
+                                 "layer_index": layer["rep"]}
+                    x_, carry_ = block(run.start + j, layer, x_, carry_)
                 return x_, carry_
 
             if run.reps == 1:
@@ -579,7 +698,11 @@ class HybridStack:
         cfg, model = self.cfg, self.model
         b, t, _ = x.shape
         di = cfg.ssm_inner_
-        carry = {"memory": jnp.zeros((b, t, di), x.dtype)}
+        carry: Dict = {}
+        if self.memory:
+            carry["memory"] = jnp.zeros((b, t, di), x.dtype)
+        if cfg.num_experts:
+            carry["routed"] = jnp.zeros((2,), jnp.int32)
 
         def attend(q, k, v, window):
             return model._attention(q, k, v, kv_mask, positions, positions,
@@ -595,6 +718,12 @@ class HybridStack:
                     jnp.zeros((b, cfg.ssm_conv_width - 1, di), x_.dtype),
                     real)
                 carry_ = {**carry_, "memory": memory}
+            elif spec.mixer == "ssm2":
+                out, _, _ = self._ssm2(
+                    layer, h, jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim,
+                                         cfg.ssm_state_size), F32),
+                    jnp.zeros((b, cfg.ssm_conv_width - 1, self.conv_width2),
+                              x_.dtype), real)
             elif spec.mixer == "gmu":
                 out = self._gmu(layer, h, carry_["memory"])
             elif spec.mixer == "attention":
@@ -613,7 +742,7 @@ class HybridStack:
                             carry_ = {**carry_, "shared": (k, v)}
                     out = self._diff_output(
                         layer, attend(q, k, v, spec.window))
-            return self._mlp(layer, x_ + out), carry_
+            return self._mlp(layer, self._residual(x_, out), real, carry_)
 
         x, _ = self._walk(layers, x, carry, block, remat=model._maybe_remat)
         return x
@@ -639,7 +768,9 @@ class HybridStack:
 
         ``scan_kernel``: what runs a chunk's selective scans (``_ssm``).
 
-        Returns (hidden before the final norm, the arrays updated)."""
+        Returns (hidden before the final norm, the arrays updated, and
+        int32 [2] = (held experts that received a pair, pairs that landed
+        here) summed over layers, or None for a model without experts)."""
         cfg = self.cfg
         b, t, _ = x.shape
         slots = self._slots
@@ -649,8 +780,10 @@ class HybridStack:
         window_cols = view["valid"].shape[1]
         q0 = positions[:, 0]
         carry: Dict = {"pools": tuple(pools)}
-        if "state" in slots:
+        if self.memory:
             carry["memory"] = jnp.zeros((b, t, cfg.ssm_inner_), x.dtype)
+        if cfg.num_experts:
+            carry["routed"] = jnp.zeros((2,), jnp.int32)
         rows = view.get("state_rows")
         fresh = view.get("fresh")
 
@@ -707,6 +840,29 @@ class HybridStack:
                 pools_[si] = pools_[si].at[at].set(state)
                 pools_[ti] = pools_[ti].at[at].set(tail)
                 carry_ = {**carry_, "memory": memory}
+            elif spec.mixer == "ssm2":
+                # the state pool is read and written as [layers, slots,
+                # H * P, N]: a reshape that is free in the pool's own
+                # layout, which keeps XLA from choosing another one for it
+                # (P before H) and relaying the whole pool out and back
+                # around a chunk program
+                si, ti = slots["state"]
+                shape = pools_[si].shape
+                flat = pools_[si].reshape(shape[:2] + (-1, shape[-1]))
+                state, tail = flat[i], pools_[ti][i]
+                if rows is not None:
+                    state, tail = state[rows], tail[rows]
+                if fresh is not None:
+                    state = jnp.where(fresh[:, None, None], 0.0, state)
+                    tail = jnp.where(fresh[:, None, None],
+                                     jnp.zeros_like(tail), tail)
+                out, state, tail = self._ssm2(
+                    layer, h, state.reshape((-1,) + shape[2:]), tail, real)
+                at = (i,) if rows is None else (i, rows)
+                pools_[si] = flat.at[at].set(
+                    state.reshape(state.shape[:1] + flat.shape[2:])
+                ).reshape(shape)
+                pools_[ti] = pools_[ti].at[at].set(tail)
             elif spec.mixer == "gmu":
                 out = self._gmu(layer, h, carry_["memory"])
             elif spec.mixer == "attention":
@@ -728,14 +884,14 @@ class HybridStack:
                             kv_valid=view["valid"],
                             kv_positions=view["pos"], window=None,
                             q_positions=positions,
-                            softmax_scale=cfg.head_dim_ ** -0.5)
+                            softmax_scale=self.attention_scale)
                     else:
                         att = blockwise_paged_attention(
                             q, pools_[ki], pools_[vi], i, tables, q0, k, v,
                             q_positions=positions,
                             block_pages=chunk_attention_block_pages(
                                 pools_[ki].shape[2], tables.shape[1]),
-                            softmax_scale=cfg.head_dim_ ** -0.5)
+                            softmax_scale=self.attention_scale)
                     at = (i, view["write_pages"], view["write_offs"])
                     pools_[ki] = pools_[ki].at[at].set(k.reshape(b, t, -1))
                     pools_[vi] = pools_[vi].at[at].set(v.reshape(b, t, -1))
@@ -778,7 +934,7 @@ class HybridStack:
                             kv_positions=view["pos"], window=None, **kw)
                     out = self._diff_output(layer, self._own_pair(att))
             carry_ = {**carry_, "pools": tuple(pools_)}
-            return self._mlp(layer, x_ + out), carry_
+            return self._mlp(layer, self._residual(x_, out), real, carry_)
 
         x, carry = self._walk(layers, x, carry, block)
-        return x, carry["pools"]
+        return x, carry["pools"], carry.get("routed")

@@ -175,11 +175,12 @@ class Transformer:
         # a model whose layers are of several kinds (cfg.layers): its
         # runs of layers live in models/hybrid.py. Its attention ops see
         # differential pairs, rows twice a head wide, so the scale is
-        # stated, not read off the operands
+        # stated, not read off the operands (muP's attention_multiplier
+        # where the config gives one)
         self.hybrid: Optional[HybridStack] = None
         if cfg.layers is not None:
             self.hybrid = HybridStack(self)
-            self._softmax_scale = cfg.head_dim_ ** -0.5
+            self._softmax_scale = self.hybrid.attention_scale
 
     # ------------------------------------------------------- storage layout
 
@@ -1663,7 +1664,12 @@ class Transformer:
             return layer_norm(x, params["final_norm"],
                               params["final_norm_bias"],
                               self.cfg.rms_norm_eps)
-        return rms_norm(x, params["final_norm"], self.cfg.rms_norm_eps)
+        h = rms_norm(x, params["final_norm"], self.cfg.rms_norm_eps)
+        if self.cfg.logits_scaling != 1.0:
+            # muP's logits / logits_scaling, taken on the row that every
+            # head path contracts (unembed, ops.fused_ce)
+            h = h / jnp.asarray(self.cfg.logits_scaling, h.dtype)
+        return h
 
     def _embed(self, params: Params, ids: jnp.ndarray) -> jnp.ndarray:
         """Token embedding read in the activation dtype. Gemma scales the
@@ -1675,6 +1681,9 @@ class Transformer:
                          ).astype(self.adtype)
             if self.cfg.arch in ("gemma", "gemma2"):
                 x = x * jnp.asarray(self.cfg.hidden_size ** 0.5, self.adtype)
+            if self.cfg.embedding_multiplier != 1.0:          # muP
+                x = x * jnp.asarray(self.cfg.embedding_multiplier,
+                                    self.adtype)
         return x
 
     def unembed_params(self, params: Params
@@ -2243,11 +2252,11 @@ class Transformer:
             if adapters is not None:
                 raise NotImplementedError(
                     "per-slot adapters on a model with a per-layer spec")
-            x, pools = self.hybrid.paged(
+            x, pools, routed = self.hybrid.paged(
                 params["layers"], view, x, positions, attention,
                 scan_kernel=self.scan_chunk_kernel(x.shape[1]))
             return (self._final_norm(params, x), pools,
-                    jnp.zeros((2,), jnp.int32))
+                    jnp.zeros((2,), jnp.int32) if routed is None else routed)
         cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,
                                  scaling=cfg.rope_scaling)
         tables = view["block_tables"]                    # [B, pages/slot]

@@ -274,7 +274,11 @@ def test_arch_overrides_cover_every_model_config_field():
         # the per-layer spec and what it implies: the architecture's own
         # shape, read from the model's config like the widths above
         "layers", "norm", "ssm_state_size", "ssm_conv_width", "ssm_expand",
-        "ssm_dt_rank", "ssm_inner_norms",
+        "ssm_dt_rank", "ssm_inner_norms", "ssm_heads", "ssm_head_dim",
+        "ssm_groups", "ssm_chunk",
+        # muP scalars: the architecture's own, like the widths
+        "embedding_multiplier", "residual_multiplier",
+        "attention_multiplier", "logits_scaling",
     }
     fields = {f.name for f in dataclasses.fields(ModelConfig)}
     candidates = fields - excluded

@@ -112,9 +112,14 @@ ENGINES = {
     # with rows of their own (the tiny-jamba preset): no window pool
     "ssm_attention": dict(page_size=4, num_pages=32, num_slots=2,
                           max_model_len=32, prefill_chunk=4),
+    # Mamba-2 layers beside plain attention with routed experts in every
+    # layer (the tiny-granite-h preset): state and the routing mark
+    "ssm_experts": dict(page_size=4, num_pages=32, num_slots=2,
+                        max_model_len=32, prefill_chunk=4),
 }
 PRESETS = {"experts": "tiny-mla-moe", "state_space": "tiny-sambay",
-           "ssm_attention": "tiny-jamba"}
+           "ssm_attention": "tiny-jamba", "ssm_experts": "tiny-granite-h"}
+ROUTED = ("experts", "ssm_experts")
 
 
 @pytest.mark.parametrize("kind", sorted(ENGINES))
@@ -156,7 +161,7 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
             "serve_decode_fetch", "serve_emit", "serve_post",
             "serve_req_submit", "serve_req_admit", "serve_req_first_token",
             "serve_req_finish", "serve_prefill_chunk", "serve_chunk_fetch"}
-    if kind == "experts":
+    if kind in ROUTED:
         want.add("serve_moe_route")
     else:
         assert not rec.named("serve_moe_route")     # no routed experts
@@ -189,7 +194,7 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
     assert len(phases) == len(live) > 0
     inside_decode = ["serve_decode_args", "serve_decode_dispatch",
                      "serve_decode_fetch", "serve_emit"]
-    if kind == "experts":       # the mark follows the step's one fetch
+    if kind in ROUTED:          # the mark follows the step's one fetch
         inside_decode.insert(3, "serve_moe_route")
     for phase, held in zip(phases, live):
         assert [k[0] for k in rec.children(phase)] == inside_decode
@@ -203,7 +208,7 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
             assert phase[1]["window_read_tokens"] == (
                 geom.num_slots * geom.window_gather_pages * geom.page_size)
             assert geom.window_gather_pages == 8 // 4 + 1
-        elif kind == "ssm_attention":
+        elif kind in ("ssm_attention", "ssm_experts"):
             assert phase[1]["state_slots"] == geom.num_slots
             assert phase[1]["window_read_tokens"] == 0
         else:
@@ -225,13 +230,14 @@ def test_engine_emits_the_span_table(model_and_params, monkeypatch, kind):
     # scan counter is the chunks' real tokens x the state-space layers
     chunks = rec.named("serve_prefill_chunk")
     assert all(c[1]["context"] == c[1]["start"] for c in chunks)
-    state_layers = {"state_space": 4, "ssm_attention": 10}.get(kind, 0)
+    state_layers = {"state_space": 4, "ssm_attention": 10,
+                    "ssm_experts": 9}.get(kind, 0)
     assert snap["serving/prefill/scan_tokens"] == state_layers * sum(
         c[1]["nvalid"] for c in chunks)
     assert snap["serving/kv_paged_layers"] == {
-        "state_space": 1, "ssm_attention": 2, "experts": 2}.get(
-            kind, model.cfg.num_layers)
-    if kind == "experts":
+        "state_space": 1, "ssm_attention": 2, "experts": 2,
+        "ssm_experts": 1}.get(kind, model.cfg.num_layers)
+    if kind in ROUTED:
         cfg = model.cfg
         routes = [r[1] for r in rec.named("serve_moe_route")]
         assert len(routes) == len(phases)
