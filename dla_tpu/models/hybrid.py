@@ -52,7 +52,10 @@ side, ``num_kv_heads * head_dim`` numbers, the same count as the paired
 row above; with one key / value head the row is the key. In a paged
 chunk program these layers read their cached rows in blocks up to the
 context (``ops.attention.blockwise_paged_attention``), not the slot's
-gathered window.
+gathered window; in the one-token step, on a TPU, through the flat form
+of the paged decode kernel (``ops/paged_attention.py``), which reads
+each slot's live pages only (``Transformer._paged_kernel_rows`` says
+which specs and widths it takes).
 """
 from __future__ import annotations
 
@@ -750,7 +753,8 @@ class HybridStack:
     # ---------------------------------------------------------- paged steps
 
     def paged(self, layers: Dict[str, Params], view: Dict, x: jnp.ndarray,
-              positions: jnp.ndarray, attention, scan_kernel=None):
+              positions: jnp.ndarray, attention, scan_kernel=None,
+              decode_kernel=None):
         """The layers of one paged step (decode: T = 1 for every slot;
         prefill chunk: T tokens of one slot) over the arrays of
         ``cache_spec()``. Beside what ``Transformer._paged_layers`` reads,
@@ -767,6 +771,10 @@ class HybridStack:
                         the trash page and move no state
 
         ``scan_kernel``: what runs a chunk's selective scans (``_ssm``).
+        ``decode_kernel``: the paged decode kernel's module where the
+        one-token step of the plain-attention layers reads their live
+        pages through it (``Transformer.paged_decode_kernel``), else
+        None.
 
         Returns (hidden before the final norm, the arrays updated, and
         int32 [2] = (held experts that received a pair, pairs that landed
@@ -867,15 +875,23 @@ class HybridStack:
                 out = self._gmu(layer, h, carry_["memory"])
             elif spec.mixer == "attention":
                 # plain attention over the layer's own rows, arrays i of
-                # the paged kind. The one-token step of every slot
-                # gathers each row's whole window (a row splits into its
-                # key / value heads; one head: the row is the key); a
-                # chunk walks the cached columns in blocks up to the
-                # rows' context, ``q0`` (``blockwise_paged_attention``)
+                # the paged kind. The one-token step of every slot reads
+                # each slot's live pages through the paged kernel's flat
+                # form (the pools whole, layer i, the mask from
+                # ``lengths``), or without it gathers each row's whole
+                # window (a row splits into its key / value heads; one
+                # head: the row is the key); a chunk walks the cached
+                # columns in blocks up to the rows' context, ``q0``
+                # (``blockwise_paged_attention``)
                 with jax.named_scope(_ATTN_SCOPE[spec.cache]):
                     ki, vi = slots["paged"]
                     q, k, v = self._plain_qkv(layer, h)
-                    if attention is decode_attention:
+                    if decode_kernel is not None:
+                        att = decode_kernel.paged_decode_attention(
+                            q[:, 0], pools_[ki], pools_[vi], tables,
+                            view["lengths"], k[:, 0], v[:, 0], layer=i,
+                            softmax_scale=self.attention_scale)[:, None]
+                    elif attention is decode_attention:
                         heads = (b, window_cols, cfg.num_kv_heads,
                                  cfg.head_dim_)
                         att = attention(

@@ -867,28 +867,39 @@ class Transformer:
     @property
     def _paged_kernel_rows(self) -> bool:
         """Whether this model's cached rows are ones the paged decode
-        kernel (ops/paged_attention.py) reads: the dense pair of keys
-        and values ``[K, D]`` with ``D`` a multiple of 128 lanes, the
-        kv heads of a token filling whole 32-bit words, a GQA group
-        inside the kernel's 8 sublanes. Latent rows (one pool), a
-        per-layer spec (models/hybrid.py's own gathers) and int8 pages
-        are not: they keep the gather."""
+        kernel (ops/paged_attention.py) reads. Its dense form: the pair
+        of keys and values ``[K, D]`` with ``D`` a multiple of 128 lanes,
+        the kv heads of a token filling whole 32-bit words, a GQA group
+        inside the kernel's 8 sublanes. Its flat form: a per-layer spec
+        whose every layer with rows of its own (``paged``) is plain
+        attention (``models/hybrid.py``: a row is the token's kv heads
+        side by side), bfloat16 rows, ``D`` a multiple of 128 lanes and
+        the group padded to 8 within the kernel's ``MAX_GROUP_ROWS``
+        (32). Latent rows (one pool), the differential pairs of SambaY's
+        shared layer and int8 pages are not: they keep the gather."""
         cfg = self.cfg
-        return (not cfg.latent_attention and self.hybrid is None
-                and not self._kv_int8
-                and cfg.head_dim_ % 128 == 0
+        group = cfg.num_heads // cfg.num_kv_heads
+        if self._kv_int8 or cfg.head_dim_ % 128:
+            return False
+        if self.hybrid is not None:
+            paged = [s.mixer for s in self.hybrid.spec if s.cache == "paged"]
+            return (set(paged) == {"attention"}
+                    and self.adtype == jnp.bfloat16
+                    and -(-group // 8) * 8 <= 32)
+        return (not cfg.latent_attention
                 and (cfg.num_kv_heads * self.adtype.itemsize) % 4 == 0
-                and cfg.num_heads // cfg.num_kv_heads <= 8)
+                and group <= 8)
 
     def paged_decode_kernel(self):
         """The module of the paged attention kernel if a
         ``decode_step_paged`` traced now, under the ambient mesh, would
         run it, else None (the gather). What is observed, no knob: the
-        rows (``_paged_kernel_rows``), a TPU backend (Mosaic compiles
-        for nothing else), and no multi-device auto mesh (a
-        ``pallas_call`` has no SPMD rule: GSPMD would replicate the
-        pools). Imports the module the first time, unless the
-        constructor's thread has by then."""
+        rows (``_paged_kernel_rows``: the dense ``[K, D]`` pairs, or a
+        layer-spec model's flat plain-attention rows, Jamba's and
+        Granite 4.0-H's), a TPU backend (Mosaic compiles for nothing
+        else), and no multi-device auto mesh (a ``pallas_call`` has no
+        SPMD rule: GSPMD would replicate the pools). Imports the module
+        the first time, unless the constructor's thread has by then."""
         if (self._paged_kernel_rows and _tpu_backend()
                 and _flash_mesh() is None):
             return _import_paged_kernel()
@@ -2219,7 +2230,9 @@ class Transformer:
         - the paged kernel (ops/paged_attention.py), when the step is one
           token a row (``attention is decode_attention``), the rows are
           ``_paged_kernel_rows``, programs compile for a TPU and no
-          multi-device mesh is in force (``paged_decode_kernel``): it
+          multi-device mesh is in force (``paged_decode_kernel``; a
+          layer-spec model's plain-attention layers take it in
+          ``HybridStack.paged``): it
           walks ``block_tables`` and reads pages ``0 .. ceil(lengths /
           page) - 1`` only. It takes the mask from ``view["lengths"]``
           alone: column c at position c, valid iff c < lengths, which is
@@ -2248,13 +2261,16 @@ class Transformer:
                 "the paged steps serve activation-dtype pages; "
                 "kv_cache_dtype=int8 is only wired into the contiguous "
                 "decode_step path")
+        kernel = (self.paged_decode_kernel()
+                  if attention is decode_attention else None)
         if self.hybrid is not None:
             if adapters is not None:
                 raise NotImplementedError(
                     "per-slot adapters on a model with a per-layer spec")
             x, pools, routed = self.hybrid.paged(
                 params["layers"], view, x, positions, attention,
-                scan_kernel=self.scan_chunk_kernel(x.shape[1]))
+                scan_kernel=self.scan_chunk_kernel(x.shape[1]),
+                decode_kernel=kernel)
             return (self._final_norm(params, x), pools,
                     jnp.zeros((2,), jnp.int32) if routed is None else routed)
         cos, sin = rotary_angles(positions, cfg.rotary_dim_, cfg.rope_theta,
@@ -2271,9 +2287,6 @@ class Transformer:
             # (ops.moe.moe_mlp_dropless, ``layer``): the block index
             # stands in for them
             stack = {k: layers.pop(k) for k in ("w_gate", "w_up", "w_down")}
-
-        kernel = (self.paged_decode_kernel()
-                  if attention is decode_attention else None)
 
         def body(carry, layer):
             h, pools = carry                  # pool [L, pages, page, h, w]

@@ -21,26 +21,33 @@ iff ``c < length`` (what ``PagedKVCache`` keeps for a running slot):
   - bf16 operands into the MXU with float32 accumulation, softmax in
     float32.
 
-Layout choices:
-  - the pools go in whole, ``[L, pages, page, K, D]`` as stored, in HBM
-    (``pl.ANY``), with the layer as a scalar operand: slicing a layer out
-    of the scan's carry, or reshaping a page to ``[page, K * D]``, would
-    copy the pool (the tiled minor dims are ``(K, D)``);
-  - a page lands in VMEM as ``[page, K, 128]`` per 128 lanes of the
-    head (Mosaic's strided load wants a 128-lane base: a 256-wide head
-    is two copies a page and two partial products a head); head ``k``
-    is rows ``k, k + K, ...`` of the ``[tokens * K, 128]`` view, a
+Two layouts of a page, told apart by the pools' rank (what the input
+shows; no option):
+  - dense, ``[L, pages, page, K, D]`` (``Transformer`` of one kind of
+    layer): a page lands in VMEM as ``[page, K, 128]`` per 128 lanes of
+    the head (Mosaic's strided load wants a 128-lane base: a 256-wide
+    head is two copies a page and two partial products a head); head
+    ``k`` is rows ``k, k + K, ...`` of the ``[tokens * K, 128]`` view, a
     strided load; 16-bit pages hold two heads to a 32-bit word and the
-    pair stays packed through the MXU (``_word_rows``: jax's ragged
-    paged attention kernel splits it with a shift and a mask, which
-    cost more than the second head's masked scores);
+    pair stays packed through the MXU (``_word_rows``: jax's ragged paged
+    attention kernel splits it with a shift and a mask, which cost more
+    than the second head's masked scores); the group padded to 8;
+  - flat, ``[L, pages, page, K * D]`` (the plain-attention layers of
+    ``models/hybrid.py``, whose rows are stored flat so that a page tiles
+    as it is): a page is one copy into ``[page, K * D]``, head ``k`` is
+    lanes ``k * D .. (k + 1) * D`` of the block's ``[tokens, K * D]``
+    view, a lane-aligned slice: one 16-bit head of 128 (Jamba's) needs
+    no word, and the group is padded to a multiple of 8 up to
+    ``MAX_GROUP_ROWS`` (Jamba's 20 to 24);
+  - both take the pools whole, in HBM (``pl.ANY``), with the layer as a
+    scalar operand: slicing a layer out of the scan's carry, or
+    reshaping a page, would copy the pool;
   - grid ``(slots,)``; inside, a loop over blocks of ``pages_per_block``
     pages with a dynamic trip count, the next block (or the next slot's
     first) in flight while this one is computed; a page past the live
     count is neither copied nor waited for, and its stale buffer is
-    masked (scores replaced, values zeroed: garbage may be NaN);
-  - the GQA group padded to 8 sublanes (padded rows are zero queries:
-    finite garbage the wrapper slices off).
+    masked (scores replaced, values zeroed: garbage may be NaN); padded
+    group rows are zero queries, finite garbage the wrapper slices off.
 
 Forward only (decode never takes gradients). This module imports Pallas
 at module level, which costs about a second of host time: import it
@@ -62,6 +69,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 GP = 8                      # query-group sublane padding
+# the flat layout's largest padded group: a head's float32 scores over
+# its largest block (4,096 columns of one 128-wide head) stay at 512 KiB
+MAX_GROUP_ROWS = 32
 # VMEM for the two pools' double buffers: 32 pages a block at Mistral's
 # [16, 8, 128] bf16 page. Larger blocks amortise the per-block work (16
 # pages: 2.29 ms a step of the decode-heavy cell's 16 layers, 32: 1.93;
@@ -149,10 +159,19 @@ def _kernel(tables_ref, lens_ref, meta_ref,              # scalar prefetch
             *, scale: float, softcap: float, pages_per_slot: int):
     b = pl.program_id(0)
     nslots = pl.num_programs(0)
-    _, nd, ppb, page, kheads, _ = k_buf.shape    # nd = D / 128 lanes
+    dh = q_ref.shape[2]
+    nd = dh // LANES                     # 128-lane parts of a head
+    flat = k_buf.ndim == 4               # [2, ppb, page, K * D]
+    if flat:
+        _, ppb, page, width = k_buf.shape
+        kheads = width // dh
+        hpw = 1                          # a head is a lane slice
+    else:
+        _, _, ppb, page, kheads, _ = k_buf.shape
+        hpw = 4 // k_buf.dtype.itemsize  # heads to a 32-bit word
     bk = ppb * page
-    hpw = 4 // k_buf.dtype.itemsize      # heads to a 32-bit word
-    gr = hpw * GP                        # query rows of a word's heads
+    group = q_ref.shape[1] // kheads     # a head's padded query rows
+    gr = hpw * group                     # query rows of a word's heads
     layer, window = meta_ref[0], meta_ref[1]
     pools = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
 
@@ -168,6 +187,22 @@ def _kernel(tables_ref, lens_ref, meta_ref,              # scalar prefetch
         """Live pages of block ``blk``: they are its first ones."""
         return jnp.clip(_cdiv(live(slot), page) - blk * ppb, 0, ppb)
 
+    def page_copies(hbm, vmem, buf, j, pid=None):
+        """(source, destination) of each copy that lands page ``pid`` of
+        the layer in place ``j`` of buffer ``buf``: one per 128 lanes of
+        the head (dense), or the flat page whole. Without ``pid`` the
+        sources are the destinations (a wait's descriptors)."""
+        out = []
+        for c in range(1 if flat else nd):
+            dst = vmem.at[buf, j] if flat else vmem.at[buf, c, j]
+            src = dst
+            if pid is not None:
+                src = hbm.at[layer, pid]
+                if nd > 1 and not flat:
+                    src = src.at[:, :, pl.ds(c * LANES, LANES)]
+            out.append((src, dst))
+        return out
+
     def start(slot, blk, buf):
         """Start the copies of block ``blk``'s live pages into buffer
         ``buf``. A rolled loop over the live pages: unrolled, the
@@ -177,12 +212,9 @@ def _kernel(tables_ref, lens_ref, meta_ref,              # scalar prefetch
         def one(j, _):
             pid = tables_ref[slot * pages_per_slot + blk * ppb + j]
             for hbm, vmem, which in pools:
-                for c in range(nd):
-                    src = hbm.at[layer, pid]
-                    if nd > 1:
-                        src = src.at[:, :, pl.ds(c * LANES, LANES)]
+                for src, dst in page_copies(hbm, vmem, buf, j, pid):
                     pltpu.make_async_copy(
-                        src, vmem.at[buf, c, j], sems.at[which, buf]).start()
+                        src, dst, sems.at[which, buf]).start()
             return ()
         jax.lax.fori_loop(0, live_pages(slot, blk), one, ())
 
@@ -202,11 +234,10 @@ def _kernel(tables_ref, lens_ref, meta_ref,              # scalar prefetch
         @pl.when(n < ppb)
         def _partial():
             def one(j, _):
-                for _, vmem, which in pools:
-                    for c in range(nd):
+                for hbm, vmem, which in pools:
+                    for src, dst in page_copies(hbm, vmem, buf, j):
                         pltpu.make_async_copy(
-                            vmem.at[buf, c, j], vmem.at[buf, c, j],
-                            sems.at[which, buf]).wait()
+                            src, dst, sems.at[which, buf]).wait()
                 return ()
             jax.lax.fori_loop(0, n, one, ())
 
@@ -248,24 +279,33 @@ def _kernel(tables_ref, lens_ref, meta_ref,              # scalar prefetch
 
         wait(b, i, cur)
         # column j of a word's [gr, hpw * bk] scores: token j // hpw,
-        # head j % hpw of the word; row r belongs to head r // Gp of it
+        # head j % hpw of the word; row r belongs to head r // group of it
         col = jax.lax.broadcasted_iota(jnp.int32, (gr, hpw * bk), 1)
         row = jax.lax.broadcasted_iota(jnp.int32, (gr, hpw * bk), 0)
         tok = i * bk + _shift(col, hpw)
         mask = (tok < length) & (length - tok < window)
         if hpw > 1:
-            mask &= (col & (hpw - 1)) == _shift(row, GP)
+            mask &= (col & (hpw - 1)) == _shift(row, group)
         vtok = i * bk + _shift(jax.lax.broadcasted_iota(
             jnp.int32, (hpw * bk, 1), 0), hpw)
         vmask = vtok < length                                # [hpw * bk, 1]
 
         def words(buf):
             """A buffer of this block as 32-bit words, one view per 128
-            lanes of the head: ``[tokens * K / hpw, 128]``."""
+            lanes of the head: ``[tokens * K / hpw, 128]``; flat, the
+            block's ``[tokens, K * D]`` rows, once for every part."""
+            if flat:
+                return [buf.at[cur].reshape(bk, kheads * dh)] * nd
             views = [buf.at[cur, c].reshape(bk * kheads, LANES)
                      for c in range(nd)]
             return views if hpw == 1 else [
                 v.bitcast(jnp.uint32) for v in views]
+
+        def word_rows(ref, w, c, dtype):
+            """128 lanes of word ``w`` (flat: of head ``w``), part ``c``."""
+            if flat:
+                return ref[:, pl.ds(w * dh + c * LANES, LANES)]
+            return _word_rows(ref, w, kheads, hpw, dtype)
         k_words, v_words = words(k_buf), words(v_buf)
         # the words of a token's heads, unrolled: their products are
         # independent and the scheduler spreads them over the MXUs
@@ -273,10 +313,10 @@ def _kernel(tables_ref, lens_ref, meta_ref,              # scalar prefetch
             rows = slice(w * gr, (w + 1) * gr)
             m_new, l_new, acc = _attend_word(
                 [q_ref[0, rows, lanes(c)] for c in range(nd)],
-                [_word_rows(ref, w, kheads, hpw, k_buf.dtype)
-                 for ref in k_words],
-                [_word_rows(ref, w, kheads, hpw, v_buf.dtype)
-                 for ref in v_words],
+                [word_rows(ref, w, c, k_buf.dtype)
+                 for c, ref in enumerate(k_words)],
+                [word_rows(ref, w, c, v_buf.dtype)
+                 for c, ref in enumerate(v_words)],
                 m_ref[rows, :1], l_ref[rows, :1],
                 [acc_ref[rows, lanes(c)] for c in range(nd)], mask, vmask,
                 scale=scale, softcap=softcap)
@@ -291,14 +331,24 @@ def _kernel(tables_ref, lens_ref, meta_ref,              # scalar prefetch
     o_ref[0, :, :] = acc_ref[...] / l_ref[:, :1]
 
 
+def _block_buffer(pool_shape, ppb: int):
+    """A pool's VMEM double buffer of ``ppb`` pages: flat pages whole
+    (``[2, ppb, page, K * D]``), dense ones a copy per 128 lanes of the
+    head (``[2, D / 128, ppb, page, K, 128]``)."""
+    if len(pool_shape) == 4:
+        return (2, ppb) + tuple(pool_shape[2:])
+    _, _, page, kheads, dh = pool_shape
+    return (2, dh // LANES, ppb, page, kheads, LANES)
+
+
 @partial(jax.jit, static_argnames=("scale", "softcap", "pages_per_block",
                                    "interpret"))
 def _call(q3, kn, vn, k_pool, v_pool, tables, lengths, meta, *, scale,
           softcap, pages_per_block, interpret):
     b, khgp, dh = q3.shape
-    _, _, page, kheads, _ = k_pool.shape
     pages_per_slot = tables.shape[1]
     ppb = max(min(pages_per_block, pages_per_slot), 1)
+    buffer = _block_buffer(k_pool.shape, ppb)
 
     def per_slot(shape):
         return pl.BlockSpec((1,) + shape, lambda bi, *_: (bi, 0, 0))
@@ -312,10 +362,8 @@ def _call(q3, kn, vn, k_pool, v_pool, tables, lengths, meta, *, scale,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=per_slot((khgp, dh)),
         scratch_shapes=[
-            pltpu.VMEM((2, dh // LANES, ppb, page, kheads, LANES),
-                       k_pool.dtype),
-            pltpu.VMEM((2, dh // LANES, ppb, page, kheads, LANES),
-                       v_pool.dtype),
+            pltpu.VMEM(buffer, k_pool.dtype),
+            pltpu.VMEM(buffer, v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),            # buffer in flight
             pltpu.VMEM((khgp, LANES), jnp.float32),   # m
@@ -337,7 +385,7 @@ def _call(q3, kn, vn, k_pool, v_pool, tables, lengths, meta, *, scale,
 
 def paged_decode_attention(
     q: jnp.ndarray,             # [B, H, D] this token's queries
-    k_pool: jnp.ndarray,        # [L, pages, page, K, D] as stored
+    k_pool: jnp.ndarray,        # [L, pages, page, K, D] or [.., K * D]
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, pages/slot] int32 physical page ids
     lengths: jnp.ndarray,       # [B] int32 cached tokens = this query's pos
@@ -356,17 +404,32 @@ def paged_decode_attention(
     ``layer`` of the pools, plus this token's own key and value; the
     pools are not written. Pages past ``ceil(lengths[b] / page)`` are
     not read; their table entries may point anywhere inside the pool
-    (the trash page). Returns [B, H, D] in ``v_new``'s dtype."""
+    (the trash page). A pool of rank 4 is the flat layout: a row is the
+    token's K heads side by side. Returns [B, H, D] in ``v_new``'s
+    dtype."""
     b, h, d = q.shape
-    kheads = k_pool.shape[3]
+    kheads = k_new.shape[1]
     g = h // kheads
-    if g > GP:
-        raise ValueError(f"GQA group {g} exceeds the kernel's sublane "
-                         f"pad {GP}; use the gather path")
-    if d % LANES or (kheads * k_pool.dtype.itemsize) % 4:
-        raise ValueError(
-            f"head width {d} must be a multiple of 128 and the kv heads "
-            f"({kheads} of {k_pool.dtype}) must fill 32-bit words")
+    if k_pool.ndim == 4:
+        gp = -(-g // GP) * GP
+        if d % LANES or k_pool.shape[3] != kheads * d:
+            raise ValueError(
+                f"a flat row of {k_pool.shape[3]} must be {kheads} heads "
+                f"of a multiple of 128 (head width {d})")
+        if gp > MAX_GROUP_ROWS:
+            raise ValueError(f"GQA group {g} pads to {gp} rows, over the "
+                             f"kernel's {MAX_GROUP_ROWS}; use the gather "
+                             "path")
+    else:
+        gp = GP
+        if g > GP:
+            raise ValueError(f"GQA group {g} exceeds the kernel's sublane "
+                             f"pad {GP}; use the gather path")
+        if d % LANES or (kheads * k_pool.dtype.itemsize) % 4:
+            raise ValueError(
+                f"head width {d} must be a multiple of 128 and the kv "
+                f"heads ({kheads} of {k_pool.dtype}) must fill 32-bit "
+                "words")
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -376,24 +439,25 @@ def paged_decode_attention(
     # [B, K * Gp, D] per-slot operands in float32 containers (their
     # 8-row slices then sit on whole tiles; the values are the model's:
     # the kernel casts to the page dtype before the MXU): the queries
-    # with their group rows zero-padded, this token's key and value with
-    # each head's row repeated over its group's rows. (Plain reshapes,
-    # one concatenate and two broadcasts: ``jnp.pad`` and ``jnp.repeat``
-    # trace to ten times as many calls, and the trace is set-up time.)
+    # with their group rows zero-padded to Gp, this token's key and value
+    # with each head's row repeated over its group's rows. (Plain
+    # reshapes, one concatenate and two broadcasts: ``jnp.pad`` and
+    # ``jnp.repeat`` trace to ten times as many calls, and the trace is
+    # set-up time.)
     q4 = q.reshape(b, kheads, g, d).astype(jnp.float32)
-    if g < GP:
+    if g < gp:
         q4 = jnp.concatenate(
-            [q4, jnp.zeros((b, kheads, GP - g, d), jnp.float32)], axis=2)
+            [q4, jnp.zeros((b, kheads, gp - g, d), jnp.float32)], axis=2)
 
     def per_group_row(x, dtype):
         x = x.astype(dtype).astype(jnp.float32)[:, :, None, :]
-        return jnp.broadcast_to(x, (b, kheads, GP, d)).reshape(
-            b, kheads * GP, d)
+        return jnp.broadcast_to(x, (b, kheads, gp, d)).reshape(
+            b, kheads * gp, d)
     meta = jnp.stack([
         jnp.asarray(layer, jnp.int32).reshape(()),
         jnp.asarray(NO_WINDOW if window is None else window,
                     jnp.int32).reshape(())])
-    out = _call(q4.reshape(b, kheads * GP, d),
+    out = _call(q4.reshape(b, kheads * gp, d),
                 per_group_row(k_new, k_pool.dtype),
                 per_group_row(v_new, v_pool.dtype),
                 k_pool, v_pool, block_tables.astype(jnp.int32),
@@ -401,5 +465,5 @@ def paged_decode_attention(
                 scale=float(scale), softcap=float(logit_softcap),
                 pages_per_block=int(pages_per_block),
                 interpret=bool(interpret))
-    out = out.reshape(b, kheads, GP, d)[:, :, :g, :]
+    out = out.reshape(b, kheads, gp, d)[:, :, :g, :]
     return out.reshape(b, h, d).astype(v_new.dtype)

@@ -50,8 +50,9 @@ class ServingMetrics:
         self.decode_steps_sampled = r.counter(
             "serving/decode_steps_sampled")
         # decode steps dispatched to a program that holds the paged
-        # attention kernel (ops/paged_attention.py): the steps that read
-        # the running slots' live pages, not every slot's whole window
+        # attention kernel (ops/paged_attention.py; dense rows, or a
+        # layer-spec model's flat plain-attention rows): the steps that
+        # read the running slots' live pages, not every slot's window
         self.decode_steps_paged_kernel = r.counter(
             "serving/decode_steps_paged_kernel")
         # what the decode and chunk dispatches sent to the device: one

@@ -1902,12 +1902,13 @@ class ServingEngine:
         the columns the step's program reads; and how many of the
         running slots sample. ``read_tokens`` follows the program: where
         the one-token step holds the paged attention kernel
-        (``Transformer.paged_decode_kernel``) it is the running slots'
-        live pages x the page size, from the host mirror of the lengths;
-        where it gathers (latent rows, a per-layer spec, a backend that
-        is no TPU, and the speculative verify forward whatever the
-        model) it is every slot's whole window, a constant of the
-        geometry."""
+        (``Transformer.paged_decode_kernel``: dense rows, or a layer-spec
+        model whose rows of its own are all plain attention, Jamba's and
+        Granite 4.0-H's) it is the running slots' live pages x the page
+        size, from the host mirror of the lengths; where it gathers
+        (latent rows, SambaY's differential rows, a backend that is no
+        TPU, and the speculative verify forward whatever the model) it
+        is every slot's whole window, a constant of the geometry."""
         geom = self.cache.geom
         if self._kernel_decode is None:
             # asked once, here: the first dispatch, which traces the

@@ -239,7 +239,8 @@ CATALOG: Tuple[MetricSpec, ...] = (
     _s("serving/decode_steps_paged_kernel", "counter", "steps",
        "decode steps dispatched to a program that holds the paged "
        "attention kernel (reads the running slots' live pages, not "
-       "every slot's whole window)", "step"),
+       "every slot's whole window: the dense rows, and the flat rows "
+       "of a layer-spec model's plain-attention layers)", "step"),
     _s("serving/step_arg_puts", "counter", "puts",
        "host-to-device puts of per-step arguments by the decode and "
        "prefill-chunk dispatches (one packed array each)", "step"),

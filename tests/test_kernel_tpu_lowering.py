@@ -6,7 +6,10 @@ Mosaic's rules on block shapes and vector ops, so the parity suites
 cannot see a kernel that does not lower — the decode kernel shipped that
 way (``(1, n)`` blocks over ``[B, n]`` arrays, a one-column bf16 dot).
 Lowering to a ``tpu_custom_call`` is the part of the chip's compiler a
-CPU can run; whether Mosaic then compiles it is chip_smoke.py's job."""
+CPU can run; whether Mosaic then compiles it is chip_smoke.py's job, or
+``tests/test_selective_scan_kernel.py``'s, which compiles the main
+path's kernels for a described v5e (the paged kernel's flat form among
+them)."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -98,6 +101,35 @@ def test_paged_decode_attention_lowers_for_tpu(slots, h, kh, d, softcap):
         _sds((slots, 128), jnp.int32), _sds((slots,), jnp.int32),
         _sds((slots, kh, d)), _sds((slots, kh, d)), _sds((), jnp.int32),
         _sds((), jnp.int32))
+    assert text.count("tpu_custom_call") == 1
+
+
+# the flat form: a layer-spec model's plain-attention pools, rows of
+# K x D stored flat ([layers, pages, 16, K * D]), at the serving cells'
+# geometry: (layers, pages, slots, pages a slot, q heads, kv heads, scale)
+FLAT_CELLS = {
+    # jamba2_3b_serve: one kv head of 128 under 20 query heads
+    "jamba": (2, 33792, 16, 2112, 20, 1, None),
+    # granite4_h_small_serve_p10_ep2: groups of 4, muP's scale 1/128
+    "granite": (1, 16384, 32, 512, 32, 8, 1 / 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(FLAT_CELLS))
+def test_paged_decode_attention_flat_form_lowers_for_tpu(cell):
+    from dla_tpu.ops.paged_attention import paged_decode_attention
+    layers, pages, slots, pps, h, kh, scale = FLAT_CELLS[cell]
+    pool = _sds((layers, pages, 16, kh * D))
+
+    def attend(q, kp, vp, tables, lengths, kn, vn, layer):
+        return paged_decode_attention(
+            q, kp, vp, tables, lengths, kn, vn, layer=layer,
+            softmax_scale=scale, interpret=False)
+
+    text = _tpu_text(
+        attend, _sds((slots, h, D)), pool, pool,
+        _sds((slots, pps), jnp.int32), _sds((slots,), jnp.int32),
+        _sds((slots, kh, D)), _sds((slots, kh, D)), _sds((), jnp.int32))
     assert text.count("tpu_custom_call") == 1
 
 

@@ -9,6 +9,7 @@ The kernel runs interpreted here (the CPU); the call site chooses it on
 a TPU backend only, so the engine tests steer ``_tpu_backend`` and the
 lowering tests hand the kernel ``interpret=False``: steering is the
 tests' business, the program has no option for it."""
+import dataclasses
 import os
 import subprocess
 import sys
@@ -197,6 +198,104 @@ def test_kernel_refuses_what_it_cannot_lay_out():
         _kernel().paged_decode_attention(**c, layer=0, interpret=True)
 
 
+# --------------------------------------------- the kernel's flat form
+#
+# A layer-spec model's plain-attention layers store a row flat, the
+# token's kv heads side by side: pools [L, pages, page, K * D].
+
+GRANITE = dict(h=32, k=8, scale=1 / 128)    # groups of 4, muP's scale
+JAMBA = dict(h=20, k=1, scale=None)         # one head of 128, group 20
+
+
+def _flat(c):
+    """The same case with its pools stored flat."""
+    def flat(p):
+        return p.reshape(p.shape[:3] + (-1,))
+    return {**c, "k_pool": flat(c["k_pool"]), "v_pool": flat(c["v_pool"])}
+
+
+def _check_flat(geometry, lengths, *, layer=1, pages_per_block=2, pps=4,
+                layers=2, dtype=jnp.bfloat16, tables=None):
+    """The flat form against ``decode_attention`` over the gathered
+    window, which reads the same numbers as heads (``_case``'s pools)."""
+    c = _case(lengths, h=geometry["h"], k=geometry["k"], pps=pps,
+              layers=layers, dtype=dtype)
+    if tables is not None:
+        c = {**c, "block_tables": jnp.asarray(tables(np.array(
+            c["block_tables"])))}
+    out = _kernel().paged_decode_attention(
+        **_flat(c), layer=layer, softmax_scale=geometry["scale"],
+        pages_per_block=pages_per_block, interpret=True)
+    want = _reference(c, layer, scale=geometry["scale"])
+    assert out.shape == want.shape and out.dtype == want.dtype
+    out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(out).all(), "garbage (NaN) leaked out of a dead column"
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(out, want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("geometry", [GRANITE, JAMBA],
+                         ids=["granite", "jamba"])
+@pytest.mark.parametrize("lengths", [
+    (0, 1, 16, 64),         # empty, one token, a page boundary, full
+    (5, 37, 21, 50),        # ragged
+], ids=lambda v: "-".join(map(str, v)))
+def test_flat_kernel_matches_decode_attention_over_the_gathered_window(
+        geometry, lengths):
+    _check_flat(geometry, lengths)
+
+
+@pytest.mark.parametrize("geometry", [GRANITE, JAMBA],
+                         ids=["granite", "jamba"])
+def test_flat_kernel_float32_pages_agree_to_rounding(geometry):
+    _check_flat(geometry, (17, 0, 64, 31), dtype=jnp.float32)
+
+
+def test_flat_kernel_layer_rides_as_a_traced_operand():
+    """A run of layers scans over the layer index: one compile reads
+    every layer it is given."""
+    c = _case((45, 64, 9, 30), h=20, k=1, layers=3)
+    paged = _kernel().paged_decode_attention
+
+    @jax.jit
+    def each(c, layers):
+        return jax.lax.map(lambda l: paged(
+            **_flat(c), layer=l, pages_per_block=2, interpret=True), layers)
+    got = each(c, jnp.asarray([0, 2], jnp.int32))
+    for out, layer in zip(got, (0, 2)):
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32),
+            np.asarray(_reference(c, layer), np.float32),
+            atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("pages_per_block", [1, 3, 16])
+def test_flat_kernel_block_size_does_not_change_the_result(pages_per_block):
+    # 3 does not divide the 4 pages of a slot; 16 is clamped to them
+    _check_flat(JAMBA, (64, 17, 0, 50), pages_per_block=pages_per_block)
+
+
+@pytest.mark.parametrize("geometry", [GRANITE, JAMBA],
+                         ids=["granite", "jamba"])
+def test_flat_kernel_does_not_read_dead_pages(geometry):
+    """Table entries past the live count may point anywhere: here at a
+    page of NaN that belongs to nobody, and at another slot's page."""
+    def tables(t):
+        t[0, 2:] = t[1, 0]
+        t[1, 3] = 0
+        return t
+    _check_flat(geometry, (20, 40), tables=tables)
+
+
+def test_flat_kernel_refuses_what_it_cannot_lay_out():
+    c = _flat(_case((5,), h=33, k=1))
+    with pytest.raises(ValueError, match="GQA group 33 pads to 40"):
+        _kernel().paged_decode_attention(**c, layer=0, interpret=True)
+    c = _flat(_case((5,), h=4, k=2, d=64))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        _kernel().paged_decode_attention(**c, layer=0, interpret=True)
+
+
 # ------------------------------------------- the call site and the engine
 
 def _dense(**kw):
@@ -204,6 +303,14 @@ def _dense(**kw):
         vocab_size=97, hidden_size=256, intermediate_size=256, num_layers=2,
         num_heads=4, num_kv_heads=2, head_dim=128, max_seq_length=64,
         dtype="float32", param_dtype="float32"), **kw})
+
+
+def _spec(preset, **kw):
+    """A layer-spec preset at widths the kernel's flat form takes: heads
+    of 128 and bfloat16 rows (the presets' own heads are 16 wide, in
+    float32, and keep the gather)."""
+    return dataclasses.replace(get_model_config(preset), **{
+        **dict(head_dim=128, dtype="bfloat16"), **kw})
 
 
 @pytest.fixture
@@ -227,9 +334,16 @@ def as_on_a_tpu(monkeypatch):
     (_dense(kv_cache_dtype="int8"), False),
     ("tiny", False), ("tiny-gqa", False), ("tiny-mla-moe", False),
     ("tiny-sambay", False),
+    (_spec("tiny-jamba", num_heads=20), True),            # flat, group 20
+    (_spec("tiny-granite-h"), True),                      # flat, group 2
+    ("tiny-jamba", False),                   # heads of 16, float32 rows
+    (_spec("tiny-jamba", num_heads=40), False),           # 40 rows > 32
+    (_spec("tiny-granite-h", dtype="float32"), False),
+    (_spec("tiny-sambay", num_heads=4, num_kv_heads=2), False),
 ], ids=["dense128", "gemma2-like", "group8", "group16", "d64", "d80",
         "mqa-bf16", "int8-pages", "tiny", "tiny-gqa", "tiny-mla-moe",
-        "tiny-sambay"])
+        "tiny-sambay", "tiny-jamba", "tiny-granite-h", "tiny-jamba-widths",
+        "jamba-group40", "granite-f32", "sambay-d128-bf16"])
 def test_which_models_the_kernel_reads(cfg, rows, as_on_a_tpu):
     if isinstance(cfg, str):
         cfg = get_model_config(cfg)
@@ -278,11 +392,15 @@ def _prompts(lengths, seed=0):
     _dense(head_dim=256, attn_logit_softcap=50.0, sliding_window=8,
            sliding_window_pattern=2, query_pre_attn_scalar=256,
            arch="gemma2"),
-], ids=["dense128", "gemma2-like"])
+    _spec("tiny-jamba", num_heads=20),
+    _spec("tiny-granite-h"),
+], ids=["dense128", "gemma2-like", "tiny-jamba", "tiny-granite-h"])
 def test_engine_greedy_tokens_equal_on_both_paths(cfg, monkeypatch):
     """Seven requests through three slots and a pool too small to hold
     them at once: admissions, finishes and page reuse; the kernel reads
-    pages other requests wrote and freed."""
+    pages other requests wrote and freed. The layer-spec models run in
+    bfloat16 (the flat form's rows): their log-probabilities agree to
+    its rounding."""
     prompts = _prompts((5, 9, 17, 3, 11, 8, 14))
     model = T.Transformer(cfg)
     params = model.init(jax.random.key(0))
@@ -292,9 +410,10 @@ def test_engine_greedy_tokens_equal_on_both_paths(cfg, monkeypatch):
     kernel, snap = _drain(_engine(model, params), prompts)
     assert snap["serving/decode_steps_paged_kernel"] == \
         snap["serving/decode_steps"] > 0
+    tol = 2e-5 if cfg.dtype == "float32" else 1e-2
     for (g_tok, g_lp), (k_tok, k_lp) in zip(gather, kernel):
         assert g_tok == k_tok
-        np.testing.assert_allclose(g_lp, k_lp, atol=2e-5)
+        np.testing.assert_allclose(g_lp, k_lp, atol=tol)
 
 
 def _decode_spans(monkeypatch):
@@ -310,12 +429,18 @@ def _decode_spans(monkeypatch):
     return seen
 
 
-@pytest.mark.parametrize("on_tpu", [False, True], ids=["gather", "kernel"])
-def test_read_tokens_says_what_the_program_reads(on_tpu, monkeypatch):
+@pytest.mark.parametrize("on_tpu,cfg", [
+    (False, _dense()), (True, _dense()),
+    (False, _spec("tiny-jamba", num_heads=20)),
+    (True, _spec("tiny-jamba", num_heads=20)),
+    (False, _spec("tiny-granite-h")), (True, _spec("tiny-granite-h")),
+], ids=["gather", "kernel", "tiny-jamba-gather", "tiny-jamba-kernel",
+        "tiny-granite-h-gather", "tiny-granite-h-kernel"])
+def test_read_tokens_says_what_the_program_reads(on_tpu, cfg, monkeypatch):
     if on_tpu:
         monkeypatch.setattr(T, "_tpu_backend", lambda: True)
     seen = _decode_spans(monkeypatch)
-    model = T.Transformer(_dense())
+    model = T.Transformer(cfg)
     eng = _engine(model, model.init(jax.random.key(0)))
     geom = eng.cache.geom
     lengths = []
@@ -474,17 +599,22 @@ def _lower_decode_for_tpu(model, monkeypatch, counts):
     return text, chunk
 
 
-@pytest.mark.parametrize("cfg", [
-    _dense(dtype="bfloat16"),
-    _dense(dtype="bfloat16", head_dim=256, attn_logit_softcap=50.0,
-           sliding_window=8, sliding_window_pattern=2,
-           query_pre_attn_scalar=256, arch="gemma2"),
-], ids=["dense128", "gemma2-like"])
+@pytest.mark.parametrize("cfg,calls,chunk_read", [
+    (_dense(dtype="bfloat16"), 1, "tensor<1x64x2x128x"),
+    (_dense(dtype="bfloat16", head_dim=256, attn_logit_softcap=50.0,
+            sliding_window=8, sliding_window_pattern=2,
+            query_pre_attn_scalar=256, arch="gemma2"), 1,
+     "tensor<1x64x2x256x"),
+    # two attention layers, each a run of its own: two calls of one
+    # traced kernel; the chunk reads blocks of 4 pages (PR 39's walk)
+    (_spec("tiny-jamba", num_heads=20), 2, "tensor<1x4x16x128x"),
+    (_spec("tiny-granite-h"), 1, "tensor<1x4x16x256x"),
+], ids=["dense128", "gemma2-like", "tiny-jamba", "tiny-granite-h"])
 def test_decode_program_holds_one_kernel_and_no_window_gather(
-        cfg, monkeypatch):
+        cfg, calls, chunk_read, monkeypatch):
     model = T.Transformer(cfg)
     # the window of the gather path: [slots, slot window, K, D]
-    window = f"tensor<4x64x2x{cfg.head_dim_}x"
+    window = f"tensor<4x64x{cfg.num_kv_heads}x{cfg.head_dim_}x"
     counts = {}
     text, _ = _lower_decode_for_tpu(model, monkeypatch, counts)
     assert "tpu_custom_call" not in text and window in text and not counts
@@ -495,19 +625,29 @@ def test_decode_program_holds_one_kernel_and_no_window_gather(
     # window left in the program
     assert text.count("tpu_custom_call") == 1
     assert window not in text
-    assert counts == {"wrapper": 1, "body": 1}
-    # the chunk lane keeps the gather, on a TPU too
+    assert counts == {"wrapper": calls, "body": 1}
+    # the chunk lane keeps its own read, on a TPU too
     assert "tpu_custom_call" not in chunk
-    assert f"tensor<1x64x2x{cfg.head_dim_}x" in chunk
+    assert chunk_read in chunk
 
 
-@pytest.mark.parametrize("rows,on_tpu,starts", [
-    (True, True, True), (True, False, False), (False, True, False)])
+PAGED, SCAN = "dla-paged-kernel-import", "dla-scan-kernel-import"
+
+
+@pytest.mark.parametrize("cfg,on_tpu,starts", [
+    (_dense(), True, [PAGED]), (_dense(), False, []), ("tiny", True, []),
+    (_spec("tiny-jamba", num_heads=20), True, [PAGED, SCAN]),
+    (_spec("tiny-jamba", num_heads=20), False, []),
+    (_spec("tiny-granite-h"), True, [PAGED]), ("tiny-sambay", True, [SCAN]),
+], ids=["True-True-True", "True-False-False", "False-True-False",
+        "tiny-jamba-tpu", "tiny-jamba-cpu", "tiny-granite-h-tpu",
+        "tiny-sambay-tpu"])
 def test_constructor_starts_the_import_off_the_critical_path(
-        rows, on_tpu, starts, monkeypatch):
+        cfg, on_tpu, starts, monkeypatch):
     """A model whose decode step will run the kernel starts importing
     its module on a daemon thread when it is built on a TPU backend;
-    no other model, and no other backend, starts anything."""
+    no other model, and no other backend, starts it (a model with
+    Mamba-1 layers starts the scan kernel's import beside it)."""
     started = []
 
     class Recorder:
@@ -518,14 +658,13 @@ def test_constructor_starts_the_import_off_the_critical_path(
             started.append(self.spec)
     monkeypatch.setattr(T.threading, "Thread", Recorder)
     monkeypatch.setattr(T, "_tpu_backend", lambda: on_tpu)
-    model = T.Transformer(_dense() if rows else get_model_config("tiny"))
+    model = T.Transformer(get_model_config(cfg) if isinstance(cfg, str)
+                          else cfg)
     jax.eval_shape(model.init, jax.random.key(0))    # starts no second one
-    if not starts:
-        assert not started
-        return
-    (target, args, name, daemon), = started
-    assert target is T._import_paged_kernel and args == ()
-    assert name.startswith("dla-") and daemon is True
+    assert [name for _, _, name, _ in started] == starts
+    for target, args, name, daemon in started:
+        assert args == () and daemon is True
+        assert (target is T._import_paged_kernel) is (name == PAGED)
 
 
 def _fresh_import_under_cache(tmp_path, cache_dir, enabled=True):

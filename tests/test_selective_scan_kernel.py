@@ -400,6 +400,33 @@ def test_mosaic_compiles_the_kernel_at_the_cells_shapes(t, one_chip,
     assert "selective_scan_chunk" in compiled.as_text()
 
 
+@pytest.mark.parametrize("layers,pages,slots,pps,h,kh,scale", [
+    (2, 33792, 16, 2112, 20, 1, None),      # jamba2_3b_serve
+    (1, 16384, 32, 512, 32, 8, 1 / 128),    # granite4_h_small_serve_p10_ep2
+], ids=["jamba", "granite"])
+def test_mosaic_compiles_the_flat_paged_decode_kernel_at_the_cells_shapes(
+        layers, pages, slots, pps, h, kh, scale, one_chip, no_compile_cache):
+    """The paged decode kernel's flat form (ops/paged_attention.py) over
+    the plain-attention pools of the two cells, rows of kv heads x 128
+    stored flat; kept here, beside this file's other compiles for a
+    described chip: one worker loads the TPU's library."""
+    from dla_tpu.ops.paged_attention import paged_decode_attention
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = sds((layers, pages, 16, kh * 128))
+    compiled = jax.jit(
+        lambda q, kp, vp, tables, lengths, kn, vn, layer:
+        paged_decode_attention(q, kp, vp, tables, lengths, kn, vn,
+                               layer=layer, softmax_scale=scale,
+                               interpret=False)
+    ).lower(sds((slots, h, 128)), pool, pool,
+            sds((slots, pps), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots, kh, 128)), sds((slots, kh, 128)),
+            sds((), jnp.int32)).compile()
+    assert "paged_decode_attention" in compiled.as_text()
+
+
 def test_compiled_chunk_program_names_the_kernel_under_the_scope(
         one_chip, no_compile_cache, monkeypatch):
     """What ``perfbench/lib/program_scopes.py`` joins: the instruction's
