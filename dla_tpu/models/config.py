@@ -224,6 +224,15 @@ class ModelConfig:
     # of the `expert` mesh axis, run without the exchange on one chip).
     moe_first_expert: int = 0
     moe_experts_held: int = 0
+    # router scores: "softmax" (the chosen logits softmaxed) or "sigmoid"
+    # (DeepSeek-V3's ``noaux_tc``: sigmoid of every logit, the top k of
+    # score + a per-expert correction bias chosen, the chosen scores
+    # without the bias renormalised, times moe_routed_scale)
+    moe_scoring: str = "softmax"
+    # leading dense layers (HF ``first_k_dense_replace``): the first this
+    # many layers run a dense MLP of intermediate_size, the rest routed
+    # experts; the two runs are scanned apart (params["dense_layers"])
+    first_k_dense: int = 0
     # Multi-head latent attention (DeepSeek-V2 / mistral4): kv_lora_rank
     # > 0 makes the attention latent — queries through a q_lora_rank
     # bottleneck (0 = a plain wq), keys and values expanded from one
@@ -242,6 +251,18 @@ class ModelConfig:
     # llama convention; True = adjacent dims (x[2i], x[2i+1]) (HF
     # ``rope_interleave``)
     rope_interleave: bool = False
+    # learned sparse attention (DSA, DeepSeek-V3.2's indexer; GLM-5):
+    # index_topk > 0 gives every latent-attention layer an indexer of
+    # index_n_heads heads of index_head_dim (its first qk_rope_head_dim
+    # dims rotated, adjacent pairs iff index_rope_interleave) whose key, a
+    # LayerNorm'd index_head_dim row a token, the paged pool keeps beside
+    # the latent row; each query's softmax runs over the index_topk
+    # cached tokens the indexer scores highest. The paged serving steps
+    # alone compute it
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_rope_interleave: bool = False
     # LoRA (the reference's model.lora block, advertised but never wired —
     # reference base_model.py:45-49 dead code, SURVEY.md sec 2.5; here it
     # is functional). lora_r == 0 disables. Adapters are a separate
@@ -316,6 +337,27 @@ class ModelConfig:
                 raise ValueError(
                     "latent attention runs without int8 KV, projection "
                     "biases, a sliding window or LoRA adapters")
+        if self.index_topk and not (
+                self.latent_attention and self.index_n_heads > 0
+                and self.index_head_dim >= self.qk_rope_head_dim
+                and self.q_lora_rank > 0):
+            raise ValueError(
+                "the sparse-attention indexer (index_topk > 0) sits inside "
+                "latent attention with a query bottleneck (q_lora_rank > "
+                "0) and needs index_n_heads and an index_head_dim of at "
+                "least qk_rope_head_dim")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax' or 'sigmoid', got "
+                f"{self.moe_scoring!r}")
+        if self.first_k_dense and not (
+                self.num_experts > 0 and self.layers is None
+                and 0 < self.first_k_dense < self.num_layers
+                and self.pipeline_stages <= 1):
+            raise ValueError(
+                f"first_k_dense={self.first_k_dense}: leading dense layers "
+                "come before routed-expert layers (num_experts > 0) of the "
+                "homogeneous stack, fewer than num_layers, unpipelined")
         if self.kv_cache_dtype not in ("bfloat16", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be 'bfloat16' or 'int8', got "
@@ -467,6 +509,11 @@ class ModelConfig:
         a page then relays the pool out (PERF.md, PR 29)."""
         width = self.kv_lora_rank + self.qk_rope_head_dim
         return width if width < 128 else -(-width // 128) * 128
+
+    @property
+    def expert_layers_(self) -> int:
+        """Layers with routed experts (0 for a dense model)."""
+        return self.num_layers - self.first_k_dense if self.num_experts else 0
 
     @property
     def expert_width_(self) -> int:
@@ -661,7 +708,22 @@ register_model("tiny-mla", ModelConfig(
     q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
     qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
     param_dtype="float32", dtype="float32", remat="none"))
-
+# the GLM-5 block at toy widths: one leading dense layer, then two layers
+# of top-2 of 8 sigmoid-routed experts with a correction bias beside a
+# shared expert, every layer's latent attention restricted to the top 8
+# of what a 2-head indexer of 16 (8 rotated) scores; contexts of tens of
+# tokens make the selection prune
+register_model("tiny-dsa", ModelConfig(
+    vocab_size=512, hidden_size=64, intermediate_size=96,
+    num_layers=3, num_heads=4, num_kv_heads=4, max_seq_length=256,
+    rope_theta=1e6, q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+    index_topk=8, index_n_heads=2, index_head_dim=16,
+    index_rope_interleave=True,
+    num_experts=8, num_experts_per_token=2, moe_intermediate_size=32,
+    num_shared_experts=1, moe_routed_scale=2.5, moe_scoring="sigmoid",
+    first_k_dense=1, moe_capacity_factor=4.0,
+    param_dtype="float32", dtype="float32", remat="none"))
 
 
 def sambay_layers(num_layers: int, window: int,

@@ -301,6 +301,21 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
             qk_rope_head_dim=int(hf_cfg["qk_rope_head_dim"]),
             v_head_dim=int(hf_cfg["v_head_dim"]),
             rope_interleave=bool(hf_cfg.get("rope_interleave", False)))
+    if hf_cfg.get("index_topk"):
+        # learned sparse attention (DeepSeek-V3.2 / glm_moe_dsa); every
+        # layer has an indexer of its own here
+        shared = sorted(set(hf_cfg.get("indexer_types") or ["full"])
+                        - {"full"})
+        if shared or hf_cfg.get("index_topk_freq", 1) != 1:
+            raise NotImplementedError(
+                f"indexers shared across layers (indexer_types {shared}, "
+                f"index_topk_freq {hf_cfg.get('index_topk_freq')})")
+        fields.update(
+            index_topk=int(hf_cfg["index_topk"]),
+            index_n_heads=int(hf_cfg["index_n_heads"]),
+            index_head_dim=int(hf_cfg["index_head_dim"]),
+            index_rope_interleave=bool(
+                hf_cfg.get("indexer_rope_interleave", False)))
     if hf_cfg.get("n_routed_experts"):
         fields.update(_routed_experts(hf_cfg))
     if model_type == "mixtral" or "num_local_experts" in hf_cfg:
@@ -357,23 +372,29 @@ def hf_config_to_model_config(hf_cfg: Dict[str, Any], **overrides) -> ModelConfi
 
 
 def _routed_experts(hf_cfg: Dict[str, Any]) -> Dict[str, Any]:
-    """The DeepSeek-lineage expert keys (mistral4): ``n_routed_experts``
-    counts the experts whose weights THIS process holds; a file that
-    stands for one chip of an expert-parallel deployment states the
-    router's width as ``experts_published`` and the held ids as
-    ``experts_held`` = [first, last]. What ops/moe.py does not compute is
-    refused, not approximated."""
-    if int(hf_cfg.get("first_k_dense_replace") or 0):
-        raise NotImplementedError(
-            "leading dense layers (first_k_dense_replace > 0) need a "
-            "per-layer layer spec; every layer here is an expert layer")
+    """The DeepSeek-lineage expert keys (mistral4, glm_moe_dsa):
+    ``n_routed_experts`` counts the experts whose weights THIS process
+    holds; a file that stands for one chip of an expert-parallel
+    deployment states the router's width as ``experts_published`` and the
+    held ids as ``experts_held`` = [first, last]. ``scoring_func``
+    sigmoid is DeepSeek-V3's ``noaux_tc`` router (a correction bias
+    moves the choice, not the weights); ``first_k_dense_replace`` leading
+    layers run a dense MLP. What ops/moe.py does not compute is refused,
+    not approximated."""
     if int(hf_cfg.get("n_group") or 1) != 1 or \
             int(hf_cfg.get("topk_group") or 1) != 1:
         raise NotImplementedError("group-limited routing (n_group > 1)")
-    if str(hf_cfg.get("scoring_func") or "softmax") != "softmax":
+    scoring = str(hf_cfg.get("scoring_func") or "softmax")
+    if scoring not in ("softmax", "sigmoid") or (
+            scoring == "sigmoid"
+            and hf_cfg.get("topk_method", "noaux_tc") != "noaux_tc"):
         raise NotImplementedError(
-            "only the softmax router is implemented (no sigmoid scores, "
-            "no correction bias)")
+            f"router scoring_func {scoring!r} with topk_method "
+            f"{hf_cfg.get('topk_method')!r}: softmax, or sigmoid with the "
+            "correction bias (noaux_tc), are implemented")
+    if int(hf_cfg.get("moe_layer_freq") or 1) != 1:
+        raise NotImplementedError(
+            "experts in every n-th layer (moe_layer_freq > 1)")
     if not hf_cfg.get("norm_topk_prob", True):
         raise NotImplementedError(
             "router weights are renormalised over the chosen experts "
@@ -388,7 +409,9 @@ def _routed_experts(hf_cfg: Dict[str, Any]) -> Dict[str, Any]:
         num_experts_per_token=int(hf_cfg["num_experts_per_tok"]),
         moe_intermediate_size=int(hf_cfg["moe_intermediate_size"]),
         num_shared_experts=int(hf_cfg.get("n_shared_experts") or 0),
-        moe_routed_scale=float(hf_cfg.get("routed_scaling_factor") or 1.0))
+        moe_routed_scale=float(hf_cfg.get("routed_scaling_factor") or 1.0),
+        moe_scoring=scoring,
+        first_k_dense=int(hf_cfg.get("first_k_dense_replace") or 0))
 
 
 def _phi_config(hf_cfg: Dict[str, Any], overrides) -> ModelConfig:

@@ -22,6 +22,7 @@ Replaces the reference's HF ``AutoModelForCausalLM`` wrapper
 """
 from __future__ import annotations
 
+import functools
 import importlib
 import math
 import sys
@@ -57,6 +58,14 @@ Params = Dict[str, Any]
 # Activation sharding: batch over the two batch axes, sequence over the
 # context-parallel axis, features replicated (TP slices live inside the block).
 ACT_SPEC = P(("data", "fsdp"), "sequence", None)
+
+# the DSA indexer's key norm: DeepSeek-V3.2's ``Indexer.k_norm``, a
+# LayerNorm at its class default eps (the published configs state none)
+INDEX_NORM_EPS = 1e-6
+# queries of one sparse-attention block: a prefill chunk's index scores
+# ([Tb, Hi, window] float32) and gathered rows ([Tb, index_topk, row])
+# are taken this many queries at a time
+DSA_QUERY_BLOCK = 64
 
 
 # shapes for which the replicated-flash fallback was already reported —
@@ -361,20 +370,26 @@ class Transformer:
             # the router scores every expert; the weights are those of
             # the experts held here (all of them unless the config says)
             E, EH, F = cfg.num_experts, cfg.experts_held_, cfg.expert_width_
+            LE = cfg.expert_layers_
             mlp = {
-                "router": mat(jax.random.fold_in(rng, 7), (L, D, E), std),
-                "w_gate": mat(keys[5], (L, EH, D, F), std),
-                "w_up": mat(keys[6], (L, EH, D, F), std),
-                "w_down": mat(keys[7], (L, EH, F, D), out_std),
+                "router": mat(jax.random.fold_in(rng, 7), (LE, D, E), std),
+                "w_gate": mat(keys[5], (LE, EH, D, F), std),
+                "w_up": mat(keys[6], (LE, EH, D, F), std),
+                "w_down": mat(keys[7], (LE, EH, F, D), out_std),
             }
             if cfg.num_shared_experts:
                 FS = cfg.num_shared_experts * F
                 mlp["ws_gate"] = mat(
-                    jax.random.fold_in(rng, 11), (L, D, FS), std)
+                    jax.random.fold_in(rng, 11), (LE, D, FS), std)
                 mlp["ws_up"] = mat(
-                    jax.random.fold_in(rng, 12), (L, D, FS), std)
+                    jax.random.fold_in(rng, 12), (LE, D, FS), std)
                 mlp["ws_down"] = mat(
-                    jax.random.fold_in(rng, 13), (L, FS, D), out_std)
+                    jax.random.fold_in(rng, 13), (LE, FS, D), out_std)
+            if cfg.moe_scoring == "sigmoid":
+                # the correction bias is learned; random here, so that
+                # the choice it moves is exercised
+                mlp["router_bias"] = mat(
+                    jax.random.fold_in(rng, 15), (LE, E), 0.05)
         else:
             mlp = {
                 "w_gate": mat(keys[5], (L, D, F), std),
@@ -399,6 +414,16 @@ class Transformer:
                                    (L, cfg.q_lora_rank, qdim), std)
             else:
                 attn["wq"] = mat(keys[1], (L, D, qdim), std)
+            if cfg.index_topk:
+                hi, di = cfg.index_n_heads, cfg.index_head_dim
+                attn["wi_q"] = mat(jax.random.fold_in(rng, 20),
+                                   (L, cfg.q_lora_rank, hi * di), std)
+                attn["wi_k"] = mat(jax.random.fold_in(rng, 21),
+                                   (L, D, di), std)
+                attn["wi_k_norm"] = jnp.ones((L, di), self.pdtype)
+                attn["wi_k_norm_bias"] = jnp.zeros((L, di), self.pdtype)
+                attn["wi_w"] = mat(jax.random.fold_in(rng, 22),
+                                   (L, D, hi), std)
         else:
             attn = {
                 "wq": mat(keys[1], (L, D, qdim), std),
@@ -426,6 +451,19 @@ class Transformer:
         if not cfg.tie_embeddings:
             params["lm_head"] = mat(
                 jax.random.fold_in(rng, 99), (D, cfg.vocab_size), std)
+        if cfg.first_k_dense:
+            # the leading dense layers: their attention and norms, and a
+            # dense MLP of intermediate_size, in a stack of their own
+            k, F = cfg.first_k_dense, cfg.intermediate_size
+            layers = params["layers"]
+            split = {n: layers[n] for n in ("attn_norm", *attn, "mlp_norm")}
+            layers.update({n: v[k:] for n, v in split.items()})
+            params["dense_layers"] = {
+                **{n: v[:k] for n, v in split.items()},
+                "w_gate": mat(jax.random.fold_in(rng, 16), (k, D, F), std),
+                "w_up": mat(jax.random.fold_in(rng, 17), (k, D, F), std),
+                "w_down": mat(jax.random.fold_in(rng, 18), (k, F, D),
+                              out_std)}
         return params
 
     # ----------------------------------------------------------------- LoRA
@@ -614,6 +652,8 @@ class Transformer:
                 mlp_specs["ws_gate"] = P("stage", "fsdp", "model")
                 mlp_specs["ws_up"] = P("stage", "fsdp", "model")
                 mlp_specs["ws_down"] = P("stage", "model", "fsdp")
+            if self.cfg.moe_scoring == "sigmoid":
+                mlp_specs["router_bias"] = P("stage", None)
         else:
             mlp_specs = {
                 "w_gate": P("stage", "fsdp", "model"),
@@ -635,6 +675,14 @@ class Transformer:
                 attn_specs["wq_b"] = P("stage", None, "model")
             else:
                 attn_specs["wq"] = P("stage", "fsdp", "model")
+            if self.cfg.index_topk:
+                # the indexer is small: replicated over `model`
+                attn_specs.update(
+                    wi_q=P("stage", None, None),
+                    wi_k=P("stage", "fsdp", None),
+                    wi_k_norm=P("stage", None),
+                    wi_k_norm_bias=P("stage", None),
+                    wi_w=P("stage", "fsdp", None))
         else:
             attn_specs = {
                 "wq": P("stage", "fsdp", "model"),
@@ -661,6 +709,13 @@ class Transformer:
             specs["layers"]["wv_bias"] = P("stage", "model")
         if not self.cfg.tie_embeddings:
             specs["lm_head"] = P("fsdp", "model")
+        if self.cfg.first_k_dense:
+            specs["dense_layers"] = {
+                "attn_norm": P("stage", None), **attn_specs,
+                "mlp_norm": P("stage", None),
+                "w_gate": P("stage", "fsdp", "model"),
+                "w_up": P("stage", "fsdp", "model"),
+                "w_down": P("stage", "model", "fsdp")}
         return specs
 
     # ---------------------------------------------------------------- block
@@ -768,11 +823,13 @@ class Transformer:
         [B, T, D] (post attn_norm) -> q_nope [B, T, H, nope], q_rope
         [B, T, H, rope] (rotated, and both carrying the position-dependent
         query scale), ckv [B, T, r] (normalised latent), kr [B, T, 1,
-        rope] (the one rotated key all heads share). The cached row is
-        [ckv | kr]."""
+        rope] (the one rotated key all heads share), and cq [B, T,
+        q_lora_rank] (the normalised query latent the indexer reads; None
+        without a query bottleneck). The cached row is [ckv | kr]."""
         cfg = self.cfg
         b, t, _ = hn.shape
         nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        cq = None
         if cfg.q_lora_rank:
             cq = rms_norm(self._dense(layer, "wq_a", hn), layer["q_norm"],
                           cfg.rms_norm_eps)
@@ -791,7 +848,7 @@ class Transformer:
         if q_scale is not None:
             q_scale = q_scale[..., None, None].astype(q.dtype)
             q_nope, q_rope = q_nope * q_scale, q_rope * q_scale
-        return q_nope, q_rope, ckv, kr
+        return q_nope, q_rope, ckv, kr, cq
 
     def _latent_expanded(self, layer: Params, hn: jnp.ndarray, cos, sin,
                          q_positions: jnp.ndarray):
@@ -802,7 +859,7 @@ class Transformer:
         cfg = self.cfg
         b, t, _ = hn.shape
         h_, nope = cfg.num_heads, cfg.qk_nope_head_dim
-        q_nope, q_rope, ckv, kr = self._latent_project(
+        q_nope, q_rope, ckv, kr, _ = self._latent_project(
             layer, hn, cos, sin, q_positions)
         kvb = self._dense(layer, "wkv_b", ckv).reshape(
             b, t, h_, nope + cfg.v_head_dim)
@@ -838,7 +895,7 @@ class Transformer:
         cfg = self.cfg
         b, t, _ = hn.shape
         h_, nope, r = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
-        q_nope, q_rope, ckv, kr = self._latent_project(
+        q_nope, q_rope, ckv, kr, cq = self._latent_project(
             layer, hn, cos, sin, q_positions)
         wkv_b = self._weight(layer, "wkv_b").reshape(
             r, h_, nope + cfg.v_head_dim)
@@ -846,20 +903,119 @@ class Transformer:
             jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :nope]),
             q_rope, heads=h_)                            # [B, T, H, row]
         row = self._latent_row(ckv, kr)
-        # the row is key and value at once: the weighted sum over whole
-        # rows costs half more multiplies than over their first r numbers
-        # and spares a sliced copy of the gathered window
-        u = attend(q_lat, row, row)[..., :r]
+        if cfg.index_topk:
+            # learned sparse attention: the indexer's query and key, and
+            # ``attend`` selects and reads the cached rows it scores best
+            with jax.named_scope("dsa_indexer"):
+                qi, ki, wts = self._index_project(layer, hn, cq, cos, sin)
+            u = attend(q_lat, (row, ki[:, :, None, :]), qi, wts)[..., :r]
+            rows = (row, ki[:, :, None, :])
+        else:
+            # the row is key and value at once: the weighted sum over
+            # whole rows costs half more multiplies than over their first
+            # r numbers and spares a sliced copy of the gathered window
+            u = attend(q_lat, row, row)[..., :r]
+            rows = row
         out = jnp.einsum("bthr,rhv->bthv", u, wkv_b[..., nope:])
-        return out.reshape(b, t, h_ * cfg.v_head_dim), row
+        return out.reshape(b, t, h_ * cfg.v_head_dim), rows
+
+    def _index_project(self, layer: Params, hn: jnp.ndarray, cq, cos, sin):
+        """The DSA indexer's projections (DeepSeek-V3.2's ``Indexer``):
+        qi [B, T, Hi, Di] = cq W_iq, ki [B, T, Di] = LayerNorm(hn W_ik),
+        both with their first qk_rope_head_dim dims rotated, and the head
+        weights [B, T, Hi] = hn W_iw * Hi^-0.5 * Di^-0.5 in float32 (the
+        score's scale folded in)."""
+        cfg = self.cfg
+        b, t, _ = hn.shape
+        hi, di, rd = (cfg.index_n_heads, cfg.index_head_dim,
+                      cfg.qk_rope_head_dim)
+        qi = self._dense(layer, "wi_q", cq).reshape(b, t, hi, di)
+        qi = apply_rotary(qi, cos, sin, rotary_dim=rd,
+                          interleave=cfg.index_rope_interleave)
+        ki = layer_norm(self._dense(layer, "wi_k", hn), layer["wi_k_norm"],
+                        layer["wi_k_norm_bias"], INDEX_NORM_EPS)
+        ki = apply_rotary(ki[:, :, None, :], cos, sin, rotary_dim=rd,
+                          interleave=cfg.index_rope_interleave)[:, :, 0]
+        wts = self._dense(layer, "wi_w", hn).astype(jnp.float32) * (
+            hi ** -0.5 * di ** -0.5)
+        return qi, ki, wts
+
+    def _sparse_latent_attend(self, q_lat, qi, wts, positions, pools, l,
+                              tables):
+        """Latent attention restricted to what the indexer selects, over
+        a paged pool whose rows for these queries are already written.
+        q_lat [B, T, H, W] (the absorbed query, ``_latent_absorbed``),
+        qi [B, T, Hi, Di] and wts [B, T, Hi] (``_index_project``),
+        positions [B, T] absolute; pools (latent rows [L, pages, page, 1,
+        W], index keys [L, pages, page, 1, Di]); tables [B, pages/slot].
+
+        Per query t at position p: I(t, j) = sum_h wts_h relu(qi_h . ki(j))
+        for every column j <= p of the slot's window (``dsa_indexer``:
+        the index keys of the whole window gathered, their scores, and the
+        exact top ``index_topk`` by ``lax.top_k``, a sort on XLA:TPU that
+        measured faster than a sort-free threshold search with a two-level
+        compaction: PERF.md section 6); then the softmax over the selected
+        rows alone, each gathered from its page (``sparse_mla``). A context shorter than ``index_topk`` selects
+        every column (the others score -inf and are masked). Queries are
+        taken ``DSA_QUERY_BLOCK`` at a time, so a chunk's scores and
+        gathered rows stay a block's. Returns ([B, T, H, W], the selected
+        columns int32 [B, T, k])."""
+        cfg = self.cfg
+        b, t = positions.shape
+        ps = pools[0].shape[2]
+        window = tables.shape[1] * ps
+        k = min(cfg.index_topk, window)
+        with jax.named_scope("dsa_indexer"):
+            keys = pools[1][l, tables].reshape(b, window, -1)   # [B, S, Di]
+        cols = jnp.arange(window, dtype=jnp.int32)
+
+        def block(xs):
+            q_b, qi_b, w_b, pos_b = xs                    # [B, Tb, ...]
+            with jax.named_scope("dsa_indexer"):
+                s = jnp.einsum("bthd,bsd->bths", qi_b, keys,
+                               preferred_element_type=jnp.float32)
+                score = jnp.einsum("bths,bth->bts", jax.nn.relu(s), w_b)
+                score = jnp.where(cols <= pos_b[..., None], score, -jnp.inf)
+                top, idx = jax.lax.top_k(score, k)        # [B, Tb, k]
+            with jax.named_scope("sparse_mla"):
+                pages = jnp.take_along_axis(
+                    tables, (idx // ps).reshape(b, -1), axis=1
+                ).reshape(idx.shape)
+                rows = pools[0][l, pages, idx % ps][..., 0, :]  # [B,Tb,k,W]
+                logits = jnp.einsum("bthw,btkw->bthk", q_b, rows,
+                                    preferred_element_type=jnp.float32)
+                logits = jnp.where(jnp.isfinite(top)[:, :, None, :],
+                                   logits * self._softmax_scale, -jnp.inf)
+                probs = jax.nn.softmax(logits, axis=-1).astype(rows.dtype)
+                return jnp.einsum("bthk,btkw->bthw", probs, rows), idx
+
+        tb = min(t, DSA_QUERY_BLOCK)
+        if t == tb or t % tb:
+            return block((q_lat, qi, wts, positions))
+        n = t // tb
+
+        def split(x):
+            return jnp.moveaxis(x.reshape(b, n, tb, *x.shape[2:]), 1, 0)
+
+        def join(x):
+            return jnp.moveaxis(x, 0, 1).reshape(b, t, *x.shape[3:])
+
+        out, idx = jax.lax.map(block, tuple(
+            split(x) for x in (q_lat, qi, wts, positions)))
+        return join(out), join(idx)
 
     def cache_rows(self) -> Tuple[Tuple[int, int], ...]:
         """What one token stores per layer, as (heads, width) per pool:
         keys and values of [KH, D] for dense attention, one lane-padded
-        [1, r + rope] latent row for latent attention. A paged pool
-        allocates one [L, pages, page_size, heads, width] array per
-        entry; the paged steps take and return tuples in this order."""
+        [1, r + rope] latent row for latent attention, and beside it the
+        [1, index_head_dim] index key under sparse attention (a pool of
+        its own: the indexer reads every live key and no latent row). A
+        paged pool allocates one [L, pages, page_size, heads, width]
+        array per entry; the paged steps take and return tuples in this
+        order."""
         cfg = self.cfg
+        if cfg.index_topk:
+            return ((1, cfg.latent_row_width_), (1, cfg.index_head_dim))
         if cfg.latent_attention:
             return ((1, cfg.latent_row_width_),)
         return ((cfg.num_kv_heads, cfg.head_dim_),) * 2
@@ -968,13 +1124,18 @@ class Transformer:
             kw = dict(k=cfg.num_experts_per_token, valid=token_valid,
                       first=cfg.moe_first_expert,
                       routed_scale=cfg.moe_routed_scale)
+            if cfg.moe_scoring != "softmax":
+                kw.update(scoring=cfg.moe_scoring, bias=layer["router_bias"])
             if dropless:
                 # a scan that keeps the experts' weights stacked hands
-                # them over whole, with its block index (_paged_layers)
+                # them over whole, with its block index (_paged_layers;
+                # behind leading dense layers, the index among the
+                # expert layers)
                 stack = layer.get("expert_stack", layer)
                 out, aux = moe_mlp_dropless(
                     h, layer["router"], stack["w_gate"], stack["w_up"],
-                    stack["w_down"], layer=layer.get("layer_index"), **kw)
+                    stack["w_down"], layer=layer.get(
+                        "expert_index", layer.get("layer_index")), **kw)
             else:
                 out, aux = moe_mlp(
                     h, layer["router"], layer["w_gate"], layer["w_up"],
@@ -1334,6 +1495,11 @@ class Transformer:
         """
         cfg = self.cfg
         b, t = input_ids.shape
+        if cfg.index_topk or cfg.first_k_dense:
+            raise NotImplementedError(
+                "learned sparse attention (index_topk) and leading dense "
+                "layers (first_k_dense) run in the paged serving steps "
+                "only: no full-sequence forward, so no training")
         if self.hybrid is not None:
             return self._hybrid_hidden_states(
                 params, input_ids, attention_mask, segment_ids, positions,
@@ -1996,7 +2162,8 @@ class Transformer:
                 attn, row = self._latent_absorbed(
                     layer, hn, cos, sin, q_positions, attend)
                 attn_out = proj("wo", attn)
-            rows = (row,)
+            # (latent rows, index keys) under sparse attention
+            rows = row if cfg.index_topk else (row,)
         else:
             q = proj("wq", hn).reshape(b, t, cfg.num_heads, dh)
             k = proj("wk", hn).reshape(b, t, cfg.num_kv_heads, dh)
@@ -2251,10 +2418,15 @@ class Transformer:
         three times a layer (PERF.md, PR 29). The kernel takes the whole
         pools and the layer index for the same reason.
 
+        Under learned sparse attention (``index_topk``) every layer writes
+        its fresh rows first and ``_sparse_latent_attend`` reads the
+        columns its indexer selects, through ``block_tables``.
+
         Returns (hidden after the final norm [B, T, D], the pools with
         the fresh rows written, and int32 [2] = (held experts that
         received a token, (token, choice) pairs that landed here) summed
-        over layers)."""
+        over layers); under sparse attention a fourth entry, the columns
+        each query attended, int32 [L, B, T, index_topk]."""
         cfg = self.cfg
         if self._kv_int8:
             raise NotImplementedError(
@@ -2277,22 +2449,31 @@ class Transformer:
                                  scaling=cfg.rope_scaling)
         tables = view["block_tables"]                    # [B, pages/slot]
         b, window = view["valid"].shape
+        sparse = bool(cfg.index_topk)
 
-        layers = dict(self._with_layer_windows(
-            self._flat_layers(params["layers"])))
-        layers["layer_index"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-        stack = None
-        if "router" in layers:
-            # the routed experts' weights stay stacked outside the scan
-            # (ops.moe.moe_mlp_dropless, ``layer``): the block index
-            # stands in for them
-            stack = {k: layers.pop(k) for k in ("w_gate", "w_up", "w_down")}
-
-        def body(carry, layer):
+        def body(carry, layer, stack):
             h, pools = carry                  # pool [L, pages, page, h, w]
             l = layer["layer_index"]
             if stack is not None:
                 layer = {**layer, "expert_stack": stack}
+            if sparse:
+                # the fresh rows go in first: the indexer scores a
+                # query's own column beside the cached ones
+                written = {}
+
+                def attend(q_lat, fresh, qi, wts):
+                    written["pools"] = tuple(
+                        p.at[l, view["write_pages"], view["write_offs"]
+                             ].set(r) for p, r in zip(pools, fresh))
+                    out, written["sel"] = self._sparse_latent_attend(
+                        q_lat, qi, wts, positions, written["pools"], l,
+                        tables)
+                    return out
+
+                h, (_, routed) = self._decode_layer(
+                    layer, h, cos, sin, attend, q_positions=positions,
+                    token_valid=view.get("real"))
+                return (h, written["pools"]), (routed, written["sel"])
             if kernel is None:
                 cached = [p[l, tables].reshape(b, window, *p.shape[3:])
                           for p in pools]
@@ -2323,10 +2504,40 @@ class Transformer:
                 for p, r in zip(pools, rows))
             return (h, pools), routed
 
-        (x, pools), routed = jax.lax.scan(
-            body, (x, tuple(view["pools"])),
-            {**layers, **self.slot_lora_xs(adapters)})
-        return self._final_norm(params, x), pools, jnp.sum(routed, axis=0)
+        # one scan a run of layers of one kind: the leading dense layers
+        # (params["dense_layers"]), then the rest; a layer's pools are
+        # its absolute index's
+        runs = [(params["layers"], cfg.first_k_dense)]
+        if cfg.first_k_dense:
+            runs.insert(0, (params["dense_layers"], 0))
+        carry, routed = (x, tuple(view["pools"])), []
+        for stacked, first in runs:
+            layers = dict(self._with_layer_windows(
+                self._flat_layers(stacked)))
+            n = next(iter(layers.values())).shape[0]
+            layers["layer_index"] = jnp.arange(first, first + n,
+                                               dtype=jnp.int32)
+            stack = None
+            if "router" in layers:
+                # the routed experts' weights stay stacked outside the
+                # scan (ops.moe.moe_mlp_dropless, ``layer``): the block
+                # index stands in for them
+                stack = {k: layers.pop(k)
+                         for k in ("w_gate", "w_up", "w_down")}
+                if first:
+                    layers["expert_index"] = jnp.arange(n, dtype=jnp.int32)
+            carry, r = jax.lax.scan(
+                functools.partial(body, stack=stack), carry,
+                {**layers, **self.slot_lora_xs(adapters)})
+            routed.append(r)
+        x, pools = carry
+        h = self._final_norm(params, x)
+        sel = ()
+        if sparse:
+            routed, sel = zip(*routed)
+            sel = (jnp.concatenate(sel, axis=0),)
+        return (h, pools, functools.reduce(
+            jnp.add, [jnp.sum(r, axis=0) for r in routed])) + sel
 
     def decode_step_paged(self, params: Params, view: Params,
                           tokens: jnp.ndarray,  # [B] the tokens just sampled
@@ -2356,15 +2567,17 @@ class Transformer:
                         a chunk's real token): routed experts skip the others
 
         Returns (logits [B, V], the pools with this step's rows written,
-        and the step's int32 [2] expert counters, see ``_paged_layers``).
+        and the step's int32 [2] expert counters, see ``_paged_layers``;
+        under sparse attention also the columns each query read, [L, B,
+        1, index_topk]).
         Rows whose window is garbage (freed serving slots) compute garbage
         that the caller masks — static shapes, no recompilation as
         requests come and go. int8 KV paging is not plumbed yet: serving
         pages store the activation dtype."""
-        h, pools, routed = self._paged_layers(
+        out = self._paged_layers(
             params, view, self._embed(params, tokens[:, None]),
             view["lengths"][:, None], adapters, decode_attention)
-        return self.unembed(params, h[:, 0]), pools, routed
+        return (self.unembed(params, out[0][:, 0]),) + out[1:]
 
     def prefill_step_paged(self, params: Params, view: Params,
                            tokens: jnp.ndarray,     # [B, C] chunk tokens
@@ -2385,7 +2598,7 @@ class Transformer:
         the caller's ``write_pages``), and the expert counters)."""
         h, pools, routed = self._paged_layers(
             params, view, self._embed(params, tokens), positions, adapters,
-            block_decode_attention)                         # [B, C, H]
+            block_decode_attention)[:3]                     # [B, C, H]
         last = h[jnp.arange(tokens.shape[0]), last_index]   # [B, H]
         return self.unembed(params, last), pools, routed
 
@@ -2407,7 +2620,7 @@ class Transformer:
             jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]  # [B, G]
         h, pools, routed = self._paged_layers(
             params, view, self._embed(params, tokens), positions, adapters,
-            block_decode_attention)
+            block_decode_attention)[:3]
         return self.unembed(params, h), pools, routed               # [B,G,V]
 
     def start_decode(self, params: Params, input_ids: jnp.ndarray,

@@ -92,6 +92,8 @@ def moe_mlp(
     group_size: int = 512,
     first: int = 0,
     routed_scale: float = 1.0,
+    scoring: str = "softmax",
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, MoEAux]:
     """Routed gated-SiLU MLP with GShard capacity dispatch (the training
     path). Returns ([B, T, D] output, aux losses). The weights may hold a
@@ -104,22 +106,35 @@ def moe_mlp(
     v2 = None if valid is None else valid.reshape(rows, g)
     out, aux = _moe_rows(h2, router_w, w_gate, w_up, w_down, k=k,
                          capacity_factor=capacity_factor, valid=v2,
-                         first=first, routed_scale=routed_scale)
+                         first=first, routed_scale=routed_scale,
+                         scoring=scoring, bias=bias)
     return out.reshape(b, t, d), aux
 
 
 def route_top_k(h: jnp.ndarray, router_w: jnp.ndarray, k: int,
-                routed_scale: float = 1.0):
+                routed_scale: float = 1.0, scoring: str = "softmax",
+                bias: Optional[jnp.ndarray] = None):
     """Router logits [..., E] in fp32 (fp32 accumulation of the
-    activation-dtype product), the k chosen experts and their weights:
-    softmax over the chosen logits, which IS the softmax over all E
-    renormalised over the chosen (Mixtral's and mistral4's
-    ``norm_topk_prob`` convention), times ``routed_scale``."""
+    activation-dtype product), the k chosen experts and their weights,
+    times ``routed_scale``. ``scoring`` "softmax": the softmax over the
+    chosen logits, which IS the softmax over all E renormalised over the
+    chosen (Mixtral's and mistral4's ``norm_topk_prob`` convention).
+    "sigmoid" (DeepSeek-V3's ``noaux_tc``, GLM-5's router): s =
+    sigmoid(logits), the k largest of s + ``bias`` (the per-expert
+    ``e_score_correction_bias`` [E]) chosen, their s without the bias
+    renormalised to sum 1."""
     with jax.named_scope("moe_router"):
         logits = jnp.einsum("...d,de->...e", h, router_w.astype(h.dtype),
                             preferred_element_type=jnp.float32)
-        top_l, top_e = jax.lax.top_k(logits, min(k, router_w.shape[1]))
-        top_w = jax.nn.softmax(top_l, axis=-1)
+        kk = min(k, router_w.shape[1])
+        if scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, top_e = jax.lax.top_k(scores + bias.astype(jnp.float32), kk)
+            top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        else:
+            top_l, top_e = jax.lax.top_k(logits, kk)
+            top_w = jax.nn.softmax(top_l, axis=-1)
         if routed_scale != 1.0:
             top_w = top_w * routed_scale
     return logits, top_e, top_w
@@ -137,6 +152,8 @@ def moe_mlp_dropless(
     routed_scale: float = 1.0,
     valid: Optional[jnp.ndarray] = None,   # [B, T] 1 = real token
     layer: Optional[jnp.ndarray] = None,   # traced index into stacked w_*
+    scoring: str = "softmax",
+    bias: Optional[jnp.ndarray] = None,    # [E] sigmoid scoring's
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Routed gated-SiLU MLP with no capacity: every (token, choice) that
     lands on a held expert is computed, whatever the imbalance — the
@@ -163,7 +180,8 @@ def moe_mlp_dropless(
     b, t, d = h.shape
     e_held = w_gate.shape[-3]
     x = h.reshape(b * t, d)
-    _, top_e, top_w = route_top_k(x, router_w, k, routed_scale)
+    _, top_e, top_w = route_top_k(x, router_w, k, routed_scale, scoring,
+                                  bias)
     kk = top_e.shape[-1]
     with jax.named_scope("moe_experts"):
         local = top_e - first
@@ -202,7 +220,8 @@ def moe_mlp_dropless(
 
 
 def _moe_rows(h, router_w, w_gate, w_up, w_down, *, k, capacity_factor,
-              valid, first=0, routed_scale=1.0):
+              valid, first=0, routed_scale=1.0, scoring="softmax",
+              bias=None):
     rows, g, d = h.shape
     e_all = router_w.shape[1]            # experts the router scores
     e = w_gate.shape[0]                  # experts held here (usually all)
@@ -211,7 +230,8 @@ def _moe_rows(h, router_w, w_gate, w_up, w_down, *, k, capacity_factor,
     v = (jnp.ones((rows, g), jnp.float32) if valid is None
          else valid.astype(jnp.float32))
 
-    logits, top_e, top_w = route_top_k(h, router_w, k, routed_scale)
+    logits, top_e, top_w = route_top_k(h, router_w, k, routed_scale,
+                                       scoring, bias)
     probs = jax.nn.softmax(logits, axis=-1)                # [R, G, E]
 
     # slot assignment: position of this token among all (token, choice)

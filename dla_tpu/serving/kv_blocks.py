@@ -271,12 +271,18 @@ class PrefixCache:
 
     Two granularities:
 
-    * **Full pages** — ``_index`` maps the exact token tuple of a
-      page-aligned prefix to the physical page holding its KV. Keys are
-      the tokens themselves (no hashing), so a hit is a guarantee, never
-      a collision. A lookup walks prefixes page by page and stops at the
-      first miss, so an interior eviction simply shortens later hits
-      (orphaned longer entries age out via the allocator's LRU).
+    * **Full pages** — ``_index`` is a trie of pages: the key of a
+      page-aligned prefix's last page is (the node of the prefix before
+      it, that page's own tokens), and it maps to (this prefix's node,
+      the physical page holding its KV). Keys are the tokens themselves
+      (no hashing), and a node id is never reused, so a hit is a
+      guarantee, never a collision; a prompt of n tokens costs O(n) to
+      walk or register (keys holding the whole prefix cost O(n^2 / page):
+      a second a 40k-token prompt on the host, PERF.md section 6). A lookup
+      walks page by page and stops at the first miss, so an interior
+      eviction simply shortens later hits (orphaned deeper entries hang
+      off a node no walk reaches again and age out via the allocator's
+      LRU).
     * **Exact full prompts** — ``_full`` additionally remembers the
       partial tail page and the last-token prefill LOGITS for recently
       completed prompts (LRU-capped), so an identical prompt skips
@@ -294,9 +300,13 @@ class PrefixCache:
         self.allocator = allocator
         self.page_size = page_size
         self.logits_capacity = max(1, int(logits_capacity))
-        self._index: Dict[Tuple[int, ...], int] = {}
+        # (parent node, page tokens) -> (node, page); a namespace's root
+        # node is the parent of its prefixes' first pages
+        self._index: Dict[Tuple[int, Tuple[int, ...]], Tuple[int, int]] = {}
+        self._roots: Dict[Optional[str], int] = {}
+        self._nodes = 0
         # page -> ("page" | "tail", key): which entry retains this page
-        self._page_key: Dict[int, Tuple[str, Tuple[int, ...]]] = {}
+        self._page_key: Dict[int, Tuple[str, tuple]] = {}
         self._full: "OrderedDict[Tuple[int, ...], _FullEntry]" = \
             OrderedDict()
         self.lookups = 0
@@ -318,7 +328,7 @@ class PrefixCache:
         self.evictions += 1
         kind, key = self._page_key.pop(page)
         if kind == "page":
-            if self._index.get(key) == page:
+            if self._index.get(key, (0, None))[1] == page:
                 del self._index[key]
         else:
             self._full.pop(key, None)
@@ -329,6 +339,24 @@ class PrefixCache:
         """True when the cache indexes ``page``'s content — writing to
         it would corrupt future hits, so writers must copy first."""
         return page in self._page_key
+
+    def _new_node(self) -> int:
+        self._nodes += 1
+        return self._nodes
+
+    def _pages_of(self, key: Tuple[int, ...], count: int,
+                  namespace: Optional[str] = None) -> List[int]:
+        """The cached pages of ``key``'s first ``count`` whole pages, from
+        the first on, up to the first that is not cached."""
+        ps, pages = self.page_size, []
+        node = self._roots.get(namespace)
+        for k in range(count if node is not None else 0):
+            hit = self._index.get((node, key[k * ps:(k + 1) * ps]))
+            if hit is None:
+                break
+            node, page = hit
+            pages.append(page)
+        return pages
 
     @staticmethod
     def _nskey(namespace: Optional[str], sub: Tuple[int, ...]):
@@ -398,14 +426,7 @@ class PrefixCache:
         # the hit stays < n; chunk alignment keeps the restart boundary
         # on the fixed absolute schedule
         max_hit = ((n - 1) // chunk) * chunk if chunk > 0 else 0
-        pages: List[int] = []
-        k = 1
-        while k * ps <= max_hit:
-            p = self._index.get(self._nskey(namespace, key[:k * ps]))
-            if p is None:
-                break
-            pages.append(p)
-            k += 1
+        pages = self._pages_of(key, max_hit // ps, namespace)
         hit = (len(pages) * ps // chunk) * chunk if chunk > 0 else 0
         return pages[:hit // ps], hit, None
 
@@ -415,12 +436,9 @@ class PrefixCache:
         """All physical pages of an exact-prompt entry, or None when an
         interior page was evicted (fall back to the chunked walk)."""
         n, ps = len(key), self.page_size
-        pages: List[int] = []
-        for k in range(1, n // ps + 1):
-            p = self._index.get(self._nskey(namespace, key[:k * ps]))
-            if p is None:
-                return None
-            pages.append(p)
+        pages = self._pages_of(key, n // ps, namespace)
+        if len(pages) < n // ps:
+            return None
         if n % ps:
             if entry.tail_page is None:
                 return None
@@ -445,15 +463,11 @@ class PrefixCache:
         self.lookups += 1
         if n == 0 or n % ps:
             return None
-        pages: List[int] = []
-        for k in range(1, n // ps + 1):
-            p = self._index.get(self._nskey(namespace, key[:k * ps]))
-            if p is None:
-                for q in pages:
-                    self.allocator.decref(q)
-                return None
+        pages = self._pages_of(key, n // ps, namespace)
+        if len(pages) < n // ps:
+            return None
+        for p in pages:
             self.allocator.incref(p)
-            pages.append(p)
         self.hit_tokens += n
         return pages
 
@@ -469,13 +483,18 @@ class PrefixCache:
         The trash page is never indexed."""
         key = tuple(tokens)
         n, ps = len(key), self.page_size
-        for k in range(1, n // ps + 1):
-            sub = self._nskey(namespace, key[:k * ps])
-            page = pages[k - 1]
-            if sub in self._index or page == 0:
-                continue
-            self._index[sub] = page
-            self._page_key[page] = ("page", sub)
+        if namespace not in self._roots:
+            self._roots[namespace] = self._new_node()
+        node = self._roots[namespace]
+        for k in range(n // ps):
+            sub = (node, key[k * ps:(k + 1) * ps])
+            hit = self._index.get(sub)
+            if hit is None:
+                if pages[k] == 0:
+                    break      # nothing past a hole can be reached
+                hit = self._index[sub] = (self._new_node(), pages[k])
+                self._page_key[pages[k]] = ("page", sub)
+            node = hit[0]
         nkey = self._nskey(namespace, key)
         if logits is None or nkey in self._full:
             return

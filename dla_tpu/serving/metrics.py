@@ -86,6 +86,14 @@ class ServingMetrics:
         self.window_pages_released = r.counter(
             "serving/window_pages_released")
         self.tokens_generated = r.counter("serving/tokens_generated")
+        # prompt tokens of the submitted requests: what the prefix cache's
+        # hit tokens are a share of
+        self.prompt_tokens = r.counter("serving/prompt_tokens")
+        # learned sparse attention, per query (decode steps and chunks),
+        # not multiplied by layers: the cached columns its indexer scored,
+        # and the latent rows it read of them (0 without an indexer)
+        self.dsa_live_tokens = r.counter("serving/dsa/live_tokens")
+        self.dsa_selected_tokens = r.counter("serving/dsa/selected_tokens")
         self.prefix_lookups = r.counter("serving/prefix_cache/lookups")
         self.prefix_hit_tokens = r.counter(
             "serving/prefix_cache/hit_tokens")
@@ -175,6 +183,10 @@ class ServingMetrics:
             "serving/window_pages_released": float(
                 self.window_pages_released.value),
             "serving/tokens_generated": float(self.tokens_generated.value),
+            "serving/prompt_tokens": float(self.prompt_tokens.value),
+            "serving/dsa/live_tokens": float(self.dsa_live_tokens.value),
+            "serving/dsa/selected_tokens": float(
+                self.dsa_selected_tokens.value),
             "serving/prefix_cache/lookups": float(
                 self.prefix_lookups.value),
             "serving/prefix_cache/hit_tokens": float(

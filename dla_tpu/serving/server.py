@@ -269,6 +269,10 @@ class ServingEngine:
         # kernel; asked of the model at the first decode span
         self._kernel_decode: Optional[bool] = None
         self._kernel_scan: Optional[bool] = None
+        # learned sparse attention: the columns a query reads are at most
+        # index_topk of those its indexer scores (0: no indexer)
+        self._dsa_topk = int(model.cfg.index_topk)
+        self.dsa_last_selection = None
         if self._stateful:
             for on, what in (
                     (cfg.prefix_cache, "ServingConfig.prefix_cache (a "
@@ -691,7 +695,9 @@ class ServingEngine:
                 "write_offs": jnp.where(active, lengths % ps, 0)[:, None]}
         if "window_tables" in f:
             view["window_tables"] = f["window_tables"]
-        logits, pools, routed = self.model.decode_step_paged(
+        # sparse attention also hands back the columns each slot's query
+        # read, [L, B, 1, k]: kept on the device, unfetched
+        logits, pools, routed, *selected = self.model.decode_step_paged(
             params, view, f["tokens"], adapters=adapters)
         # a free slot keeps its last request's temperature: zeroed, so
         # only running rows decide whether the step filters and draws
@@ -704,7 +710,7 @@ class ServingEngine:
             [new_tok, jax.lax.bitcast_convert_type(logp, jnp.int32),
              jnp.broadcast_to(routed[0], (b,)),
              jnp.broadcast_to(routed[1], (b,))])
-        return pools, packed
+        return (pools, packed, *selected)
 
     def _spec_draft_fn(self, draft_params, pools, packed, adapters=None):
         """The speculative DRAFT phase: K sequential fixed-shape decode
@@ -744,8 +750,8 @@ class ServingEngine:
                     "valid": valid_c, "pos": f["pos"], "lengths": lens_i,
                     "write_pages": jnp.where(in_win, page_ids, 0)[:, None],
                     "write_offs": jnp.where(in_win, col % ps, 0)[:, None]}
-            logits, pools_c, _ = self.model.decode_step_paged(
-                draft_params, view, cur, adapters=adapters)
+            logits, pools_c = self.model.decode_step_paged(
+                draft_params, view, cur, adapters=adapters)[:2]
             nxt, _ = sample_token_per_row(
                 f["seed"], f["gen_pos"] + i, logits, temps, f["top_p"],
                 f["top_k"])
@@ -1749,6 +1755,9 @@ class ServingEngine:
                         "request", "admitted", req.rid, t=t,
                         queue_wait_ms=(t - req.arrival_time) * 1000.0)
             n = len(req.prefix_tokens)
+            # both at the prefix cache's lookup: the hit tokens a share
+            # of the tokens looked up, in any window of steps
+            self.metrics.prompt_tokens.inc(n)
             self.metrics.prefill_tokens_saved.inc(req.prefill_pos)
             if req.prefill_pos >= n:
                 # full hit: every prompt page aliased, first-token
@@ -1819,6 +1828,8 @@ class ServingEngine:
                 -(-start // cols) * cols * layers)
             self.metrics.prefill_attn_window_tokens.inc(
                 c.geom.slot_window * layers)
+        if self._dsa_topk:
+            self._count_dsa(start + 1 + np.arange(nvalid))
         c.mark_computed(slot, start, nvalid)
         req.prefill_pos = start + nvalid
         if req.prefill_pos < n:
@@ -1850,6 +1861,14 @@ class ServingEngine:
             self._bind_slot_sampling(req)
             self._emit(req, tok, t, emitted, first_of_prefill=True,
                        logp=logp)
+
+    def _count_dsa(self, columns: np.ndarray) -> None:
+        """Sparse attention's work, per query summed over the queries of a
+        dispatch (not multiplied by layers): ``columns`` each query's
+        indexer scores, of which it reads min(index_topk, columns)."""
+        self.metrics.dsa_live_tokens.inc(int(columns.sum()))
+        self.metrics.dsa_selected_tokens.inc(
+            int(np.minimum(columns, self._dsa_topk).sum()))
 
     def _mirror_cache_counters(self) -> None:
         """Mirror the PrefixCache's plain-int counters into the metrics
@@ -1917,18 +1936,26 @@ class ServingEngine:
         # dla: disable=host-sync-in-hot-loop -- host numpy mirror of the slot lengths, no device fetch
         lengths = self.cache.lengths[active_slots]
         whole = geom.num_slots * geom.slot_window
+        selected = 0
         if self._kernel_decode:
             # the one-token forwards (the step, or a round's K drafts)
             # read live pages; a round's verify forward gathers
             live_pages = int((-(-lengths // geom.page_size)).sum())
             read_tokens = max(self._spec_k, 1) * live_pages \
                 * geom.page_size + (whole if self._spec_k else 0)
+        elif self._dsa_topk and not self._spec_k:
+            # sparse attention reads the latent rows its indexer selects
+            # (the index keys of every window it scores are the
+            # indexer's, not counted here)
+            selected = int(np.minimum(lengths + 1, self._dsa_topk).sum())
+            read_tokens = selected
         else:
             read_tokens = (self._spec_k + 1) * whole
         return annotate(
             "serve_decode", slots=len(active_slots),
             live_tokens=int(lengths.sum()),
             read_tokens=read_tokens,
+            selected_tokens=selected,
             sampling_slots=sampling_slots,
             # what the step touches beside the paged rows, constants of
             # the geometry (0 for a model with neither): the slots whose
@@ -1987,7 +2014,14 @@ class ServingEngine:
                 raise DeviceStepError(
                     "injected device error (fault plan engine_step)")
             with annotate("serve_decode_dispatch"):
-                c.pools, packed = self._decode(self.params, c.pools, *args)
+                out = self._decode(self.params, c.pools, *args)
+                c.pools, packed = out[:2]
+                if self._dsa_topk:
+                    # (the slots that ran, their columns before the step,
+                    # the columns each query read) of the newest step
+                    self.dsa_last_selection = (
+                        list(active_slots),
+                        c.lengths[active_slots].copy(), out[2])
             with annotate("serve_decode_fetch"):
                 # dla: disable=host-sync-in-hot-loop -- the designed single D2H per decode step (execution-model invariant)
                 packed_np = np.asarray(packed)
@@ -2014,6 +2048,8 @@ class ServingEngine:
                 self.metrics.decode_steps_sampled.inc()
             if self._kernel_decode:
                 self.metrics.decode_steps_paged_kernel.inc()
+            if self._dsa_topk:
+                self._count_dsa(c.lengths[active_slots] + 1)
             emitted: List[Tuple[int, int]] = []
             with annotate("serve_emit", slots=len(active_slots)):
                 for slot in active_slots:
@@ -2074,6 +2110,8 @@ class ServingEngine:
                 self.metrics.decode_steps_sampled.inc()
             if self._kernel_decode:
                 self.metrics.decode_steps_paged_kernel.inc()
+            if self._dsa_topk:
+                self._count_dsa(c.lengths[active_slots] + 1)
             emitted: List[Tuple[int, int]] = []
             with annotate("serve_emit", slots=len(active_slots)):
                 for slot in active_slots:

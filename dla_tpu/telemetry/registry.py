@@ -280,6 +280,17 @@ CATALOG: Tuple[MetricSpec, ...] = (
        "inter-token latency between consecutive decodes", "step"),
     _s("serving/queue_wait_ms", "histogram", "ms",
        "arrival -> first prefill admission", "step"),
+    _s("serving/prompt_tokens", "counter", "tokens",
+       "prompt tokens of the admitted requests, a re-admitted request's "
+       "whole prefix (what the prefix cache's hit tokens are a share of)",
+       "step"),
+    _s("serving/dsa/live_tokens", "counter", "tokens",
+       "cached columns the sparse-attention indexer scored, per query "
+       "of the decode steps and prefill chunks, not times layers (0 "
+       "without an indexer)", "step"),
+    _s("serving/dsa/selected_tokens", "counter", "tokens",
+       "latent rows sparse attention read of them: at most index_topk "
+       "a query", "step"),
     _s("serving/prefix_cache/lookups", "counter", "lookups",
        "prefix-cache probes at admission", "step"),
     _s("serving/prefix_cache/hit_tokens", "counter", "tokens",

@@ -77,9 +77,12 @@ def _arch_overrides(model_cfg: Dict[str, Any]) -> Dict[str, Any]:
                 "moe_capacity_factor", "moe_group_size", "moe_aux_weight",
                 "moe_z_weight", "moe_intermediate_size",
                 "num_shared_experts", "moe_routed_scale",
-                "moe_first_expert", "moe_experts_held",
+                "moe_first_expert", "moe_experts_held", "moe_scoring",
+                "first_k_dense",
                 "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                "qk_rope_head_dim", "v_head_dim", "rope_interleave"):
+                "qk_rope_head_dim", "v_head_dim", "rope_interleave",
+                "index_topk", "index_n_heads", "index_head_dim",
+                "index_rope_interleave"):
         if key in model_cfg:
             out[key] = model_cfg[key]
     # reference model.lora block (config/distill_config.yaml:10-14; dead

@@ -157,10 +157,12 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "serve_first_token": ("engine host loop", ("rid",)),
     # ``state_slots`` / ``window_read_tokens``: the slots whose recurrent
     # state the step reads and writes, and the columns one window layer's
-    # gather reads (0 for a model with neither)
+    # gather reads (0 for a model with neither); ``selected_tokens``: the
+    # latent rows sparse attention reads, at most index_topk a slot (0
+    # without an indexer)
     "serve_decode": ("KV pool", ("slots", "live_tokens", "read_tokens",
-                                 "sampling_slots", "state_slots",
-                                 "window_read_tokens")),
+                                 "selected_tokens", "sampling_slots",
+                                 "state_slots", "window_read_tokens")),
     # ``puts`` / ``h2d_bytes``: what the dispatch sent to the device
     "serve_decode_args": ("engine host loop", ("puts", "h2d_bytes")),
     "serve_decode_dispatch": ("engine host loop", ()),
@@ -217,7 +219,8 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
 DEVICE_SCOPES: Dict[str, str] = {
     "embed": "model step", "optimizer": "trainer",
     "step_metrics": "trainer", "head_loss": "model step",
-    "mla_attention": "model step", "moe_router": "experts",
+    "mla_attention": "model step", "dsa_indexer": "model step",
+    "sparse_mla": "model step", "moe_router": "experts",
     "moe_experts": "experts", "moe_shared": "experts",
     "ssm_mixer": "model step", "ssm_scan": "model step",
     "gmu": "model step",
